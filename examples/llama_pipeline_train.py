@@ -1,6 +1,7 @@
 """Compiled pipeline-parallel Llama training on a dp x pipe x tensor mesh.
 
-Run on any host (virtual CPU devices stand in for chips):
+Needs eight devices. Without eight chips, let virtual CPU devices stand in —
+the platform is the caller's choice, never the script's:
     XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
         python examples/llama_pipeline_train.py
 """
@@ -9,14 +10,8 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import jax
 import numpy as np
-
-jax.config.update("jax_platforms", "cpu")
-
 from jax.sharding import Mesh
 
 import paddle_tpu as paddle
@@ -31,6 +26,10 @@ def main():
     model = LlamaForCausalLM(cfg)
     opt = AdamW(learning_rate=1e-3, parameters=model.parameters())
 
+    if jax.device_count() < 8:
+        sys.exit(f"this example needs 8 devices, JAX found "
+                 f"{jax.device_count()} ({jax.default_backend()}); see the "
+                 f"module docstring for the virtual-CPU-device invocation")
     devs = np.array(jax.devices()[:8]).reshape(2, 2, 2)
     mesh = Mesh(devs, ("data", "pipe", "tensor"))
     eng = llama_pipeline_engine(model, optimizer=opt, mesh=mesh, num_micro=2)

@@ -1,13 +1,12 @@
 """Continuous-batching generation serving.
 
-    JAX_PLATFORMS=cpu python examples/llama_serving.py
+    python examples/llama_serving.py                    # the default device
+    JAX_PLATFORMS=cpu python examples/llama_serving.py  # explicitly on CPU
 """
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np
 

@@ -137,14 +137,11 @@ def _reduce_fn(op):
 def _run_on_axis(x, axis: str, per_shard_fn, out_specs_fn=None):
     """Execute per-shard collective body via shard_map over `axis` of the
     global mesh; x must be sharded over that axis (or replicated)."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
     mesh = _global_mesh
     in_spec = _infer_spec(x, axis)
     out_spec = out_specs_fn(in_spec) if out_specs_fn else in_spec
-    fn = shard_map(per_shard_fn, mesh=mesh, in_specs=(in_spec,), out_specs=out_spec,
-                   check_rep=False)
+    fn = jax.shard_map(per_shard_fn, mesh=mesh, in_specs=(in_spec,),
+                       out_specs=out_spec, check_vma=False)
     return fn(x)
 
 

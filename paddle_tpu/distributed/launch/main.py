@@ -6,7 +6,9 @@ Usage parity:
         [--nproc_per_node M] [--log_dir d] [--max_restart K] train.py args...
 
 TPU semantics: one process drives all local chips, so nproc_per_node defaults
-to 1 (the reference defaults to #GPUs). Multi-node: rendezvous over the KV
+to 1 (the reference defaults to #GPUs) and more than one is refused on a TPU
+host: every rank would claim every chip (no per-rank chip assignment exists),
+and a chip belongs to one process at a time. Multi-node: rendezvous over the KV
 master, then each process gets PADDLE_TRAINER_ID/PADDLE_TRAINER_ENDPOINTS env
 (same contract as collective.py:75-78) and jax.distributed.initialize is
 driven from them by init_parallel_env.
@@ -14,6 +16,7 @@ driven from them by init_parallel_env.
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import socket
 import subprocess
@@ -37,6 +40,17 @@ def _local_ip() -> str:
             return s.getsockname()[0]
     except OSError:
         return "127.0.0.1"
+
+
+def _ranks_would_use_tpu() -> bool:
+    """True when launched ranks would initialise a TPU backend: the host
+    exposes TPU device nodes and ``JAX_PLATFORMS`` does not pin another
+    platform. Decided without importing jax — the launcher itself must
+    never hold the chip its ranks need."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return False
+    return bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
 
 
 def parse_args(argv=None):
@@ -191,6 +205,14 @@ def build_pod(args, node_rank: int, endpoints: List[str]) -> Pod:
 def launch(argv=None) -> int:
     args = parse_args(argv)
     nnodes = int(str(args.nnodes).split(":")[0])
+    if args.nproc_per_node > 1 and _ranks_would_use_tpu():
+        print(f"[launch] refusing --nproc_per_node {args.nproc_per_node} on "
+              f"a TPU host: each rank would claim every local chip, and a "
+              f"chip belongs to one process at a time (the second rank fails "
+              f"or hangs). One process drives all local chips — use "
+              f"--nproc_per_node 1, or pin the ranks elsewhere with "
+              f"JAX_PLATFORMS=cpu.", file=sys.stderr)
+        return 2
 
     if nnodes <= 1 and args.master is None:
         endpoints = [f"127.0.0.1:{_free_port()}"]

@@ -187,8 +187,8 @@ def export_quantized_model(layer, example_inputs: Sequence[Any], path: str,
     params are per-output-channel INT8 weights, and the traced StableHLO
     program dequantizes in-graph — int8 weights live in HBM (half the
     artifact/transfer of bf16, quarter of fp32) and XLA fuses the dequant
-    into the consuming matmul (the weight-only int8 serving path that gives
-    1.55x decode throughput, BASELINE.md). Loads with the same
+    into the consuming matmul (the weight-only int8 serving path). Loads
+    with the same
     :func:`load_predictor`."""
     from jax import export as jexport
 

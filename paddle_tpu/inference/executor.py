@@ -27,6 +27,7 @@ drops the tp layout (:meth:`shard_audit`, wired into
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -146,27 +147,45 @@ class PagedExecutor:
         # At most two variants ever compile (greedy / mixed).
         decode_body = (self._decode_megakernel_fn if self.megakernel
                        else self._decode_paged_fn)
-        self.decode_paged = jax.jit(decode_body,
-                                    donate_argnums=(2,),
-                                    static_argnums=(12, 13))
-        self.chunk_prefill = jax.jit(self._chunk_prefill_fn,
-                                     donate_argnums=(2,))
+        self.decode_paged = self._jit(decode_body,
+                                      donate_argnums=(2,),
+                                      static_argnums=(12, 13))
+        self.chunk_prefill = self._jit(self._chunk_prefill_fn,
+                                       donate_argnums=(2,))
         self.spec_scan = None
         self.spec_verify = None
         if engine.spec is not None:
             if engine._spec_fused:
                 scan_body = (self._spec_scan_megakernel_fn
                              if self.megakernel else self._spec_scan_fn)
-                self.spec_scan = jax.jit(scan_body,
-                                         donate_argnums=(2,),
-                                         static_argnums=(13, 14))
+                self.spec_scan = self._jit(scan_body,
+                                           donate_argnums=(2,),
+                                           static_argnums=(13, 14))
             else:
                 verify_body = (self._spec_verify_megakernel_fn
                                if self.megakernel
                                else self._spec_verify_fn)
-                self.spec_verify = jax.jit(verify_body,
-                                           donate_argnums=(3,),
-                                           static_argnums=(14,))
+                self.spec_verify = self._jit(verify_body,
+                                             donate_argnums=(3,),
+                                             static_argnums=(14,))
+
+    def _jit(self, body, **jit_kw):
+        """jit one program body. Under a tp/cp mesh the body traces inside
+        ``mesh_context`` so kernel selection (ops/select.py) sees that
+        GSPMD partitions the program — a Mosaic kernel cannot be
+        partitioned automatically and must not be selected there."""
+        if self.mesh is None:
+            return jax.jit(body, **jit_kw)
+        from ..parallel.api import mesh_context
+
+        mesh = self.mesh
+
+        @functools.wraps(body)
+        def traced(*args, **kwargs):
+            with mesh_context(mesh):
+                return body(*args, **kwargs)
+
+        return jax.jit(traced, **jit_kw)
 
     # ----------------------------------------------------------- mesh state
     @property
@@ -421,9 +440,9 @@ class PagedExecutor:
         """One W-token tick through the whole-tick megakernel: embed →
         ``decode_tick`` (all layers as ONE Pallas program, pools aliased
         in place) → final norm → head. Returns (fp32 logits (B, W, V),
-        new flat pool list). The kernel's shape guard raises
-        ``NotImplementedError`` at trace time — callers catch it and
-        delegate to the per-layer program (the dispatch ladder)."""
+        new flat pool list). Selection happened eagerly in the
+        constructor (``megakernel_supported``); a kernel failure here
+        raises."""
         from ..ops import decode_megakernel as mk
 
         engine = self.engine
@@ -451,38 +470,31 @@ class PagedExecutor:
         """The whole-tick twin of :meth:`_decode_paged_fn` — identical
         signature, sampling pipeline, and trip structure; only the
         per-tick model call collapses into the ONE persistent Pallas
-        program. A trace-time ``NotImplementedError`` from the kernel's
-        shape guard delegates the whole body to the per-layer program."""
+        program."""
         engine = self.engine
-        try:
-            lstk = self._mk_lora(lora_flat, aidx)
+        lstk = self._mk_lora(lora_flat, aidx)
 
-            def one_tick(carry, k):
-                toks, flat_p, p = carry
-                lg, flat = self._mk_window(params, toks[:, None], flat_p,
-                                           tables, p, lstk)
-                lg = lg[:, 0]                                 # (B, V)
-                if greedy:
-                    nxt = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-                else:
-                    from ..models.generation import sample_token_rows
+        def one_tick(carry, k):
+            toks, flat_p, p = carry
+            lg, flat = self._mk_window(params, toks[:, None], flat_p,
+                                       tables, p, lstk)
+            lg = lg[:, 0]                                 # (B, V)
+            if greedy:
+                nxt = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+            else:
+                from ..models.generation import sample_token_rows
 
-                    nxt = sample_token_rows(lg, jax.random.fold_in(key, k),
-                                            temps, topks, topps)
-                return (nxt, flat, p + active), nxt
+                nxt = sample_token_rows(lg, jax.random.fold_in(key, k),
+                                        temps, topks, topps)
+            return (nxt, flat, p + active), nxt
 
-            n = engine.tick_window if ticks is None else ticks
-            if n == 1:
-                (_, flat, _), stack = one_tick((tokens, flat_pools, pos), 0)
-                return stack[None], flat
-            (_, flat, _), stack = jax.lax.scan(
-                one_tick, (tokens, flat_pools, pos), jnp.arange(n))
-            return stack, flat
-        except NotImplementedError:
-            return self._decode_paged_fn(
-                params, tokens, flat_pools, tables, pos, temps, topks,
-                topps, active, key, aidx=aidx, lora_flat=lora_flat,
-                greedy=greedy, ticks=ticks)
+        n = engine.tick_window if ticks is None else ticks
+        if n == 1:
+            (_, flat, _), stack = one_tick((tokens, flat_pools, pos), 0)
+            return stack[None], flat
+        (_, flat, _), stack = jax.lax.scan(
+            one_tick, (tokens, flat_pools, pos), jnp.arange(n))
+        return stack, flat
 
     def _spec_verify_megakernel_fn(self, params, tokens, proposals,
                                    flat_pools, tables, pos, temps, topks,
@@ -492,22 +504,16 @@ class PagedExecutor:
         window is the megakernel's natural shape — one persistent program
         scores the whole window, then the exact accept/reject runs
         unchanged."""
-        try:
-            lstk = self._mk_lora(lora_flat, aidx)
-            window = jnp.concatenate([tokens[:, None], proposals], axis=1)
-            lg, flat = self._mk_window(params, window, flat_pools, tables,
-                                       pos, lstk)
-            from .speculative import speculative_accept
+        lstk = self._mk_lora(lora_flat, aidx)
+        window = jnp.concatenate([tokens[:, None], proposals], axis=1)
+        lg, flat = self._mk_window(params, window, flat_pools, tables,
+                                   pos, lstk)
+        from .speculative import speculative_accept
 
-            out, acc = speculative_accept(lg, proposals, temps, topks,
-                                          topps, kcaps, key, qprobs,
-                                          greedy=greedy)
-            return out, acc, flat
-        except NotImplementedError:
-            return self._spec_verify_fn(
-                params, tokens, proposals, flat_pools, tables, pos, temps,
-                topks, topps, kcaps, key, qprobs, aidx=aidx,
-                lora_flat=lora_flat, greedy=greedy)
+        out, acc = speculative_accept(lg, proposals, temps, topks,
+                                      topps, kcaps, key, qprobs,
+                                      greedy=greedy)
+        return out, acc, flat
 
     def _spec_scan_megakernel_fn(self, params, ctx, flat_pools, tables,
                                  pos, temps, topks, topps, kcaps, active,
@@ -517,47 +523,41 @@ class PagedExecutor:
         window loop, drafter, accept/reject, and context update; each
         window's target scoring is the ONE persistent program."""
         engine = self.engine
-        try:
-            model_k = engine.spec_k
-            W = model_k + 1
-            B, L = ctx.shape
-            S = engine._spec_windows if windows is None else windows
-            rows = jnp.arange(B)
-            lstk = self._mk_lora(lora_flat, aidx)
-            from .speculative import speculative_accept
+        model_k = engine.spec_k
+        W = model_k + 1
+        B, L = ctx.shape
+        S = engine._spec_windows if windows is None else windows
+        rows = jnp.arange(B)
+        lstk = self._mk_lora(lora_flat, aidx)
+        from .speculative import speculative_accept
 
-            def one_window(carry, w):
-                c, flat_p, p = carry
-                cur = jnp.take_along_axis(c, p[:, None], axis=1)   # (B, 1)
-                proposals = engine.drafter.propose_device(c, p, model_k)
-                window = jnp.concatenate([cur, proposals], axis=1)
-                lg, flat = self._mk_window(params, window, flat_p, tables,
-                                           p, lstk)
-                out, acc = speculative_accept(
-                    lg, proposals, temps, topks, topps, kcaps,
-                    jax.random.fold_in(key, w), None, greedy=greedy)
-                # context/position update — verbatim from _spec_scan_fn
-                # (including the L-1 clamp rationale documented there)
-                widx = jnp.minimum(p[:, None] + 1
-                                   + jnp.arange(W)[None, :], L - 1)
-                keep = ((jnp.arange(W)[None, :] <= acc[:, None])
-                        & (active > 0)[:, None])
-                vals = jnp.where(keep, out,
-                                 jnp.take_along_axis(c, widx, axis=1))
-                c = c.at[rows[:, None], widx].set(vals)
-                p = jnp.minimum(p + (acc + 1) * active, L - 1)
-                return (c, flat, p), (out, acc)
+        def one_window(carry, w):
+            c, flat_p, p = carry
+            cur = jnp.take_along_axis(c, p[:, None], axis=1)   # (B, 1)
+            proposals = engine.drafter.propose_device(c, p, model_k)
+            window = jnp.concatenate([cur, proposals], axis=1)
+            lg, flat = self._mk_window(params, window, flat_p, tables,
+                                       p, lstk)
+            out, acc = speculative_accept(
+                lg, proposals, temps, topks, topps, kcaps,
+                jax.random.fold_in(key, w), None, greedy=greedy)
+            # context/position update — verbatim from _spec_scan_fn
+            # (including the L-1 clamp rationale documented there)
+            widx = jnp.minimum(p[:, None] + 1
+                               + jnp.arange(W)[None, :], L - 1)
+            keep = ((jnp.arange(W)[None, :] <= acc[:, None])
+                    & (active > 0)[:, None])
+            vals = jnp.where(keep, out,
+                             jnp.take_along_axis(c, widx, axis=1))
+            c = c.at[rows[:, None], widx].set(vals)
+            p = jnp.minimum(p + (acc + 1) * active, L - 1)
+            return (c, flat, p), (out, acc)
 
-            carry = (ctx, flat_pools, pos)
-            outs, accs = [], []
-            for w in range(S):
-                carry, (out, acc) = one_window(carry, w)
-                outs.append(out)
-                accs.append(acc)
-            _, flat, _ = carry
-            return jnp.stack(outs), jnp.stack(accs), flat
-        except NotImplementedError:
-            return self._spec_scan_fn(
-                params, ctx, flat_pools, tables, pos, temps, topks, topps,
-                kcaps, active, key, aidx=aidx, lora_flat=lora_flat,
-                greedy=greedy, windows=windows)
+        carry = (ctx, flat_pools, pos)
+        outs, accs = [], []
+        for w in range(S):
+            carry, (out, acc) = one_window(carry, w)
+            outs.append(out)
+            accs.append(acc)
+        _, flat, _ = carry
+        return jnp.stack(outs), jnp.stack(accs), flat

@@ -42,8 +42,11 @@ def build_server(spec: Dict[str, Any]):
     docstring). Imports live here so the subprocess pays them once."""
     import paddle_tpu as paddle
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
 
     from .serving import GenerationServer
+
+    enable_compile_cache()
 
     model_spec = dict(spec.get("model") or {})
     cfg = LlamaConfig(**dict(model_spec.get("config") or {}))

@@ -432,9 +432,9 @@ class SpecConfig:
     windows per program instead of ``tick_window``, amortizing the host
     round trip over up to ``turbo_windows*(k+1)`` tokens. Drops back the
     moment acceptance dips. A third compiled variant, built once. Worth
-    enabling when the host<->device round trip dominates (tunneled
-    backends); on a local backend the coarser slot-refill granularity
-    of long trips usually costs more than the saved round trips.
+    enabling when the host<->device round trip dominates; otherwise the
+    coarser slot-refill granularity of long trips usually costs more than
+    the saved round trips.
     """
 
     k: int = 4

@@ -2,7 +2,9 @@
 
 TokenDataset/TokenDataLoader: the pretraining input pipeline — mmap token
 file, C++ threaded prefetch, fixed (B, S) int32 blocks (inputs + next-token
-labels). Falls back to a numpy implementation when the .so can't be built.
+labels). ``libptio.so`` is built on demand (utils/native_build.py); when it
+cannot be built a numpy reader with the same sampling contract runs instead,
+with a warning that says so — ``TokenDataLoader.native`` reports which ran.
 Ref: paddle/fluid/framework/data_feed.cc + fluid/dataloader worker stack.
 """
 from __future__ import annotations
@@ -58,7 +60,8 @@ def write_token_file(tokens: np.ndarray, path: str, dtype=np.int32) -> str:
 class TokenDataLoader:
     """Pretraining loader: yields (input_ids (B,S) int32, labels (B,S) int64).
 
-    Uses the C++ prefetch core when available; numpy fallback otherwise.
+    Uses the C++ prefetch core when it builds, else the numpy reader
+    (warned once per loader; see :attr:`native`).
     shard_id/num_shards give DistributedBatchSampler-style dataset sharding.
     """
 
@@ -80,6 +83,15 @@ class TokenDataLoader:
             if not self._handle:
                 self._lib = None
         if self._lib is None:
+            import warnings
+
+            from ..utils import native_build
+
+            warnings.warn(
+                "TokenDataLoader: the native reader (csrc/ptio.cpp) is "
+                "unavailable — running the numpy reader, without threaded "
+                f"prefetch. Last build error: {native_build.LAST_BUILD_ERROR}",
+                RuntimeWarning)
             dt = {2: np.uint16, 4: np.int32, 8: np.int64}[dtype_size]
             self._tokens = np.fromfile(path, dtype=dt)
             self._rng = np.random.RandomState(seed)

@@ -20,6 +20,7 @@ from __future__ import annotations
 import itertools
 import os
 import queue
+import sys
 from dataclasses import dataclass
 from typing import Any, Optional
 
@@ -167,9 +168,21 @@ def _loads(buf):
 # --------------------------------------------------------------------------
 
 
+def _pin_worker_to_cpu():
+    """A chip belongs to one process — the parent. Children are not meant
+    to touch jax at all; pinning their platform makes a stray array in a
+    dataset or ``worker_init_fn`` land on the host instead of reaching for
+    (and hanging on) the parent's chip."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax = sys.modules.get("jax")
+    if jax is not None:  # imported while unpickling the dataset
+        jax.config.update("jax_platforms", "cpu")
+
+
 def _map_worker_loop(dataset, index_q, result_q, worker_id, num_workers,
                      seed, worker_init_fn, use_shm):
     global _worker_info
+    _pin_worker_to_cpu()
     _worker_info = WorkerInfo(worker_id, num_workers, seed + worker_id, dataset)
     np.random.seed((seed + worker_id) % (2 ** 31))
     if worker_init_fn is not None:
@@ -190,6 +203,7 @@ def _iterable_worker_loop(dataset, result_q, worker_id, num_workers, seed,
                           worker_init_fn, batch_size, drop_last, use_shm,
                           stop_ev):
     global _worker_info
+    _pin_worker_to_cpu()
     _worker_info = WorkerInfo(worker_id, num_workers, seed + worker_id, dataset)
     np.random.seed((seed + worker_id) % (2 ** 31))
     if worker_init_fn is not None:

@@ -240,13 +240,49 @@ def _attn_island(axis, local, qr, kr, vv, head_divisible=False):
             return None
     dp = "data" if "data" in mesh.shape else None
     spec = P(dp, axis, tp, None)
-    from ..ops.flash_attention import _interpret
+    return _shard_map_qkv(local, mesh, spec, qr, kr, vv)
+
+
+def _shard_map_qkv(local, mesh, spec, q, k, v):
+    from ..ops.select import pallas_interpret
 
     # the pallas HLO interpreter's internal dynamic_slice doesn't propagate
     # varying-mesh-axes types; compiled runs keep the default check
-    kw = {"check_vma": False} if _interpret() else {}
+    kw = {"check_vma": False} if pallas_interpret() else {}
     return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, **kw)(qr, kr, vv)
+                         out_specs=spec, **kw)(q, k, v)
+
+
+def _flash_bhsd(qh, kh, vh, causal):
+    """``flash_attention`` on (B, H, S, D) operands. Under a GSPMD-
+    partitioned trace (ParallelEngine on a multi-device mesh) a Mosaic
+    kernel cannot be partitioned automatically, so when the kernel would
+    be selected the call sits in a shard_map island: batch over the
+    data-like axes that divide it, heads over 'tensor' when both head
+    counts divide. Everything else takes the plain call, whose own rule
+    then picks the jnp composition under partitioning."""
+    from ..ops.flash_attention import flash_attention
+    from ..ops.select import XLA, partitioned, select_flash_attention
+    from ..parallel.api import current_mesh
+
+    def local(a, b, c):
+        return flash_attention(a, b, c, causal=causal)
+
+    if not partitioned() or select_flash_attention(
+            qh.shape, kh.shape, is_partitioned=False) == XLA:
+        return local(qh, kh, vh)
+    mesh = current_mesh()
+    batch, n = [], 1
+    for ax in ("data", "sharding"):
+        size = mesh.shape.get(ax, 1)
+        if size > 1 and qh.shape[0] % (n * size) == 0:
+            batch.append(ax)
+            n *= size
+    tpn = mesh.shape.get("tensor", 1)
+    tp = "tensor" if (tpn > 1 and qh.shape[1] % tpn == 0
+                      and kh.shape[1] % tpn == 0) else None
+    spec = P(tuple(batch) or None, tp, None, None)
+    return _shard_map_qkv(local, mesh, spec, qh, kh, vh)
 
 
 def _ring_dispatch(qr, kr, vv, rep, use_flash, causal):
@@ -344,8 +380,8 @@ class LlamaAttention(Layer):
     def _qkv(self, x, B, S, lora=None):
         """q/k/v projections. The int8 decode path can fuse the three into
         ONE concatenated matmul (quantize_int8 with PT_W8_FUSED_QKV=1 —
-        single weight stream + kernel launch per step; see the measured
-        A/B in BASELINE.md round 4). ``lora``: per-layer dict of gathered
+        single weight stream + kernel launch per step; an earlier A/B
+        measured a tie, ROADMAP C6). ``lora``: per-layer dict of gathered
         per-row (A, B, scale) factors keyed "q"/"k"/"v" (serving
         multi-adapter path) — the delta is additive AFTER the base
         projection, so it composes with both the fp and fused-int8
@@ -411,14 +447,12 @@ class LlamaAttention(Layer):
                 # at S=16k the standalone (B,S,H,D)<->(B,H,S,D) copies
                 # around the custom call were ~33% of the step (r5 per-op
                 # profile, tools/profile_step.py)
-                from ..ops.flash_attention import flash_attention
-
                 qh = _apply_rope_bhsd(jnp.swapaxes(qv, 1, 2), cv, sv,
                                       pos_offset)
                 kh = _apply_rope_bhsd(jnp.swapaxes(kv, 1, 2), cv, sv,
                                       pos_offset)
-                out = flash_attention(qh, kh, jnp.swapaxes(vv, 1, 2),
-                                      causal=True)
+                out = _flash_bhsd(qh, kh, jnp.swapaxes(vv, 1, 2),
+                                  causal=True)
                 return jnp.swapaxes(out, 1, 2)
             qr = _apply_rope(qv, cv, sv, pos_offset)
             kr = _apply_rope(kv, cv, sv, pos_offset)
@@ -866,17 +900,28 @@ class LlamaModel(Layer):
                 "moe_num_experts > 0 with cfg.recompute=True detaches the "
                 "load-balance aux loss in eager training; use "
                 "ParallelEngine(remat=True) instead of cfg.recompute")
-        self.embed_tokens = Embedding(cfg.vocab_size, cfg.hidden_size)
+        dtype = None if cfg.dtype == "float32" else convert_dtype(cfg.dtype)
+
+        def cast(layer):
+            # initializers draw float32; converting each block as it is
+            # built keeps the peak at the model's own dtype plus ONE f32
+            # block — a whole f32 model (14 GB at Llama-3-8B widths x 16
+            # layers) does not fit the chip its bf16 form is sized for
+            if dtype is not None:
+                layer._convert_dtype(dtype)
+            return layer
+
+        self.embed_tokens = cast(Embedding(cfg.vocab_size, cfg.hidden_size))
         self.embed_tokens.weight.pspec = P("tensor", None)
-        self.layers = LayerList([LlamaDecoderLayer(cfg, layer_idx=i)
+        self.layers = LayerList([cast(LlamaDecoderLayer(cfg, layer_idx=i))
                                  for i in range(cfg.num_hidden_layers)])
         self.norm = LlamaRMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
         head_dim = cfg.hidden_size // cfg.num_attention_heads
         cos, sin = _rope_tables(head_dim, cfg.max_position_embeddings, cfg.rope_theta)
         self._cos = cos
         self._sin = sin
-        if cfg.dtype != "float32":
-            self._convert_dtype(convert_dtype(cfg.dtype))
+        if dtype is not None:
+            self._convert_dtype(dtype)
 
     def forward(self, input_ids, caches=None, pos_offset=0):
         x = self.embed_tokens(input_ids)

@@ -41,22 +41,20 @@ def _sdpa_ref(q, k, v, mask=None, dropout_p=0.0, is_causal=False, scale=None):
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.0,
                                  is_causal=False, training=True, scale=None, name=None):
-    """Inputs (B, S, H, D). Uses the Pallas flash kernel on TPU when shapes
-    allow, else the XLA reference path."""
-    use_pallas = GLOBAL_FLAGS.get("use_pallas_kernels")
-    if use_pallas and attn_mask is None and dropout_p == 0.0:
-        try:
-            from ...ops.flash_attention import flash_attention_bshd
+    """Inputs (B, S, H, D). Routes unmasked, dropout-free, 128-aligned
+    calls through ``ops.flash_attention`` (Pallas on a TPU), everything
+    else through the XLA reference path."""
+    # the flash entry point selects kernel vs jnp composition itself
+    # (ops/select.py); here only the cases it cannot express are excluded
+    if (GLOBAL_FLAGS.get("use_pallas_kernels") and attn_mask is None
+            and dropout_p == 0.0 and query.shape[1] % 128 == 0
+            and key.shape[1] % 128 == 0 and query.shape[-1] >= 64):
+        from ...ops.flash_attention import flash_attention_bshd
 
-            q_shape = query.shape
-            # pallas kernel needs seq multiple of block; fall back otherwise
-            if q_shape[1] % 128 == 0 and key.shape[1] % 128 == 0 and q_shape[-1] >= 64:
-                return apply_op(
-                    lambda q, k, v: flash_attention_bshd(q, k, v, causal=is_causal,
-                                                         scale=scale),
-                    query, key, value, op_name="flash_attention")
-        except Exception:
-            pass
+        return apply_op(
+            lambda q, k, v: flash_attention_bshd(q, k, v, causal=is_causal,
+                                                 scale=scale),
+            query, key, value, op_name="flash_attention")
     args = [query, key, value]
     if attn_mask is not None:
         return apply_op(

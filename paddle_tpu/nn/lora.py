@@ -236,8 +236,8 @@ def bgmv(x: Tensor, ab: Optional[Tuple]) -> Optional[Tensor]:
 def lora_matmul(x: Tensor, w: Tensor, ab: Optional[Tuple]) -> Tensor:
     """Base projection + gathered LoRA delta in ONE op:
     ``x @ w + ((x32 @ A) @ B) * scale`` with ``ab = (A, B, scale)`` as in
-    :func:`bgmv` (None means plain matmul). Under the shared kernel
-    dispatch (``ops.use_pallas()``) the whole expression runs as one Pallas
+    :func:`bgmv` (None means plain matmul). Where the kernel is selected
+    (``ops.select.select_lora_matmul``) the whole expression runs as one Pallas
     program per batch row (``ops.paged_attention_pallas.fused_lora_matmul``)
     so multi-tenant decode stops paying a separate gather+matmul pass; the
     jnp composition is bit-identical to the Linear-then-:func:`bgmv`
@@ -248,14 +248,12 @@ def lora_matmul(x: Tensor, w: Tensor, ab: Optional[Tuple]) -> Tensor:
     A, B, s = ab
 
     def f(v, wv, a, b, sc):
-        from ..ops import use_pallas
+        from ..ops.select import XLA, record, select_lora_matmul
 
-        if use_pallas():
-            try:
-                from ..ops.paged_attention_pallas import fused_lora_matmul
-                return fused_lora_matmul(v, wv, a, b, sc)
-            except NotImplementedError:
-                pass
+        if record("lora_matmul", select_lora_matmul(
+                v.shape, wv.shape, a.shape[2], v.dtype, wv.dtype)) != XLA:
+            from ..ops.paged_attention_pallas import fused_lora_matmul
+            return fused_lora_matmul(v, wv, a, b, sc)
         y = jnp.matmul(v, wv)
         d = jnp.einsum("bsh,bhr->bsr", v.astype(jnp.float32), a)
         d = jnp.einsum("bsr,bro->bso", d, b) * sc[:, None, None]

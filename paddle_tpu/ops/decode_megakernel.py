@@ -43,12 +43,12 @@ Geometry (tile sizes, prefetch depth, dequant placement) is DATA — a
 :class:`MegakernelGeometry` the autotuner can search (autotune/space.py
 registers the knobs with VMEM-budget validity arithmetic).
 
-Dispatch is the third rung of the ``ops`` kernel contract:
-``set_kernel_mode("megakernel")`` → the executor routes decode and
-spec-verify through :func:`decode_tick`; shape guards raise
-``NotImplementedError`` and the caller falls back to the per-layer
-Pallas kernels (``use_pallas()`` stays True under megakernel mode), which
-themselves fall back to the jnp reference.
+Selection is explicit and eager: ``set_kernel_mode("megakernel")`` plus
+:func:`megakernel_supported` at executor construction (every deciding
+shape is static there) route decode and spec-verify through
+:func:`decode_tick`; a model the guard rejects compiles the per-layer
+programs instead, with the reason recorded. Once selected, a kernel that
+fails to lower or compile raises.
 """
 from __future__ import annotations
 
@@ -65,10 +65,6 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 _QEPS = 1e-8   # scale floor — must match paged_attention._QEPS exactly
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 # canonical stream order for LoRA targets inside the kernel (subset used
 # follows the adapter pool's configured targets)
 LORA_TARGETS = ("q", "k", "v", "o", "gate", "up", "down")
@@ -77,7 +73,7 @@ DEQUANT_MODES = ("scores", "tile")
 
 
 def _interpret() -> bool:
-    from . import pallas_interpret
+    from .select import pallas_interpret
 
     return pallas_interpret()
 
@@ -165,22 +161,21 @@ class MegakernelGeometry:
 
 
 # ----------------------------------------------------------- shape guards
-def _check_tick_shapes(*, D: int, bs: int, Hd: int, Hq: int, KVD: int,
-                       I: int, T: int) -> None:
-    """Mosaic alignment on real hardware; interpret mode takes any shape.
-    Raises NotImplementedError — the dispatch ladder's fall-to-pallas
-    signal (same contract as paged_attention_pallas._check_tpu_shapes)."""
+def _tick_shape_reason(*, D: int, bs: int, Hd: int, Hq: int, KVD: int,
+                       I: int, T: int) -> Optional[str]:
+    """Mosaic alignment on real hardware (interpret mode takes any shape):
+    None when the shapes lower, else the reason they do not."""
     if _interpret():
-        return
+        return None
     if D % 128 != 0:
-        raise NotImplementedError(f"head_dim {D} not lane-aligned (128)")
+        return f"head_dim {D} not lane-aligned (128)"
     if bs % 8 != 0:
-        raise NotImplementedError(f"block_size {bs} not sublane-aligned (8)")
+        return f"block_size {bs} not sublane-aligned (8)"
     for name, dim in (("hidden", Hd), ("q_width", Hq), ("kv_width", KVD),
                       ("intermediate", I), ("ffn_tile", T)):
         if dim % 128 != 0:
-            raise NotImplementedError(
-                f"{name} dim {dim} not lane-aligned (128)")
+            return f"{name} dim {dim} not lane-aligned (128)"
+    return None
 
 
 def megakernel_supported(model, cfg, *, tp: int = 1, cp: int = 1,
@@ -232,14 +227,10 @@ def megakernel_supported(model, cfg, *, tp: int = 1, cp: int = 1,
         return "hidden_size is not num_attention_heads * head_dim"
     if D % 2:
         return f"head_dim {D} is odd — rope splits it in half"
-    try:
-        _check_tick_shapes(D=D, bs=block_size, Hd=cfg.hidden_size,
-                           Hq=cfg.num_attention_heads * D,
-                           KVD=cfg.num_key_value_heads * D, I=I,
-                           T=geometry.ffn_tile or I)
-    except NotImplementedError as e:
-        return str(e)
-    return None
+    return _tick_shape_reason(D=D, bs=block_size, Hd=cfg.hidden_size,
+                              Hq=cfg.num_attention_heads * D,
+                              KVD=cfg.num_key_value_heads * D, I=I,
+                              T=geometry.ffn_tile or I)
 
 
 # ------------------------------------------------------- weight stacking
@@ -731,9 +722,8 @@ def decode_tick(x, pools, tables, pos, weights, cos_rows, sin_rows, *,
 
     Returns ``(x_out (B, W, hidden), new_pools list)`` — the tick's
     post-norm input is NOT applied here (the executor's final norm + head
-    stay outside, like the per-layer path). Raises ``NotImplementedError``
-    from the shape guard at trace time on Mosaic misalignment — the
-    dispatch ladder's fall-to-pallas signal."""
+    stay outside, like the per-layer path). Shapes
+    :func:`megakernel_supported` would have rejected raise ``ValueError``."""
     geometry = geometry or MegakernelGeometry()
     geometry.validate()
     B, W, Hd = x.shape
@@ -753,7 +743,9 @@ def decode_tick(x, pools, tables, pos, weights, cos_rows, sin_rows, *,
     quantized = pools[0].dtype == jnp.int8
     P = (4 if quantized else 2) * L
     assert len(pools) == P, (len(pools), P)
-    _check_tick_shapes(D=D, bs=bs, Hd=Hd, Hq=Hq, KVD=KVD, I=I, T=T)
+    reason = _tick_shape_reason(D=D, bs=bs, Hd=Hd, Hq=Hq, KVD=KVD, I=I, T=T)
+    if reason is not None:
+        raise ValueError(f"decode_tick: {reason}")
 
     dtype = x.dtype
     kv_dtype = jnp.int8 if quantized else pools[0].dtype
@@ -773,7 +765,7 @@ def decode_tick(x, pools, tables, pos, weights, cos_rows, sin_rows, *,
 
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
-    any_ = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_ = pl.BlockSpec(memory_space=pl.ANY)
 
     inputs = [tables, pos, *lscale_in,
               x.reshape(BW, Hd),

@@ -16,9 +16,10 @@ blocks via pl.when (~2x at long S) and handle Sq != Sk with bottom-right
 alignment. GQA: q heads route to shared kv heads through the BlockSpec index
 map — no HBM repeat of K/V.
 
-Falls back to the jnp composition on non-TPU backends (CPU tests); set
-PT_FLASH_INTERPRET=1 to exercise the Pallas kernels in interpreter mode on
-CPU.
+``select.select_flash_attention`` picks the kernels on a TPU and the jnp
+composition elsewhere (CPU tests); a selected kernel that fails to compile
+raises. Set PT_FLASH_INTERPRET=1 to exercise the Pallas kernels in
+interpreter mode on CPU.
 """
 from __future__ import annotations
 
@@ -32,16 +33,13 @@ NEG_INF = -1e30
 
 
 def _use_pallas() -> bool:
-    # Delegates to the shared dispatch helper in ops/__init__ (one env-flag
-    # contract for flash, paged, and LoRA kernels). Kept under its old name:
-    # fused_adamw and the TPU suite import it from here.
-    from . import use_pallas
+    from .select import use_pallas
 
     return use_pallas()
 
 
 def _interpret() -> bool:
-    from . import pallas_interpret
+    from .select import pallas_interpret
 
     return pallas_interpret()
 
@@ -51,16 +49,14 @@ def _vma_of(*arrays):
     pallas out_shapes must declare how outputs vary across mesh axes."""
     vma = frozenset()
     for a in arrays:
-        try:
-            vma = vma | jax.typeof(a).vma
-        except Exception:
-            pass
+        vma = vma | jax.typeof(a).vma
     return vma
 
 
 def _sds(shape, dtype, vma):
-    return jax.ShapeDtypeStruct(shape, dtype, vma=vma) if vma else \
-        jax.ShapeDtypeStruct(shape, dtype)
+    # always explicit: inside a check_vma shard_map an unset vma is an
+    # error even when the operands vary over no axis (replicated island)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _ref_bhsd(q, k, v, causal: bool, scale: float):
@@ -794,31 +790,26 @@ def _flash_bwd_bhsd(q, k, v, do, lse, delta, causal, scale,
 # --------------------------------------------------------------------------- #
 
 
-def _pallas_shapes_ok(q, k) -> bool:
-    Sq, Sk = q.shape[2], k.shape[2]
-    return Sq % min(128, Sq) == 0 and Sk % min(128, Sk) == 0
+def _select(q, k) -> str:
+    from .select import record, select_flash_attention
+
+    return record("flash_attention", select_flash_attention(q.shape, k.shape))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def flash_attention(q, k, v, causal=False, scale=None):
     """(B, H, S, D) flash attention. scale defaults to 1/sqrt(D)."""
     s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    if _use_pallas() and _pallas_shapes_ok(q, k):
-        try:
-            return _flash_fwd_bhsd(q, k, v, causal, s)[0]
-        except Exception:
-            pass
+    if _select(q, k) != "xla":
+        return _flash_fwd_bhsd(q, k, v, causal, s)[0]
     return _ref_bhsd(q, k, v, causal, s)
 
 
 def _fa_fwd(q, k, v, causal, scale):
     s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    if _use_pallas() and _pallas_shapes_ok(q, k):
-        try:
-            out, lse = _flash_fwd_bhsd(q, k, v, causal, s)
-            return out, (q, k, v, out, lse)
-        except Exception:
-            pass
+    if _select(q, k) != "xla":
+        out, lse = _flash_fwd_bhsd(q, k, v, causal, s)
+        return out, (q, k, v, out, lse)
     return _ref_bhsd(q, k, v, causal, s), (q, k, v, None, None)
 
 
@@ -828,11 +819,8 @@ def _fa_bwd(causal, scale, res, g):
     if lse is not None:
         delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1)  # rowsum(dO·O); XLA fuses this reduction
-        try:
-            return _flash_bwd_bhsd(q, k, v, g, lse, delta, causal, s)
-        except Exception:
-            pass
-    # fallback: grad of the reference composition (XLA fuses)
+        return _flash_bwd_bhsd(q, k, v, g, lse, delta, causal, s)
+    # the forward took the reference composition: differentiate that
     _, vjp_fn = jax.vjp(lambda q_, k_, v_: _ref_bhsd(q_, k_, v_, causal, s), q, k, v)
     return vjp_fn(g)
 

@@ -58,31 +58,25 @@ import jax.numpy as jnp
 
 _LANE = 128
 _SUBLANE = 8
-_WARNED_FALLBACK = False
 
 
 def _use_pallas() -> bool:
-    from .flash_attention import _use_pallas as f
+    from .select import use_pallas
 
-    return f()
+    return use_pallas()
 
 
 def _interpret() -> bool:
-    from .flash_attention import _interpret as f
+    from .select import pallas_interpret
 
-    return f()
+    return pallas_interpret()
 
 
 def usable(shape) -> bool:
     if os.environ.get("PT_FUSED_ADAMW") != "1":
         return False  # opt-in only; measured slower than XLA's overlapped
         # per-tensor fusions on the full train step (see module docstring)
-    if jax.device_count() != 1 and not _interpret():
-        return False  # non-partitionable custom call would gather
-        # ZeRO-sharded state under a multi-device pjit (interpret mode is
-        # the CPU-CI seam and exempt: it never runs on real sharded state)
-    return (_use_pallas() and len(shape) == 2 and
-            shape[0] % _SUBLANE == 0 and shape[1] % _LANE == 0)
+    return multi_tensor_usable(shape)
 
 
 def _reference_update(param_f32, grad_f32, m, v, lr, b1, b2, eps, decay,
@@ -171,14 +165,26 @@ def _fused_call(param, grad, m, v, master, scalars, b1, b2, eps, decay,
     )(*ins)
 
 
+def _scalars(lr, step, b1, b2):
+    """(1, 4) scalar block [lr, 1/(1-b1^t), 1/(1-b2^t), 0] — the bias
+    corrections arrive precomputed (see _make_kernel)."""
+    step_f = jnp.asarray(step, jnp.float32)
+    return jnp.stack(
+        [jnp.asarray(lr, jnp.float32),
+         1.0 / (1.0 - jnp.asarray(b1, jnp.float32) ** step_f),
+         1.0 / (1.0 - jnp.asarray(b2, jnp.float32) ** step_f),
+         jnp.float32(0.0)]).reshape(1, 4)
+
+
 def multi_tensor_usable(shape) -> bool:
     """The FLAT multi-tensor apply has its own knob (PT_MT_ADAMW, read by
-    the optimizer) — this only checks kernel viability: TPU backend, tiled
-    2-D shape, single device (a pallas custom call is not
-    GSPMD-partitionable; interpret mode is the CPU-CI seam)."""
-    return (_use_pallas() and len(shape) == 2 and
-            shape[0] % _SUBLANE == 0 and shape[1] % _LANE == 0 and
-            (jax.device_count() == 1 or _interpret()))
+    the optimizer) — this only checks kernel viability
+    (``select.select_fused_adamw``): TPU, tiled 2-D shape, single device
+    (a pallas custom call is not GSPMD-partitionable and would gather
+    ZeRO-sharded state; interpret mode is the CPU-CI seam)."""
+    from .select import XLA, select_fused_adamw
+
+    return select_fused_adamw(shape) != XLA
 
 
 def flat_adamw_update(param, grad, m, v, *, lr, step, b1, b2, eps, decay
@@ -192,32 +198,16 @@ def flat_adamw_update(param, grad, m, v, *, lr, step, b1, b2, eps, decay
     — the reference's multi-tensor precedent): ~50 per-tensor custom calls
     broke XLA's backward/update overlap; a single launch pays one
     serialization point and streams all state at the HBM roofline.
-    Falls back to the identical XLA math off-TPU (CPU tests train through
-    this path bit-compatibly).
+    Runs the identical XLA math where the kernel is not selected (CPU
+    tests train through that path bit-compatibly).
     """
     param = jnp.asarray(param)
     grad = jnp.asarray(grad)
     if multi_tensor_usable(param.shape):
-        try:
-            step_f = jnp.asarray(step, jnp.float32)
-            scalars = jnp.stack(
-                [jnp.asarray(lr, jnp.float32),
-                 1.0 / (1.0 - jnp.asarray(b1, jnp.float32) ** step_f),
-                 1.0 / (1.0 - jnp.asarray(b2, jnp.float32) ** step_f),
-                 jnp.float32(0.0)]).reshape(1, 4)
-            out = _fused_call(param, grad, m, v, None, scalars,
-                              float(b1), float(b2), float(eps), float(decay),
-                              False)
-            return out[0], out[1], out[2]
-        except Exception as e:  # noqa: BLE001 — Mosaic raises many types
-            global _WARNED_FALLBACK
-            if not _WARNED_FALLBACK:
-                import warnings
-
-                warnings.warn(
-                    f"flat_adamw: kernel failed ({type(e).__name__}: {e}); "
-                    f"running the XLA fallback", RuntimeWarning)
-                _WARNED_FALLBACK = True
+        out = _fused_call(param, grad, m, v, None, _scalars(lr, step, b1, b2),
+                          float(b1), float(b2), float(eps), float(decay),
+                          False)
+        return out[0], out[1], out[2]
     new_master, m2, v2 = _reference_update(
         param.astype(jnp.float32), grad.astype(jnp.float32), m, v, lr, b1,
         b2, eps, decay, step)
@@ -228,14 +218,11 @@ def fused_adamw_update(param, grad, m, v, *, lr, step, b1, b2, eps,
                        decay, master: Optional[jax.Array] = None
                        ) -> Tuple[jax.Array, jax.Array, jax.Array,
                                   Optional[jax.Array]]:
-    """(new_param, new_m, new_v, new_master|None); falls back to the XLA
-    elementwise path off-TPU / on unsupported shapes / multi-device.
-
-    Caveat: only TRACE-time kernel failures are caught here.  When the
-    pallas_call is traced inside an outer jit (the engine train step), a
-    Mosaic failure surfaces at that outer compile and propagates — with
-    the opt-in flag set, a loud error beats silently benchmarking the
-    wrong path.
+    """(new_param, new_m, new_v, new_master|None); the XLA elementwise
+    path runs where the kernel is not selected (off-TPU, unsupported
+    shapes, multi-device). A selected kernel that fails to lower or
+    compile raises — with the opt-in flag set, a loud error beats silently
+    benchmarking the wrong path.
 
     ``grad`` is consumed in float32 either way (the kernel upcasts
     internally), so both paths compute identical math.
@@ -243,30 +230,11 @@ def fused_adamw_update(param, grad, m, v, *, lr, step, b1, b2, eps,
     param = jnp.asarray(param)
     grad = jnp.asarray(grad)
     if usable(param.shape):
-        try:
-            step_f = jnp.asarray(step, jnp.float32)
-            scalars = jnp.stack(
-                [jnp.asarray(lr, jnp.float32),
-                 1.0 / (1.0 - jnp.asarray(b1, jnp.float32) ** step_f),
-                 1.0 / (1.0 - jnp.asarray(b2, jnp.float32) ** step_f),
-                 jnp.float32(0.0)]).reshape(1, 4)
-            res = _fused_call(param, grad, m, v, master, scalars,
-                              float(b1), float(b2), float(eps), float(decay),
-                              master is not None)
-            if master is not None:
-                return res[0], res[1], res[2], res[3]
-            return res[0], res[1], res[2], None
-        except Exception as e:  # noqa: BLE001 — Mosaic raises many types
-            global _WARNED_FALLBACK
-            if not _WARNED_FALLBACK:
-                import warnings
-
-                warnings.warn(
-                    f"fused_adamw: PT_FUSED_ADAMW=1 but the kernel failed "
-                    f"({type(e).__name__}: {e}); running the XLA fallback — "
-                    f"any 'fused' A/B label on this run is wrong",
-                    RuntimeWarning)
-                _WARNED_FALLBACK = True
+        res = _fused_call(param, grad, m, v, master,
+                          _scalars(lr, step, b1, b2), float(b1), float(b2),
+                          float(eps), float(decay), master is not None)
+        return res[0], res[1], res[2], (res[3] if master is not None
+                                        else None)
     pf = master if master is not None else param.astype(jnp.float32)
     # scalars stay in the caller's types (python floats in eager mode) so
     # the fallback is bit-identical to the pre-fusion XLA path

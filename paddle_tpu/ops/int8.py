@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 
 _LANE = 128
-_WARNED_FALLBACK = False
 
 
 def quantize_per_channel(w) -> Tuple[jax.Array, jax.Array]:
@@ -37,16 +36,10 @@ def quantize_per_channel(w) -> Tuple[jax.Array, jax.Array]:
     return w_q.astype(jnp.int8), scale
 
 
-def _use_pallas() -> bool:
-    from .flash_attention import _use_pallas as f
-
-    return f()
-
-
 def _interpret() -> bool:
-    from .flash_attention import _interpret as f
+    from .select import pallas_interpret
 
-    return f()
+    return pallas_interpret()
 
 
 def _w8_kernel(x_ref, w_ref, s_ref, o_ref, *, out_dtype):
@@ -87,9 +80,6 @@ def _w8_matmul_pallas(x2, w_q, scale, out_dtype, block_n: int = 0):
         ],
         out_specs=pl.BlockSpec((M, bn), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        # interpret-mode knob mirrors flash_attention: CPU CI runs the same
-        # kernel logic interpreted (compiled Mosaic lowering is TPU-only and
-        # its error escapes the caller's try/except at jit-compile time)
         interpret=_interpret(),
     )(x2, w_q, scale.reshape(1, N))
 
@@ -103,28 +93,11 @@ def w8_matmul(x, w_q, scale):
     x2 = x.reshape(-1, K)
     M = x2.shape[0]
     out_dtype = x.dtype
-    # the streaming int8 kernel only wins when the matmul is weight-read
-    # bound (single-token decode, M = decode batch). Prefill/training
-    # shapes re-use each weight block M times — there the dequantize-once
-    # XLA path is the right program. The old M<=256 gate let per-request
-    # SERVER prefills (M = one prompt bucket, 32-128) onto the streaming
-    # kernel and collapsed under-load int8 serving to 62 tok/s (r5,
-    # BASELINE.md); decode batches are <=16 in every shipped config.
-    usable = (_use_pallas() and K % _LANE == 0 and N % _LANE == 0 and
-              M <= 16)
-    if usable:
-        try:
-            out = _w8_matmul_pallas(x2, w_q, scale, out_dtype)
-            return out.reshape(*lead, N)
-        except Exception as e:  # noqa: BLE001 — Mosaic raises many types
-            global _WARNED_FALLBACK
-            if not _WARNED_FALLBACK:
-                import warnings
+    # M-gate rationale lives with the rule (select.select_w8_matmul): the
+    # streaming kernel is for weight-read-bound decode batches only
+    from .select import XLA, record, select_w8_matmul
 
-                warnings.warn(
-                    f"w8_matmul: Pallas kernel failed ({type(e).__name__}: "
-                    f"{e}); falling back to full dequantization — the int8 "
-                    "bandwidth advantage is LOST", RuntimeWarning)
-                _WARNED_FALLBACK = True
+    if record("w8_matmul", select_w8_matmul(M, K, N)) != XLA:
+        return _w8_matmul_pallas(x2, w_q, scale, out_dtype).reshape(*lead, N)
     deq = (w_q.astype(jnp.float32) * scale[None, :]).astype(out_dtype)
     return jnp.matmul(x2, deq).reshape(*lead, N)

@@ -24,11 +24,11 @@ Layout is chosen Pallas-ready, mirroring the flash kernels in
 The real kernels live in ``paged_attention_pallas.py``: one flash-style
 online-softmax kernel covering decode (W=1), speculative verify
 (W=tick_window) and chunked prefill (B=1), fp and int8-fused-dequant. The
-public attention functions below dispatch to them under the shared
-``ops.use_pallas()`` contract (TPU backend, ``PT_FLASH_INTERPRET=1``, or
-``ops.set_kernel_mode("pallas")``) and otherwise run the jnp reference
-via one parameterized ``_attention_core`` — a single seam instead of six
-twins.
+public attention functions below select them by the
+``select.select_paged_attention`` rule (a TPU, ``PT_FLASH_INTERPRET=1``, or
+``ops.set_kernel_mode("pallas")``; never under a GSPMD-partitioned trace)
+and otherwise run the jnp reference via one parameterized
+``_attention_core`` — a single seam instead of six twins.
 
 All masks/softmax run in fp32 with the same ``-1e30`` fill as the dense
 decode path (``models/llama.py LlamaAttention.decode``) so greedy outputs
@@ -142,22 +142,21 @@ def _attention_core(q, ck, cv, qpos, ksl=None, vsl=None):
 
 
 def _try_pallas(q, k_pool, v_pool, tables, pos, ks=None, vs=None):
-    """Trace-time kernel dispatch: returns the Pallas result when the
-    shared ``use_pallas()`` contract says so and the shapes compile, else
-    None (caller runs the jnp reference). NotImplementedError is the
-    kernels' unaligned-shape signal."""
-    from . import use_pallas
+    """Kernel selection (``select.select_paged_attention``, decided from
+    platform, partitioning and static shapes before the kernel traces):
+    returns the Pallas result when the kernel is selected, else None
+    (caller runs the jnp reference). A selected kernel that fails to
+    lower or compile raises."""
+    from .select import XLA, record, select_paged_attention
 
-    if not use_pallas():
+    if record("paged_attention_q" if ks is not None else "paged_attention",
+              select_paged_attention(q.shape, k_pool.shape)) == XLA:
         return None
     from . import paged_attention_pallas as pk
 
-    try:
-        if ks is None:
-            return pk.paged_attention(q, k_pool, v_pool, tables, pos)
-        return pk.paged_attention_q(q, k_pool, ks, v_pool, vs, tables, pos)
-    except NotImplementedError:
-        return None
+    if ks is None:
+        return pk.paged_attention(q, k_pool, v_pool, tables, pos)
+    return pk.paged_attention_q(q, k_pool, ks, v_pool, vs, tables, pos)
 
 
 def paged_verify_attention(q, k_pool, v_pool, block_tables, pos):

@@ -2,10 +2,12 @@
 
 One flash-style online-softmax kernel serves decode (W=1), speculative
 verify (W=tick_window), and chunked prefill (B=1, W=chunk): the grid is
-(batch, kv_head, kv_block) and the K/V ``BlockSpec`` index_map reads the
-block table through ``PrefetchScalarGridSpec`` scalar-prefetch —
-``tbl[b, m]`` picks the pool block to stream into VMEM, so the dense
-``gather_block_kv`` copy of the context never materializes in HBM. Running
+(batch, kv_block) and the K/V ``BlockSpec`` index_map reads the block
+table through ``PrefetchScalarGridSpec`` scalar-prefetch — ``tbl[b, m]``
+picks the pool block to stream into VMEM, so the dense ``gather_block_kv``
+copy of the context never materializes in HBM. One program streams WHOLE
+``(bs, KV, D)`` blocks and walks the kv heads inside (Mosaic only takes
+blocks whose last two dims are the pool's own ``(KV, D)``). Running
 max/sum/accumulator live in VMEM scratch across the block axis;
 ``pl.when`` skips blocks past each row's causal frontier, which also
 covers the all-zero scratch-block entries of short sequences. The int8
@@ -14,26 +16,25 @@ scales on the VMEM tile — k-scale on the fp32 QK accumulator, v-scale
 folded into the probabilities before PV — so a dequantized pool is never
 built. ``fused_lora_matmul`` fuses the per-slot BGMV adapter delta
 (gathered A/B/scale factors) into the base projection matmul, one program
-per batch row.
+per (output tile, batch row).
 
 The kernel's SCHEDULE is parameterized by
 :class:`~paddle_tpu.autotune.kernel_geometry.PagedAttentionGeometry`
 (and the LoRA kernel's by :class:`~paddle_tpu.autotune.kernel_geometry
 .LoRAGeometry`): KV streaming depth (blocks fetched per grid step),
-q-row tiling (extra parallel axis over the W*rep GQA rows), grid
-iteration order, and int8 cast placement. All geometry axes are
+q-row tiling (extra parallel axis over the W*rep GQA rows), and int8
+cast placement. All geometry axes are
 schedule-only — the per-block online-softmax update runs in the same
 order on the same values, so every geometry is bit-exact against the
-default, and the default geometry lowers to exactly the pre-geometry
-kernel (one block per step, full row group, bgm order). ``geometry=``
+default (one block per step, full row group). ``geometry=``
 is a trace-time parameter; when omitted, the process-wide winner cache
 (``autotune.kernel_geometry.install_geometry_cache``) is consulted at
 trace time, same contract as ``ops.set_kernel_mode``.
 
 The jnp compositions in ``ops/paged_attention.py`` remain the bit-exact
-references; dispatch between them and these kernels follows the shared
-``ops.use_pallas()`` / ``ops.pallas_interpret()`` contract (TPU backend,
-``PT_FLASH_INTERPRET=1``, or ``set_kernel_mode``). The online softmax is
+references; which of the two runs is decided by the rules in
+``ops/select.py`` (a TPU, ``PT_FLASH_INTERPRET=1``, or ``set_kernel_mode``;
+shapes Mosaic refuses stay on jnp). The online softmax is
 numerically equivalent but not bit-identical to the reference's two-pass
 softmax (~1e-6 relative); greedy decode tokens are identical, which is
 what the serving tests pin.
@@ -50,13 +51,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _interpret() -> bool:
-    from . import pallas_interpret
+    from .select import pallas_interpret
 
     return pallas_interpret()
 
@@ -67,17 +64,6 @@ def _lanes(x):
     return jnp.broadcast_to(x[:, None], (x.shape[0], 128))
 
 
-def _check_tpu_shapes(bs: int, D: int) -> None:
-    """Alignment the Mosaic compiler needs on real hardware; interpret mode
-    takes any shape. Callers catch and fall back to the jnp reference."""
-    if _interpret():
-        return
-    if D % 128 != 0:
-        raise NotImplementedError(f"head_dim {D} not lane-aligned (128)")
-    if bs % 8 != 0:
-        raise NotImplementedError(f"block_size {bs} not sublane-aligned (8)")
-
-
 def _resolve(op: str, dtype: str, key: int):
     from ..autotune.kernel_geometry import resolve_geometry
 
@@ -85,8 +71,8 @@ def _resolve(op: str, dtype: str, key: int):
 
 
 # ------------------------------------------------------------------ attention
-def _attn_kernel(tbl_ref, pos_ref, q_ref, *rest, bs, W, rep, Mp, depth, R,
-                 quantized, early, ib, ig, iq, im):
+def _attn_kernel(tbl_ref, pos_ref, q_ref, *rest, bs, W, rep, KV, Mp, depth,
+                 R, quantized, early, ib, iq, im):
     d = depth
     k_refs = rest[:d]
     v_refs = rest[d:2 * d]
@@ -121,52 +107,60 @@ def _attn_kernel(tbl_ref, pos_ref, q_ref, *rest, bs, W, rep, Mp, depth, R,
             # "early" dequant placement: the int8->fp cast is exact, so
             # hoisting it out of the skip branch changes the schedule
             # (branchless stream) but never the math
-            k_pre = k_refs[j][0, :, 0, :].astype(q_ref.dtype)
-            v_pre = v_refs[j][0, :, 0, :].astype(q_ref.dtype)
+            k_pre = [k_refs[j][0, :, g, :].astype(q_ref.dtype)
+                     for g in range(KV)]
+            v_pre = [v_refs[j][0, :, g, :].astype(q_ref.dtype)
+                     for g in range(KV)]
 
         @pl.when(needed)
         def _compute():
-            q = q_ref[0, 0]                       # (R, D)
-            if quantized and early:
-                k, v = k_pre, v_pre
-            else:
-                k = k_refs[j][0, :, 0, :]         # (bs, D)
-                v = v_refs[j][0, :, 0, :]
+            # the whole (bs, KV, D) block is resident; each kv head's
+            # online-softmax state is independent, so walking the heads
+            # here runs, per head, exactly the per-block update order of
+            # a one-head-per-program grid
+            for g in range(KV):
+                q = q_ref[0, g]                       # (R, D)
+                if quantized and early:
+                    k, v = k_pre[g], v_pre[g]
+                else:
+                    k = k_refs[j][0, :, g, :]         # (bs, D)
+                    v = v_refs[j][0, :, g, :]
+                    if quantized:
+                        k = k.astype(q.dtype)
+                        v = v.astype(q.dtype)
+                s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
                 if quantized:
-                    k = k.astype(q.dtype)
-                    v = v.astype(q.dtype)
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            if quantized:
-                # reference order: scores * k_scale, then / sqrt(D)
-                s = s * ks_refs[j][0, 0]
-            s = s / jnp.float32(math.sqrt(q.shape[-1]))
-            rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            # row -> absolute query position
-            qpos = pos_ref[b] + (row0 + rows) // rep
-            s = jnp.where(blk * bs + cols <= qpos, s, NEG_INF)
-            m_prev = m_ref[:, 0]
-            l_prev = l_ref[:, 0]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-            p = jnp.exp(s - m_new[:, None])
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[...] = _lanes(l_prev * alpha + jnp.sum(p, axis=-1))
-            if quantized:
-                p = p * vs_refs[j][0, 0]          # fold v scale into probs
-            acc_ref[...] = acc_ref[...] * alpha[:, None] + \
-                jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            m_ref[...] = _lanes(m_new)
+                    # reference order: scores * k_scale, then / sqrt(D)
+                    s = s * ks_refs[j][0, :, g:g + 1]
+                s = s / jnp.float32(math.sqrt(q.shape[-1]))
+                rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                # row -> absolute query position
+                qpos = pos_ref[b] + (row0 + rows) // rep
+                s = jnp.where(blk * bs + cols <= qpos, s, NEG_INF)
+                m_prev = m_ref[g, :, 0]
+                l_prev = l_ref[g, :, 0]
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+                p = jnp.exp(s - m_new[:, None])
+                alpha = jnp.exp(m_prev - m_new)
+                l_ref[g] = _lanes(l_prev * alpha + jnp.sum(p, axis=-1))
+                if quantized:
+                    p = p * vs_refs[j][0, :, g:g + 1]  # v scale into probs
+                acc_ref[g] = acc_ref[g] * alpha[:, None] + \
+                    jax.lax.dot_general(
+                        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                m_ref[g] = _lanes(m_new)
 
     for j in range(d):
         _step(j)
 
     @pl.when(m == Mp - 1)
     def _finish():
-        l_safe = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
+        for g in range(KV):
+            l_safe = jnp.maximum(l_ref[g, :, 0], 1e-30)
+            o_ref[0, g] = (acc_ref[g] / l_safe[:, None]).astype(o_ref.dtype)
 
 
 def _paged_attention_call(q, k_pool, v_pool, tables, pos, k_scales=None,
@@ -179,7 +173,10 @@ def _paged_attention_call(q, k_pool, v_pool, tables, pos, k_scales=None,
     rep = H // KV
     M = tables.shape[1]
     Wr = W * rep
-    _check_tpu_shapes(bs, D)
+    if not _interpret() and (D % 128 or bs % 8):
+        # select.select_paged_attention keeps these shapes off the kernel
+        raise ValueError(f"paged attention kernel needs head_dim % 128 == 0 "
+                         f"and block_size % 8 == 0, got {D} / {bs}")
     quantized = k_scales is not None
     if geometry is None:
         geometry = _resolve("paged_attention",
@@ -194,69 +191,71 @@ def _paged_attention_call(q, k_pool, v_pool, tables, pos, k_scales=None,
     NQ = Wr // R
     Mp = M // depth
     early = quantized and geometry.dequant == "early"
-    # GQA: group query heads with their shared kv head so one kernel
-    # instance covers the whole group — (B, KV, W*rep, D).
+    # GQA: group query heads with their shared kv head — (B, KV, W*rep, D).
     qt = q.reshape(B, W, KV, rep, D).transpose(0, 2, 1, 3, 4).reshape(
         B, KV, Wr, D)
-    # grid axes: the two parallel axes in the geometry's order, the
-    # optional q-row tile axis, then the sequential kv-block axis; the
-    # default (depth=1, full rows, "bgm") is exactly the pre-geometry
-    # (B, KV, M) lowering
-    axes = (["b", "g"] if geometry.grid_order == "bgm" else ["g", "b"])
-    if NQ > 1:
-        axes.append("q")
-    axes.append("m")
-    sizes = {"b": B, "g": KV, "q": NQ, "m": Mp}
+    # grid: batch (parallel), the optional q-row tile axis (parallel), then
+    # the sequential kv-block axis. The kv heads are walked INSIDE the
+    # program: Mosaic only takes K/V blocks whose last two dims are the
+    # pool's own (KV, D), so one program streams whole (bs, KV, D) blocks.
+    # ``geometry.grid_order`` ordered the former (batch, kv-head) grid axes
+    # and is moot now; it is still validated so swept profiles load.
+    axes = ["b"] + (["q"] if NQ > 1 else []) + ["m"]
+    sizes = {"b": B, "q": NQ, "m": Mp}
     grid = tuple(sizes[a] for a in axes)
-    ib, ig, im = axes.index("b"), axes.index("g"), axes.index("m")
+    ib, im = axes.index("b"), axes.index("m")
     iq = axes.index("q") if NQ > 1 else None
 
     def q_map(*a):
         ids = a[:-2]
-        return (ids[ib], ids[ig], ids[iq] if iq is not None else 0, 0)
+        return (ids[ib], 0, ids[iq] if iq is not None else 0, 0)
 
     def kv_map(j):
         def f(*a):
             ids, tbl = a[:-2], a[-2]
-            return (tbl[ids[ib], ids[im] * depth + j], 0, ids[ig], 0)
+            return (tbl[ids[ib], ids[im] * depth + j], 0, 0, 0)
         return f
 
     def sc_map(j):
         def f(*a):
             ids, tbl = a[:-2], a[-2]
-            return (tbl[ids[ib], ids[im] * depth + j], ids[ig])
+            return (tbl[ids[ib], ids[im] * depth + j], 0, 0)
         return f
 
-    in_specs = [pl.BlockSpec((1, 1, R, D), q_map)]
-    in_specs += [pl.BlockSpec((1, bs, 1, D), kv_map(j))
+    in_specs = [pl.BlockSpec((1, KV, R, D), q_map)]
+    in_specs += [pl.BlockSpec((1, bs, KV, D), kv_map(j))
                  for j in range(depth)]
-    in_specs += [pl.BlockSpec((1, bs, 1, D), kv_map(j))
+    in_specs += [pl.BlockSpec((1, bs, KV, D), kv_map(j))
                  for j in range(depth)]
     args = [tables.astype(jnp.int32), pos.astype(jnp.int32), qt]
     args += [k_pool] * depth + [v_pool] * depth
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1), sc_map(j)) for j in range(depth)]
-        in_specs += [pl.BlockSpec((1, 1), sc_map(j)) for j in range(depth)]
-        args += [k_scales.astype(jnp.float32)] * depth
-        args += [v_scales.astype(jnp.float32)] * depth
+        # (N, KV) scales ride as (N, 1, KV) so a block's last two dims are
+        # the array's own
+        in_specs += [pl.BlockSpec((1, 1, KV), sc_map(j))
+                     for j in range(depth)]
+        in_specs += [pl.BlockSpec((1, 1, KV), sc_map(j))
+                     for j in range(depth)]
+        args += [k_scales.astype(jnp.float32).reshape(N, 1, KV)] * depth
+        args += [v_scales.astype(jnp.float32).reshape(N, 1, KV)] * depth
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, R, D), q_map),
+        out_specs=pl.BlockSpec((1, KV, R, D), q_map),
         scratch_shapes=[
-            pltpu.VMEM((R, 128), jnp.float32),   # running max
-            pltpu.VMEM((R, 128), jnp.float32),   # running sum
-            pltpu.VMEM((R, D), jnp.float32),     # output accumulator
+            pltpu.VMEM((KV, R, 128), jnp.float32),   # running max
+            pltpu.VMEM((KV, R, 128), jnp.float32),   # running sum
+            pltpu.VMEM((KV, R, D), jnp.float32),     # output accumulator
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_attn_kernel, bs=bs, W=W, rep=rep, Mp=Mp,
+        functools.partial(_attn_kernel, bs=bs, W=W, rep=rep, KV=KV, Mp=Mp,
                           depth=depth, R=R, quantized=quantized,
-                          early=early, ib=ib, ig=ig, iq=iq, im=im),
+                          early=early, ib=ib, iq=iq, im=im),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, Wr, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=tuple(
                 "arbitrary" if a == "m" else "parallel" for a in axes)),
         interpret=_interpret(),
@@ -286,7 +285,7 @@ def paged_attention_q(q, kq_pool, k_scales, vq_pool, v_scales, block_tables,
 
 
 # ----------------------------------------------------------------- LoRA BGMV
-def _lora_kernel(x_ref, w_ref, a_ref, b_ref, s_ref, o_ref, *,
+def _lora_kernel(s_ref, x_ref, w_ref, a_ref, b_ref, o_ref, *,
                  delta_first=False):
     x = x_ref[0]                               # (S, in)
 
@@ -309,20 +308,26 @@ def _lora_kernel(x_ref, w_ref, a_ref, b_ref, s_ref, o_ref, *,
     else:
         y = base()
         d = delta()
-    o_ref[0] = (y + d * s_ref[0, 0]).astype(o_ref.dtype)
+    o_ref[0] = (y + d * s_ref[pl.program_id(1)]).astype(o_ref.dtype)
 
 
 def fused_lora_matmul(x, w, a, b, s, geometry=None):
-    """Base projection + per-row LoRA delta in one program per batch row:
-    ``x @ w + ((x32 @ a[i]) @ b[i]) * s[i]``. The factors are the per-slot
-    gathers from AdapterPool.gather_rows — a (B, in, R), b (B, R, out),
-    s (B,); null adapters arrive as zero factors with s=0, making the delta
-    exactly zero (bit-identical to the plain matmul).
+    """Base projection + per-row LoRA delta in one program per (output
+    tile, batch row): ``x @ w + ((x32 @ a[i]) @ b[i]) * s[i]``. The factors
+    are the per-slot gathers from AdapterPool.gather_rows — a (B, in, R),
+    b (B, R, out), s (B,); null adapters arrive as zero factors with s=0,
+    making the delta exactly zero (bit-identical to the plain matmul).
+
+    The base weight streams as ``(in, tile)`` column tiles
+    (``select.lora_block_out`` sizes the tile from the VMEM budget; the
+    output-tile axis is outermost so a tile stays resident across the
+    batch rows) and the per-row scale is a scalar-prefetch SMEM operand.
 
     ``geometry`` (:class:`LoRAGeometry`): rank padding (zero columns/rows
     contribute exact zeros — bit-exact, MXU-aligned contraction) and the
     matmul issue order."""
     from ..autotune.kernel_geometry import LoRAGeometry
+    from .select import lora_block_out
 
     B, S, IN = x.shape
     OUT = w.shape[1]
@@ -333,27 +338,36 @@ def fused_lora_matmul(x, w, a, b, s, geometry=None):
         raise ValueError(f"fused LoRA wants a LoRAGeometry, got "
                          f"{type(geometry).__name__}")
     geometry.validate()
-    if not _interpret() and (IN % 128 or OUT % 128):
-        raise NotImplementedError("projection dims not lane-aligned")
     rp = geometry.padded_rank(R)
     if rp != R:
         a = jnp.pad(a, ((0, 0), (0, 0), (0, rp - R)))
         b = jnp.pad(b, ((0, 0), (0, rp - R), (0, 0)))
         R = rp
+    if _interpret():
+        bn = OUT                     # the interpreter has no VMEM to fit
+    else:
+        bn = lora_block_out(S, IN, OUT, R, x.dtype, w.dtype)
+        if not bn:
+            # select.select_lora_matmul keeps these shapes off the kernel
+            raise ValueError(f"fused LoRA: no output tile of a ({IN}, {OUT}) "
+                             f"projection fits VMEM at S={S}, rank={R}")
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(OUT // bn, B),
+        in_specs=[
+            pl.BlockSpec((1, S, IN), lambda j, i, s_: (i, 0, 0)),
+            pl.BlockSpec((IN, bn), lambda j, i, s_: (0, j)),
+            pl.BlockSpec((1, IN, R), lambda j, i, s_: (i, 0, 0)),
+            pl.BlockSpec((1, R, bn), lambda j, i, s_: (i, 0, j)),
+        ],
+        out_specs=pl.BlockSpec((1, S, bn), lambda j, i, s_: (i, 0, j)),
+    )
     return pl.pallas_call(
         functools.partial(_lora_kernel,
                           delta_first=geometry.accum == "delta_first"),
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, S, IN), lambda i: (i, 0, 0)),
-            pl.BlockSpec((IN, OUT), lambda i: (0, 0)),
-            pl.BlockSpec((1, IN, R), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, R, OUT), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, S, OUT), lambda i: (i, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, OUT), x.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=_interpret(),
-    )(x, w, a, b, s.reshape(B, 1).astype(jnp.float32))
+    )(s.astype(jnp.float32), x, w, a, b)

@@ -32,8 +32,13 @@ def param_specs(model, mesh: Mesh, fsdp: bool = False, fsdp_axis: str = "shardin
                 ) -> Dict[str, P]:
     """fsdp=True applies the canonical ZeRO-3 layout policy, shared with
     distributed.sharding (ref group_sharded_stage3.py:60 — param sharding
-    with fwd allgather, which GSPMD emits automatically). Even splits only:
-    these specs are applied eagerly via device_put in _build_state."""
+    with fwd allgather, which GSPMD emits automatically): a parameter
+    without a layer ``pspec`` takes ``auto_shard_spec``; one that carries a
+    pspec (every large Llama weight does, for tensor parallelism) keeps it
+    and gains ``fsdp_axis`` on its largest still-free, evenly divisible dim
+    (``_add_fsdp_axis``) — otherwise fsdp would leave most bytes
+    replicated. Even splits only: these specs are applied eagerly via
+    device_put in _build_state."""
     axis_size = mesh.shape[fsdp_axis] if fsdp_axis in mesh.axis_names else 1
     specs: Dict[str, P] = {}
     for name, p in model.named_parameters():
@@ -41,10 +46,35 @@ def param_specs(model, mesh: Mesh, fsdp: bool = False, fsdp_axis: str = "shardin
         if spec is None:
             spec = (auto_shard_spec(p.value.shape, axis_size, axis=fsdp_axis)
                     if fsdp else P())
-        specs[name] = _filter_spec(spec, mesh)
+            specs[name] = _filter_spec(spec, mesh)
+        else:
+            spec = _filter_spec(spec, mesh)
+            specs[name] = (_add_fsdp_axis(spec, p.value.shape, axis_size,
+                                          fsdp_axis) if fsdp else spec)
     for name, b in model.named_buffers():
         specs[name] = P()
     return specs
+
+
+def _add_fsdp_axis(spec: P, shape, axis_size: int, axis: str,
+                   min_size: int = 1024) -> P:
+    """``spec`` with ``axis`` laid over the largest dim it leaves free and
+    ``axis_size`` divides; unchanged for tiny arrays (same ``min_size`` as
+    ``auto_shard_spec``), when ``axis`` is already used, or when no free
+    dim divides."""
+    shape = tuple(shape)
+    parts = list(spec) + [None] * (len(shape) - len(spec))
+    used = {a for p in parts if p is not None
+            for a in ((p,) if isinstance(p, str) else p)}
+    if (axis_size <= 1 or axis in used
+            or int(np.prod(shape, dtype=np.int64)) < min_size):
+        return spec
+    free = [i for i in range(len(shape))
+            if parts[i] is None and shape[i] % axis_size == 0]
+    if not free:
+        return spec
+    parts[max(free, key=lambda i: shape[i])] = axis
+    return P(*parts)
 
 
 def _sharding_of(mesh, spec):
@@ -153,7 +183,7 @@ class ParallelEngine:
         if "pinned_host" not in kinds:
             # the CPU backend has no device-placement custom call at all
             # (annotate_device_placement unregistered) — offload is a
-            # TPU-backend feature, verified on chip (BASELINE.md round 4)
+            # TPU-backend feature, verified on chip (tests_tpu/)
             raise NotImplementedError(
                 f"offload_opt_state needs a backend with pinned_host "
                 f"memory; this backend has {sorted(kinds)}")
@@ -301,10 +331,8 @@ class ParallelEngine:
         Multi-process (ref test_dist_base.py:899 per-rank readers): each
         process passes its LOCAL shard of the batch; the global array is
         assembled against the batch sharding without any cross-host gather
-        of example data. Unlike the single-process path (which silently
-        replicates a ragged batch), an unevenly-divisible local shard is an
-        error here — the data never exists in one place to replicate —
-        so pad to the bucket (io.LengthBucketBatchSampler) instead."""
+        of example data. On either path an unevenly-divisible batch is an
+        error — pad to the bucket (io.LengthBucketBatchSampler) instead."""
         def spec_of(i):
             # PartitionSpec subclasses tuple: a bare P("data") must apply
             # whole to every batch element, not be indexed into per-element
@@ -341,17 +369,25 @@ class ParallelEngine:
         return batch_vals
 
     def _batch_sharding(self, arr, spec):
-        """NamedSharding for one batch array: drop mesh axes the array's dims
-        can't be evenly split over (tiny eval batches on a big global mesh)."""
+        """NamedSharding for one batch array under ``spec``. A dim the
+        spec's mesh axes do not divide is an error: replicating the batch
+        instead would make every device compute all of it, silently."""
         spec = _filter_spec(spec, self.mesh)
         dims = []
         for i, ax in enumerate(spec):
-            if ax is None:
+            if ax is None or i >= arr.ndim:
                 dims.append(None)
                 continue
             axes = (ax,) if isinstance(ax, str) else tuple(ax)
             size = int(np.prod([self.mesh.shape[a] for a in axes]))
-            dims.append(ax if i < arr.ndim and arr.shape[i] % size == 0 else None)
+            if arr.shape[i] % size:
+                raise ValueError(
+                    f"batch array of shape {tuple(arr.shape)} does not "
+                    f"split evenly under batch_spec {spec} on mesh "
+                    f"{dict(self.mesh.shape)}: dim {i} ({arr.shape[i]}) is "
+                    f"not a multiple of {size}. Pad the batch (see the io "
+                    f"bucketing helpers) or pass a batch_spec that fits.")
+            dims.append(ax)
         return _sharding_of(self.mesh, P(*dims))
 
     def build_train_step(self):

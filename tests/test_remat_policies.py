@@ -111,7 +111,7 @@ def test_offload_opt_state_requires_pinned_host():
     """The CPU backend has no pinned_host memory (and no placement custom
     call) — the engine must say so clearly instead of failing mid-compile.
     The trains-and-stays-on-host behavior is verified ON CHIP
-    (tools/bench_offload.py; BASELINE.md round 4)."""
+    (tests_tpu/: offload parity and grad-accum cases)."""
     paddle.seed(0)
     cfg = llama_tiny_config(use_flash_attention=False)
     m = LlamaForCausalLM(cfg)

@@ -1,0 +1,357 @@
+"""Compile-only check against a TPU v5e target — from a machine with no chip.
+
+``jax.experimental.topologies.get_topology_desc(platform="tpu",
+topology_name="v5e:2x2")`` returns a compile-only v5e target (four devices,
+``device_kind`` "TPU v5 lite"). Lowering a jitted function against
+``ShapeDtypeStruct``s placed on those devices and calling ``.compile()`` runs
+XLA:TPU **and Mosaic** for real, so a kernel the chip's compiler would refuse
+is refused here, without spending chip time. It says what the compiler
+accepts, not what the chip computes — ``chip_smoke.py`` and ``tests_tpu/``
+answer that.
+
+Usage (the platform is pinned in-process; leave ``JAX_PLATFORMS`` unset —
+under ``JAX_PLATFORMS=cpu`` paddle_tpu turns on x64 and ``highest`` matmul
+precision, and Mosaic rejects kernels that are fine on the chip):
+
+    env -u JAX_PLATFORMS python tools/compile_check.py            # kernels
+    env -u JAX_PLATFORMS python tools/compile_check.py --programs # + whole
+        # train steps / serving programs, one chip and the 2x2 mesh (minutes)
+    env -u JAX_PLATFORMS python tools/compile_check.py --only flash,norm
+
+Prints one line per case and exits non-zero if a case that must compile does
+not. Cases in ``KNOWN_REFUSALS`` record a refusal nobody has repaired (the
+decode megakernel: explicit-only, never on the ``auto`` path); they are
+reported either way and never fail the run.
+"""
+from __future__ import annotations
+
+import argparse
+import contextlib
+import dataclasses
+import os
+import sys
+import time
+import traceback
+
+os.environ.pop("JAX_PLATFORMS", None)
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+
+jax.config.update("jax_platforms", "cpu")
+
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding  # noqa: E402
+from jax.sharding import PartitionSpec as P  # noqa: E402
+
+TOPOLOGY = "v5e:2x2"
+_BF16 = jnp.bfloat16
+# PR 21: Mosaic's verifier rejects the megakernel's first projection —
+# "'tpu.matmul' op Expected matmul acc to be 32-bit" (ROADMAP A2 / C2)
+KNOWN_REFUSALS = {"megakernel.1chip_decode"}
+
+# Llama-3-8B widths (the serve leg of chip_smoke.py) and the 509M train proxy
+H8, KV8, D8, HID8, FFN8 = 32, 8, 128, 4096, 14336
+
+
+def target_devices():
+    from jax.experimental import topologies
+
+    return topologies.get_topology_desc(
+        platform="tpu", topology_name=TOPOLOGY).devices
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
+
+
+def compile_on(fn, specs, sharding):
+    """Lower ``fn`` for the target with every operand under ``sharding`` and
+    compile. ``specs``: [(shape, dtype), ...]. Returns (compiled, n_mosaic)."""
+    args = [_sds(s, d, sharding) for s, d in specs]
+    lowered = jax.jit(fn).lower(*args)
+    compiled = lowered.compile()
+    return compiled, compiled.as_text().count("tpu_custom_call")
+
+
+# ------------------------------------------------------------- kernel cases
+def _flash_case(B, H, KV, S, D):
+    from paddle_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, True).astype(jnp.float32))
+
+    return (jax.grad(loss, argnums=(0, 1, 2)),
+            [((B, H, S, D), _BF16), ((B, KV, S, D), _BF16),
+             ((B, KV, S, D), _BF16)])
+
+
+def _norm_case(rows, width, dtype, kind="rms"):
+    from paddle_tpu.ops.fused_norm import _ln_pallas, _rms_pallas
+
+    if kind == "rms":
+        return (lambda x, w: _rms_pallas(x, w, 1e-6),
+                [((rows, width), dtype), ((width,), dtype)])
+    return (lambda x, w, b: _ln_pallas(x, w, b, 1e-5),
+            [((rows, width), dtype), ((width,), dtype), ((width,), dtype)])
+
+
+def _paged_case(B, W, quantized, N=512, bs=16, M=32, H=H8, KV=KV8, D=D8):
+    from paddle_tpu.ops import paged_attention_pallas as pk
+
+    q = ((B, W, H, D), _BF16)
+    tables, pos = ((B, M), jnp.int32), ((B,), jnp.int32)
+    if not quantized:
+        pool = ((N, bs, KV, D), _BF16)
+        return (lambda q_, k, v, t, p: pk.paged_attention(q_, k, v, t, p),
+                [q, pool, pool, tables, pos])
+    pool, sc = ((N, bs, KV, D), jnp.int8), ((N, KV), jnp.float32)
+    return (lambda q_, k, ks, v, vs, t, p:
+            pk.paged_attention_q(q_, k, ks, v, vs, t, p),
+            [q, pool, sc, pool, sc, tables, pos])
+
+
+def _lora_case(B, S, IN, OUT, R=16):
+    from paddle_tpu.ops.paged_attention_pallas import fused_lora_matmul
+
+    return (fused_lora_matmul,
+            [((B, S, IN), _BF16), ((IN, OUT), _BF16),
+             ((B, IN, R), jnp.float32), ((B, R, OUT), jnp.float32),
+             ((B,), jnp.float32)])
+
+
+def _w8_case(M, K, N):
+    from paddle_tpu.ops.int8 import _w8_matmul_pallas
+
+    return (lambda x, w, s: _w8_matmul_pallas(x, w, s, _BF16),
+            [((M, K), _BF16), ((K, N), jnp.int8), ((N,), jnp.float32)])
+
+
+def kernel_cases():
+    """(name, builder) for every kernel the ``auto`` rule can select at the
+    chip_smoke / tests_tpu shapes."""
+    return [
+        ("flash.train_509m_S2048", lambda: _flash_case(8, 16, 8, 2048, 128)),
+        ("flash.gqa32_8_S2048", lambda: _flash_case(1, H8, KV8, 2048, D8)),
+        ("flash.stream_S16384", lambda: _flash_case(1, 4, 2, 16384, 128)),
+        ("flash.head_dim_64", lambda: _flash_case(4, 4, 2, 512, 64)),
+        ("norm.rms_bf16_16384x2048",
+         lambda: _norm_case(16384, 2048, _BF16)),
+        ("norm.rms_bf16_8192x4096", lambda: _norm_case(8192, HID8, _BF16)),
+        ("norm.rms_f32_8192x4096",
+         lambda: _norm_case(8192, HID8, jnp.float32)),
+        ("norm.rms_bf16_decode_8x4096", lambda: _norm_case(8, HID8, _BF16)),
+        ("norm.rms_bf16_odd_40x4096", lambda: _norm_case(40, HID8, _BF16)),
+        ("norm.ln_f32_8192x4096",
+         lambda: _norm_case(8192, HID8, jnp.float32, "ln")),
+        ("paged.fp_decode_W1", lambda: _paged_case(8, 1, False)),
+        ("paged.fp_verify_W4", lambda: _paged_case(8, 4, False)),
+        ("paged.fp_prefill_C128", lambda: _paged_case(1, 128, False)),
+        ("paged.int8_decode_W1", lambda: _paged_case(8, 1, True, bs=32)),
+        ("paged.int8_verify_W4", lambda: _paged_case(8, 4, True, bs=32)),
+        ("paged.int8_prefill_C128", lambda: _paged_case(1, 128, True, bs=32)),
+        ("lora.decode_4096x4096", lambda: _lora_case(8, 1, HID8, HID8)),
+        ("lora.decode_4096x14336", lambda: _lora_case(8, 1, HID8, FFN8)),
+        ("w8.decode_4096x14336", lambda: _w8_case(8, HID8, FFN8)),
+        ("w8.decode_14336x4096", lambda: _w8_case(8, FFN8, HID8)),
+    ]
+
+
+# ------------------------------------------------------------ program cases
+# Whole programs, through the same entry points chip_smoke.py drives, at
+# Llama-3-8B WIDTHS but two layers deep (layers unroll: depth only multiplies
+# compile time). Each returns (lowered-able jitted fn, abstract args).
+def _abstract(tree, sharding_of):
+    """Every array leaf -> ShapeDtypeStruct placed by ``sharding_of(leaf)``."""
+    return jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, sharding_of(a))
+        if hasattr(a, "shape") else a, tree)
+
+
+def _train_program(devs, mesh_shape, axes, cfg, B, S, **engine_kw):
+    import paddle_tpu as paddle
+    from paddle_tpu.models import LlamaForCausalLM
+    from paddle_tpu.optimizer import AdamW
+    from paddle_tpu.parallel import ParallelEngine
+
+    n = int(np.prod(mesh_shape))
+    mesh = Mesh(np.array(devs[:n]).reshape(mesh_shape), axes)
+    paddle.seed(0)
+    model = LlamaForCausalLM(cfg)
+    opt = AdamW(learning_rate=1e-3, parameters=model.parameters())
+    eng = ParallelEngine(model, optimizer=opt, loss_fn=None, mesh=mesh,
+                         abstract=True, **engine_kw)
+    step = eng.build_train_step()
+    bspec = eng._batch_sharding(np.zeros((B, S)), eng.batch_spec)
+    batch = (_sds((B, S), jnp.int32, bspec), _sds((B, S), jnp.int64, bspec))
+    rep = NamedSharding(mesh, P())
+    return step, (eng.params, eng.opt_state, _sds((), jnp.int32, rep),
+                  1e-3, batch)
+
+
+def _serve_programs(devs, cfg, tp, kernels="auto"):
+    """(decode, prefill, reference-forward) of a paged server."""
+    import paddle_tpu as paddle
+    from paddle_tpu.framework.core import Tensor
+    from paddle_tpu.inference import GenerationServer
+    from paddle_tpu.jit import functional_call
+    from paddle_tpu.models import LlamaForCausalLM
+    from paddle_tpu.parallel import mesh_context
+    from paddle_tpu.parallel import serving_mesh as sm
+
+    paddle.seed(0)
+    model = LlamaForCausalLM(cfg)
+    srv = GenerationServer(model, cache="paged", kernels=kernels,
+                           max_batch=8, max_len=512, block_size=16,
+                           prefill_chunk=128, num_blocks=256,
+                           mesh=None if tp == 1 else f"tp={tp}")
+    if tp == 1:
+        one = SingleDeviceSharding(devs[0])
+        mesh = None
+        params = _abstract(srv.params, lambda a: one)
+        pools = _abstract(srv._pools, lambda a: one)
+
+        def rep(shape, dtype):
+            return _sds(shape, dtype, one)
+    else:
+        mesh = Mesh(np.array(devs[:tp]), (sm.SERVING_TP_AXIS,))
+        specs = sm.serving_param_specs(model, mesh)
+        params = {k: _sds(v.shape, v.dtype,
+                          NamedSharding(mesh, specs.get(k, P())))
+                  for k, v in srv.params.items()}
+        pools = [_sds(v.shape, v.dtype,
+                      NamedSharding(mesh, sm.pool_spec(v.ndim)))
+                 for v in srv._pools]
+
+        def rep(shape, dtype):
+            return _sds(shape, dtype, NamedSharding(mesh, P()))
+
+    B, M = srv.max_batch, srv._bt.shape[1]
+    f32, i32 = jnp.float32, jnp.int32
+    decode_args = (params, rep((B,), i32), pools, rep((B, M), i32),
+                   rep((B,), i32), rep((B,), f32), rep((B,), i32),
+                   rep((B,), f32), rep((B,), i32), rep((2,), jnp.uint32),
+                   None, (), True, None)
+    prefill_args = (params, rep((1, 128), i32), pools, rep((M,), i32),
+                    rep((), i32), rep((), i32), None, ())
+
+    def fwd(p, ids):
+        with (mesh_context(mesh) if mesh is not None
+              else contextlib.nullcontext()):
+            return functional_call(model, p, Tensor(ids)).value
+
+    return [(srv._decode_paged, decode_args),
+            (srv._chunk_prefill, prefill_args),
+            (jax.jit(fwd), (params, rep((1, 512), i32)))]
+
+
+def program_cases(devs):
+    from paddle_tpu.models import LlamaConfig, llama3_8b_config
+
+    proxy = LlamaConfig(vocab_size=32000, hidden_size=2048,
+                        intermediate_size=5632, num_hidden_layers=8,
+                        num_attention_heads=16, num_key_value_heads=8,
+                        max_position_embeddings=2048, dtype="bfloat16")
+
+    def wide(**kw):
+        return llama3_8b_config(num_hidden_layers=2, **kw)
+
+    built = {}          # one model + server per tp, shared by its 3 cases
+
+    def serve(tp, i):
+        def build():
+            if tp not in built:
+                built[tp] = _serve_programs(
+                    devs, wide(max_position_embeddings=512), tp)
+            return built[tp][i]
+        return build
+
+    def megakernel():
+        # the 509M proxy's widths: the stacked weights are closure
+        # constants of the program, 8B widths would embed 3 GB of them
+        from paddle_tpu.ops.select import set_kernel_mode
+
+        cfg = dataclasses.replace(proxy, num_hidden_layers=2,
+                                  max_position_embeddings=512)
+        try:
+            return _serve_programs(devs, cfg, 1, kernels="megakernel")[0]
+        finally:
+            set_kernel_mode("auto")   # the server pinned it process-wide
+
+    return [
+        ("train.1chip_509m_B4_S2048", lambda: _train_program(
+            devs, (1,), ("data",), proxy, 4, 2048)),
+        ("train.2x2_fsdp_8b_width_L2", lambda: _train_program(
+            devs, (2, 2), ("sharding", "tensor"),
+            wide(max_position_embeddings=2048), 4, 2048, fsdp=True,
+            batch_spec=P(("data", "sharding")))),
+        ("serve.1chip_decode", serve(1, 0)),
+        ("serve.1chip_prefill_chunk", serve(1, 1)),
+        ("serve.1chip_reference_forward", serve(1, 2)),
+        ("serve.tp4_decode", serve(4, 0)),
+        ("serve.tp4_prefill_chunk", serve(4, 1)),
+        ("serve.tp4_reference_forward", serve(4, 2)),
+        ("megakernel.1chip_decode", megakernel),
+    ]
+
+
+def run_cases(cases, sharding, only=()):
+    failed = []
+    for name, build in cases:
+        if only and not any(name.startswith(o) for o in only):
+            continue
+        t0 = time.time()
+        try:
+            fn, specs = build()
+            if sharding is None:       # a program case: args are abstract
+                compiled = fn.lower(*specs).compile()
+                n = compiled.as_text().count("tpu_custom_call")
+                mem = compiled.memory_analysis()
+                extra = (f" args={mem.argument_size_in_bytes / 1e9:.2f}GB "
+                         f"temps={mem.temp_size_in_bytes / 1e9:.2f}GB")
+            else:
+                _, n = compile_on(fn, specs, sharding)
+                extra = ""
+            print(f"ok       {name:34s} mosaic_calls={n:<3d} "
+                  f"{time.time() - t0:5.1f}s{extra}", flush=True)
+        except Exception as e:  # noqa: BLE001 — report, then fail the run
+            msg = " ".join(str(e).split())[:300]
+            known = " (known, not repaired)" if name in KNOWN_REFUSALS else ""
+            print(f"REFUSED  {name:34s} {type(e).__name__}: {msg}{known}",
+                  flush=True)
+            if os.environ.get("COMPILE_CHECK_TRACE"):
+                traceback.print_exc()
+            if not known:
+                failed.append(name)
+    return failed
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--only", default="",
+                    help="comma-separated case-name prefixes")
+    ap.add_argument("--programs", action="store_true",
+                    help="also compile whole train/serve programs (minutes)")
+    args = ap.parse_args()
+    only = tuple(o for o in args.only.split(",") if o)
+
+    from paddle_tpu.ops.select import target_platform
+
+    devs = target_devices()
+    print(f"target: {TOPOLOGY} device_kind={devs[0].device_kind!r} "
+          f"devices={len(devs)}")
+    with target_platform("tpu"):
+        failed = run_cases(kernel_cases(), SingleDeviceSharding(devs[0]),
+                           only)
+        if args.programs:
+            failed += run_cases(program_cases(devs), None, only)
+    if failed:
+        print(f"{len(failed)} case(s) refused: {', '.join(failed)}")
+        return 1
+    print("all cases compile")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
