@@ -480,12 +480,12 @@ class GenerationServer:
 
         from .telemetry import ServingTelemetry
 
+        # a facade built here shares the clock of the request marks, so
+        # request_metrics() lays over the spans by construction
         if telemetry is None or telemetry is False:
-            self._tel = ServingTelemetry(enabled=False) if clock is None \
-                else ServingTelemetry(enabled=False, clock=clock)
+            self._tel = ServingTelemetry(enabled=False, clock=self._wall)
         elif telemetry is True:
-            self._tel = ServingTelemetry(enabled=True) if clock is None \
-                else ServingTelemetry(enabled=True, clock=clock)
+            self._tel = ServingTelemetry(enabled=True, clock=self._wall)
         elif isinstance(telemetry, ServingTelemetry):
             self._tel = telemetry
         else:
@@ -539,6 +539,26 @@ class GenerationServer:
         self._c_degrade = reg.counter(
             "serving_degrade_events",
             "watchdog-driven degradation responses (kind label)")
+        # work done, counted where it is done (telemetry on or off)
+        self._c_tokens = reg.counter(
+            "serving_tokens_emitted",
+            "output tokens folded into requests, first tokens included")
+        self._c_dec_rows = reg.counter(
+            "serving_decode_rows",
+            "decode row-ticks whose token was folded into a request")
+        self._c_dec_ctx = reg.counter(
+            "serving_decode_ctx", "positions attended by those row-ticks")
+        self._c_pf_tokens = reg.counter(
+            "serving_prefill_tokens",
+            "prompt tokens run through chunked prefill (no padding)")
+        self._c_pf_chunks = reg.counter(
+            "serving_prefill_chunks", "prefill chunk programs dispatched")
+        self._c_pf_ctx = reg.counter(
+            "serving_prefill_ctx",
+            "positions attended by those tokens under the causal mask")
+        # flight-record seq of the tick in progress (0 with telemetry off):
+        # what every engine-row phase names as its parent
+        self._tick_seq = 0
         # program key of the last paged trip, recorded per tick by the
         # flight recorder; the watchdog keys recompile excusal on it
         self._last_prog = "idle"
@@ -608,7 +628,6 @@ class GenerationServer:
             # a fresh chunked prefill — the denominator's third leg in
             # the benchmark's tier_hit_rate
             self._cold_refills = 0
-            self._prefill_tokens = 0
             self._prefill_wall_s = 0.0
             if self._faults is not NULL_INJECTOR:
                 # thread the injector through the paged components (even
@@ -704,6 +723,13 @@ class GenerationServer:
     @property
     def _pool_stride(self) -> int:
         return self._exec.pool_stride
+
+    @property
+    def _prefill_tokens(self) -> int:
+        """Real prompt tokens prefilled so far (``serving_prefill_tokens``
+        — the numerator of ``tools/serving_benchmark.py``'s prefill
+        throughput)."""
+        return int(self._c_pf_tokens.total())
 
     def _lora_flat(self):
         """Current adapter-pool tensors for a compiled-program call — ()
@@ -924,13 +950,17 @@ class GenerationServer:
         semantics match; one host sync per assignment. Greedy requests
         skip the eager sampling-op chain (fold_in + filtering, ~1ms of
         dispatch per admission) for a host argmax — same token."""
+        tel, tick = self._tel, self._tick_seq
         if req.temperature == 0.0:
-            return int(np.argmax(np.asarray(lg[0])))
+            with tel.phase("first_token_wait", tick, rid=req.rid):
+                row = np.asarray(lg[0])
+            return int(np.argmax(row))
         from ..models.generation import next_token
 
         key = jax.random.fold_in(self._base_key, (req.rid << 20) | 1)
         nxt, _ = next_token(lg, key, req.temperature, req.top_k, req.top_p)
-        return int(nxt[0])
+        with tel.phase("first_token_wait", tick, rid=req.rid):
+            return int(nxt[0])
 
     def _activate_slot(self, slot: int, req: _Request, first: int) -> None:
         """Move a freshly-prefilled request into the decode phase."""
@@ -945,12 +975,19 @@ class GenerationServer:
         if self.cache_mode == "paged":
             self._samp_dev = None
         req.generated.append(first)
+        self._c_tokens.inc()
+        t = self._wall()
         m = self._req_metrics.get(req.rid)
         if m is not None:
-            m.setdefault("first_token_t", self._wall())
-        if self._tel.enabled:
-            self._tel.tracer.end(req.rid, "prefill")
-            self._tel.tracer.instant(req.rid, "first_token")
+            m.setdefault("first_token_t", t)
+        tel = self._tel
+        if tel.enabled:
+            tr = tel.tracer
+            tr.end(req.rid, "prefill")
+            # the mark's own reading where mark and span share a clock
+            tr.instant(req.rid, "first_token",
+                       at=t if tel.clock is self._wall else None)
+            tr.begin(req.rid, "decode")
 
     def _samp_arrays(self):
         """Device copies of the per-slot sampling params (+ draft caps and
@@ -983,12 +1020,14 @@ class GenerationServer:
         self._activate_slot(slot, req, self._first_token(req, lg))
         self._slots[slot] = req
 
-    def _fill_free_slots(self) -> None:
+    def _fill_free_slots(self) -> int:
         """Admit waiting requests into free slots in scheduler-policy
-        order. Paged admission is gated on block headroom, with NO
-        head-of-line bypass: skipping an inadmissible head for a smaller,
-        later entry could starve the head forever — and strict order is
-        safe because a draining pool always reopens headroom."""
+        order; returns how many it admitted. Paged admission is gated on
+        block headroom, with NO head-of-line bypass: skipping an
+        inadmissible head for a smaller, later entry could starve the
+        head forever — and strict order is safe because a draining pool
+        always reopens headroom."""
+        admitted = 0
         for s in range(self.max_batch):
             if self._slots[s] is not None:
                 continue
@@ -1009,13 +1048,15 @@ class GenerationServer:
                 self._admit_paged(s, ent.req)
             else:
                 self._assign(s, ent.req)
+            admitted += 1
+        return admitted
 
-    def _service_queue(self) -> None:
+    def _service_queue(self) -> int:
         """Queue maintenance at the top of every step: expire TTL'd
         waiters, fill free slots in policy order, then — paged only — if
         a strictly-more-urgent entry is stuck behind a full batch,
         preempt the least-urgent running request for it (one victim per
-        step bounds preemption churn)."""
+        step bounds preemption churn). Returns the admissions made."""
         for ent in self._sched.expire():
             self._drop_entry(ent, "expired")
         if self._lora is not None:
@@ -1024,14 +1065,15 @@ class GenerationServer:
             # most-recently-used and so evict LAST — WFQ shares govern
             # adapter residency, not just slot admission
             self._lora.warm(self._sched.adapter_demand())
-        self._fill_free_slots()
+        admitted = self._fill_free_slots()
         if self.cache_mode != "paged":
-            return
+            return admitted
         ent = self._sched.peek()
         if ent is not None and all(sl is not None for sl in self._slots):
             v = self._pick_victim(ent.priority)
             if v is not None and self._preempt_slot(v):
-                self._fill_free_slots()
+                admitted += self._fill_free_slots()
+        return admitted
 
     def _drop_entry(self, ent: SchedEntry, reason: str) -> None:
         """A queued entry leaves without finishing: record why, stamp its
@@ -1208,6 +1250,7 @@ class GenerationServer:
         self._c_resumes.inc()
         if self._tel.enabled:
             self._tel.tracer.end(req.rid, "preempted", resumed=True)
+            self._tel.tracer.begin(req.rid, "decode", resumed=True)
         return True
 
     def _pick_victim(self, than_priority: int,
@@ -1269,6 +1312,7 @@ class GenerationServer:
             if self._tel.enabled:
                 # spans the time parked on host; swap_out/swap_in spans
                 # come from the offload engine itself
+                self._tel.tracer.end(req.rid, "decode", preempted=True)
                 self._tel.tracer.begin(req.rid, "preempted",
                                        blocks=handle.n_blocks)
         self._slots[s] = None
@@ -1341,11 +1385,12 @@ class GenerationServer:
             self._stall_streak = 0
         return out
 
-    def _prefill_chunk_step(self, slot: int) -> None:
+    def _prefill_chunk_step(self, slot: int) -> int:
         """Advance one prompt chunk for a prefilling slot; on the final
         chunk, sample the first token and flip the slot to decoding (a
         corruption-recovery replay instead resumes at its saved
-        position — nothing new is sampled)."""
+        position — nothing new is sampled). Returns the chunk programs
+        dispatched (0 when the slot stalled or yielded)."""
         req = self._slots[slot]
         seq = req.replay if req.replay is not None else req.prompt
         n = len(seq)
@@ -1354,7 +1399,7 @@ class GenerationServer:
         start = req.pf_next
         end = min(start + C, n)
         if self._reserve_or_preempt(slot, -(-end // bs)) != "ok":
-            return      # aborted as its own victim, or stalled — no chunk
+            return 0    # aborted as its own victim, or stalled — no chunk
         chunk = np.zeros((1, C), np.int32)
         chunk[0, :end - start] = seq[start:end]
         last_idx = (n - 1 - start) if end == n else 0
@@ -1369,11 +1414,18 @@ class GenerationServer:
             jnp.int32(last_idx), aidx, self._lora_flat())
         # per-chip prefill throughput ledger (tools/serving_benchmark.py
         # divides by tp*cp): real prompt tokens only, not chunk padding
-        self._prefill_tokens += end - start
+        m = end - start
+        self._c_pf_tokens.inc(m)
+        self._c_pf_chunks.inc()
+        # token p of the chunk attends positions 0..p: start+1 .. end
+        self._c_pf_ctx.inc(m * start + m * (m + 1) // 2)
         self._prefill_wall_s += self._wall() - _w0
         if tel.enabled:
+            # dispatch is asynchronous: the span ends when the call
+            # returns, not when the chunk has run
             tel.tracer.complete(req.rid, "prefill_chunk", _t0, tel.clock(),
-                                start=start, tokens=end - start)
+                                start=start, tokens=m, tick=self._tick_seq,
+                                dispatch_only=True)
         # publish the prompt blocks this chunk completed for prefix reuse
         # (a freshly prefilled hash supersedes any stale warm copy)
         for i in range(start // bs, end // bs):
@@ -1393,6 +1445,7 @@ class GenerationServer:
                 # handoff instead of decoding here (replays park too:
                 # their decode phase belongs to the decode class)
                 self._handoff.add(req.rid)
+        return 1
 
     def _activate_replayed(self, slot: int, req: _Request) -> None:
         """Flip a corruption-recovery replay straight back to decoding.
@@ -1417,6 +1470,7 @@ class GenerationServer:
         self._samp_dev = None
         if self._tel.enabled:
             self._tel.tracer.end(req.rid, "prefill", replayed=True)
+            self._tel.tracer.begin(req.rid, "decode", replayed=True)
 
     def _all_greedy(self, rows) -> bool:
         """True iff every listed slot decodes at temperature 0 — the
@@ -1435,41 +1489,55 @@ class GenerationServer:
         from ..analysis.recompile_guard import compile_count
 
         a = self.alloc
-        t0 = tel.clock()
-        c0 = compile_count()
-        pre = (self._preemptions, self._prefill_aborts, self._resumes,
-               self._stalls, a.fresh_allocs, a.evictions,
-               a.swap_out_blocks, a.swap_in_blocks,
-               a.demoted_blocks, a.promoted_blocks)
-        sp0, sa0 = ((self._spec_proposed, self._spec_accepted)
-                    if self.spec is not None else (0, 0))
-        remaining = self._step_paged_inner()
-        rec = {
-            "t_wall_s": tel.clock() - t0,
-            "prog": self._last_prog,
-            "decoding": sum(1 for s in range(self.max_batch)
-                            if self._slots[s] is not None
-                            and not self._prefilling[s]),
-            "prefilling": sum(1 for s in range(self.max_batch)
-                              if self._prefilling[s]),
-            "queue_depth": len(self._sched),
-            "blocks_in_use": a.blocks_in_use,
-            "blocks_allocated": a.fresh_allocs - pre[4],
-            "evictions": a.evictions - pre[5],
-            "preemptions": self._preemptions - pre[0],
-            "prefill_aborts": self._prefill_aborts - pre[1],
-            "resumes": self._resumes - pre[2],
-            "stalls": self._stalls - pre[3],
-            "swap_out_blocks": a.swap_out_blocks - pre[6],
-            "swap_in_blocks": a.swap_in_blocks - pre[7],
-            "swap_bytes": (a.swap_out_blocks - pre[6]
-                           + a.swap_in_blocks - pre[7]) * a.bytes_per_block,
-            "host_bytes": self._offload.host.bytes_in_use,
-            "demotions": a.demoted_blocks - pre[8],
-            "promotions": a.promoted_blocks - pre[9],
-            "warm_bytes": self._offload.warm.bytes_in_use,
-            "recompiles": compile_count() - c0,
-        }
+        seq = self._tick_seq = tel.flight.total
+        with tel.phase("tick", seq) as ph:
+            c0 = compile_count()
+            w0 = tel.wait_s
+            pre = (self._preemptions, self._prefill_aborts, self._resumes,
+                   self._stalls, a.fresh_allocs, a.evictions,
+                   a.swap_out_blocks, a.swap_in_blocks,
+                   a.demoted_blocks, a.promoted_blocks)
+            chunks0, tokens0 = (self._c_pf_chunks.total(),
+                                self._c_tokens.total())
+            sp0, sa0 = ((self._spec_proposed, self._spec_accepted)
+                        if self.spec is not None else (0, 0))
+            remaining = self._step_paged_inner()
+            rec = {
+                "prog": self._last_prog,
+                "decoding": sum(1 for s in range(self.max_batch)
+                                if self._slots[s] is not None
+                                and not self._prefilling[s]),
+                "prefilling": sum(1 for s in range(self.max_batch)
+                                  if self._prefilling[s]),
+                "queue_depth": len(self._sched),
+                "blocks_in_use": a.blocks_in_use,
+                "blocks_allocated": a.fresh_allocs - pre[4],
+                "evictions": a.evictions - pre[5],
+                "preemptions": self._preemptions - pre[0],
+                "prefill_aborts": self._prefill_aborts - pre[1],
+                "resumes": self._resumes - pre[2],
+                "stalls": self._stalls - pre[3],
+                "swap_out_blocks": a.swap_out_blocks - pre[6],
+                "swap_in_blocks": a.swap_in_blocks - pre[7],
+                "swap_bytes": (a.swap_out_blocks - pre[6]
+                               + a.swap_in_blocks - pre[7])
+                * a.bytes_per_block,
+                "host_bytes": self._offload.host.bytes_in_use,
+                "demotions": a.demoted_blocks - pre[8],
+                "promotions": a.promoted_blocks - pre[9],
+                "warm_bytes": self._offload.warm.bytes_in_use,
+                "recompiles": compile_count() - c0,
+            }
+            ph.note(prog=rec["prog"], decoding=rec["decoding"],
+                    prefilling=rec["prefilling"],
+                    queue_depth=rec["queue_depth"],
+                    chunks=int(self._c_pf_chunks.total() - chunks0),
+                    tokens=int(self._c_tokens.total() - tokens0))
+        rec["t_wall_s"] = ph.dur
+        # the part of the tick the host spent waiting for the device
+        # (decode_wait + first_token_wait): a slow tick with a small
+        # wait_s is the host's, one with a large wait_s the device's
+        rec["wait_s"] = tel.wait_s - w0
         if self.spec is not None:
             rec["spec_proposed"] = self._spec_proposed - sp0
             rec["spec_accepted"] = self._spec_accepted - sa0
@@ -1493,21 +1561,26 @@ class GenerationServer:
         return remaining
 
     def _step_paged_inner(self) -> int:
-        tel_on = self._tel.enabled
+        tel, tick = self._tel, self._tick_seq
+        tel_on = tel.enabled
         if tel_on:
             self._last_prog = "idle"
         # demote BEFORE admission: freed blocks feed _service_queue's
         # headroom gate this same tick
-        self._maybe_demote()
-        self._service_queue()
+        with tel.phase("admit", tick) as ph:
+            self._maybe_demote()
+            ph.note(admitted=self._service_queue())
         # chunked prefill interleaves with decode: ONE chunk per prefilling
         # slot per step, so a long prompt never blocks slots mid-decode
         # (no head-of-line blocking) and short requests keep streaming out
         did_prefill = False
-        for s in range(self.max_batch):
-            if self._slots[s] is not None and self._prefilling[s]:
-                self._prefill_chunk_step(s)
-                did_prefill = True
+        with tel.phase("prefill", tick) as ph:
+            chunks = 0
+            for s in range(self.max_batch):
+                if self._slots[s] is not None and self._prefilling[s]:
+                    chunks += self._prefill_chunk_step(s)
+                    did_prefill = True
+            ph.note(chunks=chunks)
         active = [s for s in range(self.max_batch)
                   if self._slots[s] is not None and not self._prefilling[s]
                   and self._slots[s].rid not in self._handoff]
@@ -1677,32 +1750,29 @@ class GenerationServer:
             # static jit-cache axes of the plain decode program
             self._last_prog = (f"plain:t{'w' if ticks is None else ticks}"
                                f":g{int(self._all_greedy(active))}")
-            _t0 = tel.clock()
-            _rids = [self._slots[s].rid for s in active]
-        # the greedy-specialized programs never read the key — skip the
-        # per-step eager fold_in dispatch (~0.4ms) for it
-        key = (self._base_key if self._all_greedy(active)
-               else jax.random.fold_in(self._base_key, self._step_no))
-        active_mask = np.zeros((self.max_batch,), np.int32)
-        active_mask[active] = 1
-        # idle/prefilling rows run masked: zeroed table + pos 0 routes
-        # their (discarded) cache writes to the scratch block
-        bt = np.where(active_mask[:, None] > 0, self._bt, 0)
-        posv = self.pos * active_mask
-        temps, topks, topps, _, aidx = self._samp_arrays()
-        stack, self._pools = self._decode_paged(
-            self.params, jnp.asarray(self.tokens), self._pools,
-            jnp.asarray(bt), jnp.asarray(posv), temps, topks, topps,
-            jnp.asarray(active_mask), key, aidx, self._lora_flat(),
-            self._all_greedy(active), ticks)
-        self._harvest_window(np.asarray(stack), active, active_mask)
-        if tel.enabled:
-            # retroactive: one shared device trip advanced every listed
-            # row, so each request gets the same-walled span (the host
-            # sync happened inside the harvest's np.asarray)
-            _t1 = tel.clock()
-            for rid in _rids:
-                tel.tracer.complete(rid, "decode_window", _t0, _t1, ticks=k)
+        tick, rows = self._tick_seq, len(active)
+        with tel.phase("decode_dispatch", tick, rows=rows):
+            # the greedy-specialized programs never read the key — skip
+            # the per-step eager fold_in dispatch (~0.4ms) for it
+            key = (self._base_key if self._all_greedy(active)
+                   else jax.random.fold_in(self._base_key, self._step_no))
+            active_mask = np.zeros((self.max_batch,), np.int32)
+            active_mask[active] = 1
+            # idle/prefilling rows run masked: zeroed table + pos 0 routes
+            # their (discarded) cache writes to the scratch block
+            bt = np.where(active_mask[:, None] > 0, self._bt, 0)
+            posv = self.pos * active_mask
+            temps, topks, topps, _, aidx = self._samp_arrays()
+            stack, self._pools = self._decode_paged(
+                self.params, jnp.asarray(self.tokens), self._pools,
+                jnp.asarray(bt), jnp.asarray(posv), temps, topks, topps,
+                jnp.asarray(active_mask), key, aidx, self._lora_flat(),
+                self._all_greedy(active), ticks)
+        # the trip's one host sync, apart from the fold that follows it
+        with tel.phase("decode_wait", tick, rows=rows):
+            nxt_host = np.asarray(stack)
+        self._harvest_phase(len(active), self._harvest_window, nxt_host,
+                            active, active_mask)
 
     # ----------------------------------------------------------- speculative
     def _spec_tick(self, active) -> None:
@@ -1740,45 +1810,49 @@ class GenerationServer:
             _t0 = tel.clock()
             _rids = [(s, self._slots[s].rid) for s in active]
             _kc = {s: int(self.kcaps[s]) for s in active}
-        key = (self._base_key if self._all_greedy(active)
-               else jax.random.fold_in(self._base_key, self._step_no))
-        active_mask = np.zeros((self.max_batch,), np.int32)
-        active_mask[active] = 1
-        bt = np.where(active_mask[:, None] > 0, self._bt, 0)
-        posv = self.pos * active_mask
-        # nonzero kcaps exist only on activated, unreleased slots — exactly
-        # the active set — so the cached device kcaps already carries the
-        # idle/prefilling row masking
-        temps, topks, topps, kcaps, aidx = self._samp_arrays()
-        if self._spec_fused:
-            ctx = np.zeros((self.max_batch, self.max_len), np.int32)
-            for s in active:
-                req = self._slots[s]
-                toks = req.prompt + req.generated
-                ctx[s, :len(toks)] = toks
-            outs, accs, self._pools = self._spec_scan(
-                self.params, jnp.asarray(ctx), self._pools,
-                jnp.asarray(bt), jnp.asarray(posv), temps, topks, topps,
-                kcaps, jnp.asarray(active_mask), key, aidx,
-                self._lora_flat(), self._all_greedy(active), S)
-        else:
-            contexts: List[Optional[List[int]]] = [None] * self.max_batch
-            for s in active:
-                req = self._slots[s]
-                contexts[s] = req.prompt + req.generated
-            proposals, qprobs = self.drafter.propose(
-                contexts, k, temps=self.temps,
-                key=jax.random.fold_in(key, 1))
-            out, acc, self._pools = self._spec_verify(
-                self.params, jnp.asarray(self.tokens),
-                jnp.asarray(proposals), self._pools, jnp.asarray(bt),
-                jnp.asarray(posv), temps, topks, topps,
-                kcaps, jax.random.fold_in(key, 2),
-                None if qprobs is None else jnp.asarray(qprobs),
-                aidx, self._lora_flat(), self._all_greedy(active))
-            outs, accs = np.asarray(out)[None], np.asarray(acc)[None]
-        accs = np.asarray(accs)
-        self._harvest_spec(np.asarray(outs), accs, active)
+        tick, rows = self._tick_seq, len(active)
+        with tel.phase("decode_dispatch", tick, rows=rows):
+            key = (self._base_key if self._all_greedy(active)
+                   else jax.random.fold_in(self._base_key, self._step_no))
+            active_mask = np.zeros((self.max_batch,), np.int32)
+            active_mask[active] = 1
+            bt = np.where(active_mask[:, None] > 0, self._bt, 0)
+            posv = self.pos * active_mask
+            # nonzero kcaps exist only on activated, unreleased slots —
+            # exactly the active set — so the cached device kcaps already
+            # carries the idle/prefilling row masking
+            temps, topks, topps, kcaps, aidx = self._samp_arrays()
+            if self._spec_fused:
+                ctx = np.zeros((self.max_batch, self.max_len), np.int32)
+                for s in active:
+                    req = self._slots[s]
+                    toks = req.prompt + req.generated
+                    ctx[s, :len(toks)] = toks
+                outs, accs, self._pools = self._spec_scan(
+                    self.params, jnp.asarray(ctx), self._pools,
+                    jnp.asarray(bt), jnp.asarray(posv), temps, topks, topps,
+                    kcaps, jnp.asarray(active_mask), key, aidx,
+                    self._lora_flat(), self._all_greedy(active), S)
+            else:
+                contexts: List[Optional[List[int]]] = [None] * self.max_batch
+                for s in active:
+                    req = self._slots[s]
+                    contexts[s] = req.prompt + req.generated
+                proposals, qprobs = self.drafter.propose(
+                    contexts, k, temps=self.temps,
+                    key=jax.random.fold_in(key, 1))
+                outs, accs, self._pools = self._spec_verify(
+                    self.params, jnp.asarray(self.tokens),
+                    jnp.asarray(proposals), self._pools, jnp.asarray(bt),
+                    jnp.asarray(posv), temps, topks, topps,
+                    kcaps, jax.random.fold_in(key, 2),
+                    None if qprobs is None else jnp.asarray(qprobs),
+                    aidx, self._lora_flat(), self._all_greedy(active))
+        with tel.phase("decode_wait", tick, rows=rows):
+            outs, accs = np.asarray(outs), np.asarray(accs)
+            if not self._spec_fused:
+                outs, accs = outs[None], accs[None]   # one window a trip
+        self._harvest_phase(rows, self._harvest_spec, outs, accs, active)
         if tel.enabled:
             _t1 = tel.clock()
             for s, rid in _rids:
@@ -1815,10 +1889,11 @@ class GenerationServer:
         rejected positions' stale K/V is overwritten by the next window
         before any query can attend it)."""
         S = outs.shape[0]
+        rows = ctx = 0          # work folded, as in _harvest_window
         for s in active:
             req = self._slots[s]
             kcap = int(self.kcaps[s])
-            new_pos = int(self.pos[s])
+            pos0 = new_pos = int(self.pos[s])
             last_tok = int(self.tokens[s])
             done = False
             if self.eos is None:
@@ -1842,6 +1917,9 @@ class GenerationServer:
                     new_pos += take
                     if done:
                         break
+                d = new_pos - pos0
+                rows += d
+                ctx += d * pos0 + d * (d + 1) // 2
                 if done:
                     self._emit_result(req)
                     self._release_slot(s)
@@ -1851,6 +1929,7 @@ class GenerationServer:
                     req.table = self.alloc.truncate(req.table, new_pos)
                     self._bt[s, len(req.table):] = 0
                 continue
+            n0 = len(req.generated)
             for w in range(S):
                 a = int(accs[w, s])
                 self._spec_proposed += kcap
@@ -1871,6 +1950,9 @@ class GenerationServer:
                     break
                 new_pos += a + 1
                 last_tok = int(outs[w, s, a])
+            d = len(req.generated) - n0
+            rows += d
+            ctx += d * pos0 + d * (d + 1) // 2
             if done:
                 self._emit_result(req)
                 self._release_slot(s)
@@ -1879,6 +1961,9 @@ class GenerationServer:
                 self.tokens[s] = last_tok
                 req.table = self.alloc.truncate(req.table, new_pos)
                 self._bt[s, len(req.table):] = 0
+        self._c_tokens.inc(rows)
+        self._c_dec_rows.inc(rows)
+        self._c_dec_ctx.inc(ctx)
 
     def spec_metrics(self) -> Dict[str, float]:
         """Draft/accept counters for the speculative path (empty when
@@ -2719,6 +2804,14 @@ class GenerationServer:
         return self._tel.export_chrome_trace(path)
 
     # ------------------------------------------------------------- stepping
+    def _harvest_phase(self, rows: int, harvest, *args) -> None:
+        """Run ``harvest(*args)`` — the fold of a trip's host arrays into
+        the requests — as the tick's ``harvest`` phase."""
+        with self._tel.phase("harvest", self._tick_seq, rows=rows) as ph:
+            done0 = len(self._results)
+            harvest(*args)
+            ph.note(finished=len(self._results) - done0)
+
     def _harvest_window(self, nxt_host, active, active_mask) -> None:
         """Fold one decode window's (k, B) token stack into the per-request
         state: append tokens, detect eos/max-new/max-len completion (window
@@ -2729,6 +2822,12 @@ class GenerationServer:
         self.tokens = np.where(active_mask > 0, nxt_host[-1],
                                self.tokens).astype(np.int32)
         pos_after = self.pos
+        # work folded, for the counters: a row at position p emits its
+        # tokens at contexts p+1 .. p+k; what a finished row leaves of the
+        # window is taken off again below
+        rows = k * len(active)
+        ctx = k * int(pos_after[active].sum()) \
+            - len(active) * (k * (k - 1) // 2)
         for s in active:
             req = self._slots[s]
             done = False
@@ -2744,25 +2843,30 @@ class GenerationServer:
                     done = True
                 # nxt_host is host numpy — the window's one sync is done
                 gen.extend(nxt_host[:take, s].tolist())  # graftlint: noqa[host-sync]
-                if done:
-                    self._emit_result(req)
-                    self._release_slot(s)
-                continue
-            for t in range(k):
-                tok = int(nxt_host[t, s])
-                finished_last = (self.eos is not None and
-                                 req.generated[-1] == self.eos)
-                if not finished_last:
-                    req.generated.append(tok)
-                pos_t = int(pos_after[s]) - k + t + 1
-                if (finished_last
-                        or len(req.generated) >= req.max_new_tokens
-                        or pos_t >= self.max_len - 1):
-                    done = True
-                    break
+            else:
+                take = 0
+                for t in range(k):
+                    tok = int(nxt_host[t, s])
+                    finished_last = req.generated[-1] == self.eos
+                    if not finished_last:
+                        req.generated.append(tok)
+                        take += 1
+                    pos_t = int(pos_after[s]) - k + t + 1
+                    if (finished_last
+                            or len(req.generated) >= req.max_new_tokens
+                            or pos_t >= self.max_len - 1):
+                        done = True
+                        break
             if done:
+                cut = k - max(take, 0)
+                if cut:
+                    rows -= cut
+                    ctx -= cut * int(pos_after[s]) - cut * (cut - 1) // 2
                 self._emit_result(req)
                 self._release_slot(s)
+        self._c_tokens.inc(rows)
+        self._c_dec_rows.inc(rows)
+        self._c_dec_ctx.inc(ctx)
 
     def step(self) -> int:
         """One server step: admit queued requests, advance one prefill
@@ -2772,39 +2876,50 @@ class GenerationServer:
         if self.cache_mode == "paged":
             return self._step_paged()
         tel = self._tel
-        if tel.enabled:
-            from ..analysis.recompile_guard import compile_count
-            _tt0 = tel.clock()
-            _c0 = compile_count()
+        if not tel.enabled:
+            return self._step_dense_inner()
+        from ..analysis.recompile_guard import compile_count
+
+        seq = self._tick_seq = tel.flight.total
+        with tel.phase("tick", seq) as ph:
+            c0 = compile_count()
+            w0, tok0 = tel.wait_s, self._c_tokens.total()
+            self._last_prog = "idle"
+            remaining = self._step_dense_inner()
+            rec = {"prog": self._last_prog,
+                   "decoding": sum(sl is not None for sl in self._slots),
+                   "queue_depth": len(self._sched),
+                   "recompiles": compile_count() - c0}
+            ph.note(tokens=int(self._c_tokens.total() - tok0), **rec)
+        tel.flight.record(t_wall_s=ph.dur, wait_s=tel.wait_s - w0, **rec)
+        return remaining
+
+    def _step_dense_inner(self) -> int:
+        tel, tick = self._tel, self._tick_seq
         self._service_queue()
         active = [s for s in range(self.max_batch)
                   if self._slots[s] is not None]
         if not active:
             return 0
         self._step_no += 1
-        key = jax.random.fold_in(self._base_key, self._step_no)
-        active_mask = np.zeros((self.max_batch,), np.int32)
-        active_mask[active] = 1
         if tel.enabled:
-            _t0 = tel.clock()
-            _rids = [self._slots[s].rid for s in active]
-        # only occupied slots advance — idle slots must not drift their
-        # write position (their garbage scatters would eventually go OOB)
-        stack, self._caches = self._decode(
-            self.params, jnp.asarray(self.tokens), self._caches,
-            jnp.asarray(self.pos), jnp.asarray(self.temps),
-            jnp.asarray(self.topks), jnp.asarray(self.topps),
-            jnp.asarray(active_mask), key)
-        self._harvest_window(np.asarray(stack), active, active_mask)
-        if tel.enabled:
-            _t1 = tel.clock()
-            for rid in _rids:
-                tel.tracer.complete(rid, "decode_window", _t0, _t1,
-                                    ticks=self.tick_window)
-            tel.flight.record(t_wall_s=_t1 - _tt0, prog="dense",
-                              decoding=len(active),
-                              queue_depth=len(self._sched),
-                              recompiles=compile_count() - _c0)
+            self._last_prog = "dense"
+        with tel.phase("decode_dispatch", tick, rows=len(active)):
+            key = jax.random.fold_in(self._base_key, self._step_no)
+            active_mask = np.zeros((self.max_batch,), np.int32)
+            active_mask[active] = 1
+            # only occupied slots advance — idle slots must not drift
+            # their write position (their garbage scatters would
+            # eventually go OOB)
+            stack, self._caches = self._decode(
+                self.params, jnp.asarray(self.tokens), self._caches,
+                jnp.asarray(self.pos), jnp.asarray(self.temps),
+                jnp.asarray(self.topks), jnp.asarray(self.topps),
+                jnp.asarray(active_mask), key)
+        with tel.phase("decode_wait", tick, rows=len(active)):
+            nxt_host = np.asarray(stack)
+        self._harvest_phase(len(active), self._harvest_window, nxt_host,
+                            active, active_mask)
         return sum(sl is not None for sl in self._slots) + len(self._sched)
 
     def run(self) -> Dict[int, List[int]]:

@@ -446,7 +446,7 @@ class LlamaAttention(Layer):
                 # the inverse transposes fold into the o-proj/vjp dots) —
                 # at S=16k the standalone (B,S,H,D)<->(B,H,S,D) copies
                 # around the custom call were ~33% of the step (r5 per-op
-                # profile, tools/profile_step.py)
+                # profile)
                 qh = _apply_rope_bhsd(jnp.swapaxes(qv, 1, 2), cv, sv,
                                       pos_offset)
                 kh = _apply_rope_bhsd(jnp.swapaxes(kv, 1, 2), cv, sv,

@@ -20,12 +20,15 @@ Serving tier (facade: :class:`ServingTelemetry`, held by
   ``sched_metrics()``); only spans and the flight recorder gate on
   ``enabled``.
 - :class:`SpanTracer` — per-request lifecycle spans (queued → prefill
-  chunks → decode/spec windows → preempt/swap-out/swap-in → complete/
-  cancel/expire) dumped as chrome-trace JSON, one timeline row per
-  request. Completed spans are also forwarded to the host profiler's
-  event recorder whenever a ``paddle_tpu.profiler.Profiler`` is
-  recording, so serving timelines land in the SAME ``export()`` trace as
-  the op-level ``RecordEvent`` spans.
+  chunks → decode → preempt/swap-out/swap-in → complete/cancel/expire)
+  dumped as chrome-trace JSON, one timeline row per request, plus the
+  reserved :data:`ENGINE_RID` row with the phases of every engine tick
+  (:meth:`ServingTelemetry.phase`; each phase is also a ``pt.<name>``
+  ``jax.profiler.TraceAnnotation``, which puts it on the clock of the
+  device trace). Completed spans are also forwarded to the host
+  profiler's event recorder whenever a ``paddle_tpu.profiler.Profiler``
+  is recording, so serving timelines land in the SAME ``export()`` trace
+  as the op-level ``RecordEvent`` spans.
 - :class:`FlightRecorder` — fixed-size ring of per-tick records (batch
   occupancy, program key, block/swap deltas, preemptions, spec
   acceptance, backend-compile deltas, wall time) with :func:`watchdog`
@@ -65,10 +68,12 @@ immediately. The engine goes one further: ``telemetry=None`` (the
 default) skips even the timestamp reads and the per-step
 ``block_until_ready``.
 
-Determinism: registry and tracer take an injectable ``clock`` (default
-``time.perf_counter`` — the same base the profiler's ``RecordEvent``
-uses, so forwarded spans share its timeline), mirroring
-``Scheduler(clock=)``.
+Clocks: registry and tracer take an injectable ``clock``, mirroring
+``Scheduler(clock=)``. A ``GenerationServer`` that builds its own facade
+hands it the clock of its request marks (``time.monotonic`` unless one
+is injected), so ``request_metrics()`` and the spans are one clock by
+construction. A facade built on its own defaults to
+``time.perf_counter``, the base the profiler's ``RecordEvent`` uses.
 """
 from __future__ import annotations
 
@@ -77,11 +82,14 @@ import os
 import time
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
+import jax
 import numpy as np
+
+from . import profiler as _profiler
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
            "SpanTracer", "FlightRecorder", "ServingTelemetry", "watchdog",
-           "DEFAULT_BUCKETS", "TRAIN_RID", "GoodputLedger",
+           "DEFAULT_BUCKETS", "TRAIN_RID", "ENGINE_RID", "GoodputLedger",
            "TrainTelemetry", "train_watchdog"]
 
 # generic latency-ish bucket ladder (seconds); histograms can override
@@ -94,11 +102,19 @@ DEFAULT_BUCKETS: Tuple[float, ...] = (
 # process that both trains and serves shows the step loop and the
 # request lifecycles on one timeline.
 TRAIN_RID = -1
+# reserved rid for the serving engine itself: the phases of every tick
+# (ServingTelemetry.phase), one row below the train loop's
+ENGINE_RID = -2
+_ROW_NAMES = {TRAIN_RID: "train loop", ENGINE_RID: "engine"}
+# engine-row phases in which the host does nothing but wait for the device
+_WAIT_PHASES = frozenset(("decode_wait", "first_token_wait"))
 
 
 def _lkey(labels: Dict[str, Any]) -> Tuple[Tuple[str, str], ...]:
     """Canonical hashable key for a label set (values coerced to str —
     Prometheus labels are strings, and it keeps 1 vs 1.0 vs "1" stable)."""
+    if not labels:          # the per-tick work counters carry none
+        return ()
     return tuple(sorted((k, str(v)) for k, v in labels.items()))
 
 
@@ -440,12 +456,15 @@ class SpanTracer:
             span["args"].update(args)
         return self._finish(span, t1)
 
-    def complete(self, rid: int, name: str, t0: float, t1: float,
+    def complete(self, rid: int, name: str, t0: float, t1: float, /,
                  **args) -> None:
         self._finish({"rid": rid, "name": name, "t0": t0, "args": args}, t1)
 
-    def instant(self, rid: int, name: str, **args) -> None:
-        t = self.clock()
+    def instant(self, rid: int, name: str, at: Optional[float] = None,
+                **args) -> None:
+        """``at``: a reading of this tracer's clock the caller already
+        took for the same moment (a request mark), so the two agree."""
+        t = self.clock() if at is None else at
         self._finish({"rid": rid, "name": name, "t0": t, "args": args,
                       "instant": True}, t)
 
@@ -469,13 +488,11 @@ class SpanTracer:
         # forward into the host profiler's recorder when one is recording,
         # so serving spans land next to op-level RecordEvent spans (and
         # device traces) in Profiler.export()
-        from . import profiler as _profiler
-
         rec = _profiler._recorder
         if rec.enabled:
             rec.add(f"serving::{span['name']}", span["t0"], dur,
                     cat="serving", tid=1_000_000 + span["rid"],
-                    args=dict(span["args"], rid=span["rid"]) or None)
+                    args={"rid": span["rid"], **span["args"]})
         return dur
 
     # --------------------------------------------------------------- queries
@@ -493,13 +510,13 @@ class SpanTracer:
     def chrome_events(self) -> List[Dict[str, Any]]:
         """Chrome-trace events: one ``tid`` (= timeline row) per request,
         named via thread_name metadata — a preempted request's swap-out /
-        swap-in and its decode windows share one row. A row whose meta
-        carries ``name`` (the train loop's :data:`TRAIN_RID` row) uses it
-        as the label instead of ``req <rid>``."""
+        swap-in and its decode brackets share one row. The reserved rows
+        (:data:`TRAIN_RID`, :data:`ENGINE_RID`) and any row whose meta
+        carries ``name`` are labelled by name instead of ``req <rid>``."""
         events: List[Dict[str, Any]] = []
         for rid in self.rids():
             meta = self._meta.get(rid, {})
-            label = meta.get("name") or f"req {rid}"
+            label = meta.get("name") or _ROW_NAMES.get(rid) or f"req {rid}"
             if meta.get("tenant"):
                 label += f" [{meta['tenant']}]"
             events.append({"ph": "M", "name": "thread_name", "pid": 0,
@@ -509,7 +526,9 @@ class SpanTracer:
         for s in self.spans():
             ev = {"name": s["name"], "pid": 0, "tid": s["rid"],
                   "ts": s["t0"] * 1e6, "cat": "serving",
-                  "args": dict(s["args"], rid=s["rid"])}
+                  # (an engine-row span may name a request: its own
+                  # ``rid`` arg wins over the row's)
+                  "args": {"rid": s["rid"], **s["args"]}}
             if s.get("instant"):
                 ev.update({"ph": "i", "s": "t"})
             else:
@@ -844,8 +863,66 @@ class _NullFlight:
         return None
 
 
+class _NullPhase:
+    """Shared no-op twin of :class:`_Phase`: entering it reads no clock
+    and opens no ``TraceAnnotation``."""
+
+    __slots__ = ()
+    dur = 0.0
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def note(self, **args):
+        return None
+
+
+class _Phase:
+    """One live span on the :data:`ENGINE_RID` row, opened by
+    :meth:`ServingTelemetry.phase`. While it is open a
+    ``jax.profiler.TraceAnnotation("pt.<name>", tick=<seq>)`` is open
+    too, so any ``jax.profiler`` trace of the process carries the phase
+    on its host plane, on the clock of its device planes."""
+
+    __slots__ = ("tel", "name", "args", "t0", "dur", "_ann")
+
+    def __init__(self, tel: "ServingTelemetry", name: str, tick: int,
+                 args: Dict[str, Any]):
+        self.tel = tel
+        self.name = name
+        # the tick span carries its own number as ``seq`` (the flight
+        # record's), every other phase names the tick that ran it
+        args["seq" if name == "tick" else "tick"] = tick
+        self.args = args
+        self.dur = 0.0
+        self._ann = jax.profiler.TraceAnnotation("pt." + name, tick=tick)
+
+    def note(self, **args) -> None:
+        """Args known only when the phase ends (``finished``, ...)."""
+        self.args.update(args)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self.t0 = self.tel.clock()
+        return self
+
+    def __exit__(self, *exc):
+        tel = self.tel
+        t1 = tel.clock()
+        self._ann.__exit__(*exc)
+        self.dur = t1 - self.t0
+        if self.name in _WAIT_PHASES:
+            tel.wait_s += self.dur
+        tel.tracer.complete(ENGINE_RID, self.name, self.t0, t1, **self.args)
+        return False
+
+
 NULL_TRACER = _NullTracer()
 NULL_FLIGHT = _NullFlight()
+NULL_PHASE = _NullPhase()
 
 
 class ServingTelemetry:
@@ -871,6 +948,9 @@ class ServingTelemetry:
         self.registry = registry if registry is not None else \
             MetricsRegistry(clock=clock, max_samples=max_samples)
         self.enabled = bool(enabled)
+        # seconds the engine spent in its wait phases, over its lifetime;
+        # the tick's flight record takes the delta
+        self.wait_s = 0.0
         if self.enabled:
             self.tracer: Any = tracer if tracer is not None else \
                 SpanTracer(clock=clock, max_spans=max_spans)
@@ -878,6 +958,17 @@ class ServingTelemetry:
         else:
             self.tracer = NULL_TRACER
             self.flight = NULL_FLIGHT
+
+    def phase(self, name: str, tick: int, **args):
+        """Context manager around one phase of engine tick ``tick``
+        (``tick`` itself, ``admit``, ``prefill``, ``first_token_wait``,
+        ``decode_dispatch``, ``decode_wait``, ``harvest``): a span on the
+        :data:`ENGINE_RID` row carrying ``tick=<seq>`` plus ``args``, and
+        a ``pt.<name>`` annotation in any running ``jax.profiler`` trace.
+        Disabled, it is the shared :data:`NULL_PHASE`."""
+        if not self.enabled:
+            return NULL_PHASE
+        return _Phase(self, name, tick, args)
 
     def watchdog(self, **kw) -> List[Dict[str, Any]]:
         kw.setdefault("warm_progs", self.flight.warm_progs)

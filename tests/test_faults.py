@@ -157,7 +157,8 @@ def test_poison_request_quarantined_engine_survives():
     inj = FaultInjector(FaultPlan(
         [FaultSpec("tick", at=1, count=4, rid=0)]))
     srv = GenerationServer(model, max_batch=2, max_len=96, cache="paged",
-                           block_size=8, prefill_chunk=16, faults=inj)
+                           block_size=8, prefill_chunk=16, faults=inj,
+                           telemetry=True)
     rs = [srv.submit(p, max_new_tokens=10) for p in prompts]
     while srv.step():
         srv.assert_conserved()
@@ -165,6 +166,15 @@ def test_poison_request_quarantined_engine_survives():
     assert srv.status(rs[0]) == "failed"
     assert rs[0] not in out
     assert srv._quarantined == 1
+    # its timeline row says so: the decode bracket closed as failed, then
+    # the terminal marker; the ticks it sat out are on the flight ring
+    row = srv.telemetry.tracer.spans(rs[0])
+    assert row[-1]["name"] == "failed" and row[-1].get("instant")
+    (dec,) = [s for s in row if s["name"] == "decode"]
+    assert dec["args"]["outcome"] == "failed"
+    assert srv.telemetry.tracer.open_spans(rs[0]) == []
+    assert any(t["prog"] == "backoff"
+               for t in srv.telemetry.flight.dump())
     for a, b in list(zip(rc, rs))[1:]:
         assert out[b] == base[a]
     # the engine is alive: a fresh request completes normally

@@ -153,7 +153,7 @@ class TestSpanTracer:
 
     def test_complete_is_retroactive(self):
         tr = SpanTracer(clock=_FakeClock())
-        tr.complete(2, "decode_window", 10.0, 12.5, ticks=4)
+        tr.complete(2, "spec_window", 10.0, 12.5, ticks=4)
         (s,) = tr.spans(2)
         assert s["dur"] == 2.5 and s["args"]["ticks"] == 4
 
@@ -185,7 +185,7 @@ class TestSpanTracer:
     def test_chrome_events_one_row_per_request(self):
         tr = SpanTracer(clock=_FakeClock())
         tr.set_meta(7, tenant="acme")
-        tr.complete(7, "decode_window", 0.0, 1.0)
+        tr.complete(7, "spec_window", 0.0, 1.0)
         tr.instant(7, "first_token")
         evs = tr.chrome_events()
         meta = [e for e in evs if e["ph"] == "M" and
@@ -337,8 +337,8 @@ def _prompts(cfg, lens):
 
 def test_preempt_swap_resume_spans_share_one_timeline(tmp_path):
     """The acceptance trace: a request preempted mid-decode must show
-    queued → prefill → decode_window* → swap_out → preempted → swap_in →
-    decode_window* → complete, all on ONE chrome-trace row (tid = rid),
+    queued → prefill → decode → swap_out → preempted → swap_in →
+    decode → complete, all on ONE chrome-trace row (tid = rid),
     with no span left open — and the sched_metrics() dict must be a view
     of the same registry counters."""
     from paddle_tpu.inference.serving import GenerationServer
@@ -362,7 +362,11 @@ def test_preempt_swap_resume_spans_share_one_timeline(tmp_path):
         assert tr.open_spans(r) == [], f"rid {r} left spans open"
         names = [s["name"] for s in tr.spans(r)]
         assert names[0] == "queued" and names[-1] == "complete"
-        assert "first_token" in names and "decode_window" in names
+        assert "first_token" in names and "decode" in names
+        # O(1) spans a request: one bracket per stay in a slot, not one
+        # span per tick
+        assert "decode_window" not in names
+        assert names.count("decode") == 1 + names.count("preempted")
     victim = next(r for r in rids
                   if "swap_out" in [s["name"] for s in tr.spans(r)])
     vnames = [s["name"] for s in tr.spans(victim)]
@@ -387,7 +391,8 @@ def test_preempt_swap_resume_spans_share_one_timeline(tmp_path):
     victim_evs = [e for e in evs if e.get("tid") == victim
                   and e["ph"] in ("X", "i")]
     vnames_tr = {e["name"] for e in victim_evs}
-    assert {"swap_out", "swap_in", "decode_window"} <= vnames_tr
+    assert {"swap_out", "swap_in", "decode"} <= vnames_tr
+    assert vnames.count("decode") >= 2          # before and after the swap
     assert {e["tid"] for e in victim_evs} == {victim}
 
     # the flight ring saw the preemption ticks + per-tick pool state

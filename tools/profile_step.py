@@ -1,181 +1,221 @@
-"""Per-op device profile of one train-step config (VERDICT r5 item 2: the
-S=16384 step has a 0.4185 MFU with no train-level accounting).
+"""Read a ``jax.profiler`` trace of a serving process: how busy the device
+was, which ops took the time, and what the HOST was doing whenever the
+device sat idle.
 
-Traces N steps with jax.profiler, parses the Chrome trace the xplane
-converter writes, and buckets device-op time into attention kernels /
-lm-head+CE / optimizer updates / other fusions — so "is long-S bound by
-the 9-plane attention kernel or by CE/scan overhead?" gets a measured
-answer instead of an inference.
+    python tools/profile_step.py <trace_dir | file.xplane.pb> [--top 10]
 
-Usage: python tools/profile_step.py [--seq 16384 --batch 1]
-       [--layers 8 --hidden 2048]    # 509M headline dims by default
+A ``GenerationServer`` with telemetry on opens a
+``jax.profiler.TraceAnnotation`` around every phase of every tick
+(``pt.tick`` > ``pt.admit`` / ``pt.prefill`` > ``pt.first_token_wait`` /
+``pt.decode_dispatch`` / ``pt.decode_wait`` / ``pt.harvest``; see
+``docs/observability.md``), so a trace taken with
+``jax.profiler.start_trace`` carries them on its host plane, on the clock of
+its device planes. Every interval in which no op ran on the device is
+charged here, second by second, to the innermost ``pt.*`` annotation open on
+the host meanwhile (``outside pt.tick`` when none: the caller's own loop),
+and shown beside the programs the device ran before and after it. (A gap
+between two programs nearly always BEGINS under ``pt.decode_wait`` — the
+device finishes before the host learns of it — so the phase open at its
+beginning is counted, not charged.)
+
+Reads the ``.xplane.pb`` with ``jax.profiler.ProfileData`` and nothing else.
 """
 from __future__ import annotations
 
 import argparse
+import bisect
 import glob
-import gzip
-import json
 import os
+import re
 import sys
-import tempfile
+from typing import Dict, List, Tuple
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+OUTSIDE = "outside pt.tick"
+_HLO = re.compile(r"^%?([\w\-]+?)(?:\.\d+)? = \(?(\w+\[[\d,]*\])?.*?\s([\w\-]+)\(")
 
-
-def bucket_of(name: str, args: dict) -> str:
-    """Buckets keyed on the HLO metadata, not the mangled event name: the
-    flash BACKWARD kernels surface as `transpose_jvp___*` (the autodiff
-    transpose of the custom_vjp) with hlo_category=custom-call — name
-    matching alone mislabels them as layout copies (r5 lesson)."""
-    n = name.lower()
-    tf_op = str(args.get("tf_op", "")).lower()
-    cat = str(args.get("hlo_category", "")).lower()
-    src_line = str(args.get("source", ""))
-    if "pallas" in tf_op or "custom-call" in cat or "mosaic" in n:
-        if "flash" in src_line or "llama.py" in src_line or "flash" in n:
-            return "attention_kernels"
-        return "custom_calls"
-    if "fused_ce" in src_line or "log_softmax" in n or "take_along" in n:
-        return "lmhead_ce"
-    if "while" in n:
-        return "loops(ce_chunks/stream)"
-    if "optimizer" in src_line or "adam" in n:
-        return "optimizer"
-    if n and n[0].isdigit() or n.startswith("jit_"):
-        return "_step_markers"  # parent regions, excluded from totals
-    if "copy" in n or "transpose" in n:
-        return "copy_transpose"
-    if "fusion" in n or "dot" in n or "conv" in n:
-        return "matmul_fusions"
-    return "other"
+Event = Tuple[str, float, float]          # name, start s, end s
 
 
-def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--seq", type=int, default=16384)
-    ap.add_argument("--batch", type=int, default=1)
-    ap.add_argument("--layers", type=int, default=8)
-    ap.add_argument("--hidden", type=int, default=2048)
-    ap.add_argument("--inter", type=int, default=5632)
-    ap.add_argument("--steps", type=int, default=3)
-    ap.add_argument("--remat", action="store_true")
-    ap.add_argument("--top", type=int, default=25)
-    ap.add_argument("--keep", default=None,
-                    help="keep the trace dir at this path")
-    ap.add_argument("--parse-only", default=None,
-                    help="re-analyze an existing trace dir; no chip run")
-    args = ap.parse_args()
-
-    if args.parse_only:
-        meta = {}
-        mp = os.path.join(args.parse_only, "pt_profile_meta.json")
-        if os.path.exists(mp):
-            meta = json.load(open(mp))
-        return analyze(args.parse_only, args,
-                       ms=meta.get("step_ms", 0.0),
-                       n_params=meta.get("n_params", 0),
-                       steps_traced=meta.get("steps_traced",
-                                             args.steps + 1))
-    import jax
-    import numpy as np
-
-    import paddle_tpu as paddle
-    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
-    from paddle_tpu.optimizer import AdamW
-    from paddle_tpu.parallel import ParallelEngine
-    from paddle_tpu.utils.bench_timing import device_time_ms
-
-    assert any(d.platform == "tpu" for d in jax.devices()), \
-        "profile_step wants the real chip"
-    cfg = LlamaConfig(vocab_size=32000, hidden_size=args.hidden,
-                      intermediate_size=args.inter,
-                      num_hidden_layers=args.layers,
-                      num_attention_heads=args.hidden // 128,
-                      num_key_value_heads=max(args.hidden // 256, 1),
-                      max_position_embeddings=args.seq, dtype="bfloat16",
-                      use_flash_attention=True)
-    paddle.seed(0)
-    trace_dir = args.keep or tempfile.mkdtemp(prefix="pt_trace_")
-    model = LlamaForCausalLM(cfg)
-    n_params = sum(int(np.prod(p.shape)) for p in model.parameters())
-    opt = AdamW(learning_rate=1e-4, parameters=model.parameters())
-    engine = ParallelEngine(model, optimizer=opt, loss_fn=None,
-                            remat=args.remat)
-    engine.build_train_step()
-    rng = np.random.RandomState(0)
-    ids = paddle.to_tensor(
-        rng.randint(0, cfg.vocab_size,
-                    (args.batch, args.seq)).astype("int32"))
-    labels = paddle.to_tensor(
-        rng.randint(0, cfg.vocab_size,
-                    (args.batch, args.seq)).astype("int64"))
-    ms = device_time_ms(lambda: engine.train_batch(ids, labels),
-                        reps=2, warmup=2)  # warms compile + cache
-    jax.profiler.start_trace(trace_dir)
-    for _ in range(args.steps):
-        engine.train_batch(ids, labels)
-    # force completion INSIDE the trace window
-    float(np.asarray(engine.train_batch(ids, labels).value))
-    jax.profiler.stop_trace()
-
-    with open(os.path.join(trace_dir, "pt_profile_meta.json"), "w") as f:
-        json.dump({"step_ms": ms, "n_params": n_params,
-                   "steps_traced": args.steps + 1,
-                   "config": vars(args)}, f)
-    analyze(trace_dir, args, ms, n_params, args.steps + 1)
-    if not args.keep:
-        import shutil
-
-        shutil.rmtree(trace_dir, ignore_errors=True)
+def find_xplane(path: str) -> str:
+    if os.path.isfile(path):
+        return path
+    hits = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"),
+                            recursive=True))
+    if not hits:
+        raise SystemExit(f"no .xplane.pb under {path}")
+    return hits[-1]
 
 
-def analyze(trace_dir, args, ms, n_params, steps_traced):
-    traces = glob.glob(os.path.join(
-        trace_dir, "**", "*.trace.json.gz"), recursive=True)
-    assert traces, f"no trace written under {trace_dir}"
-    with gzip.open(sorted(traces)[-1], "rt") as f:
-        data = json.load(f)
-    events = data.get("traceEvents", [])
-    # device lanes: pick pids whose process names mention TPU/device
-    pid_names = {e["pid"]: e["args"].get("name", "")
-                 for e in events if e.get("ph") == "M"
-                 and e.get("name") == "process_name"}
-    dev_pids = {p for p, n in pid_names.items()
-                if "tpu" in n.lower() or "device" in n.lower()
-                or "/device" in n.lower()}
-    if not dev_pids:  # fall back: everything that isn't python/host
-        dev_pids = {p for p, n in pid_names.items()
-                    if "python" not in n.lower() and "host" not in n.lower()}
-    agg, buckets = {}, {}
-    total = 0.0
-    for e in events:
-        if e.get("ph") != "X" or e.get("pid") not in dev_pids:
-            continue
-        name = e.get("name", "?")
-        b = bucket_of(name, e.get("args", {}))
-        dur = e.get("dur", 0) / 1e3  # ms
-        a = agg.setdefault(name, [0, 0.0, b])
-        a[0] += 1
-        a[1] += dur
-        if b == "_step_markers":
-            continue  # parent spans would double-count their children
-        buckets[b] = buckets.get(b, 0.0) + dur
-        total += dur
-    print(f"\n== device-op profile: {n_params/1e6:.0f}M, B={args.batch} "
-          f"S={args.seq} remat={args.remat} ({steps_traced} steps traced, "
-          f"step {ms:.1f} ms) ==")
-    print(f"total device-op time {total:.1f} ms "
-          f"({total / steps_traced:.1f} ms/step vs {ms:.1f} wall — "
-          f"overlap if smaller)")
-    print("\n-- buckets --")
-    for b, t in sorted(buckets.items(), key=lambda kv: -kv[1]):
-        print(f"  {b:<20} {t:>9.1f} ms  {100 * t / max(total, 1e-9):5.1f}%")
-    print(f"\n-- top {args.top} ops --")
-    for name, (calls, t, b) in sorted(agg.items(), key=lambda kv: -kv[1][1]
-                                      )[:args.top]:
-        print(f"  {t:>9.2f} ms  x{calls:<5} [{b:<16}] {name[:90]}")
+def load(path: str) -> Dict[str, Dict[str, List[Event]]]:
+    """{plane: {line: [(name, start, end)]}}, seconds on the trace's clock."""
+    from jax.profiler import ProfileData
+
+    out: Dict[str, Dict[str, List[Event]]] = {}
+    for plane in ProfileData.from_file(path).planes:
+        lines = out.setdefault(plane.name, {})
+        for line in plane.lines:
+            lines.setdefault(line.name, []).extend(
+                (e.name, e.start_ns * 1e-9,
+                 (e.start_ns + e.duration_ns) * 1e-9) for e in line.events)
+    return out
+
+
+def union(events: List[Event]) -> List[Tuple[float, float]]:
+    """The intervals in which at least one of ``events`` ran, merged."""
+    out: List[Tuple[float, float]] = []
+    for _, s, e in sorted(events, key=lambda ev: ev[1]):
+        if out and s <= out[-1][1]:
+            out[-1] = (out[-1][0], max(out[-1][1], e))
+        else:
+            out.append((s, e))
+    return out
+
+
+def short_name(name: str) -> str:
+    """On the TPU an op event is named by its whole HLO text: fold it to
+    ``<opcode> <result> -> <shape>`` so one op of every layer adds up."""
+    m = _HLO.match(name)
+    if not m:
+        return re.sub(r"[.\d]+$", "", name)[:100] or name[:100]
+    lhs, shape, opcode = m.groups()
+    out = opcode if lhs == opcode else f"{opcode} {lhs}"
+    return f"{out} -> {shape}" if shape else out
+
+
+def phase_timeline(annotations: List[Event]):
+    """(bounds, labels): between ``bounds[i]`` and ``bounds[i + 1]`` the
+    innermost open annotation is ``labels[i]`` (the one opened last among
+    those open; annotations of one thread nest)."""
+    marks = sorted([(e, 0, k) for k, (_, _, e) in enumerate(annotations)]
+                   + [(s, 1, k) for k, (_, s, _) in enumerate(annotations)])
+    bounds, labels, open_ = [], [], []
+    for t, opens, k in marks:
+        if not bounds or t > bounds[-1]:
+            bounds.append(t)
+            labels.append(None)
+        if opens:
+            open_.append(k)
+        else:
+            open_.remove(k)
+        labels[-1] = annotations[open_[-1]][0] if open_ else OUTSIDE
+    return bounds, labels
+
+
+def charge(bounds, labels, start: float, length: float) -> Dict[str, float]:
+    """The interval's seconds by the phase the host was in meanwhile."""
+    out: Dict[str, float] = {}
+    t, stop = start, start + length
+    i = bisect.bisect_right(bounds, t) - 1
+    while t < stop:
+        nxt = bounds[i + 1] if i + 1 < len(bounds) else stop
+        label = labels[i] if 0 <= i < len(labels) else OUTSIDE
+        upto = min(max(nxt, t), stop)
+        out[label] = out.get(label, 0.0) + upto - t
+        t, i = upto, i + 1
+    return out
+
+
+def program_at(mods: List[Event], starts: List[float], t: float):
+    """(which run, name) of the program running at ``t``."""
+    i = bisect.bisect_right(starts, t + 1e-9) - 1
+    if i >= 0 and t <= mods[i][2] + 1e-9:
+        return i, re.sub(r"\(.*$", "", mods[i][0])
+    return None, "?"
+
+
+def report(planes, top: int, host_prefix: str) -> List[str]:
+    devices = sorted(p for p in planes if p.startswith("/device:TPU:"))
+    if not devices:
+        raise SystemExit(f"no /device:TPU:<n> plane in the trace; planes: "
+                         f"{sorted(planes)}")
+    busy = {d: sum(e - s for s, e in union(planes[d].get("XLA Ops", [])))
+            for d in devices}
+    dev = max(devices, key=busy.get)
+    ops = planes[dev].get("XLA Ops", [])
+    if not ops:
+        raise SystemExit(f"{dev} ran no op inside the trace")
+    runs = union(ops)
+    span = runs[-1][1] - runs[0][0]
+    gaps = [(a[1], b[0] - a[1]) for a, b in zip(runs, runs[1:])]
+    idle = sum(g for _, g in gaps)
+    out = [f"device plane {dev} (the busiest of {len(devices)}): first op to "
+           f"last op {span:.3f} s, busy {busy[dev]:.3f} s = "
+           f"{100 * busy[dev] / span:.1f} %, idle {idle:.3f} s in "
+           f"{len(gaps)} gaps", "", f"top ops (seconds, share of busy):"]
+    tot: Dict[str, float] = {}
+    for name, s, e in ops:
+        n = short_name(name)
+        tot[n] = tot.get(n, 0.0) + e - s
+    for n, t in sorted(tot.items(), key=lambda x: -x[1])[:top]:
+        out.append(f"  {t:8.3f}  {100 * t / busy[dev]:5.1f} %  {n}")
+
+    host = [ev for p, lines in planes.items() if p.startswith("/host:")
+            for evs in lines.values() for ev in evs]
+    anns = [ev for ev in host if ev[0].startswith("pt.")]
+    bounds, labels = phase_timeline(anns)
+    mods = sorted(planes[dev].get("XLA Modules", []), key=lambda m: m[1])
+    starts = [m[1] for m in mods]
+    by_phase: Dict[str, float] = {}
+    begun: Dict[str, int] = {}
+    by_pair: Dict[Tuple[str, str], float] = {}
+    for t, length in gaps:
+        (i, a), (j, b) = (program_at(mods, starts, t),
+                          program_at(mods, starts, t + length))
+        # (two runs of one program in a row are not one run)
+        around = f"inside {a}" if i == j and i is not None else f"{a} -> {b}"
+        shares = charge(bounds, labels, t, length)
+        first = next(iter(shares))
+        begun[first] = begun.get(first, 0) + 1
+        for phase, sec in shares.items():
+            by_phase[phase] = by_phase.get(phase, 0.0) + sec
+            by_pair[(around, phase)] = by_pair.get((around, phase), 0.0) + sec
+    out += ["", "idle time by host phase (each gap's seconds go to the "
+            "innermost pt.* annotation open on the host meanwhile;",
+            "'gaps begun' counts the gaps by the phase open when they "
+            "began):",
+            f"  {'seconds':>8}  {'share':>7}  {'gaps begun':>10}  phase"]
+    for phase, sec in sorted(by_phase.items(), key=lambda x: -x[1]):
+        out.append(f"  {sec:8.4f}  {100 * sec / idle:5.1f} %  "
+                   f"{begun.get(phase, 0):10d}  {phase}")
+    out.append(f"  {idle:8.4f}  100.0 %  {len(gaps):10d}  total")
+    out += ["", "the same, by the programs the device ran around the gap:"]
+    pairs: Dict[str, float] = {}
+    for (around, _), t in by_pair.items():
+        pairs[around] = pairs.get(around, 0.0) + t
+    for around, t in sorted(pairs.items(), key=lambda x: -x[1])[:top]:
+        parts = sorted(((ph, sec) for (ar, ph), sec in by_pair.items()
+                        if ar == around), key=lambda x: -x[1])
+        out.append(f"  {t:8.4f}  {around}: " + ", ".join(
+            f"{ph} {sec:.4f}" for ph, sec in parts))
+
+    out += ["", "host annotations (count, seconds):"]
+    seen: Dict[str, List[float]] = {}
+    for name, s, e in host:
+        if name.startswith("pt.") or (host_prefix
+                                      and name.startswith(host_prefix)):
+            seen.setdefault(name, []).append(e - s)
+    if not anns:
+        out.append("  no pt.* annotation: was the server's telemetry on "
+                   "while the trace ran?")
+    for name, ds in sorted(seen.items(), key=lambda x: -sum(x[1])):
+        out.append(f"  {len(ds):6d}  {sum(ds):8.3f}  {name}")
+    return out
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("trace", help="a trace directory of jax.profiler, or "
+                                  "an .xplane.pb file")
+    ap.add_argument("--top", type=int, default=10)
+    ap.add_argument("--host-prefix", default="",
+                    help="also count the host annotations whose name starts "
+                         "so (the caller's own, around step())")
+    args = ap.parse_args(argv)
+    path = find_xplane(args.trace)
+    print(f"trace: {path}")
+    print("\n".join(report(load(path), args.top, args.host_prefix)))
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -1023,6 +1023,8 @@ def main():
                                 or args.strict,
                                 kernels=args.kernels)
 
+    prefill_tokens0 = {}      # id(server) -> prompt tokens before its drain
+
     def run_pass(server, chaos_inj=None, allowed_compiles=0):
         """Warmup + the measured drain against the seeded traffic.
 
@@ -1060,7 +1062,9 @@ def main():
         if args.paged:
             # scope the prefill-throughput and cold-refill figures to
             # the measured drain (warmup churn demotes too)
-            server._prefill_tokens = 0
+            # (the token count is the lifetime serving_prefill_tokens
+            # counter: keep where the drain starts)
+            prefill_tokens0[id(server)] = server._prefill_tokens
             server._prefill_wall_s = 0.0
             server._cold_refills = 0
         if chaos_inj is not None:
@@ -1438,7 +1442,7 @@ def main():
         # chunked-prefill throughput over the measured drain, normalized
         # per chip (tp x cp) — the figure the cp axis is meant to scale
         line["prefill_tok_s_per_chip"] = round(
-            server._prefill_tokens
+            (server._prefill_tokens - prefill_tokens0.get(id(server), 0))
             / max(server._prefill_wall_s, 1e-9) / (tp * cp), 1)
         # hot/warm rates are block-level fractions of prefix-cache
         # lookups; cold is re-prefill-over-demoted-content events per
