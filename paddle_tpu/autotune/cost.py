@@ -227,8 +227,9 @@ def geometry_cost_proxy(op: str, geometry, **shape) -> float:
     from .space import MK_VMEM_LIMIT_BYTES
 
     if isinstance(geometry, PagedAttentionGeometry):
-        blocks = float(shape.get("blocks", 64))
-        steps = blocks / geometry.kv_block_depth
+        # one program per (row, q-row tile); the KV walk is in-program
+        rows = float(shape.get("window", 4) * shape.get("rep", 4))
+        steps = rows / float(geometry.q_rows or rows)
         vmem = geometry.vmem_bytes(
             head_dim=shape.get("head_dim", 128),
             block_size=shape.get("block_size", 16),

@@ -10,16 +10,15 @@ expresses the schedule as data with ``validate()`` + a VMEM-occupancy
 model, mirroring ``MegakernelGeometry``.
 
 The geometry contract is STRICTER than the megakernel's: every
-supported geometry is a schedule change only — tile/block shapes, grid
-iteration order, streaming depth, hoisted-but-exact casts — never a
-math-order change, so any geometry's output is BIT-EXACT against the
-default geometry's (the parity sweep in tests/test_kernel_geometry.py
-pins this bitwise, fp and int8). The default geometry of every class
-reproduces the pre-geometry kernels exactly: zero values mean "derive
-today's hardcoded choice". Knobs that would regroup floating-point
-accumulation (e.g. the flash kernel's kv block, which sets the online-
-softmax update granularity) exist as declared axes but are excluded
-from the sweep candidate space; the search additionally hard-rejects
+swept geometry is a schedule change only — tile/block shapes, q-row
+tiling, hoisted-but-exact casts — never a math-order change, so any
+candidate's output is BIT-EXACT against the default geometry's (the
+parity sweep in tests/test_kernel_geometry.py pins this bitwise, fp
+and int8). Zero values mean "derive the choice from the shapes". Knobs
+that regroup floating-point accumulation (the flash kernel's kv block
+and the paged kernel's blocks per group both set the online-softmax
+update granularity) exist as declared axes, are honored when set
+explicitly, and are excluded from the sweep candidate space; the search additionally hard-rejects
 any candidate whose output is not bitwise equal to the default's, so a
 non-exact schedule can never become a cached winner.
 
@@ -46,19 +45,12 @@ from typing import Any, Dict, Mapping, Optional, Tuple
 
 #: int8 dequant placements for the paged-attention kernel. Both apply
 #: the k/v scales in the reference order (bit-exact); they differ only
-#: in WHERE the exact int8->fp cast of the streamed KV tile sits:
-#: "scores" casts inside the causal-skip branch (today's schedule,
-#: skipped blocks never cast), "early" hoists the cast to the top of
-#: the grid step (branchless stream — the tile is cast as soon as its
-#: DMA lands, trading wasted casts on skipped blocks for a shorter
-#: critical path into the QK matmul).
+#: in WHERE the exact int8->fp cast of a fetched group of KV blocks
+#: sits: "scores" casts each kv head's slice where its matmul consumes
+#: it, "early" casts the whole group tile once, as soon as its copies
+#: have landed (the all-heads body consumes the tile whole, so there
+#: the two coincide).
 PA_DEQUANT_MODES = ("scores", "early")
-
-#: paged-attention grid iteration orders over the two parallel axes:
-#: "bgm" = (batch, kv_head, kv_block) — today's order; "gbm" swaps the
-#: batch and kv-head axes (same cells, different walk — changes which
-#: pool blocks are DMA-adjacent).
-PA_GRID_ORDERS = ("bgm", "gbm")
 
 #: fused-LoRA accumulation layouts: which matmul chain issues first.
 #: The final combine is ``y + d * s`` either way (bit-exact);
@@ -84,60 +76,55 @@ class PagedAttentionGeometry:
     """Schedule of the paged decode/verify/prefill attention kernel
     (ops/paged_attention_pallas.py), fp and int8.
 
-    ``kv_block_depth``: KV-pool blocks streamed per grid step. 1 =
-    today's one-block-per-step schedule; d > 1 fetches d table-routed
-    blocks into VMEM per step (d block specs) and applies the online-
-    softmax update to each IN ORDER inside the step — same math, same
-    order, fewer grid steps, deeper DMA pipelining. Clamped to a
-    divisor of the table width at trace time.
+    ``kv_block_depth``: KV-pool blocks per group — fetched together
+    and consumed by ONE online-softmax update. 0 (the default) derives
+    it from the shapes (``paged_attention_pallas.group_plan``: the row
+    count, block size, kv heads, head_dim, pool dtype and the VMEM
+    block budget); > 0 requests that many, clamped to the table width.
+    CAUTION: like flash's ``block_kv`` this sets the online-softmax
+    update granularity, so two depths agree to ~1e-6, not bitwise — it
+    is honored when set explicitly (tests, ``tools/kernel_bench.py``)
+    and excluded from the sweep candidates.
 
     ``q_rows``: q-row tile. 0 = the whole W*rep GQA row group per
-    program (today); > 0 tiles the rows across an extra parallel grid
-    axis (rows are independent in attention — bit-exact). Clamped to a
+    program; > 0 tiles the rows across an extra parallel grid axis
+    (rows are independent in attention — bit-exact). Clamped to a
     divisor of W*rep.
-
-    ``grid_order``: iteration order of the parallel axes, one of
-    :data:`PA_GRID_ORDERS`.
 
     ``dequant``: int8 cast placement, one of :data:`PA_DEQUANT_MODES`;
     dead (canonicalized to "scores") for fp pools.
     """
 
-    kv_block_depth: int = 1
+    kv_block_depth: int = 0
     q_rows: int = 0
-    grid_order: str = "bgm"
     dequant: str = "scores"
 
     def validate(self) -> None:
-        if not 1 <= self.kv_block_depth <= 8:
-            raise ValueError("kv_block_depth must be in [1, 8], got "
+        if not 0 <= self.kv_block_depth <= 64:
+            raise ValueError("kv_block_depth must be in [0, 64], got "
                              f"{self.kv_block_depth}")
         if self.q_rows < 0:
             raise ValueError(f"q_rows must be >= 0, got {self.q_rows}")
-        if self.grid_order not in PA_GRID_ORDERS:
-            raise ValueError(f"grid_order must be one of {PA_GRID_ORDERS}, "
-                             f"got {self.grid_order!r}")
         if self.dequant not in PA_DEQUANT_MODES:
             raise ValueError(f"dequant must be one of {PA_DEQUANT_MODES}, "
                              f"got {self.dequant!r}")
 
     def vmem_bytes(self, *, head_dim: int, block_size: int, window: int,
-                   rep: int, dtype_bytes: int = 4,
+                   rep: int, kv_heads: int = 8, dtype_bytes: int = 4,
                    quantized: bool = False) -> int:
-        """Worst-case VMEM residency of one grid step: the q tile, the
-        streamed KV tiles (+ scales), and the online-softmax scratch."""
+        """Worst-case VMEM residency of one program: the q tile, the two
+        slots of a K and a V group (whole blocks, every kv head), and
+        the online-softmax scratch."""
         rows = window * rep if self.q_rows == 0 \
             else min(self.q_rows, window * rep)
-        d = self.kv_block_depth
+        d = self.kv_block_depth or 8
         kv_item = 1 if quantized else dtype_bytes
-        n = rows * head_dim * dtype_bytes                  # q tile
-        n += d * 2 * block_size * head_dim * kv_item       # k/v tiles
-        if quantized:
-            n += d * 2 * 4                                 # per-block scales
-            if self.dequant == "early":
-                # hoisted casts keep fp twins of the tiles live
-                n += d * 2 * block_size * head_dim * dtype_bytes
-        n += rows * (2 * 128 + head_dim) * 4               # m/l/acc scratch
+        group = d * block_size * kv_heads * head_dim
+        n = kv_heads * rows * head_dim * dtype_bytes       # q tile
+        n += 2 * 2 * group * kv_item                       # k/v slots
+        if quantized and self.dequant == "early":
+            n += 2 * group * dtype_bytes       # fp twins of the group tile
+        n += kv_heads * rows * (2 * 128 + head_dim) * 4    # m/l/acc scratch
         return n
 
     def asdict(self) -> dict:
@@ -504,14 +491,12 @@ def geometry_candidates(op: str, *, quantized: bool = False,
         vmem_limit_bytes = MK_VMEM_LIMIT_BYTES
     cands: list = []
     if op == "paged_attention":
-        for depth in (1, 2, 4):
-            for q_rows in (0, 8, 16):
-                for order in PA_GRID_ORDERS:
-                    for deq in (PA_DEQUANT_MODES if quantized
-                                else ("scores",)):
-                        cands.append(PagedAttentionGeometry(
-                            kv_block_depth=depth, q_rows=q_rows,
-                            grid_order=order, dequant=deq))
+        # kv_block_depth stays derived: it regroups the online softmax
+        # (not parity-exact) — see PagedAttentionGeometry
+        for q_rows in (0, 8, 16):
+            for deq in (PA_DEQUANT_MODES if quantized else ("scores",)):
+                cands.append(PagedAttentionGeometry(q_rows=q_rows,
+                                                    dequant=deq))
         cands = [g for g in cands if g.vmem_bytes(
             head_dim=shape.get("head_dim", 128),
             block_size=shape.get("block_size", 16),

@@ -15,9 +15,9 @@ Layout is chosen Pallas-ready, mirroring the flash kernels in
   contiguous ``(bs, KV, D)`` tile — exactly the unit a Mosaic kernel
   streams through VMEM;
 - block tables are small int32 operands — on TPU they become
-  ``PrefetchScalarGridSpec`` scalar-prefetch args feeding the K/V
-  BlockSpec ``index_map`` (the kernel grid walks ``table[i]`` instead of
-  ``i``, which is the whole trick of paged attention);
+  ``PrefetchScalarGridSpec`` scalar-prefetch args that name the blocks
+  each program copies (the kernel walks ``table[i]`` instead of ``i``,
+  which is the whole trick of paged attention);
 - the decode gather and the chunk scatter below are the pure-jnp
   REFERENCE path: CPU tier-1 runs it bit-for-bit.
 

@@ -1,35 +1,48 @@
 """Pallas TPU kernels for the paged serving hot path.
 
 One flash-style online-softmax kernel serves decode (W=1), speculative
-verify (W=tick_window), and chunked prefill (B=1, W=chunk): the grid is
-(batch, kv_block) and the K/V ``BlockSpec`` index_map reads the block
-table through ``PrefetchScalarGridSpec`` scalar-prefetch — ``tbl[b, m]``
-picks the pool block to stream into VMEM, so the dense ``gather_block_kv``
-copy of the context never materializes in HBM. One program streams WHOLE
-``(bs, KV, D)`` blocks and walks the kv heads inside (Mosaic only takes
-blocks whose last two dims are the pool's own ``(KV, D)``). Running
-max/sum/accumulator live in VMEM scratch across the block axis;
-``pl.when`` skips blocks past each row's causal frontier, which also
-covers the all-zero scratch-block entries of short sequences. The int8
-twin streams the code pool directly and applies the per-(block, kv-head)
-scales on the VMEM tile — k-scale on the fp32 QK accumulator, v-scale
-folded into the probabilities before PV — so a dequantized pool is never
-built. ``fused_lora_matmul`` fuses the per-slot BGMV adapter delta
-(gathered A/B/scale factors) into the base projection matmul, one program
-per (output tile, batch row).
+verify (W=tick_window), and chunked prefill (B=1, W=chunk). The grid is
+one program per batch row (x an optional q-row tile); the KV axis is NOT
+on the grid. The block tables and the positions are the two
+scalar-prefetch operands, the pools stay whole in HBM, and each program
+loops over its own row's LIVE blocks only — ``(pos[b] + W - 1) // bs +
+1`` of them, a bound read from the positions, so the work of a call
+follows the tokens attended and not ``max_batch * max_len`` — in groups
+of ``G`` blocks: ``G`` whole ``(bs, KV, D)`` copies of K and of V
+(``make_async_copy`` of the block ``tbl[b, i*G + j]`` names) land in a
+two-slot VMEM buffer, group ``i + 1`` in flight while group ``i`` is
+consumed by ONE online-softmax update. The dense ``gather_block_kv`` copy
+of the context never materializes in HBM, table entries past a row's
+causal frontier (scratch block 0, stale ids) are never read, and there is
+still one decode program and one chunk program whatever the lengths.
+
+:func:`group_plan` decides ``G`` and the body at trace time from the
+shapes. When all of a program's query rows fit one MXU pass (decode,
+small verify windows) the group tile is consumed as it landed — reshaped
+to ``(G*bs*KV, D)`` against ALL kv heads' query rows with a block-diagonal
+mask: 8x the FLOPs of a memory-bound kernel, no per-head strided read of
+the ``(G*bs, KV, D)`` tile. A prefill chunk (hundreds of rows a head,
+compute bound) walks the kv heads instead. Running max/sum/accumulator
+live in VMEM scratch across the loop. The int8 twin copies the code pool
+directly and applies the per-(block, kv-head) scales on the VMEM tile —
+k-scale on the fp32 QK accumulator, v-scale folded into the probabilities
+before PV — so a dequantized pool is never built; its scales arrive as
+per-row ``(B, M, KV)`` tables gathered through the block table.
+``fused_lora_matmul`` fuses the per-slot BGMV adapter delta (gathered
+A/B/scale factors) into the base projection matmul, one program per
+(output tile, batch row).
 
 The kernel's SCHEDULE is parameterized by
 :class:`~paddle_tpu.autotune.kernel_geometry.PagedAttentionGeometry`
 (and the LoRA kernel's by :class:`~paddle_tpu.autotune.kernel_geometry
-.LoRAGeometry`): KV streaming depth (blocks fetched per grid step),
-q-row tiling (extra parallel axis over the W*rep GQA rows), and int8
-cast placement. All geometry axes are
-schedule-only — the per-block online-softmax update runs in the same
-order on the same values, so every geometry is bit-exact against the
-default (one block per step, full row group). ``geometry=``
-is a trace-time parameter; when omitted, the process-wide winner cache
-(``autotune.kernel_geometry.install_geometry_cache``) is consulted at
-trace time, same contract as ``ops.set_kernel_mode``.
+.LoRAGeometry`): q-row tiling (extra parallel axis over the W*rep GQA
+rows) and int8 cast placement are schedule-only — bit-exact against the
+default. ``kv_block_depth`` overrides ``G``; that moves the
+online-softmax update boundaries, so two depths agree to ~1e-6 and in
+every greedy token, not bitwise, and the geometry sweep never varies it.
+``geometry=`` is a trace-time parameter; when omitted, the process-wide
+winner cache (``autotune.kernel_geometry.install_geometry_cache``) is
+consulted at trace time, same contract as ``ops.set_kernel_mode``.
 
 The jnp compositions in ``ops/paged_attention.py`` remain the bit-exact
 references; which of the two runs is decided by the rules in
@@ -58,12 +71,6 @@ def _interpret() -> bool:
     return pallas_interpret()
 
 
-def _lanes(x):
-    """Broadcast a (rows,) vector across the 128-lane minor dim so the
-    running max/sum scratch keeps a TPU-native (rows, 128) layout."""
-    return jnp.broadcast_to(x[:, None], (x.shape[0], 128))
-
-
 def _resolve(op: str, dtype: str, key: int):
     from ..autotune.kernel_geometry import resolve_geometry
 
@@ -71,96 +78,200 @@ def _resolve(op: str, dtype: str, key: int):
 
 
 # ------------------------------------------------------------------ attention
-def _attn_kernel(tbl_ref, pos_ref, q_ref, *rest, bs, W, rep, KV, Mp, depth,
-                 R, quantized, early, ib, iq, im):
-    d = depth
-    k_refs = rest[:d]
-    v_refs = rest[d:2 * d]
-    n = 2 * d
+# Tokens per group. Measured on the v5e at the serving cells' shapes
+# (tools/kernel_bench.py --ops ragged, PR 26): 64 ragged decode rows read
+# 41 / 56 / 62 / 60 % of the memory roofline at 64 / 128 / 256 / 512, and
+# a 128-token chunk 10 / 18 / 25 % of the compute roofline at 64 / 128 /
+# 256 (512 does not fit VMEM beside the chunk's accumulator).
+_GROUP_TOKENS = 256
+
+
+def group_plan(rows, KV, bs, D, M, kv_itemsize, depth=0):
+    """(G, all_heads): blocks fetched and consumed per online-softmax
+    update, and which body consumes them — decided at trace time from the
+    shapes. ``rows`` is one program's q rows per kv head (W*rep, or the
+    q-row tile).
+
+    All kv heads at once when every q row of the program fits ONE MXU
+    pass (KV*rows <= 128): the pass streams < 128 rows either way, so the
+    block-diagonal waste is free and the group tile is consumed as it
+    landed, with no per-head strided read (2.1x the per-head body at 64
+    decode rows on the v5e). Past that (a prefill chunk: hundreds of rows
+    a head) the 8x FLOPs are not free and each head runs its own matmul.
+
+    G aims the group at ``_GROUP_TOKENS`` tokens, inside the table width
+    and half the VMEM block budget (two slots of K and of V plus the f32
+    score/probability temporaries; the other half is the q/out blocks and
+    the accumulator, which ``select_paged_attention`` bounds). ``depth`` >
+    0 (``PagedAttentionGeometry.kv_block_depth``) overrides G."""
+    from .select import _VMEM_BLOCK_BUDGET
+
+    all_heads = KV > 1 and KV * rows <= 128
+    if depth > 0:
+        return min(depth, M), all_heads
+    qrows, cols = (KV * rows, KV) if all_heads else (rows, 1)
+
+    def vmem(g):
+        t = g * bs
+        return 4 * t * KV * D * kv_itemsize + 3 * qrows * t * cols * 4
+
+    G = max(1, min(_GROUP_TOKENS // bs, M))
+    while G > 1 and vmem(G) > _VMEM_BLOCK_BUDGET // 2:
+        G //= 2
+    return G, all_heads
+
+
+def _attn_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest, bs, rep, KV,
+                 M, G, R, quantized, early, all_heads, tiled):
     if quantized:
-        ks_refs = rest[n:n + d]
-        vs_refs = rest[n + d:n + 2 * d]
-        n += 2 * d
-    else:
-        ks_refs = vs_refs = None
-    o_ref, m_ref, l_ref, acc_ref = rest[n:]
-    b = pl.program_id(ib)
-    m = pl.program_id(im)
+        ks_ref, vs_ref = rest[:2]
+        rest = rest[2:]
+    o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref = rest
+    D = q_ref.shape[-1]
+    T = G * bs
+    b = pl.program_id(0)
     # first global q row of this program's tile (0 unless q_rows tiles
     # the W*rep group across its own grid axis)
-    row0 = pl.program_id(iq) * R if iq is not None else 0
+    row0 = pl.program_id(1) * R if tiled else 0
+    pos = pos_ref[b]
+    # live blocks: through the causal frontier of the tile's LAST q row.
+    # Table entries past it (scratch block 0, stale ids) are never read.
+    nblk = jnp.minimum((pos + (row0 + R - 1) // rep) // bs + 1, M)
+    ngroups = (nblk + G - 1) // G
 
-    @pl.when(m == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def dma(i, slot, start):
+        """Start (or wait for) group i's whole-block copies into ``slot``.
+        A block past the frontier is not fetched; its V slab is zeroed so
+        a zero probability never meets stale non-finite bits. (Loops, not
+        an unrolled ``pl.when`` a block: the trace stays the size of one
+        block whatever G is.)"""
+        live = jnp.clip(nblk - i * G, 0, G)
 
-    def _step(j):
-        blk = m * d + j
-        # Skip blocks entirely past the last query row's causal frontier
-        # — this also covers block-table tail entries that still point at
-        # scratch block 0. The frontier test is per batch row (not per
-        # q tile) so the skip schedule is geometry-independent.
-        needed = blk * bs <= pos_ref[b] + (W - 1)
-        if quantized and early:
-            # "early" dequant placement: the int8->fp cast is exact, so
-            # hoisting it out of the skip branch changes the schedule
-            # (branchless stream) but never the math
-            k_pre = [k_refs[j][0, :, g, :].astype(q_ref.dtype)
-                     for g in range(KV)]
-            v_pre = [v_refs[j][0, :, g, :].astype(q_ref.dtype)
-                     for g in range(KV)]
-
-        @pl.when(needed)
-        def _compute():
-            # the whole (bs, KV, D) block is resident; each kv head's
-            # online-softmax state is independent, so walking the heads
-            # here runs, per head, exactly the per-block update order of
-            # a one-head-per-program grid
-            for g in range(KV):
-                q = q_ref[0, g]                       # (R, D)
-                if quantized and early:
-                    k, v = k_pre[g], v_pre[g]
+        def one(j, carry):
+            blk = tbl_ref[b, i * G + j]
+            for s, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                cp = pltpu.make_async_copy(
+                    hbm.at[blk], buf.at[slot, pl.ds(j * bs, bs)],
+                    sem.at[s, slot])
+                if start:
+                    cp.start()
                 else:
-                    k = k_refs[j][0, :, g, :]         # (bs, D)
-                    v = v_refs[j][0, :, g, :]
-                    if quantized:
-                        k = k.astype(q.dtype)
-                        v = v.astype(q.dtype)
-                s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-                if quantized:
-                    # reference order: scores * k_scale, then / sqrt(D)
-                    s = s * ks_refs[j][0, :, g:g + 1]
-                s = s / jnp.float32(math.sqrt(q.shape[-1]))
-                rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-                cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                # row -> absolute query position
-                qpos = pos_ref[b] + (row0 + rows) // rep
-                s = jnp.where(blk * bs + cols <= qpos, s, NEG_INF)
-                m_prev = m_ref[g, :, 0]
-                l_prev = l_ref[g, :, 0]
-                m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-                p = jnp.exp(s - m_new[:, None])
-                alpha = jnp.exp(m_prev - m_new)
-                l_ref[g] = _lanes(l_prev * alpha + jnp.sum(p, axis=-1))
-                if quantized:
-                    p = p * vs_refs[j][0, :, g:g + 1]  # v scale into probs
-                acc_ref[g] = acc_ref[g] * alpha[:, None] + \
-                    jax.lax.dot_general(
-                        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                m_ref[g] = _lanes(m_new)
+                    cp.wait()
+            return carry
 
-    for j in range(d):
-        _step(j)
+        jax.lax.fori_loop(0, live, one, 0)
+        if start:
+            def zero(j, carry):
+                vbuf[slot, pl.ds(j * bs, bs)] = jnp.zeros((bs, KV, D),
+                                                          vbuf.dtype)
+                return carry
 
-    @pl.when(m == Mp - 1)
-    def _finish():
+            jax.lax.fori_loop(live, G, zero, 0)
+
+    # loop-invariant index vectors: row-derived as (rows, 1), column-
+    # derived as (1, cols) — only the compares run at (rows, cols)
+    NG, rows, _ = acc_ref.shape       # (1, KV*R) all heads, (KV, R) per head
+    C = T * KV if all_heads else T
+    ri = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
+    if all_heads:
+        # rows are (kv head, q row), columns (token, kv head): a column
+        # belongs to a row's softmax only on the block diagonal
+        row_qpos = pos + (row0 + ri % R) // rep
+        col_tok, col_head = ci // KV, ci % KV
+        diag = col_head == ri // R
+    else:
+        row_qpos = pos + (row0 + ri) // rep
+        col_tok, diag = ci, None
+    if quantized:
+        gi = jax.lax.broadcasted_iota(jnp.int32, (G, 1), 0)
+        col_blk = col_tok // bs == gi            # (G, C) block one-hot
+
+    def col_scales(sg):
+        """(G, KV) per-block scales -> per kv head, the (1, C) scale of
+        every column (exact: one-hot selects)."""
+        def pick(g):
+            return jnp.sum(jnp.where(col_blk, sg[:, g:g + 1], 0.0), axis=0,
+                           keepdims=True)
+        if not all_heads:
+            return [pick(g) for g in range(KV)]
+        out = jnp.zeros((1, C), jnp.float32)
         for g in range(KV):
-            l_safe = jnp.maximum(l_ref[g, :, 0], 1e-30)
-            o_ref[0, g] = (acc_ref[g] / l_safe[:, None]).astype(o_ref.dtype)
+            out = jnp.where(col_head == g, pick(g), out)
+        return [out]
+
+    def compute(i, slot):
+        base = i * T
+        mask = base + col_tok <= row_qpos
+        if all_heads:
+            mask = jnp.logical_and(mask, diag)
+        if quantized:
+            off = pl.multiple_of(i * G, G)
+            ksc = col_scales(ks_ref[0, pl.ds(off, G), :])
+            # blocks past the frontier carry whatever scale their stale
+            # table entry points at: keep it off the zero probabilities
+            vsc = col_scales(jnp.where(i * G + gi < nblk,
+                                       vs_ref[0, pl.ds(off, G), :], 0.0))
+        kt, vt = kbuf[slot], vbuf[slot]                   # (T, KV, D)
+        if quantized and (early or all_heads):
+            # the int8->fp cast is exact wherever it sits; "early" casts
+            # the whole group tile once, "scores" each head's slice
+            kt, vt = kt.astype(q_ref.dtype), vt.astype(q_ref.dtype)
+        for g in range(NG):
+            if all_heads:
+                q = q_ref[0].reshape(rows, D)
+                k, v = kt.reshape(C, D), vt.reshape(C, D)
+            else:
+                q = q_ref[0, g]                           # (R, D)
+                k, v = kt[:, g, :], vt[:, g, :]           # (T, D)
+                if quantized and not early:
+                    k, v = k.astype(q.dtype), v.astype(q.dtype)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            if quantized:
+                # reference order: scores * k_scale, then / sqrt(D)
+                s = s * ksc[g]
+            s = s / jnp.float32(math.sqrt(D))
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_ref[g][:, :1]
+            l_prev = l_ref[g][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[g] = jnp.broadcast_to(
+                l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True),
+                (rows, 128))
+            if quantized:
+                p = p * vsc[g]                    # v scale into the probs
+            acc_ref[g] = acc_ref[g] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[g] = jnp.broadcast_to(m_new, (rows, 128))
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    dma(0, 0, True)
+
+    def body(i, carry):
+        slot = i % 2
+
+        @pl.when(i + 1 < ngroups)
+        def _prefetch():
+            dma(i + 1, 1 - slot, True)
+
+        dma(i, slot, False)
+        compute(i, slot)
+        return carry
+
+    jax.lax.fori_loop(0, ngroups, body, 0)
+    for g in range(NG):
+        l_safe = jnp.maximum(l_ref[g][:, :1], 1e-30)
+        out = (acc_ref[g] / l_safe).astype(o_ref.dtype)
+        if all_heads:
+            o_ref[0] = out.reshape(KV, R, D)
+        else:
+            o_ref[0, g] = out
 
 
 def _paged_attention_call(q, k_pool, v_pool, tables, pos, k_scales=None,
@@ -185,79 +296,61 @@ def _paged_attention_call(q, k_pool, v_pool, tables, pos, k_scales=None,
         raise ValueError(f"paged attention wants a PagedAttentionGeometry, "
                          f"got {type(geometry).__name__}")
     geometry.validate()
-    # geometry values quantize onto this shape deterministically
-    depth = _largest_divisor(M, geometry.kv_block_depth)
     R = Wr if geometry.q_rows == 0 else _largest_divisor(Wr, geometry.q_rows)
     NQ = Wr // R
-    Mp = M // depth
+    G, all_heads = group_plan(R, KV, bs, D, M, k_pool.dtype.itemsize,
+                              geometry.kv_block_depth)
     early = quantized and geometry.dequant == "early"
+    tables = tables.astype(jnp.int32)
     # GQA: group query heads with their shared kv head — (B, KV, W*rep, D).
     qt = q.reshape(B, W, KV, rep, D).transpose(0, 2, 1, 3, 4).reshape(
         B, KV, Wr, D)
-    # grid: batch (parallel), the optional q-row tile axis (parallel), then
-    # the sequential kv-block axis. The kv heads are walked INSIDE the
-    # program: Mosaic only takes K/V blocks whose last two dims are the
-    # pool's own (KV, D), so one program streams whole (bs, KV, D) blocks.
-    # ``geometry.grid_order`` ordered the former (batch, kv-head) grid axes
-    # and is moot now; it is still validated so swept profiles load.
-    axes = ["b"] + (["q"] if NQ > 1 else []) + ["m"]
-    sizes = {"b": B, "q": NQ, "m": Mp}
-    grid = tuple(sizes[a] for a in axes)
-    ib, im = axes.index("b"), axes.index("m")
-    iq = axes.index("q") if NQ > 1 else None
+    # grid: one program per batch row (x the optional q-row tile), both
+    # parallel. The KV axis is NOT on the grid: each program loops over
+    # its own row's live blocks, so the pools stay whole in HBM and the
+    # program copies the blocks its table names.
 
-    def q_map(*a):
-        ids = a[:-2]
-        return (ids[ib], 0, ids[iq] if iq is not None else 0, 0)
+    def q_map(b, *a):
+        return (b, 0, a[0] if NQ > 1 else 0, 0)
 
-    def kv_map(j):
-        def f(*a):
-            ids, tbl = a[:-2], a[-2]
-            return (tbl[ids[ib], ids[im] * depth + j], 0, 0, 0)
-        return f
-
-    def sc_map(j):
-        def f(*a):
-            ids, tbl = a[:-2], a[-2]
-            return (tbl[ids[ib], ids[im] * depth + j], 0, 0)
-        return f
-
-    in_specs = [pl.BlockSpec((1, KV, R, D), q_map)]
-    in_specs += [pl.BlockSpec((1, bs, KV, D), kv_map(j))
-                 for j in range(depth)]
-    in_specs += [pl.BlockSpec((1, bs, KV, D), kv_map(j))
-                 for j in range(depth)]
-    args = [tables.astype(jnp.int32), pos.astype(jnp.int32), qt]
-    args += [k_pool] * depth + [v_pool] * depth
+    in_specs = [pl.BlockSpec((1, KV, R, D), q_map),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    args = [tables, pos.astype(jnp.int32), qt, k_pool, v_pool]
     if quantized:
-        # (N, KV) scales ride as (N, 1, KV) so a block's last two dims are
-        # the array's own
-        in_specs += [pl.BlockSpec((1, 1, KV), sc_map(j))
-                     for j in range(depth)]
-        in_specs += [pl.BlockSpec((1, 1, KV), sc_map(j))
-                     for j in range(depth)]
-        args += [k_scales.astype(jnp.float32).reshape(N, 1, KV)] * depth
-        args += [v_scales.astype(jnp.float32).reshape(N, 1, KV)] * depth
+        # per-row scale tables (B, M, KV) gathered through the block
+        # table; width padded to whole groups
+        Mp = -(-M // G) * G
+
+        def row_scales(sc):
+            return jnp.pad(sc.astype(jnp.float32)[tables],
+                           ((0, 0), (0, Mp - M), (0, 0)))
+
+        in_specs += [pl.BlockSpec((1, Mp, KV), lambda b, *a: (b, 0, 0))] * 2
+        args += [row_scales(k_scales), row_scales(v_scales)]
+    NG, rows = (1, KV * R) if all_heads else (KV, R)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
+        grid=(B, NQ) if NQ > 1 else (B,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, KV, R, D), q_map),
         scratch_shapes=[
-            pltpu.VMEM((KV, R, 128), jnp.float32),   # running max
-            pltpu.VMEM((KV, R, 128), jnp.float32),   # running sum
-            pltpu.VMEM((KV, R, D), jnp.float32),     # output accumulator
+            pltpu.VMEM((2, G * bs, KV, D), k_pool.dtype),   # K group slots
+            pltpu.VMEM((2, G * bs, KV, D), v_pool.dtype),   # V group slots
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((NG, rows, 128), jnp.float32),   # running max
+            pltpu.VMEM((NG, rows, 128), jnp.float32),   # running sum
+            pltpu.VMEM((NG, rows, D), jnp.float32),     # output accumulator
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_attn_kernel, bs=bs, W=W, rep=rep, KV=KV, Mp=Mp,
-                          depth=depth, R=R, quantized=quantized,
-                          early=early, ib=ib, iq=iq, im=im),
+        functools.partial(_attn_kernel, bs=bs, rep=rep, KV=KV, M=M, G=G, R=R,
+                          quantized=quantized, early=early,
+                          all_heads=all_heads, tiled=NQ > 1),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, Wr, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=tuple(
-                "arbitrary" if a == "m" else "parallel" for a in axes)),
+            dimension_semantics=("parallel",) * (2 if NQ > 1 else 1)),
         interpret=_interpret(),
     )(*args)
     return out.reshape(B, KV, W, rep, D).transpose(0, 2, 1, 3, 4).reshape(

@@ -13,6 +13,10 @@ import jax.numpy as jnp
 
 import paddle_tpu as paddle
 from paddle_tpu import ops
+from paddle_tpu.autotune.kernel_geometry import (GeometryCache,
+                                                 PagedAttentionGeometry,
+                                                 install_geometry_cache,
+                                                 local_device_kind)
 from paddle_tpu.inference.serving import GenerationServer
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.ops.paged_attention import (paged_prefill_attention,
@@ -28,6 +32,7 @@ TOL = dict(rtol=2e-6, atol=2e-6)   # online softmax vs two-pass reference
 def _restore_kernel_mode():
     yield
     ops.set_kernel_mode("auto")
+    install_geometry_cache(None)
 
 
 def _paged_case(seed=0, B=3, W=4, H=8, KV=2, D=64, N=16, bs=8,
@@ -54,7 +59,81 @@ def _paged_case(seed=0, B=3, W=4, H=8, KV=2, D=64, N=16, bs=8,
             jnp.asarray(tables), jnp.asarray(np.array(pos, np.int32)))
 
 
+def _edge_case(quant, W, M=10, bs=8, G=3, H=8, KV=2, D=64, seed=3):
+    """The live-block walk's edges at a group of ``G`` blocks that does
+    not divide the table width ``M``: rows whose causal frontier sits at
+    position 0, mid-block, one short of a group boundary, exactly on it,
+    one past it and at the full table width, plus an idle row (zero
+    table, position 0). Every pool block NOT inside a row's frontier is
+    filled with NaN (int8: NaN scales) and the table tails point AT such
+    blocks — a kernel that merely masks what it read still returns NaN
+    through ``0 * NaN``; only one that never reads them stays finite.
+    Returns (poisoned args, clean args for the reference)."""
+    rng = np.random.default_rng(seed)
+    T = G * bs
+    fronts = [W - 1, min(M * bs - 1, W + 12), 2 * T - 2, 2 * T - 1, 2 * T,
+              M * bs - 1]
+    pos = [max(f - (W - 1), 0) for f in fronts] + [0]
+    B = len(pos)
+    N = B * M + 2
+    kp = rng.standard_normal((N, bs, KV, D)).astype(np.float32)
+    vp = rng.standard_normal((N, bs, KV, D)).astype(np.float32)
+    kp[0] = vp[0] = 0.0                       # scratch block
+    q = rng.standard_normal((B, W, H, D)).astype(np.float32)
+    free = rng.permutation(np.arange(1, N))
+    tables = np.zeros((B, M), np.int32)
+    live = np.zeros(N, bool)
+    live[0] = True
+    took = 0
+    for b in range(B - 1):                    # the last row stays idle
+        tables[b] = free[took:took + M]       # tail entries: dead blocks
+        took += M
+        live[tables[b, :(pos[b] + W - 1) // bs + 1]] = True
+    tables, pos = jnp.asarray(tables), jnp.asarray(np.array(pos, np.int32))
+    q = jnp.asarray(q)
+    dead = ~live
+    if quant == "int8":
+        kq, ks = (np.array(a) for a in quantize_block_kv(kp))
+        vq, vs = (np.array(a) for a in quantize_block_kv(vp))
+        clean = tuple(jnp.array(a) for a in (kq, ks, vq, vs))  # copies
+        kq[dead], vq[dead] = 127, 127
+        ks[dead], vs[dead] = np.nan, np.nan
+        poisoned = tuple(jnp.asarray(a) for a in (kq, ks, vq, vs))
+    else:
+        clean_k, clean_v = kp.copy(), vp.copy()
+        clean_k[dead] = clean_v[dead] = 0.0
+        clean = (jnp.asarray(clean_k), jnp.asarray(clean_v))
+        kp[dead] = vp[dead] = np.nan
+        poisoned = (jnp.asarray(kp), jnp.asarray(vp))
+    return q, poisoned, clean, tables, pos
+
+
 class TestKernelParity:
+    @pytest.mark.parametrize("depth", [3, 0])
+    @pytest.mark.parametrize("W", [1, 4, 40])
+    @pytest.mark.parametrize("quant", ["fp", "int8"])
+    def test_live_block_walk_edges(self, quant, W, depth):
+        """fp / int8 x decode (W=1), verify (W=4: all kv heads at once)
+        and a chunk-sized window (W=40: the per-head body), at a group
+        of 3 blocks in a 10-block table and at the derived group."""
+        from paddle_tpu.ops import paged_attention_pallas as pk
+
+        q, poisoned, clean, tables, pos = _edge_case(quant, W)
+        G, all_heads = pk.group_plan(W * 4, 2, 8, 64, 10, 4, depth)
+        assert all_heads == (W < 40) and (G == 3 or depth == 0)
+        ref_op = (paged_verify_attention_q if quant == "int8"
+                  else paged_verify_attention)
+        ref = ref_op(q, *clean, tables, pos)
+        ops.set_kernel_mode("pallas")
+        op = pk.paged_attention_q if quant == "int8" else pk.paged_attention
+        out = op(q, *poisoned, tables, pos,
+                 geometry=PagedAttentionGeometry(kv_block_depth=depth))
+        assert np.isfinite(np.asarray(out)).all()   # dead blocks not read
+        np.testing.assert_allclose(np.asarray(ref)[:-1],
+                                   np.asarray(out)[:-1], **TOL)
+        # the idle row attends position 0 of the (zero) scratch block
+        np.testing.assert_array_equal(np.asarray(out)[-1], 0.0)
+
     @pytest.mark.parametrize("W", [1, 4])
     def test_fp_verify_and_decode(self, W):
         q, kp, vp, tables, pos = _paged_case(W=W)
@@ -237,12 +316,15 @@ def _lora_setup(cfg, rank=4, alpha=8.0):
     return LoRAConfig(reg, max_live_adapters=2, max_rank=rank)
 
 
-@pytest.mark.parametrize("scenario", ["fp", "int8", "lora", "spec"])
+@pytest.mark.parametrize("scenario", ["fp", "int8", "lora", "spec",
+                                      "fp_depth2", "int8_depth3"])
 def test_greedy_token_identity_pallas_vs_reference(scenario):
     """THE acceptance criterion: greedy serving output must be
     token-identical between the Pallas (interpret) and reference paths —
     fp, int8 KV, +LoRA, +speculative — under multi-chunk prefill, slot
-    churn and partial final blocks."""
+    churn and partial final blocks; and at an explicit blocks-per-group
+    (``_depthN``: the online-softmax update boundaries move, so outputs
+    agree to ~1e-6 instead of bitwise — the tokens must not)."""
     model, cfg = _tiny_model()
     rng = np.random.RandomState(11)
     prompts = [rng.randint(1, cfg.vocab_size, (n,)).tolist()
@@ -250,7 +332,14 @@ def test_greedy_token_identity_pallas_vs_reference(scenario):
 
     kw = dict(max_batch=2, max_len=64, cache="paged", block_size=4,
               prefill_chunk=8)
-    if scenario == "int8":
+    if "_depth" in scenario:
+        cache = GeometryCache()
+        cache.put("paged_attention",
+                  "int8" if scenario.startswith("int8") else "float32", 16,
+                  local_device_kind(), PagedAttentionGeometry(
+                      kv_block_depth=int(scenario[-1])))
+        install_geometry_cache(cache)
+    if scenario.startswith("int8"):
         kw["kv_quant"] = "int8"
     elif scenario == "spec":
         from paddle_tpu.inference.speculative import SpecConfig

@@ -224,6 +224,71 @@ def test_paged_attention_kernel_matches_f32_reference(B, W, quantized):
                                np.asarray(want), rtol=3e-2, atol=3e-2)
 
 
+def _cell_setup(B, W, quantized, seed):
+    """The serving cells' own shapes (benchmarks/configs): a 4096-block
+    pool, tables 4096 tokens wide, ragged rows — position 0, either side
+    of a block and of a 256-token group boundary, the full table width —
+    idle rows (zero table, position 0) and table tails that point at a
+    NaN block (fp; NaN scales for int8), which must not be read."""
+    from paddle_tpu.ops import paged_attention as pa
+
+    rng = np.random.RandomState(seed)
+    N, bs = 4096, 32 if quantized else 16
+    M = 4096 // bs
+    edges = [0, 15, 16, 255, 256, 257, 4096 - W]
+    if B == 1:
+        pos = np.array([4096 - 2 * W], np.int32)      # a late chunk
+        idle = 0
+    else:
+        idle = 8
+        pos = np.concatenate([
+            np.minimum(edges, 4096 - W),
+            rng.randint(64, 1400, B - idle - len(edges)),
+            np.zeros(idle, int)]).astype(np.int32)
+    dead = N - 1
+    tables = np.full((B, M), dead, np.int32)
+    free, took = rng.permutation(np.arange(1, dead)), 0
+    for b in range(B):
+        nb = (pos[b] + W - 1) // bs + 1
+        tables[b, :nb] = free[took:took + nb]
+        took += nb
+    tables[B - idle:] = 0
+    q = jnp.asarray(rng.randn(B, W, _PH, _PD).astype("float32")
+                    ).astype(jnp.bfloat16)
+    kf = rng.randn(N, bs, _PKV, _PD).astype("float32")
+    vf = rng.randn(N, bs, _PKV, _PD).astype("float32")
+    kf[[0, dead]] = vf[[0, dead]] = 0.0
+    tables, pos = jnp.asarray(tables), jnp.asarray(pos)
+    qpos = pos[:, None] + jnp.arange(W)[None, :]
+    if quantized:
+        kq, ks = pa.quantize_block_kv(jnp.asarray(kf))
+        vq, vs = pa.quantize_block_kv(jnp.asarray(vf))
+        kref, vref = pa.dequantize_block_kv(kq, ks), \
+            pa.dequantize_block_kv(vq, vs)
+        pools = (kq, ks.at[dead].set(jnp.nan), vq, vs.at[dead].set(jnp.nan))
+    else:
+        kb, vb = jnp.asarray(kf, jnp.bfloat16), jnp.asarray(vf, jnp.bfloat16)
+        kref, vref = kb.astype(jnp.float32), vb.astype(jnp.float32)
+        pools = (kb.at[dead].set(jnp.nan), vb.at[dead].set(jnp.nan))
+    want = jax.jit(lambda q_, k_, v_: pa._attention_core(
+        q_.astype(jnp.float32), pa.gather_block_kv(k_, tables),
+        pa.gather_block_kv(v_, tables), qpos))(q, kref, vref)
+    return q, pools, tables, pos, want
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+@pytest.mark.parametrize("B,W", [(64, 1), (1, 128)],
+                         ids=["decode_B64", "chunk_C128_late"])
+def test_paged_attention_at_the_serving_cells_shapes(B, W, quantized):
+    from paddle_tpu.ops import paged_attention_pallas as pk
+
+    q, pools, tables, pos, want = _cell_setup(B, W, quantized, 5 + W)
+    op = pk.paged_attention_q if quantized else pk.paged_attention
+    got = np.asarray(jax.jit(op)(q, *pools, tables, pos), np.float32)
+    assert np.isfinite(got).all()          # the NaN block was never read
+    np.testing.assert_allclose(got, np.asarray(want), rtol=3e-2, atol=3e-2)
+
+
 def test_paged_attention_public_ops_select_the_kernel():
     from paddle_tpu.ops import paged_attention as pa
 

@@ -112,6 +112,10 @@ def _paged_case(B, W, quantized, N=512, bs=16, M=32, H=H8, KV=KV8, D=D8):
             [q, pool, sc, pool, sc, tables, pos])
 
 
+_CELL = dict(N=4096, bs=16, M=256)
+_CELL_INT8 = dict(N=4096, bs=32, M=128)     # same 4096-token tables
+
+
 def _lora_case(B, S, IN, OUT, R=16):
     from paddle_tpu.ops.paged_attention_pallas import fused_lora_matmul
 
@@ -151,6 +155,18 @@ def kernel_cases():
         ("paged.int8_decode_W1", lambda: _paged_case(8, 1, True, bs=32)),
         ("paged.int8_verify_W4", lambda: _paged_case(8, 4, True, bs=32)),
         ("paged.int8_prefill_C128", lambda: _paged_case(1, 128, True, bs=32)),
+        # the serving cells' own shapes (benchmarks/configs): 64 slots,
+        # 4096-token tables, the 4 GiB pool
+        ("paged.cell_fp_decode_B64_M256",
+         lambda: _paged_case(64, 1, False, **_CELL)),
+        ("paged.cell_fp_verify_W4_B64",
+         lambda: _paged_case(64, 4, False, **_CELL)),
+        ("paged.cell_fp_prefill_C128_M256",
+         lambda: _paged_case(1, 128, False, **_CELL)),
+        ("paged.cell_int8_decode_B64_M256",
+         lambda: _paged_case(64, 1, True, **_CELL_INT8)),
+        ("paged.cell_int8_prefill_C128",
+         lambda: _paged_case(1, 128, True, **_CELL_INT8)),
         ("lora.decode_4096x4096", lambda: _lora_case(8, 1, HID8, HID8)),
         ("lora.decode_4096x14336", lambda: _lora_case(8, 1, HID8, FFN8)),
         ("w8.decode_4096x14336", lambda: _w8_case(8, HID8, FFN8)),

@@ -34,11 +34,29 @@ When the eager guard rejects the geometry the row carries
 ``megakernel_active: false`` with the reason and still benches the
 other two rungs — the ladder degrading is a result, not an error.
 
+``--ops ragged`` adds the SERVING-CELL rows (independent of ``--shapes``):
+the decode kernel at the benchmark cells' own shapes — ``--ragged-rows``
+slots (64) of which ``--ragged-live`` hold a request (``64,9``: the decode
+and the prefill cell's tick), a ``--ragged-table`` wide table (256 blocks
+of 16 tokens), a 4096-block bf16 pool, 32/8 heads of 128, lognormal
+lengths that sum to ``--ragged-tokens`` (44000, scaled by live/rows) — and
+the 128-token chunk at starts 0 / 1920 / 3840. Each row is 16 dependent
+calls in ONE program (a tick's 16 layers: one dispatch, so the host's
+launch cost is not in the number) and prints microseconds a call and the
+share of the roofline: memory for decode, bytes as
+``benchmarks/roofline/paged_attention.py`` counts them; compute for the
+chunk, FLOPs as ``benchmarks/roofline/prefill_attention.py`` counts them;
+peaks from ``benchmarks/peaks.json`` by ``device_kind`` (no share off the
+chip). ``--ragged-depths 0,8,32`` repeats each row at explicit blocks per
+group (``PagedAttentionGeometry.kv_block_depth``; 0 = derived), which is
+how the group width in ``ops/paged_attention_pallas.py`` was chosen.
+
 Usage:
     python tools/kernel_bench.py [--json] [--iters 10]
         [--shapes 2,4,8;4,8,16] [--window 4] [--heads 8] [--kv-heads 2]
-        [--head-dim 128] [--layers 2] [--ops decode,verify,prefill,tick]
-        [--quant fp,int8] [--tp N]
+        [--head-dim 128] [--layers 2]
+        [--ops decode,verify,prefill,tick,ragged] [--quant fp,int8] [--tp N]
+    python tools/kernel_bench.py --ops ragged [--ragged-depths 0,8,32]
 
 One JSON line per (op, quant, B, M, bs) combo under --json (bench.py
 style); a human table otherwise.
@@ -109,6 +127,14 @@ def main():
                     help="decoder layers for the whole-tick row")
     ap.add_argument("--quant", default="fp,int8")
     ap.add_argument("--iters", type=int, default=10)
+    ap.add_argument("--ragged-rows", type=int, default=64)
+    ap.add_argument("--ragged-live", default="64,9",
+                    help="comma list: live rows of --ragged-rows (the "
+                         "rest idle: zero table, position 0)")
+    ap.add_argument("--ragged-table", type=int, default=256)
+    ap.add_argument("--ragged-tokens", type=int, default=44000)
+    ap.add_argument("--ragged-depths", default="0",
+                    help="comma list of blocks per group (0 = derived)")
     ap.add_argument("--tp", type=int, default=1,
                     help="also run every combo sharded over an N-way "
                          "'tp' mesh (shard_map, serving shard layout) "
@@ -508,11 +534,110 @@ def main():
         row["parity"] = diff < 2e-4
         return row
 
+    def ragged_rows():
+        """The decode kernel and the chunk at the serving cells' shapes:
+        us a call and the share of the roofline (module docstring)."""
+        from benchmarks.roofline import paged_attention as dec_work
+        from benchmarks.roofline import prefill_attention as chunk_work
+        from paddle_tpu.autotune.kernel_geometry import \
+            PagedAttentionGeometry
+        from paddle_tpu.ops import paged_attention_pallas as pk
+
+        H, KV, D, bs, N, C, calls = 32, 8, 128, 16, 4096, 128, 16
+        B, M = args.ragged_rows, args.ragged_table
+        peaks = json.load(open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmarks", "peaks.json")))
+        peak = peaks.get(jax.devices()[0].device_kind)
+        rng = np.random.RandomState(args.seed)
+        pool = [jnp.asarray(rng.randn(N, bs, KV, D).astype(np.float32),
+                            jnp.bfloat16) for _ in range(2)]
+
+        def case(lens, W):
+            tables = np.zeros((len(lens), M), np.int32)
+            free, took = rng.permutation(np.arange(1, N)), 0
+            for b, n in enumerate(lens):
+                if n:
+                    nb = (n + W - 1) // bs + 1
+                    tables[b, :nb] = free[took:took + nb]
+                    took += nb
+            q = jnp.asarray(rng.randn(len(lens), W, H, D).astype(np.float32),
+                            jnp.bfloat16)
+            return q, jnp.asarray(tables), jnp.asarray(
+                np.asarray(lens, np.int32))
+
+        def chained(depth):
+            geom = PagedAttentionGeometry(kv_block_depth=depth)
+
+            def run(q, t, p, k, v):
+                def layer(c, _):
+                    o = pk.paged_attention(c, k, v, t, p, geometry=geom)
+                    return c + (o * 0).astype(c.dtype), o
+                return jax.lax.scan(layer, q, None, length=calls)[1][-1]
+            return run
+
+        def one(label, q, t, p, depth, work, peak_key):
+            mode = ops.kernel_mode()
+            try:
+                ops.set_kernel_mode("reference")
+                ref = jax.jit(pa.paged_verify_attention)(q, *pool, t, p)
+                ops.set_kernel_mode("pallas")
+                secs, out = timed(chained(depth), (q, t, p, *pool))
+            finally:
+                ops.set_kernel_mode(mode)
+            live = np.asarray(p) > 0 if q.shape[0] > 1 else slice(None)
+            diff = float(jnp.max(jnp.abs(
+                out.astype(jnp.float32) - ref.astype(jnp.float32))[live]))
+            W = q.shape[1]
+            G, all_heads = pk.group_plan(W * H // KV, KV, bs, D, M, 2, depth)
+            return {
+                "metric": "paged_ragged_kernel_us", "op": label,
+                "quant": "fp", "B": q.shape[0], "M": M, "bs": bs, "W": W,
+                "backend": backend,
+                "pallas_mode": "mosaic" if on_tpu else "interpret",
+                "blocks_per_group": G,
+                "body": "all_heads" if all_heads else "per_head",
+                "us_per_call": round(secs / calls * 1e6, 1),
+                "roofline_pct": (
+                    round(100 * work / peak[peak_key] * calls / secs, 2)
+                    if peak and on_tpu else None),
+                "max_abs_diff": diff, "parity": diff < 2e-2,
+            }
+
+        out = []
+        depths = [int(d) for d in args.ragged_depths.split(",")]
+        for live in (int(x) for x in args.ragged_live.split(",")):
+            lens = np.zeros(B, int)
+            draw = np.clip(rng.lognormal(np.log(620), 0.55, live), 64,
+                           M * bs - 1)
+            lens[rng.permutation(B)[:live]] = np.clip(
+                draw * args.ragged_tokens * live / B / draw.sum(), 1,
+                M * bs - 1).astype(int)
+            q, t, p = case(lens, 1)
+            ctx = int(lens.sum() + B)
+            for depth in depths:
+                out.append(one(
+                    f"decode{live}of{B}", q, t, p, depth,
+                    dec_work.nbytes(H, KV, D, ctx, B, 1), "hbm_bytes_per_s"))
+                out[-1]["ctx_tokens"] = ctx
+        q, t, _ = case([M * bs - C], C)
+        for start in dict.fromkeys(
+                (0, (M * bs // 2 - C) // bs * bs, M * bs - 2 * C)):
+            for depth in depths:
+                out.append(one(
+                    f"chunk@{start}", q, t, jnp.full((1,), start, jnp.int32),
+                    depth, chunk_work.flops(H, D, [(start, C)], 1),
+                    "bf16_flops"))
+        return out
+
     rows = []
+    op_list = args.ops.split(",")
+    if "ragged" in op_list:
+        op_list.remove("ragged")
+        rows += ragged_rows()
     for B, M, bs in parse_shapes(args.shapes):
         for quant in args.quant.split(","):
             rng = np.random.RandomState(args.seed)
-            for op in args.ops.split(","):
+            for op in op_list:
                 if op == "tick":
                     for lora_on in (False, True):
                         rows.append(
@@ -637,6 +762,13 @@ def main():
         print(hdr)
         print("-" * len(hdr))
         for r in rows:
+            if r["metric"] == "paged_ragged_kernel_us":
+                print(f"{r['op']:14} B={r['B']:<3} W={r['W']:<4} "
+                      f"G={r['blocks_per_group']:<3} {r['body']:9} "
+                      f"{r['us_per_call']:>9} us/call  roofline "
+                      f"{r['roofline_pct']} %  max|diff| "
+                      f"{r['max_abs_diff']:.2e}")
+                continue
             if r["metric"] == "geometry_sweep":
                 print(f"{r['op']:8} sweep  winner="
                       f"{json.dumps(r['winner_geometry'], sort_keys=True)} "
