@@ -55,28 +55,45 @@ class PagedExecutor:
 
         self.engine = engine
         cfg = engine.cfg
-        kv = cfg.num_key_value_heads
-        d = cfg.hidden_size // cfg.num_attention_heads
+        spec = self.spec = engine.cache_spec
         cdtype = convert_dtype(cfg.dtype)
         bs = engine.block_size
         kv_quant = engine.kv_quant
-        if kv_quant == "int8":
-            # per layer: K codes, K scales, V codes, V scales — the
-            # scale rows ride in the flat pool list so donation and
-            # in-place updates cover them too
-            self.pools: List[Any] = []
-            for _ in range(cfg.num_hidden_layers):
-                for _kv in range(2):
+        # the shared block pool: one entry per ``full`` layer of the spec
+        self.pools: List[Any] = []
+        for i in spec.full_layers:
+            block = spec.layers[i].block_shape(bs)
+            kv = spec.layers[i].kv_heads
+            for _kv in range(2):
+                if kv_quant == "int8":
+                    # K codes, K scales, V codes, V scales — the scale
+                    # rows ride in the flat pool list so donation and
+                    # in-place updates cover them too
                     self.pools.append(jnp.zeros(
-                        (int(num_blocks), bs, kv, d), jnp.int8))
+                        (int(num_blocks),) + block, jnp.int8))
                     self.pools.append(jnp.zeros(
                         (int(num_blocks), kv), jnp.float32))
-        else:
-            self.pools = [jnp.zeros((int(num_blocks), bs, kv, d), cdtype)
-                          for _ in range(2 * cfg.num_hidden_layers)]
+                else:
+                    self.pools.append(jnp.zeros(
+                        (int(num_blocks),) + block, cdtype))
         # tensors per layer entry in the flat pool list: fp (K, V) = 2;
         # int8 (Kq, Kscale, Vq, Vscale) = 4
         self.pool_stride = 4 if kv_quant == "int8" else 2
+        # what a slot owns for as long as it is occupied: the window
+        # layers' rings (slot b = blocks 1 + b*R .. (b+1)*R; block 0 is
+        # scratch) and the state layers' slot-indexed arrays
+        self.slot_pools: List[Any] = []
+        self._slot_index: Dict[int, range] = {}
+        for i in spec.slot_layers:
+            l, at = spec.layers[i], len(self.slot_pools)
+            if l.kind == "window":
+                n = 1 + engine.max_batch * spec.ring_blocks(i, bs)
+                self.slot_pools += [jnp.zeros((n,) + l.block_shape(bs), cdtype)
+                                    for _kv in range(2)]
+            else:
+                self.slot_pools += [jnp.zeros((engine.max_batch,) + shape, t)
+                                    for _, shape, t in l.shapes]
+            self._slot_index[i] = range(at, len(self.slot_pools))
 
         self.mesh = None
         self.tp = 1
@@ -148,10 +165,10 @@ class PagedExecutor:
         decode_body = (self._decode_megakernel_fn if self.megakernel
                        else self._decode_paged_fn)
         self.decode_paged = self._jit(decode_body,
-                                      donate_argnums=(2,),
+                                      donate_argnums=(2, 14),
                                       static_argnums=(12, 13))
         self.chunk_prefill = self._jit(self._chunk_prefill_fn,
-                                       donate_argnums=(2,))
+                                       donate_argnums=(2, 8))
         self.spec_scan = None
         self.spec_verify = None
         if engine.spec is not None:
@@ -205,18 +222,61 @@ class PagedExecutor:
         return sm.audit_pool_shardings(self.pools, self.mesh)
 
     # ------------------------------------------------------------ pool views
-    def _pool_views(self, flat_p):
-        """Group the flat per-layer pool list back into per-layer tuples:
-        fp → (K, V); int8 → (Kq, Kscale, Vq, Vscale). The model's paged
-        methods branch on the tuple arity, so the same compiled-fn bodies
-        serve both pool formats."""
+    def _pool_views(self, flat_p, slot_p=()):
+        """One view per layer, as the cache spec declares it: a ``full``
+        layer's entry of the flat block-pool list — fp → (K, V); int8 →
+        (Kq, Kscale, Vq, Vscale); the model's paged methods branch on the
+        tuple arity, so the same compiled-fn bodies serve both pool
+        formats — a ``window`` layer's ring pools or a ``state`` layer's
+        slot arrays out of ``slot_p``, and ``()`` for a layer that owns
+        nothing."""
         st = self.pool_stride
-        return [tuple(Tensor(flat_p[st * i + j]) for j in range(st))
-                for i in range(self.engine.cfg.num_hidden_layers)]
+        views = [()] * len(self.spec.layers)
+        for n, i in enumerate(self.spec.full_layers):
+            views[i] = tuple(Tensor(flat_p[st * n + j]) for j in range(st))
+        for i, idx in self._slot_index.items():
+            views[i] = tuple(Tensor(slot_p[j]) for j in idx)
+        return views
 
-    @staticmethod
-    def _flat_pools(new):
-        return [t.value for entry in new for t in entry]
+    def _flat_pools(self, new):
+        """The model's new views, split back into (block pools, slot
+        pools) in the order :meth:`_pool_views` read them."""
+        flat = [t.value for i in self.spec.full_layers for t in new[i]]
+        return flat, [t.value for i in self._slot_index for t in new[i]]
+
+    # ----------------------------------------------------------- slot state
+    def save_slot(self, slot: int) -> List[Any]:
+        """Host copies of everything ``slot`` owns besides blocks of the
+        shared pool (window rings, state rows), in ``slot_pools`` order:
+        what a preempted or captured request carries along."""
+        import numpy as np
+
+        out = []
+        for i in self.spec.slot_layers:
+            for j in self._slot_index[i]:
+                p = self.slot_pools[j]
+                if self.spec.layers[i].kind == "window":
+                    R = self.spec.ring_blocks(i, self.engine.block_size)
+                    p = p[1 + slot * R:1 + (slot + 1) * R]
+                else:
+                    p = p[slot]
+                out.append(np.asarray(p))   # graftlint: noqa[host-sync]
+        return out
+
+    def restore_slot(self, slot: int, arrays) -> None:
+        """Write :meth:`save_slot`'s arrays into ``slot`` (any slot: ring
+        entries are addressed by position, not by block id)."""
+        new, it = list(self.slot_pools), iter(arrays)
+        for i in self.spec.slot_layers:
+            for j in self._slot_index[i]:
+                a = jnp.asarray(next(it)).astype(new[j].dtype)
+                if self.spec.layers[i].kind == "window":
+                    R = self.spec.ring_blocks(i, self.engine.block_size)
+                    new[j] = jax.lax.dynamic_update_slice_in_dim(
+                        new[j], a, 1 + slot * R, 0)
+                else:
+                    new[j] = new[j].at[slot].set(a)
+        self.slot_pools = new
 
     def _gather_lora(self, lora_flat, aidx):
         """Gather each row's adapter factors from the paged LoRA pool —
@@ -230,7 +290,8 @@ class PagedExecutor:
     # ------------------------------------------------------------- programs
     def _decode_paged_fn(self, params, tokens, flat_pools, tables, pos,
                          temps, topks, topps, active, key, aidx=None,
-                         lora_flat=(), greedy=False, ticks=None):
+                         lora_flat=(), greedy=False, ticks=None,
+                         slot_pools=()):
         """Paged decode window: K/V reads/writes go through per-slot
         block tables into the shared pool. ``tables``: int32
         (B, table_width) — the engine zeroes rows of idle/prefilling slots
@@ -241,19 +302,24 @@ class PagedExecutor:
         longer windows than its verify trips (SpecConfig.gate_ticks).
         ``aidx``/``lora_flat``: per-slot adapter page indices + the LoRA
         pool's stacked factor tensors — gathered ONCE per trip (rows are
-        loop-invariant across ticks) and applied in-program (BGMV)."""
+        loop-invariant across ticks) and applied in-program (BGMV).
+        ``slot_pools``: the window rings and state arrays of the spec's
+        slot kinds (donated like the block pools; empty for a dense
+        decoder), and with them the model is told through ``active=``
+        which rows may touch them."""
         engine = self.engine
         model = engine.model
         lora = self._gather_lora(lora_flat, aidx)
+        slot_kw = {"active": active} if self.spec.has_slot_state else {}
 
         def one_tick(carry, k):
-            toks, flat_p, p = carry
-            pools = self._pool_views(flat_p)
+            toks, (flat_p, slot_p), p = carry
+            pools = self._pool_views(flat_p, slot_p)
 
             def call():
                 h, new = model.model.paged_decode_step(Tensor(toks[:, None]),
                                                        pools, tables, p,
-                                                       lora=lora)
+                                                       lora=lora, **slot_kw)
                 return engine._head(h), new
 
             logits, new = functional_call(model, params, call_fn=call)
@@ -269,15 +335,17 @@ class PagedExecutor:
             return (nxt, flat, p + active), nxt
 
         n = engine.tick_window if ticks is None else ticks
+        carry = (tokens, (list(flat_pools), list(slot_pools)), pos)
         if n == 1:
-            (_, flat, _), stack = one_tick((tokens, flat_pools, pos), 0)
-            return stack[None], flat
-        (_, flat, _), stack = jax.lax.scan(
-            one_tick, (tokens, flat_pools, pos), jnp.arange(n))
-        return stack, flat
+            (_, (flat, slot), _), stack = one_tick(carry, 0)
+            return stack[None], flat, slot
+        (_, (flat, slot), _), stack = jax.lax.scan(one_tick, carry,
+                                                   jnp.arange(n))
+        return stack, flat, slot
 
     def _chunk_prefill_fn(self, params, chunk, flat_pools, table, start,
-                          last_idx, aidx=None, lora_flat=()):
+                          last_idx, aidx=None, lora_flat=(), slot_pools=(),
+                          slot=None):
         """ONE compiled program for every prefill chunk of every prompt
         length: chunk (1, C) right-padded; K/V scatter into the slot's
         block table at block-aligned ``start``; returns fp32 logits at
@@ -285,6 +353,11 @@ class PagedExecutor:
         chunk; ignored on earlier chunks) + updated pools. ``aidx`` is the
         prefilling slot's adapter page index, shape (1,) — prompt tokens
         must see the same adapter delta the decode ticks will.
+        ``slot_pools`` as in :meth:`_decode_paged_fn`; ``slot``: int32
+        ``(slot index, real tokens in this chunk, 1 on the request's last
+        chunk)`` — what a model with per-slot state needs to find its rows,
+        stop its state at the last real token, and skip stateless layers
+        where no logits are wanted.
 
         Context parallelism is a one-line steer: at ``cp > 1`` the chunk
         is constrained to shard its sequence dim over the ``cp`` axis.
@@ -306,18 +379,21 @@ class PagedExecutor:
                 chunk, NamedSharding(self.mesh, P(None, SERVING_CP_AXIS)))
         engine = self.engine
         model = engine.model
-        pools = self._pool_views(flat_pools)
+        pools = self._pool_views(flat_pools, slot_pools)
         lora = self._gather_lora(lora_flat, aidx)
+        slot_kw = {"slot": slot} if self.spec.has_slot_state else {}
 
         def call():
             h, new = model.model.paged_prefill_chunk(Tensor(chunk), pools,
                                                      table, start,
-                                                     lora=lora)
-            last = jax.lax.dynamic_slice_in_dim(h.value, last_idx, 1, 1)
-            return engine._head(Tensor(last)), new
+                                                     lora=lora,
+                                                     last_idx=last_idx,
+                                                     **slot_kw)
+            return engine._head(h), new
 
         logits, new = functional_call(model, params, call_fn=call)
-        return logits.value[:, 0].astype(jnp.float32), self._flat_pools(new)
+        return (logits.value[:, 0].astype(jnp.float32),
+                *self._flat_pools(new))
 
     def _spec_verify_fn(self, params, tokens, proposals, flat_pools, tables,
                         pos, temps, topks, topps, kcaps, key, qprobs,
@@ -342,7 +418,7 @@ class PagedExecutor:
             return engine._head(h), new
 
         logits, new = functional_call(model, params, call_fn=call)
-        flat = self._flat_pools(new)
+        flat = self._flat_pools(new)[0]
         from .speculative import speculative_accept
 
         out, acc = speculative_accept(
@@ -390,7 +466,7 @@ class PagedExecutor:
                 return engine._head(h), new
 
             logits, new = functional_call(model, params, call_fn=call)
-            flat = self._flat_pools(new)
+            flat = self._flat_pools(new)[0]
             out, acc = speculative_accept(
                 logits.value.astype(jnp.float32), proposals, temps, topks,
                 topps, kcaps, jax.random.fold_in(key, w), None,
@@ -466,7 +542,7 @@ class PagedExecutor:
     def _decode_megakernel_fn(self, params, tokens, flat_pools, tables,
                               pos, temps, topks, topps, active, key,
                               aidx=None, lora_flat=(), greedy=False,
-                              ticks=None):
+                              ticks=None, slot_pools=()):
         """The whole-tick twin of :meth:`_decode_paged_fn` — identical
         signature, sampling pipeline, and trip structure; only the
         per-tick model call collapses into the ONE persistent Pallas
@@ -491,10 +567,10 @@ class PagedExecutor:
         n = engine.tick_window if ticks is None else ticks
         if n == 1:
             (_, flat, _), stack = one_tick((tokens, flat_pools, pos), 0)
-            return stack[None], flat
+            return stack[None], flat, list(slot_pools)
         (_, flat, _), stack = jax.lax.scan(
             one_tick, (tokens, flat_pools, pos), jnp.arange(n))
-        return stack, flat
+        return stack, flat, list(slot_pools)
 
     def _spec_verify_megakernel_fn(self, params, tokens, proposals,
                                    flat_pools, tables, pos, temps, topks,

@@ -79,6 +79,11 @@ class SwapHandle:
     hashes: List[int] = field(default_factory=list)  # leading full-prompt-block chain hashes
     nbytes: int = 0          # logical bytes charged to the host pool
     checksum: int = 0        # CRC32 of the parked payload (0 = unverified)
+    # trailing arrays of the payload that are NOT pool blocks: what the
+    # slot owned besides them (window rings, recurrent state; the cache
+    # spec's slot kinds). swap_in hands them back in ``extra``.
+    n_extra: int = 0
+    extra: Optional[List[np.ndarray]] = None
 
 
 class HostKVPool:
@@ -461,11 +466,16 @@ class KVOffloadEngine:
     # ------------------------------------------------------------- swap out
     def swap_out(self, rid: int, table: Sequence[int], hashes: Sequence[int],
                  pools: List[Any], n_tokens: int,
-                 last_token: int) -> Optional[SwapHandle]:
+                 last_token: int, extra: Sequence[np.ndarray] = ()
+                 ) -> Optional[SwapHandle]:
         """Park a request's KV on host and free its device blocks.
 
         ``table`` must already be truncated to exactly the blocks covering
         ``n_tokens`` (the server drops speculative reservations first).
+        ``extra``: host arrays that travel with the request besides its
+        blocks (the slot's window rings and recurrent state); they join the
+        payload — one CRC, one host-pool charge — and come back from
+        :meth:`swap_in` in ``handle.extra``.
         Returns None — and changes nothing — when the host pool is full
         (or an injected ``host_put`` fault says it is).
         """
@@ -473,12 +483,12 @@ class KVOffloadEngine:
         _t0 = tel.clock() if tel is not None and tel.enabled else None
         a = self.alloc
         n = len(table)
-        nbytes = n * a.bytes_per_block
+        nbytes = n * a.bytes_per_block + sum(x.nbytes for x in extra)
         if self.faults is not None and self.faults.fire("host_put") is not None:
             return None
         if not self.host.fits(nbytes):
             return None
-        arrays = self.gather_payload(table, pools)
+        arrays = self.gather_payload(table, pools) + list(extra)
         checksum = payload_checksum(arrays)
         if not self.host.put(rid, arrays, nbytes):
             return None
@@ -498,7 +508,7 @@ class KVOffloadEngine:
         return SwapHandle(rid=rid, n_tokens=int(n_tokens),
                           last_token=int(last_token), n_blocks=n,
                           hashes=list(hashes), nbytes=nbytes,
-                          checksum=checksum)
+                          checksum=checksum, n_extra=len(extra))
 
     # -------------------------------------------------------------- swap in
     def restore_cost(self, handle: SwapHandle) -> int:
@@ -576,6 +586,8 @@ class KVOffloadEngine:
             didx = jnp.asarray(idx)
             pools = [p.at[didx].set(jnp.asarray(arr).astype(p.dtype))
                      for p, arr in zip(pools, arrays)]
+        handle.extra = list(arrays[len(arrays) - handle.n_extra:]) \
+            if handle.n_extra else None
         for i in range(len(matched), min(len(handle.hashes), len(table))):
             a.register(table[i], handle.hashes[i])
             self.forget_warm(handle.hashes[i])

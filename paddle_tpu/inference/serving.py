@@ -49,19 +49,9 @@ def kv_block_bytes(cfg, block_size: int, kv_quant: str = "none") -> int:
     """HBM bytes one KV block costs across ALL layers (K + V pools, plus
     the f32 scale rows for the int8 pool) — the unit `pool_bytes=` sizing
     and the benchmark's ``kv_bytes_per_token`` are derived from."""
-    from ..framework.dtype import convert_dtype
+    from .cache_spec import dense_decoder_spec
 
-    import jax.numpy as jnp
-
-    kv = cfg.num_key_value_heads
-    d = cfg.hidden_size // cfg.num_attention_heads
-    if kv_quant == "int8":
-        # int8 codes + one f32 scale per (block, kv head)
-        per_pool = block_size * kv * d * 1 + kv * 4
-    else:
-        itemsize = jnp.zeros((), convert_dtype(cfg.dtype)).dtype.itemsize
-        per_pool = block_size * kv * d * itemsize
-    return 2 * cfg.num_hidden_layers * per_pool
+    return dense_decoder_spec(cfg).block_bytes(block_size, kv_quant)
 
 
 @dataclass
@@ -298,6 +288,30 @@ class GenerationServer:
         assert max_len <= cfg.max_position_embeddings
         if cache not in ("dense", "paged"):
             raise ValueError(f"cache must be 'dense' or 'paged', got {cache!r}")
+        # what each layer keeps per request (inference/cache_spec.py): the
+        # pools, admission, preemption and snapshots below are built from
+        # it; a model that declares nothing is a dense decoder
+        from .cache_spec import CacheSpecError, dense_decoder_spec
+
+        self.cache_spec = (model.cache_spec() if hasattr(model, "cache_spec")
+                           else dense_decoder_spec(cfg))
+        if self.cache_spec.has_slot_state:
+            # per-slot window rings and recurrent state: features that
+            # would need a state rollback, a quantized ring or a sharded
+            # state are refused here, by name, not found out mid-request
+            for what, on in (("cache='dense'", cache != "paged"),
+                             ("spec= (speculative decoding)",
+                              spec is not None),
+                             ("lora=", lora is not None),
+                             ("kv_quant='int8'", kv_quant != "none"),
+                             ("mesh=", mesh not in (None, 1)),
+                             ("kernels='megakernel'",
+                              kernels == "megakernel")):
+                if on:
+                    raise CacheSpecError(
+                        f"{type(model).__name__} keeps per-slot state "
+                        f"(window rings, recurrent state): {what} is not "
+                        f"supported for it")
         if kv_quant not in ("none", "int8"):
             raise ValueError(
                 f"kv_quant must be 'none' or 'int8', got {kv_quant!r}")
@@ -389,7 +403,7 @@ class GenerationServer:
             install_geometry_cache(self.profile.geometry_cache(),
                                    source="profile")
         self.kernel_geometry = resolve_server_geometries(
-            head_dim=cfg.hidden_size // cfg.num_attention_heads,
+            head_dim=self.cache_spec.kv_geometry()[1],
             hidden=cfg.hidden_size,
             dtype=str(jnp.zeros((), _cvt(cfg.dtype)).dtype),
             kv_quant=kv_quant,
@@ -415,8 +429,7 @@ class GenerationServer:
 
         from ..framework.dtype import convert_dtype
 
-        kv = cfg.num_key_value_heads
-        d = cfg.hidden_size // cfg.num_attention_heads
+        kv, d = self.cache_spec.kv_geometry()
         cdtype = convert_dtype(cfg.dtype)
         # per-slot scalars live HOST-side (numpy): slot assignment would
         # otherwise cost one eager device dispatch per field per request
@@ -556,6 +569,24 @@ class GenerationServer:
         self._c_pf_ctx = reg.counter(
             "serving_prefill_ctx",
             "positions attended by those tokens under the causal mask")
+        # the same, by cache kind, for a spec that has such layers (per
+        # ONE layer of the kind: a reader multiplies by the layer count)
+        self._c_dec_ctx_win = reg.counter(
+            "serving_decode_ctx_window",
+            "positions a decode row-tick attended in a window layer "
+            "(its context capped at the window)")
+        self._c_dec_ctx_shared = reg.counter(
+            "serving_decode_ctx_shared",
+            "positions a decode row-tick attended in the full layer whose "
+            "pool other layers share")
+        self._c_state_saves = reg.counter(
+            "serving_state_saves",
+            "slot states copied to the host (reason label: preempt, "
+            "snapshot)")
+        wins = [l.window for l in self.cache_spec.layers
+                if l.kind == "window"]
+        self._ctx_window = min(wins) if wins else 0
+        self._ctx_shared = bool(self.cache_spec.of_kind("shared"))
         # flight-record seq of the tick in progress (0 with telemetry off):
         # what every engine-row phase names as its parent
         self._tick_seq = 0
@@ -603,7 +634,7 @@ class GenerationServer:
                 slack = max(slack, -(-(wmax * (int(self.spec.k) + 1)) // bs),
                             -(-int(self.spec.gate_ticks) // bs))
             self._table_width = entries + slack
-            per_block = kv_block_bytes(cfg, bs, kv_quant)
+            per_block = self.cache_spec.block_bytes(bs, kv_quant)
             if num_blocks is None:
                 if pool_bytes is not None:
                     # byte-budget sizing: this is where the int8 pool's
@@ -719,6 +750,16 @@ class GenerationServer:
     @_pools.setter
     def _pools(self, value):
         self._exec.pools = value
+
+    @property
+    def _slot_pools(self):
+        """The executor's window rings and state arrays (the spec's slot
+        kinds; empty for a dense decoder), rotated like ``_pools``."""
+        return self._exec.slot_pools
+
+    @_slot_pools.setter
+    def _slot_pools(self, value):
+        self._exec.slot_pools = value
 
     @property
     def _pool_stride(self) -> int:
@@ -1107,9 +1148,16 @@ class GenerationServer:
         # chain blocks swap in through the compile-once promotion
         # scatter (kv_offload.match_prefix_tiered) — either way the
         # matched span skips its chunked prefill
-        req.table, self._pools, tiers = self._offload.match_prefix_tiered(
-            seq, self._pools)
-        req.hashes = self.alloc.chain_hashes(seq)
+        if self.cache_spec.has_slot_state:
+            # no prefix sharing: another request's blocks hold the FULL
+            # layers' K/V only — the window rings and the recurrent state
+            # at the end of the shared prefix exist nowhere, so a request
+            # that skipped those tokens would start from zero state
+            req.table, tiers, req.hashes = [], {"hot": 0, "warm": 0}, []
+        else:
+            req.table, self._pools, tiers = \
+                self._offload.match_prefix_tiered(seq, self._pools)
+            req.hashes = self.alloc.chain_hashes(seq)
         req.pf_next = len(req.table) * self.block_size
         if req.pf_next < len(seq) and self._offload.warm.demoted_blocks:
             # the chain ran out of cached ancestry while a warm tier is
@@ -1233,6 +1281,15 @@ class GenerationServer:
                                if req.adapter is not None else 0)
         handle, ent.swap = ent.swap, None
         req.table, self._pools = res
+        if handle.extra:
+            # the window rings and recurrent state the request left with
+            tel = self._tel
+            _t0 = tel.clock() if tel.enabled else 0.0
+            self._exec.restore_slot(slot, handle.extra)
+            if tel.enabled:
+                tel.tracer.complete(req.rid, "state_restore", _t0,
+                                    tel.clock(), slot=slot)
+            handle.extra = None
         self._bt[slot, :] = 0
         self._bt[slot, :len(req.table)] = req.table
         self._prefilling[slot] = None
@@ -1252,6 +1309,23 @@ class GenerationServer:
             self._tel.tracer.end(req.rid, "preempted", resumed=True)
             self._tel.tracer.begin(req.rid, "decode", resumed=True)
         return True
+
+    def _save_slot_state(self, s: int, rid: int, reason: str):
+        """Host copies of what slot ``s`` owns besides pool blocks (window
+        rings, recurrent state) — [] for a spec without slot kinds. They
+        ride the swap payload: same CRC, same host-pool accounting, same
+        snapshot arrays."""
+        if not self.cache_spec.has_slot_state:
+            return []
+        tel = self._tel
+        _t0 = tel.clock() if tel.enabled else 0.0
+        arrays = self._exec.save_slot(s)
+        self._c_state_saves.inc(reason=reason)
+        if tel.enabled:
+            tel.tracer.complete(rid, "state_save", _t0, tel.clock(),
+                                reason=reason,
+                                bytes=sum(a.nbytes for a in arrays))
+        return arrays
 
     def _pick_victim(self, than_priority: int,
                      exclude=()) -> Optional[int]:
@@ -1302,7 +1376,8 @@ class GenerationServer:
             handle = self._offload.swap_out(
                 req.rid, req.table,
                 req.hashes[:min(len(req.hashes), len(req.table))],
-                self._pools, n_tokens=n, last_token=int(self.tokens[s]))
+                self._pools, n_tokens=n, last_token=int(self.tokens[s]),
+                extra=self._save_slot_state(s, req.rid, "preempt"))
             if handle is None:
                 return False
             req.table = []
@@ -1408,10 +1483,11 @@ class GenerationServer:
         tel = self._tel
         _t0 = tel.clock() if tel.enabled else 0.0
         _w0 = self._wall()
-        lg, self._pools = self._chunk_prefill(
+        lg, self._pools, self._slot_pools = self._chunk_prefill(
             self.params, jnp.asarray(chunk), self._pools,
             jnp.asarray(self._bt[slot]), jnp.int32(start),
-            jnp.int32(last_idx), aidx, self._lora_flat())
+            jnp.int32(last_idx), aidx, self._lora_flat(), self._slot_pools,
+            jnp.asarray(np.array([slot, end - start, end == n], np.int32)))
         # per-chip prefill throughput ledger (tools/serving_benchmark.py
         # divides by tp*cp): real prompt tokens only, not chunk padding
         m = end - start
@@ -1428,7 +1504,7 @@ class GenerationServer:
                                 dispatch_only=True)
         # publish the prompt blocks this chunk completed for prefix reuse
         # (a freshly prefilled hash supersedes any stale warm copy)
-        for i in range(start // bs, end // bs):
+        for i in range(start // bs, min(end // bs, len(req.hashes))):
             self.alloc.register(req.table[i], req.hashes[i])
             self._offload.forget_warm(req.hashes[i])
         req.pf_next = start + C
@@ -1763,11 +1839,11 @@ class GenerationServer:
             bt = np.where(active_mask[:, None] > 0, self._bt, 0)
             posv = self.pos * active_mask
             temps, topks, topps, _, aidx = self._samp_arrays()
-            stack, self._pools = self._decode_paged(
+            stack, self._pools, self._slot_pools = self._decode_paged(
                 self.params, jnp.asarray(self.tokens), self._pools,
                 jnp.asarray(bt), jnp.asarray(posv), temps, topks, topps,
                 jnp.asarray(active_mask), key, aidx, self._lora_flat(),
-                self._all_greedy(active), ticks)
+                self._all_greedy(active), ticks, self._slot_pools)
         # the trip's one host sync, apart from the fold that follows it
         with tel.phase("decode_wait", tick, rows=rows):
             nxt_host = np.asarray(stack)
@@ -2146,6 +2222,29 @@ class GenerationServer:
         out = self.alloc.stats()
         out.update(self._offload.tier_stats())
         out["cold_refills"] = self._cold_refills
+        out.update(self.cache_bytes())
+        return out
+
+    def cache_bytes(self) -> Dict[str, int]:
+        """Bytes in use and allotted, by cache kind of the spec: ``full``
+        in blocks of the shared pool, ``window`` and ``state`` per occupied
+        slot (``serving_cache_bytes{kind=}`` in the registry)."""
+        occupied = sum(sl is not None for sl in self._slots)
+        per_slot = self.cache_spec.slot_bytes(self.block_size)
+        out = {"cache_bytes_full": (self.alloc.blocks_in_use
+                                    * self.alloc.bytes_per_block),
+               "cache_bytes_full_allotted": ((self.alloc.num_blocks - 1)
+                                             * self.alloc.bytes_per_block),
+               "state_slots": occupied if per_slot["state"] else 0}
+        g = self._tel.registry.gauge("serving_cache_bytes")
+        for kind in ("window", "state"):
+            out[f"cache_bytes_{kind}"] = occupied * per_slot[kind]
+            out[f"cache_bytes_{kind}_allotted"] = (self.max_batch
+                                                   * per_slot[kind])
+        for kind in ("full", "window", "state"):
+            g.set(float(out[f"cache_bytes_{kind}"]), kind=kind)
+        self._tel.registry.gauge("serving_state_slots").set(
+            float(out["state_slots"]))
         return out
 
     # ------------------------------------------------------ fault tolerance
@@ -2345,17 +2444,20 @@ class GenerationServer:
                 d["replay"] = (list(req.prompt)
                                + list(req.generated))[:int(self.pos[s])]
             else:
+                extra = self._save_slot_state(s, req.rid, "snapshot")
                 arrays = self._offload.gather_payload(req.table,
-                                                      self._pools)
+                                                      self._pools) + extra
                 d["phase"] = "kv"
                 d["kv"] = {
                     "arrays": arrays,
                     "n_tokens": int(self.pos[s]),
                     "last_token": int(self.tokens[s]),
                     "n_blocks": len(req.table),
+                    "n_extra": len(extra),
                     "hashes": list(
                         req.hashes[:min(len(req.hashes), len(req.table))]),
-                    "nbytes": len(req.table) * self.alloc.bytes_per_block,
+                    "nbytes": (len(req.table) * self.alloc.bytes_per_block
+                               + sum(a.nbytes for a in extra)),
                     "checksum": payload_checksum(arrays)}
             reqs.append(d)
         for ent in self._sched.waiting():
@@ -2368,7 +2470,7 @@ class GenerationServer:
                 d["phase"] = "kv"
                 d["kv"] = {"arrays": arrays, "n_tokens": h.n_tokens,
                            "last_token": h.last_token,
-                           "n_blocks": h.n_blocks,
+                           "n_blocks": h.n_blocks, "n_extra": h.n_extra,
                            "hashes": list(h.hashes), "nbytes": h.nbytes,
                            "checksum": h.checksum}
             else:
@@ -2536,7 +2638,8 @@ class GenerationServer:
                 last_token=int(kv["last_token"]),
                 n_blocks=int(kv["n_blocks"]),
                 hashes=list(kv["hashes"]), nbytes=int(kv["nbytes"]),
-                checksum=int(kv["checksum"]))
+                checksum=int(kv["checksum"]),
+                n_extra=int(kv.get("n_extra", 0)))
             self._offload.adopt(
                 handle, [np.asarray(a) for a in kv["arrays"]])
             ent.swap = handle
@@ -2828,6 +2931,7 @@ class GenerationServer:
         rows = k * len(active)
         ctx = k * int(pos_after[active].sum()) \
             - len(active) * (k * (k - 1) // 2)
+        W, ctx_win = self._ctx_window, 0
         for s in active:
             req = self._slots[s]
             done = False
@@ -2857,6 +2961,11 @@ class GenerationServer:
                             or pos_t >= self.max_len - 1):
                         done = True
                         break
+            if W:
+                # the emitted tokens' contexts, each capped at the window
+                p0 = int(pos_after[s]) - k
+                ctx_win += sum(min(p0 + t, W)
+                               for t in range(1, max(take, 0) + 1))
             if done:
                 cut = k - max(take, 0)
                 if cut:
@@ -2867,6 +2976,10 @@ class GenerationServer:
         self._c_tokens.inc(rows)
         self._c_dec_rows.inc(rows)
         self._c_dec_ctx.inc(ctx)
+        if W:
+            self._c_dec_ctx_win.inc(ctx_win)
+        if self._ctx_shared:
+            self._c_dec_ctx_shared.inc(ctx)
 
     def step(self) -> int:
         """One server step: admit queued requests, advance one prefill

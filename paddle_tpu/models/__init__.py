@@ -7,5 +7,7 @@ from .gpt import GPTConfig, GPTForCausalLM, gpt2_small_config, gpt_tiny_config
 from .ernie import ErnieConfig, ErnieForMaskedLM, ErnieForQuestionAnswering, \
     ErnieForSequenceClassification, ErnieForTokenClassification, ErnieModel, \
     ernie_tiny_config
+from .phi4flash import (Phi4FlashConfig, Phi4FlashForCausalLM,
+                        phi4flash_tiny_config)
 
 __all__ = [n for n in dir() if not n.startswith("_")]

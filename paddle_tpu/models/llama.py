@@ -998,12 +998,13 @@ class LlamaModel(Layer):
         return self.norm(x), new
 
     def paged_prefill_chunk(self, input_ids, pools, block_table, start,
-                            lora=None):
+                            lora=None, last_idx=None):
         """Stream ONE prompt chunk into the paged pool (chunked prefill:
         the same compiled program serves every chunk of every prompt
         length — no per-bucket compile family). input_ids: Tensor (1, C);
         start: traced int32 block-aligned chunk origin. Returns (normed
-        hidden for the chunk, new pools)."""
+        hidden, new pools): the hidden of the whole chunk, or with
+        ``last_idx`` (traced) of that one token, (1, 1, H)."""
         x = self.embed_tokens(input_ids)
         new = []
         for i, (layer, pool) in enumerate(zip(self.layers, pools)):
@@ -1011,7 +1012,10 @@ class LlamaModel(Layer):
                 x, self._cos, self._sin, pool, block_table, start,
                 lora=None if lora is None else lora[i])
             new.append(pool)
-        return self.norm(x), new
+        h = self.norm(x)
+        if last_idx is not None:
+            h = Tensor(jax.lax.dynamic_slice_in_dim(h.value, last_idx, 1, 1))
+        return h, new
 
     def _should_recompute(self):
         from ..framework.core import is_grad_enabled

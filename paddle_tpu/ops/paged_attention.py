@@ -44,21 +44,25 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def gather_block_kv(pool, block_tables):
+def gather_block_kv(pool, block_tables, head_major=False):
     """Gather per-row K (or V) context from the block pool.
 
-    pool: (N, bs, KV, D); block_tables: int32 (B, M) (or (M,) for one
-    row). Returns (B, M*bs, KV, D) — the dense-equivalent context window,
-    where table entry 0 conventionally points at the scratch block and is
-    masked out by the caller's position mask.
+    pool: (N, bs, KV, D), or (N, KV, bs, D) ``head_major``; block_tables:
+    int32 (B, M) (or (M,) for one row). Returns (B, M*bs, KV, D) — the
+    dense-equivalent context window, where table entry 0 conventionally
+    points at the scratch block and is masked out by the caller's position
+    mask.
     """
     bt = block_tables if block_tables.ndim == 2 else block_tables[None]
     gathered = pool[bt]                       # (B, M, bs, KV, D)
-    b, m, bs = gathered.shape[:3]
-    return gathered.reshape(b, m * bs, *pool.shape[2:])
+    if head_major:
+        gathered = gathered.swapaxes(2, 3)
+    b, m, bs, kv, d = gathered.shape
+    return gathered.reshape(b, m * bs, kv, d)
 
 
-def write_window_kv(k_pool, v_pool, k, v, block_tables, pos):
+def write_window_kv(k_pool, v_pool, k, v, block_tables, pos,
+                    head_major=False):
     """Scatter a WINDOW of new tokens' K/V per row through the block table.
 
     k/v: (B, W, KV, D); block_tables: (B, M); pos: int32 (B,) — row ``b``'s
@@ -70,24 +74,45 @@ def write_window_kv(k_pool, v_pool, k, v, block_tables, pos):
     the scratch block (idle/prefilling slots) harmlessly overwrite
     scratch.
     """
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2 if head_major else 1]
     W = k.shape[1]
     pj = pos[:, None] + jnp.arange(W)[None, :]          # (B, W)
     bid = jnp.take_along_axis(block_tables, pj // bs, axis=1)
-    off = pj % bs
-    k_pool = k_pool.at[bid, off].set(k.astype(k_pool.dtype))
-    v_pool = v_pool.at[bid, off].set(v.astype(v_pool.dtype))
-    return k_pool, v_pool
+    return _set_tokens(k_pool, v_pool, k, v, bid, pj % bs, head_major)
 
 
-def write_decode_kv(k_pool, v_pool, k, v, block_tables, pos):
+def _set_tokens(k_pool, v_pool, k, v, bid, off, head_major):
+    """pool[block ``bid``, token ``off``] = k, v (leading dims alike; k, v
+    (..., KV, D)). A head-major pool is written through its ``(N*KV*bs,
+    D)`` row view (a bitcast: whole tiles merge), one row per kv head: a
+    scatter over dimensions 0 and 2 of the 4-D pool makes XLA:TPU copy the
+    WHOLE pool into a token-major layout and back around it (seen in the
+    compiled decode program of Phi-4-mini-flash: 4 x 1.3 GB a tick)."""
+    if not head_major:
+        return (k_pool.at[bid, off].set(k.astype(k_pool.dtype)),
+                v_pool.at[bid, off].set(v.astype(v_pool.dtype)))
+    N, KV, bs, D = k_pool.shape
+    rows = ((bid[..., None] * KV + jnp.arange(KV)) * bs
+            + off[..., None]).reshape(-1)
+
+    def put(pool, x):
+        flat = pool.reshape(N * KV * bs, D)
+        return flat.at[rows].set(x.reshape(-1, D).astype(pool.dtype)
+                                 ).reshape(pool.shape)
+
+    return put(k_pool, k), put(v_pool, v)
+
+
+def write_decode_kv(k_pool, v_pool, k, v, block_tables, pos,
+                    head_major=False):
     """Scatter ONE new token's K/V per row — :func:`write_window_kv` at
     W = 1. k/v: (B, KV, D)."""
     return write_window_kv(k_pool, v_pool, k[:, None], v[:, None],
-                           block_tables, pos)
+                           block_tables, pos, head_major)
 
 
-def write_chunk_kv(k_pool, v_pool, k, v, block_table, start):
+def write_chunk_kv(k_pool, v_pool, k, v, block_table, start,
+                   head_major=False):
     """Scatter a prefill CHUNK's K/V into consecutive table entries.
 
     k/v: (C, KV, D) with C a multiple of ``bs``; block_table: (M,);
@@ -96,14 +121,16 @@ def write_chunk_kv(k_pool, v_pool, k, v, block_table, start):
     table, then one blocked scatter (the Pallas version would walk the
     same slice as scalar-prefetch grid indices).
     """
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2 if head_major else 1]
     nb = k.shape[0] // bs
     blocks = jax.lax.dynamic_slice_in_dim(block_table, start // bs, nb, 0)
-    k_pool = k_pool.at[blocks].set(
-        k.reshape(nb, bs, *k.shape[1:]).astype(k_pool.dtype))
-    v_pool = v_pool.at[blocks].set(
-        v.reshape(nb, bs, *v.shape[1:]).astype(v_pool.dtype))
-    return k_pool, v_pool
+
+    def tiles(x, pool):
+        x = x.reshape(nb, bs, *x.shape[1:])
+        return (x.swapaxes(1, 2) if head_major else x).astype(pool.dtype)
+
+    return (k_pool.at[blocks].set(tiles(k, k_pool)),
+            v_pool.at[blocks].set(tiles(v, v_pool)))
 
 
 def _attention_core(q, ck, cv, qpos, ksl=None, vsl=None):
@@ -141,7 +168,8 @@ def _attention_core(q, ck, cv, qpos, ksl=None, vsl=None):
     return out.reshape(B, S, H, D)
 
 
-def _try_pallas(q, k_pool, v_pool, tables, pos, ks=None, vs=None):
+def _try_pallas(q, k_pool, v_pool, tables, pos, ks=None, vs=None, window=0,
+                ring=0, head_major=False):
     """Kernel selection (``select.select_paged_attention``, decided from
     platform, partitioning and static shapes before the kernel traces):
     returns the Pallas result when the kernel is selected, else None
@@ -149,22 +177,28 @@ def _try_pallas(q, k_pool, v_pool, tables, pos, ks=None, vs=None):
     lower or compile raises."""
     from .select import XLA, record, select_paged_attention
 
-    if record("paged_attention_q" if ks is not None else "paged_attention",
-              select_paged_attention(q.shape, k_pool.shape)) == XLA:
+    op = ("paged_window_attention" if window else
+          "paged_attention_q" if ks is not None else "paged_attention")
+    if record(op, select_paged_attention(
+            q.shape, k_pool.shape, head_major=head_major)) == XLA:
         return None
     from . import paged_attention_pallas as pk
 
     if ks is None:
-        return pk.paged_attention(q, k_pool, v_pool, tables, pos)
+        return pk.paged_attention(q, k_pool, v_pool, tables, pos,
+                                  window=window, ring=ring,
+                                  head_major=head_major)
     return pk.paged_attention_q(q, k_pool, ks, v_pool, vs, tables, pos)
 
 
-def paged_verify_attention(q, k_pool, v_pool, block_tables, pos):
+def paged_verify_attention(q, k_pool, v_pool, block_tables, pos,
+                           head_major=False):
     """Multi-token verify attention through block tables (GQA-native) —
     the decode window generalized from 1 to W positions.
 
     q: (B, W, H, D) rope'd queries at positions ``pos[b] + arange(W)``;
-    pools: (N, bs, KV, D); block_tables: (B, M); pos: int32 (B,) window
+    pools: (N, bs, KV, D), or (N, KV, bs, D) ``head_major``;
+    block_tables: (B, M); pos: int32 (B,) window
     start per row (the window's K/V must already be written at
     ``pos..pos+W-1``, :func:`write_window_kv`). IN-WINDOW CAUSAL MASK:
     query j attends context positions ``<= pos[b] + j`` — earlier window
@@ -178,23 +212,26 @@ def paged_verify_attention(q, k_pool, v_pool, block_tables, pos):
     """
     W = q.shape[1]
     bt = block_tables if block_tables.ndim == 2 else block_tables[None]
-    out = _try_pallas(q, k_pool, v_pool, bt, pos)
+    out = _try_pallas(q, k_pool, v_pool, bt, pos, head_major=head_major)
     if out is not None:
         return out
-    ck = gather_block_kv(k_pool, bt)              # (B, L, KV, D)
-    cv = gather_block_kv(v_pool, bt)
+    ck = gather_block_kv(k_pool, bt, head_major)  # (B, L, KV, D)
+    cv = gather_block_kv(v_pool, bt, head_major)
     qpos = pos[:, None] + jnp.arange(W)[None, :]  # (B, W)
     return _attention_core(q, ck, cv, qpos)
 
 
-def paged_decode_attention(q, k_pool, v_pool, block_tables, pos):
+def paged_decode_attention(q, k_pool, v_pool, block_tables, pos,
+                           head_major=False):
     """Single-token decode attention — :func:`paged_verify_attention` at
     W = 1 (mask ``arange(L) <= pos + 0`` is the plain ``<= pos``).
     q: (B, 1, H, D)."""
-    return paged_verify_attention(q, k_pool, v_pool, block_tables, pos)
+    return paged_verify_attention(q, k_pool, v_pool, block_tables, pos,
+                                  head_major)
 
 
-def paged_prefill_attention(q, k_pool, v_pool, block_table, start):
+def paged_prefill_attention(q, k_pool, v_pool, block_table, start,
+                            head_major=False):
     """Chunked-prefill attention: one chunk of queries against ALL paged
     context written so far (earlier chunks + shared prefix blocks) plus
     the causal part of the chunk itself.
@@ -209,11 +246,11 @@ def paged_prefill_attention(q, k_pool, v_pool, block_table, start):
     C = q.shape[1]
     bt = block_table if block_table.ndim == 2 else block_table[None]
     start_v = jnp.full((1,), start, jnp.int32)
-    out = _try_pallas(q, k_pool, v_pool, bt, start_v)
+    out = _try_pallas(q, k_pool, v_pool, bt, start_v, head_major=head_major)
     if out is not None:
         return out
-    ck = gather_block_kv(k_pool, bt)              # (1, L, KV, D)
-    cv = gather_block_kv(v_pool, bt)
+    ck = gather_block_kv(k_pool, bt, head_major)  # (1, L, KV, D)
+    cv = gather_block_kv(v_pool, bt, head_major)
     qpos = (start + jnp.arange(C))[None, :]       # (1, C)
     return _attention_core(q, ck, cv, qpos)
 
@@ -230,6 +267,58 @@ def paged_prefill_attention(q, k_pool, v_pool, block_table, start):
 # a Pallas kernel would apply it on the VMEM tile). int8 codes (|q| ≤ 127)
 # are exact in bf16/f32, so the only error is the quantization rounding.
 # --------------------------------------------------------------------------- #
+
+# ------------------------------------------------------------ sliding window
+def ring_positions(ring_blocks, block_size, pos):
+    """Absolute position held by every entry of a row's ring, given that the
+    row is writing position ``pos``: logical block ``j`` lives in ring block
+    ``j % ring_blocks``, so ring block ``r`` holds the newest ``j <=
+    pos // bs`` with ``j % ring_blocks == r``. pos (B,) -> (B, ring*bs);
+    entries of blocks not yet written come out negative."""
+    cur = pos[:, None] // block_size                              # (B, 1)
+    r = jnp.arange(ring_blocks)[None, :]
+    j = cur - (cur - r) % ring_blocks                             # (B, ring)
+    return (j[:, :, None] * block_size
+            + jnp.arange(block_size)[None, None, :]).reshape(pos.shape[0], -1)
+
+
+def write_ring_kv(k_pool, v_pool, k, v, ring_tables, pos, head_major=False):
+    """Scatter ONE token's K/V per row into its window ring. k/v (B, KV,
+    D); ring_tables (B, ring) block ids (all 0 = scratch for a masked row);
+    pos (B,)."""
+    bs = k_pool.shape[2 if head_major else 1]
+    ring = ring_tables.shape[1]
+    bid = jnp.take_along_axis(ring_tables, ((pos // bs) % ring)[:, None],
+                              axis=1)[:, 0]
+    return _set_tokens(k_pool, v_pool, k, v, bid, pos % bs, head_major)
+
+
+def paged_window_attention(q, k_pool, v_pool, ring_tables, pos, window,
+                           head_major=False):
+    """Single-token decode attention over the last ``window`` positions,
+    read from a per-row RING of blocks (the token's own K/V already written,
+    :func:`write_ring_kv`). q (B, 1, H, D); ring_tables (B, ring). The
+    Pallas kernel starts its walk at the window's first block; the jnp
+    reference gathers the whole ring and masks by the position each entry
+    holds."""
+    out = _try_pallas(q, k_pool, v_pool, ring_tables, pos, window=window,
+                      ring=ring_tables.shape[1], head_major=head_major)
+    if out is not None:
+        return out
+    B, _, H, D = q.shape
+    ck = gather_block_kv(k_pool, ring_tables, head_major)  # (B, ring*bs, ..)
+    cv = gather_block_kv(v_pool, ring_tables, head_major)
+    bs, KV = ck.shape[1] // ring_tables.shape[1], ck.shape[2]
+    kpos = ring_positions(ring_tables.shape[1], bs, pos)
+    mask = ((kpos >= 0) & (kpos <= pos[:, None])
+            & (kpos > pos[:, None] - window))       # (B, L)
+    qg = q.reshape(B, KV, H // KV, D)
+    scores = jnp.einsum("bgrd,btgd->bgrt", qg, ck).astype(jnp.float32) \
+        / math.sqrt(D)
+    scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
+    p = jax.nn.softmax(scores, -1).astype(q.dtype)
+    return jnp.einsum("bgrt,btgd->bgrd", p, cv).reshape(B, 1, H, D)
+
 
 _QEPS = 1e-8   # scale floor: an all-zero block quantizes to scale ~0 with
                # zero codes instead of dividing by zero

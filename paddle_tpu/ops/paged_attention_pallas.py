@@ -122,7 +122,8 @@ def group_plan(rows, KV, bs, D, M, kv_itemsize, depth=0):
 
 
 def _attn_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest, bs, rep, KV,
-                 M, G, R, quantized, early, all_heads, tiled):
+                 M, G, R, quantized, early, all_heads, tiled, window=0,
+                 ring=0, head_major=False):
     if quantized:
         ks_ref, vs_ref = rest[:2]
         rest = rest[2:]
@@ -136,8 +137,21 @@ def _attn_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest, bs, rep, KV,
     pos = pos_ref[b]
     # live blocks: through the causal frontier of the tile's LAST q row.
     # Table entries past it (scratch block 0, stale ids) are never read.
-    nblk = jnp.minimum((pos + (row0 + R - 1) // rep) // bs + 1, M)
-    ngroups = (nblk + G - 1) // G
+    last = (pos + (row0 + R - 1) // rep) // bs + 1
+    nblk = last if ring else jnp.minimum(last, M)
+    # a sliding window starts the walk at the block that holds the FIRST q
+    # row's oldest visible position; ``ring`` > 0 says the table is a ring
+    # of that many blocks (logical block j lives in entry j % ring)
+    blk0 = (jnp.maximum(pos + row0 // rep - (window - 1), 0) // bs
+            if window else 0)
+    ngroups = (nblk - blk0 + G - 1) // G
+
+    def block_of(buf, slot, j):
+        """Where block ``j`` of a group lands in a slot of the VMEM buffer:
+        ``(2, G*bs, KV, D)`` token-major, ``(2, G, KV, bs, D)`` head-major
+        (the pool's own block layout either way: one contiguous copy)."""
+        return (buf.at[slot, j] if head_major
+                else buf.at[slot, pl.ds(j * bs, bs)])
 
     def dma(i, slot, start):
         """Start (or wait for) group i's whole-block copies into ``slot``.
@@ -145,14 +159,14 @@ def _attn_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest, bs, rep, KV,
         a zero probability never meets stale non-finite bits. (Loops, not
         an unrolled ``pl.when`` a block: the trace stays the size of one
         block whatever G is.)"""
-        live = jnp.clip(nblk - i * G, 0, G)
+        live = jnp.clip(nblk - blk0 - i * G, 0, G)
 
         def one(j, carry):
-            blk = tbl_ref[b, i * G + j]
+            e = blk0 + i * G + j
+            blk = tbl_ref[b, e % ring if ring else e]
             for s, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
                 cp = pltpu.make_async_copy(
-                    hbm.at[blk], buf.at[slot, pl.ds(j * bs, bs)],
-                    sem.at[s, slot])
+                    hbm.at[blk], block_of(buf, slot, j), sem.at[s, slot])
                 if start:
                     cp.start()
                 else:
@@ -162,8 +176,8 @@ def _attn_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest, bs, rep, KV,
         jax.lax.fori_loop(0, live, one, 0)
         if start:
             def zero(j, carry):
-                vbuf[slot, pl.ds(j * bs, bs)] = jnp.zeros((bs, KV, D),
-                                                          vbuf.dtype)
+                dst = block_of(vbuf, slot, j)
+                dst[...] = jnp.zeros(dst.shape, vbuf.dtype)
                 return carry
 
             jax.lax.fori_loop(live, G, zero, 0)
@@ -178,7 +192,12 @@ def _attn_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest, bs, rep, KV,
         # rows are (kv head, q row), columns (token, kv head): a column
         # belongs to a row's softmax only on the block diagonal
         row_qpos = pos + (row0 + ri % R) // rep
-        col_tok, col_head = ci // KV, ci % KV
+        if head_major:
+            # the tile landed (block, kv head, token in block)
+            col_tok = ci // (KV * bs) * bs + ci % bs
+            col_head = ci // bs % KV
+        else:
+            col_tok, col_head = ci // KV, ci % KV
         diag = col_head == ri // R
     else:
         row_qpos = pos + (row0 + ri) // rep
@@ -201,8 +220,11 @@ def _attn_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest, bs, rep, KV,
         return [out]
 
     def compute(i, slot):
-        base = i * T
+        base = (blk0 + i * G) * bs
         mask = base + col_tok <= row_qpos
+        if window:
+            mask = jnp.logical_and(mask,
+                                   base + col_tok > row_qpos - window)
         if all_heads:
             mask = jnp.logical_and(mask, diag)
         if quantized:
@@ -212,7 +234,8 @@ def _attn_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest, bs, rep, KV,
             # table entry points at: keep it off the zero probabilities
             vsc = col_scales(jnp.where(i * G + gi < nblk,
                                        vs_ref[0, pl.ds(off, G), :], 0.0))
-        kt, vt = kbuf[slot], vbuf[slot]                   # (T, KV, D)
+        if not head_major or all_heads:
+            kt, vt = kbuf[slot], vbuf[slot]   # (T, KV, D) | (G, KV, bs, D)
         if quantized and (early or all_heads):
             # the int8->fp cast is exact wherever it sits; "early" casts
             # the whole group tile once, "scores" each head's slice
@@ -223,7 +246,12 @@ def _attn_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest, bs, rep, KV,
                 k, v = kt.reshape(C, D), vt.reshape(C, D)
             else:
                 q = q_ref[0, g]                           # (R, D)
-                k, v = kt[:, g, :], vt[:, g, :]           # (T, D)
+                if head_major:
+                    # a head's tokens are whole (bs, D) tiles of the group
+                    k = kbuf[slot, :, g].reshape(T, D)
+                    v = vbuf[slot, :, g].reshape(T, D)
+                else:
+                    k, v = kt[:, g, :], vt[:, g, :]       # (T, D)
                 if quantized and not early:
                     k, v = k.astype(q.dtype), v.astype(q.dtype)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -275,12 +303,16 @@ def _attn_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest, bs, rep, KV,
 
 
 def _paged_attention_call(q, k_pool, v_pool, tables, pos, k_scales=None,
-                          v_scales=None, geometry=None):
+                          v_scales=None, geometry=None, window=0, ring=0,
+                          head_major=False):
     from ..autotune.kernel_geometry import (PagedAttentionGeometry,
                                             _largest_divisor)
 
     B, W, H, D = q.shape
-    N, bs, KV, _ = k_pool.shape
+    if head_major:
+        N, KV, bs, _ = k_pool.shape
+    else:
+        N, bs, KV, _ = k_pool.shape
     rep = H // KV
     M = tables.shape[1]
     Wr = W * rep
@@ -289,6 +321,14 @@ def _paged_attention_call(q, k_pool, v_pool, tables, pos, k_scales=None,
         raise ValueError(f"paged attention kernel needs head_dim % 128 == 0 "
                          f"and block_size % 8 == 0, got {D} / {bs}")
     quantized = k_scales is not None
+    if bool(window) != bool(ring):
+        raise ValueError(f"a sliding window is walked through a ring table: "
+                         f"window and ring come together, got {window} / "
+                         f"{ring}")
+    if quantized and (window or ring or head_major):
+        raise ValueError("the int8 pool has no sliding-window walk (its "
+                         "scale tables are read a whole group at a time) "
+                         "and no head-major layout")
     if geometry is None:
         geometry = _resolve("paged_attention",
                             "int8" if quantized else str(q.dtype), D)
@@ -329,14 +369,15 @@ def _paged_attention_call(q, k_pool, v_pool, tables, pos, k_scales=None,
         in_specs += [pl.BlockSpec((1, Mp, KV), lambda b, *a: (b, 0, 0))] * 2
         args += [row_scales(k_scales), row_scales(v_scales)]
     NG, rows = (1, KV * R) if all_heads else (KV, R)
+    slots = (2, G, KV, bs, D) if head_major else (2, G * bs, KV, D)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, NQ) if NQ > 1 else (B,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, KV, R, D), q_map),
         scratch_shapes=[
-            pltpu.VMEM((2, G * bs, KV, D), k_pool.dtype),   # K group slots
-            pltpu.VMEM((2, G * bs, KV, D), v_pool.dtype),   # V group slots
+            pltpu.VMEM(slots, k_pool.dtype),            # K group slots
+            pltpu.VMEM(slots, v_pool.dtype),            # V group slots
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((NG, rows, 128), jnp.float32),   # running max
             pltpu.VMEM((NG, rows, 128), jnp.float32),   # running sum
@@ -346,7 +387,9 @@ def _paged_attention_call(q, k_pool, v_pool, tables, pos, k_scales=None,
     out = pl.pallas_call(
         functools.partial(_attn_kernel, bs=bs, rep=rep, KV=KV, M=M, G=G, R=R,
                           quantized=quantized, early=early,
-                          all_heads=all_heads, tiled=NQ > 1),
+                          all_heads=all_heads, tiled=NQ > 1,
+                          window=int(window), ring=int(ring),
+                          head_major=bool(head_major)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, Wr, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -357,16 +400,26 @@ def _paged_attention_call(q, k_pool, v_pool, tables, pos, k_scales=None,
         B, W, H, D)
 
 
-def paged_attention(q, k_pool, v_pool, block_tables, pos, geometry=None):
+def paged_attention(q, k_pool, v_pool, block_tables, pos, geometry=None,
+                    window=0, ring=0, head_major=False):
     """Fused paged decode/verify attention over an fp block pool.
 
     q: (B, W, H, D) — W=1 decode, W=tick_window verify, W=chunk prefill.
     pos: (B,) int — absolute position of each row's FIRST query token.
     geometry: trace-time :class:`PagedAttentionGeometry` (None = the
     process-wide winner cache, falling back to the default schedule).
+    window > 0 and ring > 0, together: each query sees its last ``window``
+    positions only, the walk starts at the block that holds the oldest of
+    them, and the table is a ring of ``ring`` blocks (``block_tables`` (B,
+    ring); logical block j lives in entry j % ring).
+    head_major: the pools are ``(N, KV, bs, D)`` — a block holds each kv
+    head's tokens as whole ``(bs, D)`` tiles, so a number of kv heads that
+    is not a sublane multiple (10 packed pairs) costs no padding; the
+    per-head body then reads a head's tiles with no stride.
     """
     return _paged_attention_call(q, k_pool, v_pool, block_tables, pos,
-                                 geometry=geometry)
+                                 geometry=geometry, window=window, ring=ring,
+                                 head_major=head_major)
 
 
 def paged_attention_q(q, kq_pool, k_scales, vq_pool, v_scales, block_tables,

@@ -222,24 +222,66 @@ def select_fused_norm(rows: int, width: int, dtype, *, platform=None,
     return PALLAS
 
 
-def select_paged_attention(q_shape, pool_shape, *, platform=None,
-                           is_partitioned=None) -> str:
+def select_paged_attention(q_shape, pool_shape, *, head_major=False,
+                           platform=None, is_partitioned=None) -> str:
     """Paged decode / verify / prefill-chunk attention, fp and int8 pools.
-    q (B, W, H, D); pool (N, bs, KV, D). Pallas when kernels are on and
-    the trace is not partitioned (the tp serving executor is GSPMD: jnp
-    there); Mosaic additionally needs a lane-aligned head_dim, a block
-    size that is a sublane multiple of the pool dtype, and the per-program
-    f32 accumulator (KV * W*rep * D) inside the VMEM block budget."""
+    q (B, W, H, D); pool (N, bs, KV, D), or (N, KV, bs, D) ``head_major``.
+    Pallas when kernels are on and the trace is not partitioned (the tp
+    serving executor is GSPMD: jnp there); Mosaic additionally needs a
+    lane-aligned head_dim, whole sublane tiles in a block's second-minor
+    dimension — the kv heads of a token-major block (a multiple of 8, or
+    1, 2, 4: Mosaic refused 10 from the sandbox, PR 27), the block size of
+    a head-major one (a multiple of 16: bf16 tiles) — and
+    the per-program f32 accumulator (KV * W*rep * D) inside the VMEM
+    block budget.
+
+    ``head_dim`` is the width of a POOL row, not of the model's head: a
+    model whose heads are 64 wide and come in pairs (differential
+    attention, models/phi4flash.py) stores the pair side by side, 128
+    wide, and asks this rule with those shapes — its ``(q1|0)`` and
+    ``(0|q2)`` query rows then run the same kernel. Its 10 packed kv heads
+    are no sublane multiple, so its pools are head-major. A 64-wide pool
+    that nobody packed, or 10 kv heads token-major, go to the jnp
+    composition, and ``selected()`` says so under the caller's op name
+    (``paged_attention``, ``paged_attention_q``,
+    ``paged_window_attention``)."""
     backend = _kernel_backend(platform, is_partitioned)
     if backend is None:
         return XLA
     if backend == INTERPRET:
         return backend
     _, w, h, d = q_shape
-    _, bs, kv, _ = pool_shape
-    if d % 128 or bs % 8:
+    if head_major:
+        _, kv, bs, _ = pool_shape
+        aligned = bs % 16 == 0
+    else:
+        _, bs, kv, _ = pool_shape
+        aligned = bs % 8 == 0 and (kv % 8 == 0 or kv in (1, 2, 4))
+    if d % 128 or not aligned:
         return XLA
     if h * w * d * 4 > _VMEM_BLOCK_BUDGET // 2:
+        return XLA
+    return PALLAS
+
+
+def select_selective_scan(h_shape, tokens: int = 1, *, platform=None,
+                          is_partitioned=None) -> str:
+    """Selective-scan state update (ops/selective_scan.py): the one-step
+    update over all slots (``tokens`` 1) and the chunk scan of one slot.
+    h (rows, d_state, d_inner) float32, channels on the lanes. Pallas when
+    kernels are on and the trace is not partitioned; Mosaic needs whole
+    lane tiles of channels, whole sublane tiles of states, and the chunk's
+    lane-padded (tokens, d_state, 1) B and C operands inside the VMEM
+    block budget."""
+    backend = _kernel_backend(platform, is_partitioned)
+    if backend is None:
+        return XLA
+    if backend == INTERPRET:
+        return backend
+    _, s, d = h_shape
+    if d % 128 or s % 8:
+        return XLA
+    if 2 * 2 * tokens * s * 128 * 4 > _VMEM_BLOCK_BUDGET:
         return XLA
     return PALLAS
 

@@ -316,7 +316,7 @@ def test_long_full_batch_run_drops_no_span(model):
                            block_size=16, prefill_chunk=16, telemetry=True)
 
     def fake_decode(params, tokens, pools, *rest):
-        return np.ones((1, B), np.int32), pools
+        return np.ones((1, B), np.int32), pools, rest[-1]   # + slot pools
 
     srv._decode_paged = fake_decode
     rng = np.random.RandomState(3)

@@ -304,6 +304,156 @@ def test_paged_attention_public_ops_select_the_kernel():
                                np.asarray(want), rtol=3e-2, atol=3e-2)
 
 
+# --------------------------------------------------------------------------- #
+# Phi-4-mini-flash-reasoning's kernels at its published widths: 40 q / 20 kv
+# heads of 64 as 10 packed pairs of 128 in head-major blocks, window 512 over
+# a ring of 33 blocks of 16, d_inner 5120 x d_state 16
+# --------------------------------------------------------------------------- #
+_FH, _FKV, _FD, _FW, _FBS = 40, 10, 128, 512, 16
+
+
+def _pair_attention_f32(q, ck, cv, mask):
+    """Differential attention's two softmaxes as the model's docstring has
+    them, in float32, from UNPACKED 64-wide heads: q (B, W, 40, 64); ck, cv
+    (B, L, 20, 64); mask (B, W, L). Returns each softmax applied to the
+    128-wide value pair, (B, W, 20 pairs, 2 halves, 128): what the packed
+    kernel rows return before the model takes their difference."""
+    B, W = q.shape[:2]
+    L, d, f = ck.shape[1], q.shape[-1], jnp.float32
+    qh = q.astype(f).reshape(B, W, 10, 2, 2, d)     # kv pair, q pair, half
+    kh = ck.astype(f).reshape(B, L, 10, 2, d)       # kv pair: k1, k2
+    vv = cv.astype(f).reshape(B, L, 10, 2 * d)      # (v1 | v2)
+    outs = []
+    for half in range(2):
+        sc = jnp.einsum("bwgrd,blgd->bgrwl", qh[:, :, :, :, half],
+                        kh[:, :, :, half], precision="highest") / np.sqrt(d)
+        sc = jnp.where(mask[:, None, None], sc, -1e30)
+        outs.append(jnp.einsum("bgrwl,blge->bwgre", jax.nn.softmax(sc, -1),
+                               vv, precision="highest"
+                               ).reshape(B, W, 20, 2 * d))
+    return jnp.stack(outs, 3)
+
+
+def _pair_case(B, W, seed, window=0):
+    """Head-major pools holding packed pairs, the model's own packed query
+    rows, ragged positions; the reference works from the unpacked heads.
+    Table entry j of a row holds the context's positions j*bs .. j*bs+bs-1
+    (of a ring: the positions ``pa.ring_positions`` names)."""
+    from paddle_tpu.models.phi4flash import _pack_q
+    from paddle_tpu.ops import paged_attention as pa
+
+    rng = np.random.RandomState(seed)
+    ring = -(-_FW // _FBS) + 1 if window else 0
+    M = ring or 256
+    N, L = 1 + B * M, M * _FBS
+    q64 = jnp.asarray(rng.randn(B, W, _FH * 64).astype("float32")
+                      ).astype(jnp.bfloat16)
+    if window:
+        pos = np.array([0, 17, _FW - 1, _FW, 3 * _FW + 5,
+                        40 * ring * _FBS + 3,
+                        *rng.randint(_FW, 6000, B - 6)], np.int32)
+    elif B == 1:
+        pos = np.array([L - 2 * W], np.int32)             # a late chunk
+    else:
+        pos = np.array([0, 15, 16, 255, 256, 257, L - W,
+                        *rng.randint(64, 3000, B - 7)], np.int32)
+    tables = jnp.asarray(
+        1 + np.arange(B)[:, None] * M + np.arange(M)[None, :], jnp.int32)
+    ck = jnp.asarray(rng.randn(B, L, 20, 64).astype("float32"), jnp.bfloat16)
+    cv = jnp.asarray(rng.randn(B, L, 20, 64).astype("float32"), jnp.bfloat16)
+
+    def pool(c):
+        blocks = c.reshape(B, M, _FBS, _FKV, _FD).swapaxes(2, 3)
+        return jnp.zeros((N, _FKV, _FBS, _FD), jnp.bfloat16).at[tables].set(
+            blocks)
+
+    pos = jnp.asarray(pos)
+    qpos = pos[:, None] + jnp.arange(W)[None, :]
+    kpos = (pa.ring_positions(ring, _FBS, pos) if window
+            else jnp.broadcast_to(jnp.arange(L)[None], (B, L)))[:, None, :]
+    mask = (kpos >= 0) & (kpos <= qpos[:, :, None])
+    if window:
+        mask = mask & (kpos > qpos[:, :, None] - window)
+    want = jax.jit(_pair_attention_f32)(q64.reshape(B, W, _FH, 64), ck, cv,
+                                        mask)
+    return _pack_q(q64, 64), pool(ck), pool(cv), tables, pos, want
+
+
+@pytest.mark.parametrize("B,W", [(128, 1), (1, 128), (1, 1)],
+                         ids=["decode_B128", "chunk_C128_late", "last_token"])
+def test_packed_pair_attention_at_head_dim_64_matches_the_unpacked_math(B, W):
+    """The differential pair at head_dim 64 through the SAME kernel: 10
+    packed kv heads of 128 in head-major blocks, query rows (q1|0), (0|q2).
+    Against the two softmaxes computed from the unpacked 64-wide heads."""
+    from paddle_tpu.ops import paged_attention as pa
+
+    q, kp, vp, tables, pos, want = _pair_case(B, W, 21 + W)
+    op = pa.paged_decode_attention if W == 1 else pa.paged_verify_attention
+    got = jax.jit(lambda *a: op(*a, head_major=True))(q, kp, vp, tables, pos)
+    took("paged_attention")
+    got = np.asarray(got, np.float32).reshape(B, W, 20, 2, 128)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=3e-2, atol=3e-2)
+
+
+def test_window_walk_starts_at_the_windows_first_block():
+    """128 rows over rings of 33 blocks, positions before, at and far past
+    the window (rings that wrapped many times)."""
+    from paddle_tpu.ops import paged_attention as pa
+
+    q, kp, vp, tables, pos, want = _pair_case(128, 1, 33, window=_FW)
+    got = jax.jit(lambda *a: pa.paged_window_attention(
+        *a, _FW, head_major=True))(q, kp, vp, tables, pos)
+    took("paged_window_attention")
+    got = np.asarray(got, np.float32).reshape(128, 1, 20, 2, 128)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(want), rtol=3e-2, atol=3e-2)
+
+
+def _scan_inputs(lead, seed, d=5120, S=16):
+    rng = np.random.RandomState(seed)
+    f = lambda *s: jnp.asarray(rng.randn(*s).astype("float32"))
+    x, Bm, Cm = f(lead, d), f(lead, S), f(lead, S)
+    dt = jnp.asarray(rng.uniform(1e-3, 0.2, (lead, d)).astype("float32"))
+    A = -jnp.exp(f(S, d) * 0.3)
+    return x, dt, A, Bm, Cm, f(d)
+
+
+def test_ssm_step_kernel_at_the_published_widths():
+    """One token for 128 slots, d_inner 5120 x d_state 16, float32 state;
+    a masked row (dt 0) keeps its state bit for bit."""
+    from paddle_tpu.ops import selective_scan as ss
+
+    x, dt, A, Bm, Cm, D = _scan_inputs(128, 7)
+    h = jnp.asarray(np.random.RandomState(8).randn(128, 16, 5120)
+                    .astype("float32"))
+    dt = dt.at[5].set(0.0)
+    want_y, want_h = ss.ssm_step_ref(x, dt, A, Bm, Cm, D, h)
+    got_y, got_h = jax.jit(ss.ssm_step)(x, dt, A, Bm, Cm, D, h + 0.0)
+    took("ssm_step")
+    np.testing.assert_allclose(np.asarray(got_y), np.asarray(want_y),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(got_h), np.asarray(want_h),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(got_h[5]), np.asarray(h[5]))
+
+
+def test_ssm_chunk_scan_kernel_at_the_published_widths():
+    """A 128-token chunk from a state to a state against ``lax.scan``."""
+    from paddle_tpu.ops import selective_scan as ss
+
+    x, dt, A, Bm, Cm, D = _scan_inputs(128, 9)
+    h0 = jnp.asarray(np.random.RandomState(10).randn(16, 5120)
+                     .astype("float32"))
+    dt = dt.at[100:].set(0.0)          # the chunk's padding keeps the state
+    want_y, want_h = jax.jit(ss.ssm_chunk_scan_ref)(x, dt, A, Bm, Cm, D, h0)
+    got_y, got_h = jax.jit(ss.ssm_chunk_scan)(x, dt, A, Bm, Cm, D, h0)
+    took("ssm_chunk_scan")
+    np.testing.assert_allclose(np.asarray(got_y), np.asarray(want_y),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(got_h), np.asarray(want_h),
+                               rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.parametrize("B,S,IN,OUT", [(8, 1, 4096, 4096),
                                         (8, 1, 4096, 14336),
                                         (1, 128, 4096, 4096)],

@@ -97,14 +97,16 @@ def _norm_case(rows, width, dtype, kind="rms"):
             [((rows, width), dtype), ((width,), dtype), ((width,), dtype)])
 
 
-def _paged_case(B, W, quantized, N=512, bs=16, M=32, H=H8, KV=KV8, D=D8):
+def _paged_case(B, W, quantized, N=512, bs=16, M=32, H=H8, KV=KV8, D=D8,
+                head_major=False):
     from paddle_tpu.ops import paged_attention_pallas as pk
 
     q = ((B, W, H, D), _BF16)
     tables, pos = ((B, M), jnp.int32), ((B,), jnp.int32)
     if not quantized:
-        pool = ((N, bs, KV, D), _BF16)
-        return (lambda q_, k, v, t, p: pk.paged_attention(q_, k, v, t, p),
+        pool = ((N, KV, bs, D) if head_major else (N, bs, KV, D), _BF16)
+        return (lambda q_, k, v, t, p: pk.paged_attention(
+                    q_, k, v, t, p, head_major=head_major),
                 [q, pool, pool, tables, pos])
     pool, sc = ((N, bs, KV, D), jnp.int8), ((N, KV), jnp.float32)
     return (lambda q_, k, ks, v, vs, t, p:
@@ -112,7 +114,35 @@ def _paged_case(B, W, quantized, N=512, bs=16, M=32, H=H8, KV=KV8, D=D8):
             [q, pool, sc, pool, sc, tables, pos])
 
 
+def _window_case(B, ring=33, window=512, bs=16, KV=10, H=40, D=128):
+    """Decode over a per-slot ring of head-major blocks (the differential
+    pair packed 128 wide: Phi-4-mini-flash's window layers)."""
+    from paddle_tpu.ops import paged_attention_pallas as pk
+
+    pool = ((1 + B * ring, KV, bs, D), _BF16)
+    return (lambda q_, k, v, t, p: pk.paged_attention(
+                q_, k, v, t, p, window=window, ring=ring, head_major=True),
+            [((B, 1, H, D), _BF16), pool, pool, ((B, ring), jnp.int32),
+             ((B,), jnp.int32)])
+
+
+def _ssm_case(rows, tokens, d=5120, S=16):
+    from paddle_tpu.ops import selective_scan as ss
+
+    f = jnp.float32
+    if tokens == 1:
+        return (ss.ssm_step_pallas,
+                [((rows, d), f), ((rows, d), f), ((S, d), f), ((rows, S), f),
+                 ((rows, S), f), ((d,), f), ((rows, S, d), f)])
+    return (ss.ssm_chunk_scan_pallas,
+            [((tokens, d), f), ((tokens, d), f), ((S, d), f), ((tokens, S), f),
+             ((tokens, S), f), ((d,), f), ((S, d), f)])
+
+
 _CELL = dict(N=4096, bs=16, M=256)
+# Phi-4-mini-flash's cell: 128 slots, 8192-token tables, 10 packed kv heads
+# (no sublane multiple: head-major blocks)
+_PHI = dict(N=32769, bs=16, M=520, H=40, KV=10, D=128, head_major=True)
 _CELL_INT8 = dict(N=4096, bs=32, M=128)     # same 4096-token tables
 
 
@@ -167,6 +197,15 @@ def kernel_cases():
          lambda: _paged_case(64, 1, True, **_CELL_INT8)),
         ("paged.cell_int8_prefill_C128",
          lambda: _paged_case(1, 128, True, **_CELL_INT8)),
+        ("paged.phi_pair_decode_B128_M520",
+         lambda: _paged_case(128, 1, False, **_PHI)),
+        ("paged.phi_pair_prefill_C128_M520",
+         lambda: _paged_case(1, 128, False, **_PHI)),
+        ("paged.phi_pair_last_token_B1",
+         lambda: _paged_case(1, 1, False, **_PHI)),
+        ("paged.phi_window_decode_B128_ring33", lambda: _window_case(128)),
+        ("ssm.step_B128_5120x16", lambda: _ssm_case(128, 1)),
+        ("ssm.chunk_scan_C128_5120x16", lambda: _ssm_case(1, 128)),
         ("lora.decode_4096x4096", lambda: _lora_case(8, 1, HID8, HID8)),
         ("lora.decode_4096x14336", lambda: _lora_case(8, 1, HID8, FFN8)),
         ("w8.decode_4096x14336", lambda: _w8_case(8, HID8, FFN8)),
@@ -206,7 +245,8 @@ def _train_program(devs, mesh_shape, axes, cfg, B, S, **engine_kw):
                   1e-3, batch)
 
 
-def _serve_programs(devs, cfg, tp, kernels="auto"):
+def _serve_programs(devs, cfg, tp, kernels="auto", model_cls=None,
+                    **served):
     """(decode, prefill, reference-forward) of a paged server."""
     import paddle_tpu as paddle
     from paddle_tpu.framework.core import Tensor
@@ -217,16 +257,18 @@ def _serve_programs(devs, cfg, tp, kernels="auto"):
     from paddle_tpu.parallel import serving_mesh as sm
 
     paddle.seed(0)
-    model = LlamaForCausalLM(cfg)
+    model = (model_cls or LlamaForCausalLM)(cfg)
+    served = {**dict(max_batch=8, max_len=512, block_size=16,
+                     prefill_chunk=128, num_blocks=256), **served}
     srv = GenerationServer(model, cache="paged", kernels=kernels,
-                           max_batch=8, max_len=512, block_size=16,
-                           prefill_chunk=128, num_blocks=256,
-                           mesh=None if tp == 1 else f"tp={tp}")
+                           mesh=None if tp == 1 else f"tp={tp}", **served)
+    slot_pools = []
     if tp == 1:
         one = SingleDeviceSharding(devs[0])
         mesh = None
         params = _abstract(srv.params, lambda a: one)
         pools = _abstract(srv._pools, lambda a: one)
+        slot_pools = _abstract(srv._slot_pools, lambda a: one)
 
         def rep(shape, dtype):
             return _sds(shape, dtype, one)
@@ -248,9 +290,10 @@ def _serve_programs(devs, cfg, tp, kernels="auto"):
     decode_args = (params, rep((B,), i32), pools, rep((B, M), i32),
                    rep((B,), i32), rep((B,), f32), rep((B,), i32),
                    rep((B,), f32), rep((B,), i32), rep((2,), jnp.uint32),
-                   None, (), True, None)
-    prefill_args = (params, rep((1, 128), i32), pools, rep((M,), i32),
-                    rep((), i32), rep((), i32), None, ())
+                   None, (), True, None, slot_pools)
+    prefill_args = (params, rep((1, srv.prefill_chunk), i32), pools,
+                    rep((M,), i32), rep((), i32), rep((), i32), None, (),
+                    slot_pools, rep((3,), i32))
 
     def fwd(p, ids):
         with (mesh_context(mesh) if mesh is not None
@@ -259,7 +302,7 @@ def _serve_programs(devs, cfg, tp, kernels="auto"):
 
     return [(srv._decode_paged, decode_args),
             (srv._chunk_prefill, prefill_args),
-            (jax.jit(fwd), (params, rep((1, 512), i32)))]
+            (jax.jit(fwd), (params, rep((1, srv.max_len), i32)))]
 
 
 def program_cases(devs):
@@ -281,6 +324,22 @@ def program_cases(devs):
                 built[tp] = _serve_programs(
                     devs, wide(max_position_embeddings=512), tp)
             return built[tp][i]
+        return build
+
+    def phi(i):
+        """Phi-4-mini-flash-reasoning whole, as its benchmark cell serves
+        it (benchmarks/configs/phi-4-mini-flash-reasoning.json): argument
+        and temp bytes of the two programs against the chip's 16 GB."""
+        def build():
+            if "phi" not in built:
+                from paddle_tpu.models.phi4flash import (Phi4FlashConfig,
+                                                         Phi4FlashForCausalLM)
+
+                built["phi"] = _serve_programs(
+                    devs, Phi4FlashConfig(max_position_embeddings=8192), 1,
+                    model_cls=Phi4FlashForCausalLM, max_batch=128,
+                    max_len=8192, num_blocks=32769)
+            return built["phi"][i]
         return build
 
     def megakernel():
@@ -308,6 +367,8 @@ def program_cases(devs):
         ("serve.tp4_decode", serve(4, 0)),
         ("serve.tp4_prefill_chunk", serve(4, 1)),
         ("serve.tp4_reference_forward", serve(4, 2)),
+        ("serve.phi4flash_decode_B128", phi(0)),
+        ("serve.phi4flash_prefill_chunk", phi(1)),
         ("megakernel.1chip_decode", megakernel),
     ]
 
