@@ -44,6 +44,15 @@ def batch_occupancy(run) -> Optional[float]:
         [s["slots_occupied"] / s["slots_total"] for s in st])
 
 
+def queue_depth_min(run) -> Optional[float]:
+    """Least ``load_metrics()["queue_depth"]`` over the window's steps: the
+    room a capacity cell has left. 0 means a slot waited for work, and
+    ``serve_tok_s`` is then no longer a capacity."""
+    depths = [s["queue_depth"] for s in window_steps(run)
+              if "queue_depth" in s]
+    return float(min(depths)) if depths else None
+
+
 def queue_wait_p95_ms(run) -> Optional[float]:
     waits = [s["dur"] * 1e3 for s in run.get("spans", [])
              if s["name"] == "queued"]

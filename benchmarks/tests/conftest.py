@@ -12,3 +12,14 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "fixtures")
+
+
+def served_of(mix):
+    """The ``served`` arguments of the configurations whose cells, in the
+    repo's BENCHMARK.json, send the traffic mix ``mix``."""
+    from benchmarks import run as R
+
+    m = R.load_manifest(ROOT)
+    files = {c["name"]: c["file"] for c in m["configs"]}
+    return [R.load_json(ROOT, files[w["config"]])["served"]
+            for w in m["workloads"] if w["traffic"] == mix]
