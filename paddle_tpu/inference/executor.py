@@ -95,6 +95,10 @@ class PagedExecutor:
                                     for _, shape, t in l.shapes]
             self._slot_index[i] = range(at, len(self.slot_pools))
 
+        # stand-ins for the token stack of a pending trip, by trip length
+        # (:meth:`prev_stack`)
+        self._no_prev: Dict[int, Any] = {}
+
         self.mesh = None
         self.tp = 1
         self.cp = 1
@@ -167,6 +171,8 @@ class PagedExecutor:
         self.decode_paged = self._jit(decode_body,
                                       donate_argnums=(2, 14),
                                       static_argnums=(12, 13))
+        # (``prev``, argument 15, is NOT donated: the host still has to
+        # read the pending trip's stack)
         self.chunk_prefill = self._jit(self._chunk_prefill_fn,
                                        donate_argnums=(2, 8))
         self.spec_scan = None
@@ -248,9 +254,13 @@ class PagedExecutor:
     def save_slot(self, slot: int) -> List[Any]:
         """Host copies of everything ``slot`` owns besides blocks of the
         shared pool (window rings, state rows), in ``slot_pools`` order:
-        what a preempted or captured request carries along."""
+        what a preempted or captured request carries along. The state on
+        the device is one trip AHEAD of the engine's ``generated`` while a
+        decode trip is pending, so that trip is retired first: what comes
+        back is the state of exactly the tokens the engine then holds."""
         import numpy as np
 
+        self.engine._retire_pending("save_slot")
         out = []
         for i in self.spec.slot_layers:
             for j in self._slot_index[i]:
@@ -288,10 +298,38 @@ class PagedExecutor:
         return self.engine._lora.gather_rows(list(lora_flat), aidx)
 
     # ------------------------------------------------------------- programs
+    def prev_stack(self, trip, k: int):
+        """The ``prev`` operand of a ``k``-tick decode trip: the token
+        stack of the pending ``trip`` (same length: only a server whose
+        every plain trip is ``tick_window`` long leaves one pending), or a
+        cached stand-in of that shape which no row reads."""
+        if trip is not None:
+            return trip.stack
+        if k not in self._no_prev:
+            z = jnp.zeros((k, self.engine.max_batch), jnp.int32)
+            if self.mesh is not None:
+                # placed as a trip's own stack comes out (replicated over
+                # the mesh): one placement, so ONE compiled decode variant
+                from ..parallel.serving_mesh import place_replicated
+
+                z = place_replicated(z, self.mesh)
+            self._no_prev[k] = z
+        return self._no_prev[k]
+
+    @staticmethod
+    def _feed(tokens, prev, active):
+        """Each row's input token, merged inside the program: the host's
+        ``tokens`` where ``active`` is 1, the last row of the pending
+        trip's stack ``prev`` — which never visits the host — where it is
+        2. Returns (tokens, active as 0/1)."""
+        if prev is not None:
+            tokens = jnp.where(active == 2, prev[-1], tokens)
+        return tokens, (active > 0).astype(active.dtype)
+
     def _decode_paged_fn(self, params, tokens, flat_pools, tables, pos,
                          temps, topks, topps, active, key, aidx=None,
                          lora_flat=(), greedy=False, ticks=None,
-                         slot_pools=()):
+                         slot_pools=(), prev=None):
         """Paged decode window: K/V reads/writes go through per-slot
         block tables into the shared pool. ``tables``: int32
         (B, table_width) — the engine zeroes rows of idle/prefilling slots
@@ -306,10 +344,14 @@ class PagedExecutor:
         ``slot_pools``: the window rings and state arrays of the spec's
         slot kinds (donated like the block pools; empty for a dense
         decoder), and with them the model is told through ``active=``
-        which rows may touch them."""
+        which rows may touch them. ``active``: int32 (B,), 0 = idle, 1 =
+        decodes from ``tokens[b]``, 2 = decodes from ``prev[-1, b]``, where
+        ``prev`` (k, B) is the token stack of the trip before, still
+        unread (:meth:`_feed`)."""
         engine = self.engine
         model = engine.model
         lora = self._gather_lora(lora_flat, aidx)
+        tokens, active = self._feed(tokens, prev, active)
         slot_kw = {"active": active} if self.spec.has_slot_state else {}
 
         def one_tick(carry, k):
@@ -542,13 +584,14 @@ class PagedExecutor:
     def _decode_megakernel_fn(self, params, tokens, flat_pools, tables,
                               pos, temps, topks, topps, active, key,
                               aidx=None, lora_flat=(), greedy=False,
-                              ticks=None, slot_pools=()):
+                              ticks=None, slot_pools=(), prev=None):
         """The whole-tick twin of :meth:`_decode_paged_fn` — identical
         signature, sampling pipeline, and trip structure; only the
         per-tick model call collapses into the ONE persistent Pallas
         program."""
         engine = self.engine
         lstk = self._mk_lora(lora_flat, aidx)
+        tokens, active = self._feed(tokens, prev, active)
 
         def one_tick(carry, k):
             toks, flat_p, p = carry

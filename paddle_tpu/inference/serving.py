@@ -77,6 +77,19 @@ class _Request:
     replay: Optional[List[int]] = None
 
 
+@dataclass
+class _Trip:
+    """A plain decode trip that was dispatched and whose tokens the host
+    has not read yet (``GenerationServer._retire_pending`` reads them)."""
+    stack: Any                  # (k, B) int32 token stack, on the device
+    rows: List[int]             # the slots it advanced
+    mask: np.ndarray            # (B,) 0/1 over those slots
+    ends: frozenset             # rows whose budget (max_new_tokens/max_len)
+    #                             runs out inside it: not in the next trip
+    k: int                      # ticks in it = tokens a row has in flight
+    tick: int                   # flight seq of the tick that dispatched it
+
+
 class GenerationServer:
     """Continuous-batching decode server for a ``LlamaForCausalLM`` —
     greedy by default, per-request sampling via
@@ -113,7 +126,13 @@ class GenerationServer:
         before the host sees the tokens — eos detection and slot refill lag
         by up to k-1 tokens (the surplus is discarded), in exchange for
         amortizing the device→host sync, which bounds the tick rate. The
-        serving analogue of generate()'s fully-compiled scan loop.
+        serving analogue of generate()'s fully-compiled scan loop. On the
+        paged path a plain trip's tokens are read only after the NEXT trip
+        has been dispatched (docs/serving.md, "The pending trip"): the next
+        trip takes the last row of the k-token stack on the device, a row
+        whose budget ends inside the pending window is left out of it, and
+        with k>1 a row whose eos falls in mid-window runs one more window
+        of k discarded tokens before its slot is released.
 
         ``cache="paged"``: block-table KV pool. ``block_size`` tokens per
         block; ``num_blocks`` bounds total KV memory (default: dense
@@ -583,12 +602,34 @@ class GenerationServer:
             "serving_state_saves",
             "slot states copied to the host (reason label: preempt, "
             "snapshot)")
+        # how often the pending trip engages (docs/observability.md):
+        # every plain decode trip is retired either after the next one was
+        # dispatched (overlapped) or at once, for the reason named
+        self._c_overlapped = reg.counter(
+            "serving_decode_trips_overlapped",
+            "decode trips read only after the next trip was dispatched")
+        self._c_early = reg.counter(
+            "serving_decode_trips_retired_early",
+            "decode trips read before another was dispatched (reason "
+            "label: spec, preempt, snapshot, save_slot, cancel, fault, "
+            "backoff, idle, demote, metrics, restore)")
+        self._c_discarded = reg.counter(
+            "serving_decode_rows_discarded",
+            "decode row-ticks computed for a request after its eos (the "
+            "rest of its window, and the trip dispatched before the host "
+            "had read it)")
+        # decode trips dispatched and not read yet, oldest first: one
+        # between steps, two for the moment between a dispatch and the
+        # retiring of the trip before
+        self._trips: List[_Trip] = []
+        self._trip_no = 0               # plain trips dispatched so far
         wins = [l.window for l in self.cache_spec.layers
                 if l.kind == "window"]
         self._ctx_window = min(wins) if wins else 0
         self._ctx_shared = bool(self.cache_spec.of_kind("shared"))
-        # flight-record seq of the tick in progress (0 with telemetry off):
-        # what every engine-row phase names as its parent
+        # flight-record seq of the tick in progress (0 with telemetry off,
+        # -1 between ticks with it on): what every engine-row phase names
+        # as its parent
         self._tick_seq = 0
         # program key of the last paged trip, recorded per tick by the
         # flight recorder; the watchdog keys recompile excusal on it
@@ -994,7 +1035,9 @@ class GenerationServer:
         tel, tick = self._tel, self._tick_seq
         if req.temperature == 0.0:
             with tel.phase("first_token_wait", tick, rid=req.rid):
-                row = np.asarray(lg[0])
+                # (the whole (1, V) array: ``lg[0]`` would launch an eager
+                # slice between the chunk and the decode program)
+                row = np.asarray(lg)[0]
             return int(np.argmax(row))
         from ..models.generation import next_token
 
@@ -1112,7 +1155,10 @@ class GenerationServer:
         ent = self._sched.peek()
         if ent is not None and all(sl is not None for sl in self._slots):
             v = self._pick_victim(ent.priority)
-            if v is not None and self._preempt_slot(v):
+            # (a victim that ended in the pending trip, which a
+            # preemption reads first, leaves its slot free all the same)
+            if v is not None and (self._preempt_slot(v)
+                                  or self._slots[v] is None):
                 admitted += self._fill_free_slots()
         return admitted
 
@@ -1242,6 +1288,8 @@ class GenerationServer:
             return
         victims = a.coldest_cached(want)
         if victims:
+            # the copy to the host reads the pools the pending trip writes
+            self._retire_pending("demote")
             self._offload.demote(victims, self._pools)
 
     def _resume_swapped(self, slot: int, ent: SchedEntry) -> bool:
@@ -1356,8 +1404,13 @@ class GenerationServer:
         the re-run's prefix match skips them anyway. A decoding slot
         SWAPS: its table (truncated of speculative reservations) parks in
         host memory via the offload engine for a bit-exact resume.
-        Returns False — slot untouched — when the host pool is full."""
+        Returns False — slot untouched — when the host pool is full (or
+        the request ended in the pending trip, which is read first: a
+        victim's position, last token and state have to be the host's)."""
+        self._retire_pending("preempt")
         req = self._slots[s]
+        if req is None:
+            return False
         ent = req.sched
         if self._prefilling[s]:
             for bid in req.table:
@@ -1420,6 +1473,10 @@ class GenerationServer:
         keeps its state and simply sits out this trip)."""
         tried = {s}
         while True:
+            if self._slots[s] is None:
+                # ``s`` ended in the pending trip that a preemption below
+                # had to read first
+                return "gone"
             try:
                 self._ensure_blocks(s, entries)
                 return "ok"
@@ -1430,7 +1487,7 @@ class GenerationServer:
                     tried.add(v)
                     self._preempt_slot(v)
                     continue
-                if self._preempt_slot(s):
+                if self._preempt_slot(s) or self._slots[s] is None:
                     return "gone"
                 self._stalls += 1
                 self._c_stalls.inc()
@@ -1448,7 +1505,9 @@ class GenerationServer:
                 continue        # preempted as a victim earlier in the loop
             if self._reserve_or_preempt(s, need_fn(s)) == "ok":
                 out.append(s)
-        out.sort()
+        # (a preemption retires the pending trip, which may end a row that
+        # was reserved before it)
+        out = sorted(s for s in out if self._slots[s] is not None)
         if not out and active:
             self._stall_streak += 1
             if self._stall_streak > 256:
@@ -1634,6 +1693,7 @@ class GenerationServer:
                     for f in finds:
                         self._c_degrade.inc(kind=f["kind"])
                 self._degraded_ticks = 64
+        self._tick_seq = -1         # between ticks: a phase names none
         return remaining
 
     def _step_paged_inner(self) -> int:
@@ -1657,11 +1717,25 @@ class GenerationServer:
                     chunks += self._prefill_chunk_step(s)
                     did_prefill = True
             ph.note(chunks=chunks)
-        active = [s for s in range(self.max_batch)
-                  if self._slots[s] is not None and not self._prefilling[s]
-                  and self._slots[s].rid not in self._handoff]
+        # rows for the coming trip: the decoding slots, less those that the
+        # pending trip's harvest will end whatever its tokens are — their
+        # budget runs out inside it, or their last harvested token was eos
+        pend = self._trips[-1] if self._trips else None
+        active = []
+        for s in range(self.max_batch):
+            req = self._slots[s]
+            if req is None or self._prefilling[s] \
+                    or req.rid in self._handoff:
+                continue
+            if pend is not None and pend.mask[s] and (
+                    s in pend.ends or req.done
+                    or (self.eos is not None
+                        and req.generated[-1] == self.eos)):
+                continue
+            active.append(s)
         if self._degraded_ticks > 0:
             self._degraded_ticks -= 1
+        trips0, idle_why = self._trip_no, "idle"
         if active:
             self._step_no += 1
             if self._backoff_ticks > 0:
@@ -1670,6 +1744,7 @@ class GenerationServer:
                 # sitting out a few ticks lets a transient failure domain
                 # clear before the identical trip is retried
                 self._backoff_ticks -= 1
+                idle_why = "backoff"
                 if tel_on:
                     self._last_prog = "backoff"
             else:
@@ -1680,6 +1755,8 @@ class GenerationServer:
                     from .faults import TickFault
 
                     if isinstance(e, TickFault):
+                        # (strikes and quarantine act on retired state)
+                        self._retire_pending("fault")
                         self._on_tick_fault(rids, e)
                     else:
                         # an exception AFTER compiled dispatch may have
@@ -1693,6 +1770,11 @@ class GenerationServer:
                     # fault domain that struck them was transient
                     for r in rids:
                         self._strikes.pop(r, None)
+        if self._trip_no == trips0:
+            # no trip went out this tick (no decoding row, a backoff tick,
+            # every row stalled): nothing will overlap the pending one, so
+            # it is read now — run() drains, step() comes down to 0
+            self._retire_pending(idle_why)
         if tel_on and did_prefill:
             # prefill-bearing ticks get their own program-key suffix: the
             # chunk program's (and first-token sampling's) one-time
@@ -1787,6 +1869,7 @@ class GenerationServer:
         """Terminal ``failed`` status for one request: release its slot,
         blocks, and adapter ref; record why. The engine itself keeps
         serving — that is the entire point of the quarantine rung."""
+        self._retire_pending("fault")
         self._strikes.pop(rid, None)
         self._quarantined += 1
         self._dropped[rid] = "failed"
@@ -1812,9 +1895,18 @@ class GenerationServer:
     def _plain_decode_trip(self, active, ticks=None) -> None:
         """One plain (non-speculative) decode trip: ``ticks`` (default
         ``tick_window``) ticks in one compiled program across the listed
-        slots."""
+        slots. The trip is DISPATCHED here and stays pending: its tokens
+        are read (:meth:`_retire_pending`) only after the next trip has
+        been dispatched, so the chip has work queued while the host folds
+        tokens, admits and dispatches prefill chunks. What is known
+        without the tokens advances now (positions, block reservations);
+        what the tokens decide (``generated``, counters, results, slot
+        release) advances when the trip is retired. A speculative server
+        retires at once: its drafters read the tokens on the host."""
         k = self.tick_window if ticks is None else ticks
         tel = self._tel
+        # (under pool pressure a preemption in here retires the pending
+        # trip first, and the rows it ended drop out of ``active``)
         active = self._reserve_active(
             active, lambda s: -(-(int(self.pos[s]) + k) // self.block_size))
         if not active:
@@ -1827,6 +1919,7 @@ class GenerationServer:
             self._last_prog = (f"plain:t{'w' if ticks is None else ticks}"
                                f":g{int(self._all_greedy(active))}")
         tick, rows = self._tick_seq, len(active)
+        prev = self._trips[-1] if self._trips else None
         with tel.phase("decode_dispatch", tick, rows=rows):
             # the greedy-specialized programs never read the key — skip
             # the per-step eager fold_in dispatch (~0.4ms) for it
@@ -1834,6 +1927,12 @@ class GenerationServer:
                    else jax.random.fold_in(self._base_key, self._step_no))
             active_mask = np.zeros((self.max_batch,), np.int32)
             active_mask[active] = 1
+            # where a row's input token comes from: 1 = the host's array
+            # (a first token, a resume, a replay, a trip read early), 2 =
+            # the last row of the pending trip's stack, which never visits
+            # the host; the program merges the two
+            feed = active_mask if prev is None \
+                else active_mask * (1 + prev.mask)
             # idle/prefilling rows run masked: zeroed table + pos 0 routes
             # their (discarded) cache writes to the scratch block
             bt = np.where(active_mask[:, None] > 0, self._bt, 0)
@@ -1842,13 +1941,62 @@ class GenerationServer:
             stack, self._pools, self._slot_pools = self._decode_paged(
                 self.params, jnp.asarray(self.tokens), self._pools,
                 jnp.asarray(bt), jnp.asarray(posv), temps, topks, topps,
-                jnp.asarray(active_mask), key, aidx, self._lora_flat(),
-                self._all_greedy(active), ticks, self._slot_pools)
-        # the trip's one host sync, apart from the fold that follows it
-        with tel.phase("decode_wait", tick, rows=rows):
-            nxt_host = np.asarray(stack)
-        self._harvest_phase(len(active), self._harvest_window, nxt_host,
-                            active, active_mask)
+                jnp.asarray(feed), key, aidx, self._lora_flat(),
+                self._all_greedy(active), ticks, self._slot_pools,
+                self._exec.prev_stack(prev, k))
+            # rows that this trip takes to the end of their budget: the
+            # harvest will release them, the next trip leaves them out
+            ends = []
+            for s in active:
+                req = self._slots[s]
+                flying = prev.k if feed[s] == 2 else 0
+                if k >= min(req.max_new_tokens - len(req.generated) - flying,
+                            self.max_len - 1 - int(self.pos[s])):
+                    ends.append(s)
+            self._trips.append(_Trip(stack, active, active_mask,
+                                     frozenset(ends), k, tick))
+            self._trip_no += 1
+            self.pos = self.pos + active_mask * k
+        self._retire_pending(keep=1)
+        if self.spec is not None:
+            self._retire_pending("spec")
+
+    def _retire_pending(self, reason: Optional[str] = None,
+                        keep: int = 0) -> None:
+        """Wait for and harvest the decode trips still unread, oldest
+        first, down to the newest ``keep``: the tick's ``decode_wait`` and
+        ``harvest`` phases, each naming in ``trip`` the tick that
+        dispatched what it reads. ``reason`` None is the overlapped order
+        (called after the next dispatch); everything that reads or moves
+        per-slot state — a preemption, a snapshot, a cancel, the executor's
+        ``save_slot`` — and every tick that dispatches nothing calls it
+        with the reason it could not wait, so that ``pos`` / ``tokens`` /
+        ``generated`` and the device agree when it returns."""
+        tel = self._tel
+        if self._failed is not None:
+            # the device is not to be trusted, nor waited for: forget the
+            # trips; positions fall back to the last harvest, which is
+            # what ``generated`` holds
+            for trip in self._trips:
+                self.pos = self.pos - trip.mask * trip.k
+                for s in trip.rows:
+                    if self._slots[s] is not None and self._slots[s].done:
+                        self._release_slot(s)
+            self._trips = []
+        while len(self._trips) > keep:
+            trip = self._trips[0]
+            rows = len(trip.rows)
+            # the trip's one host sync, apart from the fold that follows it
+            with tel.phase("decode_wait", self._tick_seq, rows=rows,
+                           trip=trip.tick):
+                nxt_host = np.asarray(trip.stack)
+            del self._trips[0]
+            if reason is None:
+                self._c_overlapped.inc()
+            else:
+                self._c_early.inc(reason=reason)
+            self._harvest_phase(rows, trip.tick, self._harvest_window,
+                                nxt_host, trip.rows, trip.mask)
 
     # ----------------------------------------------------------- speculative
     def _spec_tick(self, active) -> None:
@@ -1924,11 +2072,14 @@ class GenerationServer:
                     kcaps, jax.random.fold_in(key, 2),
                     None if qprobs is None else jnp.asarray(qprobs),
                     aidx, self._lora_flat(), self._all_greedy(active))
-        with tel.phase("decode_wait", tick, rows=rows):
+        with tel.phase("decode_wait", tick, rows=rows, trip=tick):
             outs, accs = np.asarray(outs), np.asarray(accs)
             if not self._spec_fused:
                 outs, accs = outs[None], accs[None]   # one window a trip
-        self._harvest_phase(rows, self._harvest_spec, outs, accs, active)
+        self._harvest_phase(rows, tick, self._harvest_spec, outs, accs,
+                            active)
+        # (a drafter reads the tokens on the host: never left pending)
+        self._c_early.inc(reason="spec")
         if tel.enabled:
             _t1 = tel.clock()
             for s, rid in _rids:
@@ -2098,6 +2249,11 @@ class GenerationServer:
         for s in range(self.max_batch):
             req = self._slots[s]
             if req is not None and req.rid == rid:
+                # its blocks go back to the pool: the trip that still
+                # writes them is read first, and may have finished it
+                self._retire_pending("cancel")
+                if self._slots[s] is not req:
+                    return False
                 if self.cache_mode == "paged":
                     req.table = self.alloc.truncate(req.table, 0)
                 self._dropped[rid] = "cancelled"
@@ -2189,7 +2345,10 @@ class GenerationServer:
         """Per-rid wall-clock marks — ``submit_t``, ``first_token_t``,
         ``done_t``, ``n_generated`` (plus the request's ``tenant``) —
         from which TTFT and per-token latency are derived
-        (tools/serving_benchmark.py)."""
+        (tools/serving_benchmark.py). The marks of the pending trip are
+        among them: it is read first, so between steps this is also the
+        call after which ``pos`` / ``generated`` and the device agree."""
+        self._retire_pending("metrics")
         return self._req_metrics
 
     def _release_slot(self, slot: int) -> None:
@@ -2423,6 +2582,9 @@ class GenerationServer:
                 f"snapshot(trust_kv=False) to salvage from host state")
         from .kv_offload import payload_checksum
 
+        # what is captured is retired state (a failed engine's unread trips
+        # are forgotten instead: the salvage trusts the last harvest only)
+        self._retire_pending("snapshot")
         reqs: List[Dict[str, Any]] = []
         for s in range(self.max_batch):
             req = self._slots[s]
@@ -2521,6 +2683,7 @@ class GenerationServer:
         if self._failed is not None:
             raise ValueError(f"cannot restore into a failed server "
                              f"({self._failed}) — build a fresh one")
+        self._retire_pending("restore")
         if any(sl is not None for sl in self._slots) or len(self._sched):
             raise ValueError("restore() needs an idle server: slots and "
                              "queue must be empty")
@@ -2907,10 +3070,13 @@ class GenerationServer:
         return self._tel.export_chrome_trace(path)
 
     # ------------------------------------------------------------- stepping
-    def _harvest_phase(self, rows: int, harvest, *args) -> None:
+    def _harvest_phase(self, rows: int, trip: int, harvest, *args) -> None:
         """Run ``harvest(*args)`` — the fold of a trip's host arrays into
-        the requests — as the tick's ``harvest`` phase."""
-        with self._tel.phase("harvest", self._tick_seq, rows=rows) as ph:
+        the requests — as the tick's ``harvest`` phase; ``trip`` is the
+        tick that dispatched what is folded (this one, or the one before
+        for a trip that was left pending)."""
+        with self._tel.phase("harvest", self._tick_seq, rows=rows,
+                             trip=trip) as ph:
             done0 = len(self._results)
             harvest(*args)
             ph.note(finished=len(self._results) - done0)
@@ -2919,23 +3085,35 @@ class GenerationServer:
         """Fold one decode window's (k, B) token stack into the per-request
         state: append tokens, detect eos/max-new/max-len completion (window
         surplus past completion is discarded — tick_window semantics) and
-        free finished slots for next window's refill."""
+        free finished slots for next window's refill. ``pos`` advanced
+        when the window was dispatched, and again for a trip dispatched
+        since and still unread (the one left in ``_trips``): a row that
+        ends here on eos and is in that newer trip keeps its slot and
+        blocks until it is retired, where its tokens are discarded."""
         k = nxt_host.shape[0]
-        self.pos = self.pos + active_mask * k
         self.tokens = np.where(active_mask > 0, nxt_host[-1],
                                self.tokens).astype(np.int32)
-        pos_after = self.pos
+        newer = self._trips[-1] if self._trips else None
+        pos_after = self.pos if newer is None \
+            else self.pos - newer.mask * newer.k
         # work folded, for the counters: a row at position p emits its
         # tokens at contexts p+1 .. p+k; what a finished row leaves of the
         # window is taken off again below
         rows = k * len(active)
         ctx = k * int(pos_after[active].sum()) \
             - len(active) * (k * (k - 1) // 2)
-        W, ctx_win = self._ctx_window, 0
+        W, ctx_win, discarded = self._ctx_window, 0, 0
         for s in active:
             req = self._slots[s]
             done = False
-            if self.eos is None:
+            if req.done:
+                # a whole surplus trip, dispatched before the host had read
+                # the eos in the trip before (tick_window > 1 only: with one
+                # token a trip the host sees an eos coming): nothing to
+                # fold, the slot goes now
+                take, done = 0, True
+                discarded += k
+            elif self.eos is None:
                 # no-eos fast path (see _harvest_spec): emission is one
                 # slice per window instead of a per-token python walk
                 gen = req.generated
@@ -2950,14 +3128,16 @@ class GenerationServer:
             else:
                 take = 0
                 for t in range(k):
-                    tok = int(nxt_host[t, s])
-                    finished_last = req.generated[-1] == self.eos
-                    if not finished_last:
-                        req.generated.append(tok)
-                        take += 1
+                    if req.generated[-1] == self.eos:
+                        # ended on the token before: this one and the rest
+                        # of the window were computed for nothing
+                        done = True
+                        discarded += k - t
+                        break
+                    req.generated.append(int(nxt_host[t, s]))
+                    take += 1
                     pos_t = int(pos_after[s]) - k + t + 1
-                    if (finished_last
-                            or len(req.generated) >= req.max_new_tokens
+                    if (len(req.generated) >= req.max_new_tokens
                             or pos_t >= self.max_len - 1):
                         done = True
                         break
@@ -2971,11 +3151,16 @@ class GenerationServer:
                 if cut:
                     rows -= cut
                     ctx -= cut * int(pos_after[s]) - cut * (cut - 1) // 2
-                self._emit_result(req)
-                self._release_slot(s)
+                if not req.done:
+                    self._emit_result(req)
+                    req.done = True
+                if newer is None or not newer.mask[s]:
+                    self._release_slot(s)
         self._c_tokens.inc(rows)
         self._c_dec_rows.inc(rows)
         self._c_dec_ctx.inc(ctx)
+        if discarded:
+            self._c_discarded.inc(discarded)
         if W:
             self._c_dec_ctx_win.inc(ctx_win)
         if self._ctx_shared:
@@ -3005,6 +3190,7 @@ class GenerationServer:
                    "recompiles": compile_count() - c0}
             ph.note(tokens=int(self._c_tokens.total() - tok0), **rec)
         tel.flight.record(t_wall_s=ph.dur, wait_s=tel.wait_s - w0, **rec)
+        self._tick_seq = -1
         return remaining
 
     def _step_dense_inner(self) -> int:
@@ -3029,10 +3215,11 @@ class GenerationServer:
                 jnp.asarray(self.pos), jnp.asarray(self.temps),
                 jnp.asarray(self.topks), jnp.asarray(self.topps),
                 jnp.asarray(active_mask), key)
-        with tel.phase("decode_wait", tick, rows=len(active)):
+        with tel.phase("decode_wait", tick, rows=len(active), trip=tick):
             nxt_host = np.asarray(stack)
-        self._harvest_phase(len(active), self._harvest_window, nxt_host,
-                            active, active_mask)
+        self.pos = self.pos + active_mask * nxt_host.shape[0]
+        self._harvest_phase(len(active), tick, self._harvest_window,
+                            nxt_host, active, active_mask)
         return sum(sl is not None for sl in self._slots) + len(self._sched)
 
     def run(self) -> Dict[int, List[int]]:

@@ -316,7 +316,7 @@ def test_long_full_batch_run_drops_no_span(model):
                            block_size=16, prefill_chunk=16, telemetry=True)
 
     def fake_decode(params, tokens, pools, *rest):
-        return np.ones((1, B), np.int32), pools, rest[-1]   # + slot pools
+        return np.ones((1, B), np.int32), pools, rest[-2]   # + slot pools
 
     srv._decode_paged = fake_decode
     rng = np.random.RandomState(3)
@@ -332,8 +332,11 @@ def test_long_full_batch_run_drops_no_span(model):
     tr = srv.telemetry.tracer
     assert tr.dropped == 0
     assert srv.telemetry.flight.total == 1200
+    # (a slot whose answer ends sits out the one trip that is dispatched
+    # before its last tokens are read: an answer a slot every ~100 ticks
+    # keeps a few of the 64 rows out of most trips)
     full = [s for s in tr.spans(ENGINE_RID) if s["name"] == "decode_dispatch"
-            and s["args"]["rows"] == B]
+            and s["args"]["rows"] >= B - 4]
     assert len(full) > 1000
     per_request = (len(tr.spans()) - len(tr.spans(ENGINE_RID))) / submitted
     assert per_request < 12

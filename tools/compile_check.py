@@ -290,7 +290,8 @@ def _serve_programs(devs, cfg, tp, kernels="auto", model_cls=None,
     decode_args = (params, rep((B,), i32), pools, rep((B, M), i32),
                    rep((B,), i32), rep((B,), f32), rep((B,), i32),
                    rep((B,), f32), rep((B,), i32), rep((2,), jnp.uint32),
-                   None, (), True, None, slot_pools)
+                   None, (), True, None, slot_pools,
+                   rep((srv.tick_window, B), i32))   # the pending trip's stack
     prefill_args = (params, rep((1, srv.prefill_chunk), i32), pools,
                     rep((M,), i32), rep((), i32), rep((), i32), None, (),
                     slot_pools, rep((3,), i32))
