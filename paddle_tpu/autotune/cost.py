@@ -221,10 +221,9 @@ def geometry_cost_proxy(op: str, geometry, **shape) -> float:
     costs: grid-step count (launch/bookkeeping overhead amortized by
     deeper streaming / larger tiles) plus a VMEM-pressure penalty once
     the occupancy model nears the per-core budget."""
-    from .kernel_geometry import (CEGeometry, FlashAttentionGeometry,
-                                  LoRAGeometry, NormGeometry,
-                                  PagedAttentionGeometry)
-    from .space import MK_VMEM_LIMIT_BYTES
+    from .kernel_geometry import (VMEM_PER_CORE_BYTES, CEGeometry,
+                                  FlashAttentionGeometry, LoRAGeometry,
+                                  NormGeometry, PagedAttentionGeometry)
 
     if isinstance(geometry, PagedAttentionGeometry):
         # one program per (row, q-row tile); the KV walk is in-program
@@ -259,5 +258,5 @@ def geometry_cost_proxy(op: str, geometry, **shape) -> float:
             if isinstance(geometry, CEGeometry) else {"width": width}))
     else:
         raise ValueError(f"no cost proxy for {type(geometry).__name__}")
-    pressure = max(0.0, vmem / MK_VMEM_LIMIT_BYTES - 0.5)
+    pressure = max(0.0, vmem / VMEM_PER_CORE_BYTES - 0.5)
     return float(steps * (1.0 + 4.0 * pressure * pressure))

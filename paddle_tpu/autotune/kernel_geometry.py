@@ -1,20 +1,16 @@
 """Per-layer kernel geometry: tunable schedules for the per-op Pallas
 kernels, plus the per-(op, dtype, shape, chip) winner cache.
 
-PR 16 made the whole-tick megakernel's schedule tunable
-(:class:`~paddle_tpu.ops.decode_megakernel.MegakernelGeometry`); this
-module is the open half of ROADMAP item 3 — the *per-layer* kernels
-(paged attention fp/int8, fused LoRA, flash attention, fused norm,
-fused CE) get the same treatment. One frozen dataclass per op family
-expresses the schedule as data with ``validate()`` + a VMEM-occupancy
-model, mirroring ``MegakernelGeometry``.
+The per-layer kernels (paged attention fp/int8, fused LoRA, flash
+attention, fused norm, fused CE) take their schedule as data: one
+frozen dataclass per op family, with ``validate()`` + a VMEM-occupancy
+model.
 
-The geometry contract is STRICTER than the megakernel's: every
-swept geometry is a schedule change only — tile/block shapes, q-row
-tiling, hoisted-but-exact casts — never a math-order change, so any
-candidate's output is BIT-EXACT against the default geometry's (the
-parity sweep in tests/test_kernel_geometry.py pins this bitwise, fp
-and int8). Zero values mean "derive the choice from the shapes". Knobs
+The geometry contract is strict: every swept geometry is a schedule
+change only — tile/block shapes, q-row tiling, hoisted-but-exact casts
+— never a math-order change, so any candidate's output is BIT-EXACT
+against the default geometry's (the parity sweep in
+tests/test_kernel_geometry.py pins this bitwise, fp and int8). Zero values mean "derive the choice from the shapes". Knobs
 that regroup floating-point accumulation (the flash kernel's kv block
 and the paged kernel's blocks per group both set the online-softmax
 update granularity) exist as declared axes, are honored when set
@@ -26,7 +22,7 @@ Winners are cached per ``(op, dtype, head_dim_or_row, device_kind)`` in
 a :class:`GeometryCache` — the schedule space is hardware-generation-
 specific (TVM / the XLA fusion study, PAPERS.md), so a fleet on mixed
 TPU generations resolves per-chip winners from one artifact. The cache
-persists inside ``TunedProfile`` (schema v3) and carries its own
+persists inside ``TunedProfile`` (since schema v3) and carries its own
 fingerprint; a hand-edited cache fails at load, same contract as the
 profile's ``config_fingerprint``.
 
@@ -57,6 +53,10 @@ PA_DEQUANT_MODES = ("scores", "early")
 #: "delta_first" starts the low-rank chain before the base projection
 #: so the small matmuls hide under the big one's MXU occupancy.
 LORA_ACCUM_LAYOUTS = ("base_first", "delta_first")
+
+#: per-core VMEM budget every occupancy model here is checked against
+#: (Mosaic's scoped-VMEM limit on the v5e)
+VMEM_PER_CORE_BYTES = 16 << 20
 
 
 def _largest_divisor(n: int, want: int) -> int:
@@ -452,8 +452,7 @@ def resolve_server_geometries(*, head_dim: int, hidden: int, dtype: str,
                               device_kind: Optional[str] = None
                               ) -> Dict[str, Tuple[Any, str]]:
     """The per-op resolution a GenerationServer performs at
-    construction — the per-layer twin of the megakernel's
-    ``mk_geometry`` resolution. Keys follow the cache convention:
+    construction. Keys follow the cache convention:
     head_dim for the attention ops, the adapter rank for fused LoRA,
     the hidden width for the row-tiled fused ops; the paged-attention
     dtype is "int8" under KV quantization (the int8 kernel is a
@@ -486,9 +485,7 @@ def geometry_candidates(op: str, *, quantized: bool = False,
     index 0 is always the default geometry — ties in the sweep resolve
     toward it."""
     if vmem_limit_bytes is None:
-        from .space import MK_VMEM_LIMIT_BYTES
-
-        vmem_limit_bytes = MK_VMEM_LIMIT_BYTES
+        vmem_limit_bytes = VMEM_PER_CORE_BYTES
     cands: list = []
     if op == "paged_attention":
         # kv_block_depth stays derived: it regroups the online softmax

@@ -21,15 +21,17 @@ from typing import Any, Dict, Mapping, Optional
 from .space import ALL_KNOBS, ConfigSpace
 from .workload import WorkloadSpec
 
-# v2: the config gained the kernel tier (kernels + mk_* megakernel
-# geometry knobs) — v1 profiles are missing knobs under the new space
-# and must retune rather than guess
+# v2: the config gained the kernel tier — v1 profiles are missing knobs
+# under the new space and must retune rather than guess
 # v3: profiles carry the per-layer kernel-geometry winner cache
 # (``kernel_geometry``, a GeometryCache dict keyed by (op, dtype,
 # shape, chip)) — v2 profiles lack the per-op tier entirely, and a
 # default-geometry guess would silently discard the sweep, so they
 # must retune rather than guess, same rule as v1->v2
-PROFILE_SCHEMA_VERSION = 3
+# v4: the kernel tier is the ``kernels`` knob alone, with three values —
+# a v3 profile may name a fourth mode and three knobs of a kernel that
+# is gone, and is refused whole rather than applied in part
+PROFILE_SCHEMA_VERSION = 4
 
 
 @dataclasses.dataclass
@@ -176,13 +178,6 @@ def config_server_kwargs(config: Mapping[str, Any], model_cfg, *,
     kernels = str(cfg.get("kernels", "auto"))
     if kernels != "auto":
         kw["kernels"] = kernels
-    if kernels == "megakernel":
-        from ..ops.decode_megakernel import MegakernelGeometry
-
-        kw["mk_geometry"] = MegakernelGeometry(
-            ffn_tile=int(cfg.get("mk_ffn_tile", 0)),
-            prefetch_depth=int(cfg.get("mk_prefetch_depth", 2)),
-            dequant=str(cfg.get("mk_dequant", "scores")))
     return kw
 
 

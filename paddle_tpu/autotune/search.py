@@ -306,7 +306,7 @@ def autotune(runner: TrialRunner, *, budget: int = 8, seed: int = 0,
     .GeometryCache` from ``sweep_kernel_geometry`` /
     ``kernel_bench.py --sweep-geometry``) is stamped into the profile's
     per-op tier so ``GenerationServer(profile=)`` resolves per-layer
-    kernel geometry the same way it resolves ``mk_geometry``."""
+    kernel geometry from it."""
     emit = log or (lambda s: None)
     if space is None:
         import jax

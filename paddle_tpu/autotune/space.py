@@ -25,14 +25,6 @@ Knobs interact, so validity is first-class:
 - the fleet knobs (``prefix_weight``/``load_weight``/``probe_every``/
   ``degrade_cooldown_s``) are dead at ``fleet_replicas == 1`` and
   canonicalize to their defaults.
-- the kernel tier (``mk_ffn_tile``/``mk_prefetch_depth``/``mk_dequant``
-  — the megakernel's :class:`~..ops.decode_megakernel.MegakernelGeometry`
-  as knobs) is dead weight when ``kernels != "megakernel"`` and
-  canonicalizes to defaults; under ``kernels == "megakernel"`` with a
-  ``model_cfg`` bound to the space, validity runs the geometry's
-  VMEM-residency arithmetic against the per-core budget (~16 MiB on
-  current TPUs) and the ffn-tile divisibility check — a geometry that
-  cannot fit VMEM is invalid, not an OOM mid-search.
 - ``cp > 1`` (context-parallel prefill) requires a mesh the host can
   actually build (the space's ``devices`` bound) and must divide
   ``prefill_chunk`` — the chunk shards evenly by construction.
@@ -104,24 +96,12 @@ ENGINE_KNOBS: Tuple[Knob, ...] = (
          "(canonicalized to None) when tier_demote_low is None"),
 )
 
-#: the kernel tier: dispatch mode plus the whole-tick megakernel's
-#: geometry (ops/decode_megakernel.MegakernelGeometry) expressed as
-#: knobs — dead (canonicalized to defaults) unless kernels="megakernel".
+#: the kernel tier: the dispatch mode (the per-op schedules are swept
+#: apart from this space, autotune/kernel_geometry.py)
 KERNEL_KNOBS: Tuple[Knob, ...] = (
-    Knob("kernels", ("auto", "pallas", "megakernel", "reference"), "auto",
+    Knob("kernels", ("auto", "pallas", "reference"), "auto",
          "kernel dispatch rung for the compiled serving programs "
          "(ops.set_kernel_mode)"),
-    Knob("mk_ffn_tile", (0, 512, 1024, 2048), 0,
-         "megakernel FFN intermediate-dim tile width; 0 streams each "
-         "layer's full gate/up/down weights (reference-exact contraction "
-         "order)"),
-    Knob("mk_prefetch_depth", (1, 2, 4), 2,
-         "megakernel weight-stream lookahead in chunks (VMEM buffers per "
-         "stream); 2 = classic double buffering"),
-    Knob("mk_dequant", ("scores", "tile"), "scores",
-         "megakernel int8 KV dequant placement: 'scores' folds scales "
-         "into the softmax accumulators (token-exact vs reference), "
-         "'tile' dequantizes the whole VMEM tile up front"),
 )
 
 FLEET_KNOBS: Tuple[Knob, ...] = (
@@ -139,11 +119,6 @@ FLEET_KNOBS: Tuple[Knob, ...] = (
 
 ALL_KNOBS: Tuple[Knob, ...] = ENGINE_KNOBS + KERNEL_KNOBS + FLEET_KNOBS
 
-#: per-core VMEM the megakernel's residency estimate is checked against
-#: (~16 MiB on current TPU generations; override per space if yours
-#: differs)
-MK_VMEM_LIMIT_BYTES = 16 << 20
-
 
 class ConfigSpace:
     """Typed knob space with validity, canonicalization, and seeded
@@ -154,27 +129,13 @@ class ConfigSpace:
     ``block_size`` choices so one block never exceeds the serving
     horizon; ``devices`` bounds the ``cp`` mesh axis — a cp degree the
     host cannot build a mesh for is invalid, not a runtime crash.
-
-    ``model_cfg`` binds a model geometry to the space and arms the
-    kernel tier's validity arithmetic: under ``kernels="megakernel"``
-    the candidate :class:`~..ops.decode_megakernel.MegakernelGeometry`'s
-    worst-case VMEM residency (``vmem_bytes``, at ``max_batch`` rows ×
-    the config's verify window) must fit ``vmem_limit_bytes``
-    (default :data:`MK_VMEM_LIMIT_BYTES`), and ``mk_ffn_tile`` must
-    divide the model's intermediate size. Without a bound model the
-    kernel knobs only get the geometry's own range checks.
     """
 
     def __init__(self, knobs: Sequence[Knob] = ALL_KNOBS, *,
                  pins: Optional[Dict[str, Any]] = None,
                  max_len: Optional[int] = None,
-                 devices: Optional[int] = None,
-                 model_cfg=None, max_batch: int = 8,
-                 vmem_limit_bytes: int = MK_VMEM_LIMIT_BYTES):
+                 devices: Optional[int] = None):
         self.devices = devices
-        self.model_cfg = model_cfg
-        self.max_batch = int(max_batch)
-        self.vmem_limit_bytes = int(vmem_limit_bytes)
         self.knobs: Tuple[Knob, ...] = tuple(knobs)
         names = [k.name for k in self.knobs]
         if len(set(names)) != len(names):
@@ -273,51 +234,6 @@ class ConfigSpace:
                 errs.append(
                     f"tier watermarks must satisfy 0 < low < high <= 1, "
                     f"got low={lo} high={hi}")
-        if config.get("kernels", "auto") == "megakernel":
-            errs.extend(self._megakernel_errors(config))
-        return errs
-
-    def _megakernel_errors(self, config: Dict[str, Any]) -> List[str]:
-        """Kernel-tier feasibility: the candidate geometry's own range
-        checks, plus — with a model bound — ffn-tile divisibility and
-        the worst-case VMEM-residency arithmetic against the per-core
-        budget."""
-        errs: List[str] = []
-        from ..ops.decode_megakernel import MegakernelGeometry
-
-        try:
-            geom = MegakernelGeometry(
-                ffn_tile=int(config.get("mk_ffn_tile", 0)),
-                prefetch_depth=int(config.get("mk_prefetch_depth", 2)),
-                dequant=str(config.get("mk_dequant", "scores")))
-            geom.validate()
-        except ValueError as e:
-            return [f"megakernel geometry: {e}"]
-        mc = self.model_cfg
-        if mc is None:
-            return errs
-        I = int(mc.intermediate_size)
-        if geom.ffn_tile and I % geom.ffn_tile:
-            errs.append(
-                f"mk_ffn_tile={geom.ffn_tile} does not divide the bound "
-                f"model's intermediate_size={I}")
-            return errs
-        heads = int(mc.num_attention_heads)
-        head_dim = int(mc.hidden_size) // heads
-        need = geom.vmem_bytes(
-            hidden=int(mc.hidden_size), heads=heads,
-            kv_heads=int(mc.num_key_value_heads), head_dim=head_dim,
-            intermediate=I, layers=int(mc.num_hidden_layers),
-            batch=self.max_batch,
-            window=int(config.get("draft_k", 0)) + 1,
-            block_size=int(config.get("block_size", 16)),
-            quantized=config.get("kv_quant", "none") == "int8")
-        if need > self.vmem_limit_bytes:
-            errs.append(
-                f"megakernel geometry needs ~{need / (1 << 20):.1f} MiB "
-                f"VMEM residency, over the "
-                f"{self.vmem_limit_bytes / (1 << 20):.1f} MiB per-core "
-                f"budget — shrink mk_ffn_tile/mk_prefetch_depth")
         return errs
 
     def is_valid(self, config: Dict[str, Any]) -> bool:
@@ -344,13 +260,6 @@ class ConfigSpace:
         if cfg.get("fleet_replicas", 1) == 1:
             for name in ("prefix_weight", "load_weight", "probe_every",
                          "degrade_cooldown_s"):
-                if name in self._by_name:
-                    cfg[name] = self._by_name[name].default
-        if cfg.get("kernels", "auto") != "megakernel":
-            # the megakernel geometry is dead weight on every other
-            # dispatch rung — two configs that cannot behave differently
-            # must share one fingerprint
-            for name in ("mk_ffn_tile", "mk_prefetch_depth", "mk_dequant"):
                 if name in self._by_name:
                     cfg[name] = self._by_name[name].default
         return cfg
@@ -418,18 +327,11 @@ class ConfigSpace:
 
 def engine_space(max_len: Optional[int] = None,
                  pins: Optional[Dict[str, Any]] = None,
-                 devices: Optional[int] = None,
-                 model_cfg=None, max_batch: int = 8,
-                 vmem_limit_bytes: int = MK_VMEM_LIMIT_BYTES
-                 ) -> ConfigSpace:
+                 devices: Optional[int] = None) -> ConfigSpace:
     """The single-engine search space: full knob surface declared, fleet
     tier pinned to its defaults (fleet_replicas=1 collapses the routing
     knobs too). ``devices`` bounds the cp axis to meshes the host can
-    build; ``model_cfg``/``max_batch``/``vmem_limit_bytes`` arm the
-    kernel tier's VMEM-validity arithmetic (see :class:`ConfigSpace`).
-    This is what ``tools/autotune.py`` searches."""
+    build. This is what ``tools/autotune.py`` searches."""
     p = {k.name: k.default for k in FLEET_KNOBS}
     p.update(pins or {})
-    return ConfigSpace(ALL_KNOBS, pins=p, max_len=max_len, devices=devices,
-                       model_cfg=model_cfg, max_batch=max_batch,
-                       vmem_limit_bytes=vmem_limit_bytes)
+    return ConfigSpace(ALL_KNOBS, pins=p, max_len=max_len, devices=devices)

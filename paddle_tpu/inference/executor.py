@@ -125,50 +125,19 @@ class PagedExecutor:
                     lambda flat: sm.place_lora_flat(lp.targets, flat,
                                                     self.mesh))
 
-        # megakernel (kernels="megakernel"): the structural/shape guard
-        # runs EAGERLY here — every deciding shape is static at
-        # construction, so the megakernel→pallas rung of the dispatch
-        # ladder resolves once, not per trace. On rejection the reason is
-        # recorded and the per-layer programs compile exactly as before.
-        self.megakernel = False
-        self.megakernel_reason: Optional[str] = None
-        self._mk_geometry = None
-        self._mk_weights = None
         # per-layer kernel geometry, resolved by the engine ctor from
         # the installed winner cache (autotune/kernel_geometry.py) —
-        # recorded here like _mk_geometry so the executor's compiled
-        # programs are attributable to the schedules they traced under
+        # recorded here so the executor's compiled programs are
+        # attributable to the schedules they traced under
         self.kernel_geometry = dict(getattr(engine, "kernel_geometry",
                                             None) or {})
-        from .. import ops
-
-        if ops.use_megakernel():
-            from ..ops import decode_megakernel as mk
-
-            geom = engine.mk_geometry or mk.MegakernelGeometry()
-            reason = mk.megakernel_supported(
-                engine.model, cfg, tp=self.tp, cp=self.cp, block_size=bs,
-                geometry=geom, lora=engine._lora is not None)
-            if reason is None:
-                self.megakernel = True
-                self._mk_geometry = geom
-                # one-time (L, in, out) stacks become closure constants
-                # of the jitted decode programs (XLA parameters, not
-                # baked into the executable). This DOUBLES the served
-                # model's weight HBM — the per-layer params stay alive
-                # for prefill — the megakernel's documented tradeoff.
-                self._mk_weights = mk.stack_layer_weights(engine.model)
-            else:
-                self.megakernel_reason = reason
 
         # ``greedy`` (the trailing static arg) specializes the program
         # for all-temp-0 ticks: XLA folds the whole sampling pipeline
         # (top-k/top-p filtering = per-row sorts over the vocab) down
         # to one argmax — measured ~2.3ms/window at CPU bench shapes.
         # At most two variants ever compile (greedy / mixed).
-        decode_body = (self._decode_megakernel_fn if self.megakernel
-                       else self._decode_paged_fn)
-        self.decode_paged = self._jit(decode_body,
+        self.decode_paged = self._jit(self._decode_paged_fn,
                                       donate_argnums=(2, 14),
                                       static_argnums=(12, 13))
         # (``prev``, argument 15, is NOT donated: the host still has to
@@ -179,16 +148,11 @@ class PagedExecutor:
         self.spec_verify = None
         if engine.spec is not None:
             if engine._spec_fused:
-                scan_body = (self._spec_scan_megakernel_fn
-                             if self.megakernel else self._spec_scan_fn)
-                self.spec_scan = self._jit(scan_body,
+                self.spec_scan = self._jit(self._spec_scan_fn,
                                            donate_argnums=(2,),
                                            static_argnums=(13, 14))
             else:
-                verify_body = (self._spec_verify_megakernel_fn
-                               if self.megakernel
-                               else self._spec_verify_fn)
-                self.spec_verify = self._jit(verify_body,
+                self.spec_verify = self._jit(self._spec_verify_fn,
                                              donate_argnums=(3,),
                                              static_argnums=(14,))
 
@@ -535,143 +499,6 @@ class PagedExecutor:
         # pure memcpy); straight-line code lets XLA alias the pool
         # buffers through all S windows for free. S is small and static,
         # so program size stays modest and the jit cache sees one shape.
-        carry = (ctx, flat_pools, pos)
-        outs, accs = [], []
-        for w in range(S):
-            carry, (out, acc) = one_window(carry, w)
-            outs.append(out)
-            accs.append(acc)
-        _, flat, _ = carry
-        return jnp.stack(outs), jnp.stack(accs), flat
-
-    # ------------------------------------------------- megakernel programs
-    def _mk_lora(self, lora_flat, aidx):
-        """Gathered per-layer factor dicts → the per-target (L, B, ·, ·)
-        stacks the megakernel streams (None when LoRA is off)."""
-        if not lora_flat:
-            return None
-        from ..ops import decode_megakernel as mk
-
-        return mk.stack_lora(self._gather_lora(lora_flat, aidx))
-
-    def _mk_window(self, params, window, flat_pools, tables, pos, lstk):
-        """One W-token tick through the whole-tick megakernel: embed →
-        ``decode_tick`` (all layers as ONE Pallas program, pools aliased
-        in place) → final norm → head. Returns (fp32 logits (B, W, V),
-        new flat pool list). Selection happened eagerly in the
-        constructor (``megakernel_supported``); a kernel failure here
-        raises."""
-        from ..ops import decode_megakernel as mk
-
-        engine = self.engine
-        model = engine.model
-        m = model.model
-        W = window.shape[1]
-
-        def call():
-            x = m.embed_tokens(Tensor(window))
-            cosr, sinr = mk.gather_rope_rows(m._cos, m._sin, pos, W)
-            xo, new = mk.decode_tick(
-                x.value, list(flat_pools), tables, pos, self._mk_weights,
-                cosr, sinr, block_size=engine.block_size,
-                geometry=self._mk_geometry, eps=engine.cfg.rms_norm_eps,
-                lora=lstk)
-            return engine._head(m.norm(Tensor(xo))), new
-
-        logits, new = functional_call(model, params, call_fn=call)
-        return logits.value.astype(jnp.float32), list(new)
-
-    def _decode_megakernel_fn(self, params, tokens, flat_pools, tables,
-                              pos, temps, topks, topps, active, key,
-                              aidx=None, lora_flat=(), greedy=False,
-                              ticks=None, slot_pools=(), prev=None):
-        """The whole-tick twin of :meth:`_decode_paged_fn` — identical
-        signature, sampling pipeline, and trip structure; only the
-        per-tick model call collapses into the ONE persistent Pallas
-        program."""
-        engine = self.engine
-        lstk = self._mk_lora(lora_flat, aidx)
-        tokens, active = self._feed(tokens, prev, active)
-
-        def one_tick(carry, k):
-            toks, flat_p, p = carry
-            lg, flat = self._mk_window(params, toks[:, None], flat_p,
-                                       tables, p, lstk)
-            lg = lg[:, 0]                                 # (B, V)
-            if greedy:
-                nxt = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-            else:
-                from ..models.generation import sample_token_rows
-
-                nxt = sample_token_rows(lg, jax.random.fold_in(key, k),
-                                        temps, topks, topps)
-            return (nxt, flat, p + active), nxt
-
-        n = engine.tick_window if ticks is None else ticks
-        if n == 1:
-            (_, flat, _), stack = one_tick((tokens, flat_pools, pos), 0)
-            return stack[None], flat, list(slot_pools)
-        (_, flat, _), stack = jax.lax.scan(
-            one_tick, (tokens, flat_pools, pos), jnp.arange(n))
-        return stack, flat, list(slot_pools)
-
-    def _spec_verify_megakernel_fn(self, params, tokens, proposals,
-                                   flat_pools, tables, pos, temps, topks,
-                                   topps, kcaps, key, qprobs, aidx=None,
-                                   lora_flat=(), greedy=False):
-        """Whole-tick twin of :meth:`_spec_verify_fn`: the W = k+1 verify
-        window is the megakernel's natural shape — one persistent program
-        scores the whole window, then the exact accept/reject runs
-        unchanged."""
-        lstk = self._mk_lora(lora_flat, aidx)
-        window = jnp.concatenate([tokens[:, None], proposals], axis=1)
-        lg, flat = self._mk_window(params, window, flat_pools, tables,
-                                   pos, lstk)
-        from .speculative import speculative_accept
-
-        out, acc = speculative_accept(lg, proposals, temps, topks,
-                                      topps, kcaps, key, qprobs,
-                                      greedy=greedy)
-        return out, acc, flat
-
-    def _spec_scan_megakernel_fn(self, params, ctx, flat_pools, tables,
-                                 pos, temps, topks, topps, kcaps, active,
-                                 key, aidx=None, lora_flat=(),
-                                 greedy=False, windows=None):
-        """Whole-tick twin of :meth:`_spec_scan_fn` — same unrolled
-        window loop, drafter, accept/reject, and context update; each
-        window's target scoring is the ONE persistent program."""
-        engine = self.engine
-        model_k = engine.spec_k
-        W = model_k + 1
-        B, L = ctx.shape
-        S = engine._spec_windows if windows is None else windows
-        rows = jnp.arange(B)
-        lstk = self._mk_lora(lora_flat, aidx)
-        from .speculative import speculative_accept
-
-        def one_window(carry, w):
-            c, flat_p, p = carry
-            cur = jnp.take_along_axis(c, p[:, None], axis=1)   # (B, 1)
-            proposals = engine.drafter.propose_device(c, p, model_k)
-            window = jnp.concatenate([cur, proposals], axis=1)
-            lg, flat = self._mk_window(params, window, flat_p, tables,
-                                       p, lstk)
-            out, acc = speculative_accept(
-                lg, proposals, temps, topks, topps, kcaps,
-                jax.random.fold_in(key, w), None, greedy=greedy)
-            # context/position update — verbatim from _spec_scan_fn
-            # (including the L-1 clamp rationale documented there)
-            widx = jnp.minimum(p[:, None] + 1
-                               + jnp.arange(W)[None, :], L - 1)
-            keep = ((jnp.arange(W)[None, :] <= acc[:, None])
-                    & (active > 0)[:, None])
-            vals = jnp.where(keep, out,
-                             jnp.take_along_axis(c, widx, axis=1))
-            c = c.at[rows[:, None], widx].set(vals)
-            p = jnp.minimum(p + (acc + 1) * active, L - 1)
-            return (c, flat, p), (out, acc)
-
         carry = (ctx, flat_pools, pos)
         outs, accs = [], []
         for w in range(S):

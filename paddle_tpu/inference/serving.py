@@ -118,7 +118,6 @@ class GenerationServer:
                  tier_demote_high: Optional[float] = None,
                  lora=None, telemetry=None, faults=None,
                  fault_retries: int = 3, kernels: str = "auto",
-                 mk_geometry=None,
                  mesh=None, role: str = "any", profile=None,
                  clock=None):
         """``tick_window``: decode ticks per host round trip. 1 = exact
@@ -239,12 +238,7 @@ class GenerationServer:
         ``kernels``: attention/projection kernel dispatch for the compiled
         serving programs — ``"auto"`` (default) picks the Pallas kernels on
         a TPU backend and the jnp reference elsewhere, ``"pallas"`` forces
-        the kernels (interpret mode off-TPU — CPU parity testing),
-        ``"megakernel"`` requests the whole-tick persistent kernel
-        (ops/decode_megakernel.py): the full decode / spec-verify tick —
-        all layers — as ONE Pallas program, degrading to the per-layer
-        kernels when the executor's structural/shape guard rejects the
-        model (``PagedExecutor.megakernel_reason`` records why), and
+        the kernels (interpret mode off-TPU — CPU parity testing), and
         ``"reference"`` pins the jnp compositions. Process-wide
         (``ops.set_kernel_mode``) and read at trace time, so it must agree
         across servers compiling in one process; ``"auto"`` leaves the
@@ -252,13 +246,6 @@ class GenerationServer:
         restore refuses a snapshot taken under a different mode (greedy
         tokens are kernel-identical, but sampling paths need not be
         bit-equal across kernels).
-
-        ``mk_geometry``: a :class:`~..ops.decode_megakernel
-        .MegakernelGeometry` overriding the whole-tick kernel's schedule
-        (FFN tile width, weight-prefetch depth, int8 dequant placement).
-        Only meaningful — and only accepted — with
-        ``kernels="megakernel"``; part of the snapshot fingerprint. The
-        autotuner searches it (autotune/space.py kernel tier).
 
         ``profile``: a tuned profile from the autotuner
         (``paddle_tpu/autotune/``) — a path to the profile JSON, a
@@ -323,9 +310,7 @@ class GenerationServer:
                               spec is not None),
                              ("lora=", lora is not None),
                              ("kv_quant='int8'", kv_quant != "none"),
-                             ("mesh=", mesh not in (None, 1)),
-                             ("kernels='megakernel'",
-                              kernels == "megakernel")):
+                             ("mesh=", mesh not in (None, 1))):
                 if on:
                     raise CacheSpecError(
                         f"{type(model).__name__} keeps per-slot state "
@@ -390,17 +375,6 @@ class GenerationServer:
         if kernels not in KERNEL_MODES:
             raise ValueError(
                 f"kernels must be one of {KERNEL_MODES}, got {kernels!r}")
-        if kernels == "megakernel" and cache != "paged":
-            raise ValueError("kernels='megakernel' requires cache='paged' "
-                             "(the whole-tick kernel serves the paged "
-                             "decode path)")
-        if mk_geometry is not None:
-            if kernels != "megakernel":
-                raise ValueError("mk_geometry= requires "
-                                 "kernels='megakernel' (the geometry only "
-                                 "parameterizes the whole-tick kernel)")
-            mk_geometry.validate()
-        self.mk_geometry = mk_geometry
         if kernels != "auto":
             set_kernel_mode(kernels)
         self.kernels = kernels
@@ -2519,8 +2493,6 @@ class GenerationServer:
                 "spec_k": self.spec_k if self.spec is not None else None,
                 "lora": self._lora is not None,
                 "kernels": self.kernels,
-                "mk_geometry": (self.mk_geometry.asdict()
-                                if self.mk_geometry is not None else None),
                 # resolved per-layer kernel geometry (non-default ops
                 # only; None when everything runs the default schedule,
                 # which keeps pre-geometry snapshots restorable)

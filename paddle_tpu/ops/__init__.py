@@ -8,21 +8,19 @@ kernel mode, GSPMD partitioning and static shapes; a selected kernel that
 fails to lower or compile is an error that reaches the caller. The mode
 helpers are re-exported here:
 
-* ``use_megakernel()`` — the whole-tick decode megakernel
-  (ops/decode_megakernel.py) was requested via ``set_kernel_mode``.
 * ``use_pallas()`` — a Pallas code path may run: on a TPU, under
   ``PT_FLASH_INTERPRET=1`` (interpret mode on CPU), or when the mode was
-  pinned to ``"pallas"``/``"megakernel"``. ``"reference"`` pins the jnp
-  compositions regardless of platform.
+  pinned to ``"pallas"``. ``"reference"`` pins the jnp compositions
+  regardless of platform.
 * ``pallas_interpret()`` — ``pl.pallas_call`` must run interpreted (Pallas
   requested on a platform without the Mosaic compiler).
 
-All are read at TRACE time, so flipping the mode between compiled program
+Both are read at TRACE time, so flipping the mode between compiled program
 invocations has no effect — set it before the first trace
 (GenerationServer does this in its constructor via ``kernels=``).
 """
 from .select import (KERNEL_MODES, kernel_mode, pallas_interpret,
-                     set_kernel_mode, use_megakernel, use_pallas)
+                     set_kernel_mode, use_pallas)
 
 from .flash_attention import flash_attention, flash_attention_bshd
 from .fused_norm import fused_rms_norm, fused_layer_norm
@@ -40,7 +38,6 @@ __all__ = ["flash_attention", "flash_attention_bshd", "fused_rms_norm",
            "kernel_mode", "paged_decode_attention",
            "paged_decode_attention_q", "paged_prefill_attention",
            "paged_prefill_attention_q", "pallas_interpret",
-           "quantize_block_kv", "set_kernel_mode", "use_megakernel",
-           "use_pallas",
+           "quantize_block_kv", "set_kernel_mode", "use_pallas",
            "write_chunk_kv", "write_chunk_kv_q", "write_decode_kv",
            "write_decode_kv_q"]

@@ -35,7 +35,7 @@ PALLAS = "pallas"
 INTERPRET = "pallas-interpret"
 XLA = "xla"
 
-KERNEL_MODES = ("auto", "pallas", "megakernel", "reference")
+KERNEL_MODES = ("auto", "pallas", "reference")
 
 _KERNEL_MODE = "auto"
 _TARGET_PLATFORM: Optional[str] = None
@@ -49,10 +49,9 @@ _VMEM_BLOCK_BUDGET = 8 * 1024 * 1024
 
 # ------------------------------------------------------------------- mode
 def set_kernel_mode(mode: str) -> None:
-    """Pin the process-wide kernel dispatch: ``"megakernel"`` requests the
-    whole-tick persistent kernel, ``"pallas"`` forces the per-layer Pallas
-    kernels (interpret mode off-TPU), ``"reference"`` forces the jnp
-    compositions, ``"auto"`` restores platform-based selection."""
+    """Pin the process-wide kernel dispatch: ``"pallas"`` forces the
+    Pallas kernels (interpret mode off-TPU), ``"reference"`` forces the
+    jnp compositions, ``"auto"`` restores platform-based selection."""
     global _KERNEL_MODE
     if mode not in KERNEL_MODES:
         raise ValueError(
@@ -62,12 +61,6 @@ def set_kernel_mode(mode: str) -> None:
 
 def kernel_mode() -> str:
     return _KERNEL_MODE
-
-
-def use_megakernel() -> bool:
-    """Only an explicit ``kernels="megakernel"`` opts in (never ``"auto"``
-    — the tick-level fusion changes program structure)."""
-    return _KERNEL_MODE == "megakernel"
 
 
 # --------------------------------------------------------------- platform
@@ -98,7 +91,7 @@ def pallas_backend(platform: Optional[str] = None) -> Optional[str]:
         return None
     if platform == "tpu":
         return PALLAS
-    if (_KERNEL_MODE in ("pallas", "megakernel")
+    if (_KERNEL_MODE == "pallas"
             or os.environ.get("PT_FLASH_INTERPRET") == "1"):
         return INTERPRET
     return None

@@ -43,6 +43,21 @@ class TestConfigSpace:
         assert cfg == space.canonicalize(cfg)
         assert set(cfg) == {k.name for k in ALL_KNOBS}
 
+    def test_kernel_tier_is_the_dispatch_mode_alone(self):
+        """18 knobs, none of a whole-tick kernel's geometry (PR 31), and
+        the ``kernels`` knob offers what ``ops.set_kernel_mode`` takes."""
+        from paddle_tpu.ops import KERNEL_MODES
+
+        for space in (ConfigSpace(ALL_KNOBS), engine_space(max_len=256)):
+            assert len(space.knobs) == 18
+            assert not [k.name for k in space.knobs
+                        if k.name.startswith("mk_")]
+            assert space.knob("kernels").choices == KERNEL_MODES \
+                == ("auto", "pallas", "reference")
+            rng = np.random.RandomState(31)
+            for _ in range(200):
+                space.validate(space.sample(rng))
+
     def test_sample_deterministic_per_seed(self):
         space = engine_space(max_len=256)
         rng1, rng2 = np.random.RandomState(7), np.random.RandomState(7)
@@ -408,6 +423,22 @@ class TestTunedProfile:
         d2["schema"] = 99
         with pytest.raises(ValueError, match="schema"):
             TunedProfile.from_dict(d2)
+
+    def test_schema3_profile_naming_the_removed_kernel_is_refused(self):
+        """A profile an older tree tuned onto the whole-tick kernel (a
+        fourth ``kernels`` value plus its three ``mk_*`` knobs) is refused
+        at load by the schema check — retuned, never applied in part."""
+        space = ConfigSpace(ALL_KNOBS)
+        d = _profile_for(space, space.default(),
+                         WorkloadSpec(requests=4, max_new=8)).to_dict()
+        assert d["schema"] == 4
+        d["schema"] = 3
+        d["config"].update({"kernels": "mega" "kernel",
+                            **{f"mk_{k}": v for k, v in (
+                                ("ffn_tile", 512), ("prefetch_depth", 2),
+                                ("dequant", "scores"))}})
+        with pytest.raises(ValueError, match="schema 3 != 4 — retune"):
+            TunedProfile.from_dict(d)
 
     def test_resolve_profile_accepts_all_forms(self, tmp_path):
         space = ConfigSpace(ALL_KNOBS)

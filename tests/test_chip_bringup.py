@@ -281,18 +281,6 @@ class TestSelectedKernelFailureReachesTheCaller:
         with pytest.raises(_Boom):
             fa.flat_adamw_update(p, p, p, p, **hp)
 
-    def test_megakernel_twins_do_not_fall_back(self):
-        # once the executor selected the megakernel, its programs no longer
-        # catch NotImplementedError and re-dispatch to the per-layer path
-        import inspect
-
-        from paddle_tpu.inference.executor import PagedExecutor
-
-        for name in ("_decode_megakernel_fn", "_spec_verify_megakernel_fn",
-                     "_spec_scan_megakernel_fn"):
-            assert "except" not in inspect.getsource(
-                getattr(PagedExecutor, name)), name
-
     def test_device_platform_does_not_answer_cpu_for_a_dead_backend(
             self, monkeypatch):
         from paddle_tpu import device
@@ -321,6 +309,55 @@ def test_selected_kernels_compile_for_the_v5e_target():
     assert out.returncode == 0, tail
     assert "all cases compile" in out.stdout, tail
     assert "device_kind='TPU v5 lite'" in out.stdout, tail
+
+
+def test_compile_check_exempts_no_case():
+    """Every case ``tools/compile_check.py`` lists must compile: a case that
+    raises fails the run whatever its name — there is no list of refusals
+    the tool forgives (the one it had left with the kernel it was kept for,
+    PR 31). In a child: importing the tool re-pins the platform."""
+    code = (
+        "import json, compile_check as cc\n"
+        "def boom(): raise RuntimeError('refused')\n"
+        "names = [n for n, _ in cc.kernel_cases() + cc.program_cases(None)]\n"
+        "failed = cc.run_cases([(n, boom) for n in names], None)\n"
+        "print(json.dumps({'names': names, 'failed': failed,\n"
+        "                  'exempt': hasattr(cc, 'KNOWN_' 'REFUSALS')}))\n")
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=300, env=_clean_env(
+            PYTHONPATH=os.path.join(REPO, "tools") + os.pathsep + REPO))
+    assert out.returncode == 0, out.stderr[-2000:]
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    assert len(got["names"]) > 20 and got["failed"] == got["names"]
+    assert not got["exempt"]
+
+
+@pytest.mark.parametrize("mode", ["auto", "pallas", "reference"])
+def test_each_serving_program_has_its_one_body(mode):
+    """No kernel mode swaps a program's body: the executor jits
+    ``_decode_paged_fn`` and ``_chunk_prefill_fn`` themselves, and holds one
+    ``*_fn`` body per program and no twin."""
+    import paddle_tpu as paddle
+    from paddle_tpu.inference.executor import PagedExecutor
+    from paddle_tpu.inference.serving import GenerationServer
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+
+    paddle.seed(0)
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64,
+        num_hidden_layers=1, num_attention_heads=2, num_key_value_heads=1,
+        max_position_embeddings=64, dtype="float32",
+        use_flash_attention=False))
+    ex = GenerationServer(model, max_len=32, cache="paged", block_size=4,
+                          kernels=mode)._exec
+    assert ex.decode_paged.__wrapped__.__func__ \
+        is PagedExecutor._decode_paged_fn
+    assert ex.chunk_prefill.__wrapped__.__func__ \
+        is PagedExecutor._chunk_prefill_fn
+    assert sorted(n for n in vars(PagedExecutor) if n.endswith("_fn")) == [
+        "_chunk_prefill_fn", "_decode_paged_fn", "_spec_scan_fn",
+        "_spec_verify_fn"]
 
 
 # ------------------------------------------------------------ peaks, cache

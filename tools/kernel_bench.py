@@ -20,20 +20,6 @@ sharded output against the unsharded reference too (attention has no
 cross-head reduction, so sharding must not move the result). On CPU
 (JAX_PLATFORMS=cpu) the tool forces N XLA host devices for the dryrun.
 
-``--ops tick`` adds the WHOLE-TICK row: a full decode trip (embed +
-every layer's attention + FFN) through three dispatch paths on one tiny
-Llama built from the head geometry — the reference jnp layer loop, the
-per-layer Pallas loop, and the ``ops/decode_megakernel.py`` persistent
-program — fp and int8, with and without LoRA. Each path reports tok/s
-AND ``*_dispatch_us`` (host time to ISSUE the jitted call, before
-blocking — the megakernel's whole premise is collapsing per-layer
-dispatches into one program launch), plus ``hbm_bytes_megakernel`` /
-``hbm_bytes_layered`` per-trip traffic estimates from
-``hbm_bytes_per_trip`` and a token-level parity gate across all three.
-When the eager guard rejects the geometry the row carries
-``megakernel_active: false`` with the reason and still benches the
-other two rungs — the ladder degrading is a result, not an error.
-
 ``--ops ragged`` adds the SERVING-CELL rows (independent of ``--shapes``):
 the decode kernel at the benchmark cells' own shapes — ``--ragged-rows``
 slots (64) of which ``--ragged-live`` hold a request (``64,9``: the decode
@@ -54,8 +40,8 @@ how the group width in ``ops/paged_attention_pallas.py`` was chosen.
 Usage:
     python tools/kernel_bench.py [--json] [--iters 10]
         [--shapes 2,4,8;4,8,16] [--window 4] [--heads 8] [--kv-heads 2]
-        [--head-dim 128] [--layers 2]
-        [--ops decode,verify,prefill,tick,ragged] [--quant fp,int8] [--tp N]
+        [--head-dim 128]
+        [--ops decode,verify,prefill,ragged] [--quant fp,int8] [--tp N]
     python tools/kernel_bench.py --ops ragged [--ragged-depths 0,8,32]
 
 One JSON line per (op, quant, B, M, bs) combo under --json (bench.py
@@ -121,10 +107,7 @@ def main():
     ap.add_argument("--kv-heads", type=int, default=2)
     ap.add_argument("--head-dim", type=int, default=128)
     ap.add_argument("--ops", default="decode,verify,prefill",
-                    help="comma list of decode,verify,prefill,tick "
-                         "(tick = whole-trip megakernel row, opt-in)")
-    ap.add_argument("--layers", type=int, default=2,
-                    help="decoder layers for the whole-tick row")
+                    help="comma list of decode,verify,prefill,ragged")
     ap.add_argument("--quant", default="fp,int8")
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--ragged-rows", type=int, default=64)
@@ -227,7 +210,7 @@ def main():
     def timed(fn, fn_args):
         # fresh lambda: jax's tracing cache is keyed on function identity,
         # so re-jitting `fn` itself after a kernel-mode flip (any rung of
-        # the auto/pallas/megakernel/reference enum) OR a kernel-geometry
+        # the auto/pallas/reference enum) OR a kernel-geometry
         # re-bind (installing a different winner cache is invisible to the
         # cache key, exactly like the mode flag) would silently reuse the
         # other configuration's jaxpr
@@ -239,23 +222,6 @@ def main():
             out = jf(*fn_args)
         out.block_until_ready()
         return (clk() - t0) / args.iters, out
-
-    def timed_tick(fn, fn_args):
-        # like timed(), but also splits out the host-side ISSUE time of
-        # each call (returns before the device finishes) — the dispatch
-        # overhead the megakernel collapses
-        jf = jax.jit(lambda *a: fn(*a))
-        out = jf(*fn_args)
-        out.block_until_ready()
-        disp = 0.0
-        t0 = clk()
-        for _ in range(args.iters):
-            t1 = clk()
-            out = jf(*fn_args)
-            disp += clk() - t1
-            out.block_until_ready()
-        total = clk() - t0
-        return total / args.iters, disp / args.iters, out
 
     # ---------------------------------------------- kernel-geometry tier
     sweep_cache = None
@@ -404,136 +370,6 @@ def main():
             ops.set_kernel_mode(mode)
         return out_rows
 
-    def bench_tick(B, M, bs, quant, lora_on):
-        """Whole decode trip (W=1): embed + all layers, three rungs."""
-        import paddle_tpu as paddle
-        from paddle_tpu.framework.core import Tensor
-        from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
-        from paddle_tpu.ops import decode_megakernel as mk
-
-        H, KV, D, L = args.heads, args.kv_heads, args.head_dim, args.layers
-        cfg = LlamaConfig(
-            vocab_size=128, hidden_size=H * D, intermediate_size=2 * H * D,
-            num_hidden_layers=L, num_attention_heads=H,
-            num_key_value_heads=KV, max_position_embeddings=M * bs + 8,
-            dtype="float32", use_flash_attention=False)
-        paddle.seed(7)
-        model = LlamaForCausalLM(cfg)
-        m = model.model
-        W = 1
-        rng = np.random.RandomState(args.seed)
-        _, _, tables, pos = make_inputs(rng, jnp, B, M, bs, H, KV, D, W,
-                                        "fp")
-        N = max(B * M + 1, 2)
-        st = 4 if quant == "int8" else 2
-        flat = []
-        for _ in range(L):
-            for kvp in range(2):
-                p = rng.randn(N, bs, KV, D).astype(np.float32) * 0.5
-                p[0] = 0.0
-                if quant == "int8":
-                    pq, ps = pa.quantize_block_kv(jnp.asarray(p))
-                    flat += [pq, ps]
-                else:
-                    flat.append(jnp.asarray(p))
-        tokens = jnp.asarray(
-            rng.randint(1, cfg.vocab_size, (B, W)).astype(np.int32))
-        lora = None
-        if lora_on:
-            Hd, KVD, I = H * D, KV * D, 2 * H * D
-            dims = {"q": (Hd, Hd), "k": (Hd, KVD), "v": (Hd, KVD),
-                    "o": (Hd, Hd), "gate": (Hd, I), "up": (Hd, I),
-                    "down": (I, Hd)}
-            scale = jnp.asarray(
-                [0.5 if b % 2 == 0 else 0.0 for b in range(B)], jnp.float32)
-            lora = []
-            for _ in range(L):
-                lora.append({t: (
-                    jnp.asarray(rng.normal(0, 0.05, (B, fi, 4)),
-                                jnp.float32),
-                    jnp.asarray(rng.normal(0, 0.05, (B, 4, fo)),
-                                jnp.float32),
-                    scale) for t, (fi, fo) in dims.items()})
-
-        def layered(tok, tbl, ps, *fl):
-            x = m.embed_tokens(Tensor(tok))
-            for i, layer in enumerate(m.layers):
-                pool = tuple(Tensor(fl[st * i + j]) for j in range(st))
-                x, _ = layer.paged_verify(
-                    x, m._cos, m._sin, pool, tbl, ps,
-                    lora=None if lora is None else lora[i])
-            return x.value
-
-        stk_w = mk.stack_layer_weights(model)
-        stk_l = mk.stack_lora(lora)
-
-        def megakernel(tok, tbl, ps, *fl):
-            x = m.embed_tokens(Tensor(tok)).value
-            cosr, sinr = mk.gather_rope_rows(m._cos, m._sin, ps, W)
-            xo, _ = mk.decode_tick(x, list(fl), tbl, ps, stk_w, cosr,
-                                   sinr, block_size=bs,
-                                   eps=cfg.rms_norm_eps, lora=stk_l)
-            return xo
-
-        fn_args = (tokens, tables, pos, *flat)
-        mode = ops.kernel_mode()
-        mk_s = mk_disp = mk_out = None
-        try:
-            ops.set_kernel_mode("reference")
-            ref_s, ref_disp, ref_out = timed_tick(layered, fn_args)
-            ops.set_kernel_mode("pallas")
-            pal_s, pal_disp, pal_out = timed_tick(layered, fn_args)
-            # guard under megakernel mode — interpret-vs-Mosaic shape
-            # rules depend on the active mode, exactly as at executor
-            # construction
-            ops.set_kernel_mode("megakernel")
-            reason = mk.megakernel_supported(model, cfg, block_size=bs,
-                                             lora=lora_on)
-            if reason is None:
-                mk_s, mk_disp, mk_out = timed_tick(megakernel, fn_args)
-        finally:
-            ops.set_kernel_mode(mode)
-        tok = B * W
-        ref32 = ref_out.astype(jnp.float32)
-        diff = float(jnp.max(jnp.abs(ref32 - pal_out.astype(jnp.float32))))
-        acb = float(np.mean((np.asarray(pos) + W - 1) // bs + 1))
-        kvq = "int8" if quant == "int8" else "none"
-        row = {
-            "metric": "whole_tick_tok_s",
-            "op": "tick", "quant": quant, "lora": lora_on,
-            "B": B, "M": M, "bs": bs, "W": W, "layers": L,
-            "heads": H, "kv_heads": KV, "head_dim": D,
-            "backend": backend,
-            "pallas_mode": "mosaic" if on_tpu else "interpret",
-            "ref_tok_s": round(tok / ref_s, 1),
-            "pallas_tok_s": round(tok / pal_s, 1),
-            "speedup": round(ref_s / pal_s, 3),
-            "max_abs_diff": diff,
-            "ref_dispatch_us": round(ref_disp * 1e6, 1),
-            "pallas_dispatch_us": round(pal_disp * 1e6, 1),
-            "megakernel_active": reason is None,
-            "hbm_bytes_megakernel": mk.hbm_bytes_per_trip(
-                cfg, batch=B, window=W, block_size=bs, avg_ctx_blocks=acb,
-                kv_quant=kvq, megakernel=True),
-            "hbm_bytes_layered": mk.hbm_bytes_per_trip(
-                cfg, batch=B, window=W, block_size=bs, avg_ctx_blocks=acb,
-                kv_quant=kvq, megakernel=False),
-        }
-        if reason is None:
-            mk_diff = float(jnp.max(jnp.abs(
-                ref32 - mk_out.astype(jnp.float32))))
-            diff = max(diff, mk_diff)
-            row.update({
-                "megakernel_tok_s": round(tok / mk_s, 1),
-                "tick_dispatch_us": round(mk_disp * 1e6, 1),
-                "mk_speedup": round(ref_s / mk_s, 3),
-                "mk_max_abs_diff": mk_diff,
-            })
-        else:
-            row["megakernel_reason"] = reason
-        row["parity"] = diff < 2e-4
-        return row
-
     def ragged_rows():
         """The decode kernel and the chunk at the serving cells' shapes:
         us a call and the share of the roofline (module docstring)."""
@@ -638,11 +474,6 @@ def main():
         for quant in args.quant.split(","):
             rng = np.random.RandomState(args.seed)
             for op in op_list:
-                if op == "tick":
-                    for lora_on in (False, True):
-                        rows.append(
-                            bench_tick(B, M, bs, quant, lora_on))
-                    continue
                 W = {"decode": 1, "verify": args.window,
                      "prefill": 2 * bs}[op]
                 if op == "prefill":

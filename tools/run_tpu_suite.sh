@@ -433,59 +433,6 @@ assert td["tier_hit_rate"]["cold"] > 0, \
 assert td["tier_promotions"] > 0, "warm hits never promoted back to HBM"
 PY
 
-echo "== 7j. whole-tick megakernel gate (tick parity + one-program serving token-equal to reference at zero recompiles) =="
-# interpret-mode parity first (same rationale as 7g): the whole-tick
-# program vs the model's own per-layer loop, on the host interpreter
-JAX_PLATFORMS=cpu python -m pytest tests/test_megakernel.py -q \
-  || { echo "megakernel parity suite FAILED (whole-tick program diverged"\
-       "from the per-layer loop in interpret mode)"; exit 1; }
-python tools/kernel_bench.py --ops tick --shapes 2,4,8 --iters 3 --json \
-  | tee /tmp/tpu_runs/kernel_bench_tick.json \
-  || { echo "whole-tick bench FAILED (tick parity above tolerance)"; exit 1; }
-JAX_PLATFORMS=cpu python tools/serving_benchmark.py --paged --requests 12 \
-  --slots 4 --max-new 24 --kernels reference --guard-recompiles --json \
-  2>/dev/null | tee /tmp/tpu_runs/serving_mk_ref.json \
-  || { echo "reference twin for the megakernel gate FAILED"; exit 1; }
-JAX_PLATFORMS=cpu python tools/serving_benchmark.py --paged --requests 12 \
-  --slots 4 --max-new 24 --kernels megakernel --guard-recompiles --json \
-  2>/dev/null | tee /tmp/tpu_runs/serving_mk.json \
-  || { echo "megakernel serving gate FAILED (recompile budget tripped or"\
-       "the whole-tick path crashed)"; exit 1; }
-python - <<'PY'
-# megakernel gate: every tick-bench combo must hold parity with the
-# per-layer reference AND actually engage the megakernel (a ladder that
-# silently fell to pallas would make the row vacuous — off-TPU the tiny
-# CI geometry is interpret-legal, on-TPU the default head geometry is
-# Mosaic-aligned); the serving line must be TOKEN-IDENTICAL to its
-# reference twin (same seed, same traffic) with the rung recorded; on
-# real hardware the one-program trip must also beat the jnp reference
-# end-to-end — in interpret mode the speed clause is skipped (same
-# rationale as 7g)
-import json
-rows = [json.loads(l) for l in open("/tmp/tpu_runs/kernel_bench_tick.json")]
-ref = json.load(open("/tmp/tpu_runs/serving_mk_ref.json"))
-srv = json.load(open("/tmp/tpu_runs/serving_mk.json"))
-on_tpu = rows[0]["backend"] == "tpu"
-assert rows and all(r["parity"] for r in rows), "tick parity FAILED"
-assert all(r["megakernel_active"] for r in rows), \
-    "megakernel never engaged in the tick bench — gate vacuous"
-assert ref.get("kernels") == "reference"
-assert srv.get("kernels") == "megakernel" and srv.get("megakernel_active"), \
-    srv.get("megakernel_reason")
-assert srv["tokens_fingerprint"] == ref["tokens_fingerprint"], \
-    "megakernel serving diverged from reference tokens"
-print(f"{len(rows)} tick combos parity-clean ({rows[0]['pallas_mode']} "
-      f"mode), dispatch {rows[0].get('tick_dispatch_us')}us/trip vs "
-      f"layered {rows[0]['ref_dispatch_us']}us; serving "
-      f"{srv['megakernel_tok_s']} tok/s whole-tick vs per-op "
-      f"{srv['kernel_tok_s']}, tokens fingerprint-equal to reference")
-if on_tpu:
-    slow = [r for r in rows if r.get("mk_speedup", 0) < 1.0]
-    assert not slow, f"megakernel slower than jnp reference on TPU: {slow}"
-    assert srv["megakernel_tok_s"] >= srv["kernel_ref_tok_s"], \
-        "whole-tick program lost to the gather reference on TPU"
-PY
-
 echo "== 7k. kernel-geometry gate (per-op schedule sweep: bit-exact candidates, deterministic winners, swept serving token-equal to default) =="
 # interpret-mode parity first (same rationale as 7g): every supported
 # geometry must be BIT-exact vs the default schedule, fp and int8

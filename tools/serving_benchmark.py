@@ -151,10 +151,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 #: Every v3 key is still present with its v3 meaning at cp=1.
 #: 5 = ``kernels`` is stamped on EVERY line (v4 only stamped it on paged
 #: microbench lines — readers keying dispatch mode off its presence must
-#: read its value instead); ``--kernels megakernel`` joins the enum and
-#: paged microbench lines gain ``megakernel_active`` (the eager guard's
-#: verdict) plus ``megakernel_tok_s`` / ``megakernel_dispatch_us`` (the
-#: whole-tick program at server shapes) when the rung engaged.
+#: read its value instead). (The fourth ``--kernels`` choice v5 added, and
+#: the keys only it emitted, left with that kernel: no line of a remaining
+#: choice changed, so no bump.)
 #: 6 = fleet scale: ``--fleet`` lines now carry ``slo`` (per-tenant
 #: TTFT/TPOT attainment + burn rate from the router's roll-up) on every
 #: line, not only under --strict; the new ``--sim`` mode emits a
@@ -232,57 +231,9 @@ def kernel_microbench(server, cfg, args, iters: int = 10):
         ref_s = timed(lambda *a: op(*a))
     finally:
         ops.set_kernel_mode(mode)
-    out = {"kernel_tok_s": round(B / active_s, 1),
-           "kernel_ref_tok_s": round(B / ref_s, 1),
-           "kernel_dispatch_us": round(active_s * 1e6, 1)}
-    ex = getattr(server, "_exec", None)
-    if args.kernels == "megakernel":
-        out["megakernel_active"] = bool(getattr(ex, "megakernel", False))
-        if not out["megakernel_active"]:
-            out["megakernel_reason"] = getattr(
-                ex, "megakernel_reason", None)
-    if out.get("megakernel_active"):
-        # the whole-tick persistent program at the same server shapes —
-        # ``kernel_tok_s`` above times ONE layer's attention op, this
-        # times embed-to-last-layer in a single dispatch
-        from paddle_tpu.ops import decode_megakernel as mkk
-
-        L = cfg.num_hidden_layers
-        flat = []
-        for _ in range(L):
-            for _kv in range(2):
-                if args.kv_quant == "int8":
-                    flat.append(jnp.asarray(
-                        rng.randint(-127, 128, (N, bs, KV, D)), jnp.int8))
-                    flat.append(jnp.asarray(
-                        np.abs(rng.randn(N, KV)).astype(np.float32) + 1e-3))
-                else:
-                    flat.append(jnp.asarray(rng.randn(N, bs, KV, D), dt))
-        xa = jnp.asarray(rng.randn(B, 1, cfg.hidden_size), dt)
-        m = server.model.model
-        cosr, sinr = mkk.gather_rope_rows(m._cos, m._sin, pos, 1)
-        w, geom = ex._mk_weights, ex._mk_geometry
-
-        def tick_fn(xx, *fl):
-            xo, _ = mkk.decode_tick(
-                xx, list(fl), tables, pos, w, cosr, sinr,
-                block_size=bs, geometry=geom, eps=cfg.rms_norm_eps)
-            return xo
-
-        try:
-            ops.set_kernel_mode("megakernel")
-            jf = jax.jit(lambda *a: tick_fn(*a))
-            jf(xa, *flat).block_until_ready()
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                tick_out = jf(xa, *flat)
-            tick_out.block_until_ready()
-        finally:
-            ops.set_kernel_mode(mode)
-        mk_s = (time.perf_counter() - t0) / iters
-        out["megakernel_tok_s"] = round(B / mk_s, 1)
-        out["megakernel_dispatch_us"] = round(mk_s * 1e6, 1)
-    return out
+    return {"kernel_tok_s": round(B / active_s, 1),
+            "kernel_ref_tok_s": round(B / ref_s, 1),
+            "kernel_dispatch_us": round(active_s * 1e6, 1)}
 
 
 def sim_main(args):
@@ -442,16 +393,13 @@ def main():
                          "adapters (default min(N, slots)); N > M forces "
                          "LRU eviction + re-upload churn")
     ap.add_argument("--kernels",
-                    choices=("auto", "pallas", "megakernel", "reference"),
+                    choices=("auto", "pallas", "reference"),
                     default="auto",
                     help="attention/projection kernel dispatch for the "
                          "compiled serving programs: auto = Pallas on TPU / "
                          "jnp reference elsewhere, pallas = force the "
                          "Pallas kernels (interpret mode off-TPU), "
-                         "megakernel = the whole-tick persistent program "
-                         "(paged only; falls back to pallas when the "
-                         "eager guard rejects the geometry), reference = "
-                         "pin the jnp compositions")
+                         "reference = pin the jnp compositions")
     ap.add_argument("--guard-recompiles", action="store_true",
                     help="wrap the measured drain in jit_cache_guard: any "
                          "steady-state recompile after warmup fails the "
