@@ -142,8 +142,20 @@ class PagedExecutor:
                                       static_argnums=(12, 13))
         # (``prev``, argument 15, is NOT donated: the host still has to
         # read the pending trip's stack)
-        self.chunk_prefill = self._jit(self._chunk_prefill_fn,
-                                       donate_argnums=(2, 8))
+        # a prompt chunk is the server's second program, and there are two
+        # of it: where nothing below stands in the way the chunk rides in
+        # the decode program's own call (:meth:`_decode_chunk_fn`), so that
+        # a tick reads the weights once; everywhere else it runs alone
+        # (:meth:`_chunk_prefill_fn`). A server jits ONE of the two.
+        self.chunk_alone_why = self._why_chunks_run_alone()
+        self.chunk_prefill = self.decode_chunk = None
+        if self.chunk_alone_why is None:
+            self.decode_chunk = self._jit(self._decode_chunk_fn,
+                                          donate_argnums=(2,),
+                                          static_argnums=(15,))
+        else:
+            self.chunk_prefill = self._jit(self._chunk_prefill_fn,
+                                           donate_argnums=(2, 8))
         self.spec_scan = None
         self.spec_verify = None
         if engine.spec is not None:
@@ -155,6 +167,34 @@ class PagedExecutor:
                 self.spec_verify = self._jit(self._spec_verify_fn,
                                              donate_argnums=(3,),
                                              static_argnums=(14,))
+
+    def _why_chunks_run_alone(self) -> Optional[str]:
+        """None where a prompt chunk can ride in the decode trip's program
+        call, else the reason it cannot — what the engine counts its chunks
+        under (``serving_prefill_chunks_alone{reason}``). Decided once,
+        from what the server was built with: per-slot state and a ``cp``
+        mesh shape the chunk program itself (slot operands, the
+        sequence-dim constraint), a speculative server and a
+        ``tick_window`` scan have no one-tick plain trip to ride in,
+        adapter rows would gather C more copies of the chunk's adapter,
+        routed experts see another capacity when rows are joined, and a
+        model class may not offer the joint step."""
+        engine = self.engine
+        if self.spec.has_slot_state:
+            return "slot_state"
+        if self.cp > 1:
+            return "cp"
+        if engine.spec is not None:
+            return "spec"
+        if engine.tick_window != 1:
+            return "tick_window"
+        if engine._lora is not None:
+            return "lora"
+        if getattr(engine.cfg, "moe_num_experts", 0) > 0:
+            return "moe"
+        if not hasattr(engine.model.model, "paged_decode_chunk_step"):
+            return "model"
+        return None
 
     def _jit(self, body, **jit_kw):
         """jit one program body. Under a tp/cp mesh the body traces inside
@@ -400,6 +440,43 @@ class PagedExecutor:
         logits, new = functional_call(model, params, call_fn=call)
         return (logits.value[:, 0].astype(jnp.float32),
                 *self._flat_pools(new))
+
+    def _decode_chunk_fn(self, params, tokens, flat_pools, tables, pos,
+                         temps, topks, topps, active, key, prev, chunk,
+                         table, start, last_idx, greedy=False):
+        """A one-tick decode trip AND one prompt chunk in one program, so
+        that the tick reads every weight once (the chunk alone would
+        stream them all a second time): the operands of
+        :meth:`_decode_paged_fn` (``tokens`` … ``prev``; ``greedy`` STATIC)
+        and of :meth:`_chunk_prefill_fn` (``chunk`` (1, C), the slot's
+        ``table``, ``start``, ``last_idx``), the model's joint step over
+        B + C rows, the head on B + 1. It is THE chunk program of a server
+        that has it — a chunk that meets no decoding row runs it with every
+        row masked (``active`` 0, zeroed ``tables``), as idle rows always
+        run — so a server compiles two programs whatever its traffic.
+        Returns the trip's (1, B) token stack, the chunk's float32 logits
+        row (1, V), the pools."""
+        engine = self.engine
+        model = engine.model
+        tokens, _ = self._feed(tokens, prev, active)
+        ids = jnp.concatenate([tokens[None, :], chunk], axis=1)
+        pools = self._pool_views(flat_pools)
+
+        def call():
+            h, new = model.model.paged_decode_chunk_step(
+                Tensor(ids), pools, tables, pos, table, start, last_idx)
+            return engine._head(h), new
+
+        logits, new = functional_call(model, params, call_fn=call)
+        lg = logits.value[0].astype(jnp.float32)          # (B + 1, V)
+        if greedy:
+            nxt = jnp.argmax(lg[:-1], axis=-1).astype(jnp.int32)
+        else:
+            from ..models.generation import sample_token_rows
+
+            nxt = sample_token_rows(lg[:-1], jax.random.fold_in(key, 0),
+                                    temps, topks, topps)
+        return nxt[None], lg[-1:], self._flat_pools(new)[0]
 
     def _spec_verify_fn(self, params, tokens, proposals, flat_pools, tables,
                         pos, temps, topks, topps, kcaps, key, qprobs,

@@ -90,6 +90,19 @@ class _Trip:
     tick: int                   # flight seq of the tick that dispatched it
 
 
+@dataclass
+class _Chunk:
+    """One prompt chunk whose blocks are reserved and whose tokens are laid
+    out, waiting for the program call that carries it
+    (``GenerationServer._prepare_chunk``)."""
+    slot: int
+    req: _Request
+    ids: np.ndarray             # (1, C) int32, right-padded
+    start: int                  # first position, block-aligned
+    end: int                    # one past its last real token
+    n: int                      # length of the sequence being prefilled
+
+
 class GenerationServer:
     """Continuous-batching decode server for a ``LlamaForCausalLM`` —
     greedy by default, per-request sampling via
@@ -562,6 +575,18 @@ class GenerationServer:
         self._c_pf_ctx = reg.counter(
             "serving_prefill_ctx",
             "positions attended by those tokens under the causal mask")
+        # how often a chunk shares its program call (and so its read of
+        # the weights) with the tick's decode rows; the two partition
+        # ``serving_prefill_chunks``
+        self._c_pf_fused = reg.counter(
+            "serving_prefill_chunks_fused",
+            "prefill chunks that rode in a decode trip's program call "
+            "with at least one decoding row")
+        self._c_pf_alone = reg.counter(
+            "serving_prefill_chunks_alone",
+            "prefill chunks that had a program call to themselves (reason "
+            "label: no_decoding_row, second_chunk, slot_state, cp, spec, "
+            "tick_window, lora, moe, model)")
         # the same, by cache kind, for a spec that has such layers (per
         # ONE layer of the kind: a reader multiplies by the layer count)
         self._c_dec_ctx_win = reg.counter(
@@ -747,7 +772,13 @@ class GenerationServer:
             self._exec = PagedExecutor(self, num_blocks=int(num_blocks),
                                        tp=self._tp, cp=self._cp)
             self._decode_paged = self._exec.decode_paged
+            # a server has ONE of the two chunk programs (executor.py)
             self._chunk_prefill = self._exec.chunk_prefill
+            self._decode_chunk = self._exec.decode_chunk
+            # the tick's chunk that waits for the decode trip to take it
+            # along, and the operands of a trip with every row masked
+            self._rider: Optional[_Chunk] = None
+            self._masked_rows = None
             if self.spec is not None:
                 if self._spec_fused:
                     self._spec_scan = self._exec.spec_scan
@@ -1493,34 +1524,75 @@ class GenerationServer:
             self._stall_streak = 0
         return out
 
-    def _prefill_chunk_step(self, slot: int) -> int:
-        """Advance one prompt chunk for a prefilling slot; on the final
-        chunk, sample the first token and flip the slot to decoding (a
-        corruption-recovery replay instead resumes at its saved
-        position — nothing new is sampled). Returns the chunk programs
-        dispatched (0 when the slot stalled or yielded)."""
+    def _prepare_chunk(self, slot: int) -> Optional[_Chunk]:
+        """The next prompt chunk of a prefilling slot, its blocks reserved
+        and its tokens laid out — or None when the slot stalled or yielded
+        (aborted as its own victim): no chunk this tick."""
         req = self._slots[slot]
         seq = req.replay if req.replay is not None else req.prompt
         n = len(seq)
-        bs = self.block_size
         C = self.prefill_chunk
         start = req.pf_next
         end = min(start + C, n)
-        if self._reserve_or_preempt(slot, -(-end // bs)) != "ok":
-            return 0    # aborted as its own victim, or stalled — no chunk
-        chunk = np.zeros((1, C), np.int32)
-        chunk[0, :end - start] = seq[start:end]
-        last_idx = (n - 1 - start) if end == n else 0
-        aidx = (jnp.asarray(self.aidx[slot:slot + 1])
-                if self._lora is not None else None)
+        if self._reserve_or_preempt(slot, -(-end // self.block_size)) != "ok":
+            return None
+        ids = np.zeros((1, C), np.int32)
+        ids[0, :end - start] = seq[start:end]
+        return _Chunk(slot, req, ids, start, end, n)
+
+    def _chunk_operands(self, ck: _Chunk):
+        """(chunk, table, start, last_idx) as the chunk programs take them;
+        ``last_idx`` is the last real prompt token on the final chunk and
+        ignored on earlier ones."""
+        last_idx = (ck.n - 1 - ck.start) if ck.end == ck.n else 0
+        return (jnp.asarray(ck.ids), jnp.asarray(self._bt[ck.slot]),
+                jnp.int32(ck.start), jnp.int32(last_idx))
+
+    def _chunk_alone(self, ck: _Chunk, why: str) -> None:
+        """Dispatch one prompt chunk in a program call of its own, counted
+        under ``why``, and on a final chunk read the first token. A server
+        whose chunks can ride in the decode trip (``_decode_chunk``) runs
+        that same program with every decode row masked — the way idle rows
+        run in every trip — so that it compiles no third program; every
+        other server runs the chunk program."""
         tel = self._tel
         _t0 = tel.clock() if tel.enabled else 0.0
         _w0 = self._wall()
-        lg, self._pools, self._slot_pools = self._chunk_prefill(
-            self.params, jnp.asarray(chunk), self._pools,
-            jnp.asarray(self._bt[slot]), jnp.int32(start),
-            jnp.int32(last_idx), aidx, self._lora_flat(), self._slot_pools,
-            jnp.asarray(np.array([slot, end - start, end == n], np.int32)))
+        slot, (chunk, table, start, last_idx) = ck.slot, \
+            self._chunk_operands(ck)
+        if self._decode_chunk is None:
+            aidx = (jnp.asarray(self.aidx[slot:slot + 1])
+                    if self._lora is not None else None)
+            lg, self._pools, self._slot_pools = self._chunk_prefill(
+                self.params, chunk, self._pools, table, start, last_idx,
+                aidx, self._lora_flat(), self._slot_pools,
+                jnp.asarray(np.array([slot, ck.end - ck.start,
+                                      ck.end == ck.n], np.int32)))
+        else:
+            if self._masked_rows is None:
+                B = self.max_batch
+                self._masked_rows = (
+                    jnp.zeros((B, self._table_width), jnp.int32),
+                    jnp.zeros((B,), self.pos.dtype),
+                    jnp.zeros((B,), jnp.int32))
+            bt, posv, active = self._masked_rows
+            temps, topks, topps, _, _ = self._samp_arrays()
+            # (the stack of an all-masked trip is nobody's tokens)
+            _, lg, self._pools = self._decode_chunk(
+                self.params, jnp.asarray(self.tokens), self._pools, bt, posv,
+                temps, topks, topps, active, self._base_key,
+                self._exec.prev_stack(None, 1), chunk, table, start,
+                last_idx, self._all_greedy(range(self.max_batch)))
+        self._c_pf_alone.inc(reason=why)
+        self._chunk_dispatched(ck, _t0, _w0)
+        self._chunk_ended(ck, lg)
+
+    def _chunk_dispatched(self, ck: _Chunk, _t0: float, _w0: float) -> None:
+        """What the host knows of a chunk once its program call returned,
+        without waiting for it: the work counted, its span, its blocks
+        published, the slot's next chunk."""
+        req, start, end = ck.req, ck.start, ck.end
+        bs = self.block_size
         # per-chip prefill throughput ledger (tools/serving_benchmark.py
         # divides by tp*cp): real prompt tokens only, not chunk padding
         m = end - start
@@ -1529,6 +1601,7 @@ class GenerationServer:
         # token p of the chunk attends positions 0..p: start+1 .. end
         self._c_pf_ctx.inc(m * start + m * (m + 1) // 2)
         self._prefill_wall_s += self._wall() - _w0
+        tel = self._tel
         if tel.enabled:
             # dispatch is asynchronous: the span ends when the call
             # returns, not when the chunk has run
@@ -1540,21 +1613,29 @@ class GenerationServer:
         for i in range(start // bs, min(end // bs, len(req.hashes))):
             self.alloc.register(req.table[i], req.hashes[i])
             self._offload.forget_warm(req.hashes[i])
-        req.pf_next = start + C
-        if end == n:
-            if req.replay is not None:
-                self._activate_replayed(slot, req)
-            else:
-                self._activate_slot(slot, req, self._first_token(req, lg))
-            self._prefilling[slot] = None
-            if self.role == "prefill" and self._slots[slot] is req:
-                # prefill-class replica: the request now holds exactly
-                # the KV + first token a decode replica resumes from —
-                # park it for the router's evacuate(rids=)/admit_migrated
-                # handoff instead of decoding here (replays park too:
-                # their decode phase belongs to the decode class)
-                self._handoff.add(req.rid)
-        return 1
+        req.pf_next = start + self.prefill_chunk
+
+    def _chunk_ended(self, ck: _Chunk, lg) -> None:
+        """After a prompt's final chunk, sample the first token from its
+        logits row ``lg`` (the one host sync of a prefill) and flip the
+        slot to decoding; a corruption-recovery replay instead resumes at
+        its saved position — nothing new is sampled. Nothing to do after
+        an earlier chunk."""
+        if ck.end != ck.n:
+            return
+        slot, req = ck.slot, ck.req
+        if req.replay is not None:
+            self._activate_replayed(slot, req)
+        else:
+            self._activate_slot(slot, req, self._first_token(req, lg))
+        self._prefilling[slot] = None
+        if self.role == "prefill" and self._slots[slot] is req:
+            # prefill-class replica: the request now holds exactly
+            # the KV + first token a decode replica resumes from —
+            # park it for the router's evacuate(rids=)/admit_migrated
+            # handoff instead of decoding here (replays park too:
+            # their decode phase belongs to the decode class)
+            self._handoff.add(req.rid)
 
     def _activate_replayed(self, slot: int, req: _Request) -> None:
         """Flip a corruption-recovery replay straight back to decoding.
@@ -1675,6 +1756,7 @@ class GenerationServer:
         tel_on = tel.enabled
         if tel_on:
             self._last_prog = "idle"
+        self._rider = None
         # demote BEFORE admission: freed blocks feed _service_queue's
         # headroom gate this same tick
         with tel.phase("admit", tick) as ph:
@@ -1683,30 +1765,37 @@ class GenerationServer:
         # chunked prefill interleaves with decode: ONE chunk per prefilling
         # slot per step, so a long prompt never blocks slots mid-decode
         # (no head-of-line blocking) and short requests keep streaming out
+        # Where a chunk can ride in the decode trip's program call
+        # (executor.py, ``chunk_alone_why``) the tick's first one waits for
+        # that dispatch, below, so that the tick reads the weights once;
+        # every other chunk has a call of its own, here.
         did_prefill = False
+        alone_why = self._exec.chunk_alone_why
         with tel.phase("prefill", tick) as ph:
             chunks = 0
             for s in range(self.max_batch):
                 if self._slots[s] is not None and self._prefilling[s]:
-                    chunks += self._prefill_chunk_step(s)
                     did_prefill = True
-            ph.note(chunks=chunks)
+                    ck = self._prepare_chunk(s)
+                    if ck is None:
+                        continue
+                    why = alone_why
+                    if why is None and self._rider is not None:
+                        why = "second_chunk"
+                    if why is None and not self._decoding_rows():
+                        # nothing to ride with: run now, so that a final
+                        # chunk's slot decodes in this very tick
+                        why = "no_decoding_row"
+                    if why is None:
+                        self._rider = ck
+                        continue
+                    self._chunk_alone(ck, why)
+                    chunks += 1
+            ph.note(chunks=chunks, riding=int(self._rider is not None))
         # rows for the coming trip: the decoding slots, less those that the
         # pending trip's harvest will end whatever its tokens are — their
         # budget runs out inside it, or their last harvested token was eos
-        pend = self._trips[-1] if self._trips else None
-        active = []
-        for s in range(self.max_batch):
-            req = self._slots[s]
-            if req is None or self._prefilling[s] \
-                    or req.rid in self._handoff:
-                continue
-            if pend is not None and pend.mask[s] and (
-                    s in pend.ends or req.done
-                    or (self.eos is not None
-                        and req.generated[-1] == self.eos)):
-                continue
-            active.append(s)
+        active = self._decoding_rows()
         if self._degraded_ticks > 0:
             self._degraded_ticks -= 1
         trips0, idle_why = self._trip_no, "idle"
@@ -1723,6 +1812,9 @@ class GenerationServer:
                     self._last_prog = "backoff"
             else:
                 rids = [self._slots[s].rid for s in active]
+                if self._rider is not None:
+                    # (the chunk in the trip's call is a participant too)
+                    rids.append(self._rider.req.rid)
                 try:
                     self._dispatch_trips(active)
                 except Exception as e:
@@ -1744,6 +1836,11 @@ class GenerationServer:
                     # fault domain that struck them was transient
                     for r in rids:
                         self._strikes.pop(r, None)
+        ck = self._take_rider()
+        if ck is not None:
+            # no trip took the chunk along (no decoding row, a backoff
+            # tick, every row stalled, a fault)
+            self._chunk_alone(ck, "no_decoding_row")
         if self._trip_no == trips0:
             # no trip went out this tick (no decoding row, a backoff tick,
             # every row stalled): nothing will overlap the pending one, so
@@ -1770,6 +1867,35 @@ class GenerationServer:
         else:
             self._idle_streak = 0
         return occupied + len(self._sched)
+
+    def _decoding_rows(self) -> List[int]:
+        """Rows for the coming trip: the decoding slots, less those that
+        the pending trip's harvest will end whatever its tokens are — their
+        budget runs out inside it, or their last harvested token was eos."""
+        pend = self._trips[-1] if self._trips else None
+        active = []
+        for s in range(self.max_batch):
+            req = self._slots[s]
+            if req is None or self._prefilling[s] \
+                    or req.rid in self._handoff:
+                continue
+            if pend is not None and pend.mask[s] and (
+                    s in pend.ends or req.done
+                    or (self.eos is not None
+                        and req.generated[-1] == self.eos)):
+                continue
+            active.append(s)
+        return active
+
+    def _take_rider(self) -> Optional[_Chunk]:
+        """The chunk that waits for this tick's decode trip, if it still
+        stands: a block reservation made since may have preempted its slot
+        (the request then starts over from the queue; nothing of the chunk
+        had been dispatched)."""
+        ck, self._rider = self._rider, None
+        if ck is not None and self._slots[ck.slot] is not ck.req:
+            return None
+        return ck
 
     def _dispatch_trips(self, active) -> None:
         """Dispatch the step's decode work for ``active`` slots — the one
@@ -1887,11 +2013,15 @@ class GenerationServer:
             if tel.enabled:
                 self._last_prog = "stalled"
             return
+        # the tick's waiting prompt chunk rides in this trip's program call
+        ck = self._take_rider()
         if tel.enabled:
             # program key: tick count + greedy specialization are the
             # static jit-cache axes of the plain decode program
-            self._last_prog = (f"plain:t{'w' if ticks is None else ticks}"
-                               f":g{int(self._all_greedy(active))}")
+            self._last_prog = (
+                f"plain{'' if ck is None else '+chunk'}"
+                f":t{'w' if ticks is None else ticks}"
+                f":g{int(self._all_greedy(active))}")
         tick, rows = self._tick_seq, len(active)
         prev = self._trips[-1] if self._trips else None
         with tel.phase("decode_dispatch", tick, rows=rows):
@@ -1912,12 +2042,23 @@ class GenerationServer:
             bt = np.where(active_mask[:, None] > 0, self._bt, 0)
             posv = self.pos * active_mask
             temps, topks, topps, _, aidx = self._samp_arrays()
-            stack, self._pools, self._slot_pools = self._decode_paged(
-                self.params, jnp.asarray(self.tokens), self._pools,
-                jnp.asarray(bt), jnp.asarray(posv), temps, topks, topps,
-                jnp.asarray(feed), key, aidx, self._lora_flat(),
-                self._all_greedy(active), ticks, self._slot_pools,
-                self._exec.prev_stack(prev, k))
+            if ck is None:
+                stack, self._pools, self._slot_pools = self._decode_paged(
+                    self.params, jnp.asarray(self.tokens), self._pools,
+                    jnp.asarray(bt), jnp.asarray(posv), temps, topks, topps,
+                    jnp.asarray(feed), key, aidx, self._lora_flat(),
+                    self._all_greedy(active), ticks, self._slot_pools,
+                    self._exec.prev_stack(prev, k))
+            else:
+                _t0 = tel.clock() if tel.enabled else 0.0
+                _w0 = self._wall()
+                stack, lg, self._pools = self._decode_chunk(
+                    self.params, jnp.asarray(self.tokens), self._pools,
+                    jnp.asarray(bt), jnp.asarray(posv), temps, topks, topps,
+                    jnp.asarray(feed), key, self._exec.prev_stack(prev, k),
+                    *self._chunk_operands(ck), self._all_greedy(active))
+                self._c_pf_fused.inc()
+                self._chunk_dispatched(ck, _t0, _w0)
             # rows that this trip takes to the end of their budget: the
             # harvest will release them, the next trip leaves them out
             ends = []
@@ -1932,6 +2073,11 @@ class GenerationServer:
             self._trip_no += 1
             self.pos = self.pos + active_mask * k
         self._retire_pending(keep=1)
+        if ck is not None:
+            # a final chunk's first token: the one sync on THIS call, made
+            # after the trip before has been folded (that fold overlaps the
+            # call); the slot joins the decode rows from the next tick
+            self._chunk_ended(ck, lg)
         if self.spec is not None:
             self._retire_pending("spec")
 

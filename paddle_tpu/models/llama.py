@@ -183,6 +183,89 @@ def _apply_rope_bhsd(x, cos, sin, pos_offset=0):
     return _rope_rotate(x, c, s)
 
 
+def _write_rows_kv(qv, kv, vv, pool, cos, sin, block_tables, pos):
+    """B single-token rows against the paged pool, first half: rope at each
+    row's own ``pos`` and K/V scattered through the row's block table. qv
+    (B, 1, H, D); ``pool`` the layer's arrays — (kp, vp), or (kq, ks, vq,
+    vs) for int8 KV. Returns (rotated q, new pool)."""
+    from ..ops import paged_attention as pa
+
+    qr = _apply_rope_rows(qv, cos, sin, pos)
+    kr = _apply_rope_rows(kv, cos, sin, pos)
+    write = pa.write_decode_kv_q if len(pool) == 4 else pa.write_decode_kv
+    return qr, write(*pool, kr[:, 0], vv[:, 0], block_tables, pos)
+
+
+def _attend_rows(qr, pool, block_tables, pos):
+    """Second half: each row's attention over the context its table names,
+    (B, 1, H, D)."""
+    from ..ops import paged_attention as pa
+
+    attend = (pa.paged_decode_attention_q if len(pool) == 4
+              else pa.paged_decode_attention)
+    return attend(qr, *pool, block_tables, pos)
+
+
+def _write_chunk_kv(qv, kv, vv, pool, cos, sin, block_table, start):
+    """One prompt chunk (1, C, H, D) at positions ``start + arange(C)``,
+    first half: rope and its K/V into consecutive entries of
+    ``block_table``. ``pool`` and the return as in :func:`_write_rows_kv`."""
+    from ..ops import paged_attention as pa
+
+    qr = _apply_rope_chunk(qv, cos, sin, start)
+    kr = _apply_rope_chunk(kv, cos, sin, start)
+    write = pa.write_chunk_kv_q if len(pool) == 4 else pa.write_chunk_kv
+    return qr, write(*pool, kr[0], vv[0], block_table, start)
+
+
+def _attend_chunk(qr, pool, block_table, start):
+    """Second half: causal attention over everything written so far,
+    (1, C, H, D)."""
+    from ..ops import paged_attention as pa
+
+    attend = (pa.paged_prefill_attention_q if len(pool) == 4
+              else pa.paged_prefill_attention)
+    return attend(qr, *pool, block_table, start)
+
+
+def _rows_and_chunk_attention(block_tables, pos, block_table, start):
+    """The attention of a step over B decode rows and one prompt chunk, for
+    every layer of ONE program trace: ``attend(q, k, v, cos, sin, *pool) ->
+    (out, *new pool)`` with q/k/v (1, B + C, heads, D), the decode rows
+    first. The rows take :meth:`LlamaAttention.paged_decode`'s path, the
+    chunk :meth:`~LlamaAttention.paged_prefill_chunk`'s, the same writes and
+    kernels. No row reads what another row of the call writes (a decode
+    row's and the chunk's private blocks are different blocks), so the order
+    is free — and it is BOTH writes, then both attentions over the pool as
+    it then stands: with a write between the two reads the compiler has to
+    keep the pool of before for the first reader and copies it whole (134
+    MB a pool at the Mistral cells' size, found in the compiled text and in
+    the trace: PERF.md, PR 32).
+
+    It is jitted, and made anew for each trace of the step: jit keys a trace
+    on the function's identity and the operands' shapes, which are the same
+    in every layer, so the two kernels are traced and lowered once a program
+    instead of once a layer (every ``pallas_call`` is a new function to jit:
+    0.14–0.30 s a call site on the chip's host, 7 of the joint program's 14
+    s), and nothing of one trace (kernel mode, mesh, geometry) can leak into
+    another server's."""
+    B = block_tables.shape[0]
+
+    def attend(qv, kv, vv, cos, sin, tables, posv, table, startv, *pool):
+        qd, pool = _write_rows_kv(qv[0, :B, None], kv[0, :B, None],
+                                  vv[0, :B, None], pool, cos, sin, tables,
+                                  posv)
+        qc, pool = _write_chunk_kv(qv[:, B:], kv[:, B:], vv[:, B:], pool, cos,
+                                   sin, table, startv)
+        rows = _attend_rows(qd, pool, tables, posv)
+        chunk = _attend_chunk(qc, pool, table, startv)
+        return (jnp.concatenate([rows[None, :, 0], chunk], axis=1), *pool)
+
+    attend = jax.jit(attend)
+    return lambda qv, kv, vv, cos, sin, *pool: attend(
+        qv, kv, vv, cos, sin, block_tables, pos, block_table, start, *pool)
+
+
 # --------------------------------------------------------------------------- #
 # Context-parallel attention dispatch
 # --------------------------------------------------------------------------- #
@@ -607,29 +690,11 @@ class LlamaAttention(Layer):
         H, D = self.num_heads, self.head_dim
         q, k, v = self._qkv(x, B, 1, lora=lora)
 
-        if len(pool) == 4:
-            def step(qv, kv, vv, kqv, ksv, vqv, vsv, cosv, sinv):
-                from ..ops.paged_attention import (paged_decode_attention_q,
-                                                   write_decode_kv_q)
-
-                qr = _apply_rope_rows(qv, cosv, sinv, pos)
-                kr = _apply_rope_rows(kv, cosv, sinv, pos)
-                kqv, ksv, vqv, vsv = write_decode_kv_q(
-                    kqv, ksv, vqv, vsv, kr[:, 0], vv[:, 0], block_tables, pos)
-                out = paged_decode_attention_q(qr, kqv, ksv, vqv, vsv,
-                                               block_tables, pos)
-                return out, kqv, ksv, vqv, vsv
-        else:
-            def step(qv, kv, vv, kpv, vpv, cosv, sinv):
-                from ..ops.paged_attention import (paged_decode_attention,
-                                                   write_decode_kv)
-
-                qr = _apply_rope_rows(qv, cosv, sinv, pos)
-                kr = _apply_rope_rows(kv, cosv, sinv, pos)
-                kpv, vpv = write_decode_kv(kpv, vpv, kr[:, 0], vv[:, 0],
-                                           block_tables, pos)
-                out = paged_decode_attention(qr, kpv, vpv, block_tables, pos)
-                return out, kpv, vpv
+        def step(qv, kv, vv, *rest):
+            *pl, cosv, sinv = rest
+            qr, pl = _write_rows_kv(qv, kv, vv, pl, cosv, sinv, block_tables,
+                                    pos)
+            return (_attend_rows(qr, pl, block_tables, pos), *pl)
 
         out, *pool = apply_op(step, q, k, v, *pool, Tensor(cos), Tensor(sin),
                               op_name="paged_decode_attention")
@@ -692,34 +757,31 @@ class LlamaAttention(Layer):
         H, D = self.num_heads, self.head_dim
         q, k, v = self._qkv(x, B, S, lora=lora)
 
-        if len(pool) == 4:
-            def step(qv, kv, vv, kqv, ksv, vqv, vsv, cosv, sinv):
-                from ..ops.paged_attention import (paged_prefill_attention_q,
-                                                   write_chunk_kv_q)
-
-                qr = _apply_rope_chunk(qv, cosv, sinv, start)
-                kr = _apply_rope_chunk(kv, cosv, sinv, start)
-                kqv, ksv, vqv, vsv = write_chunk_kv_q(
-                    kqv, ksv, vqv, vsv, kr[0], vv[0], block_table, start)
-                out = paged_prefill_attention_q(qr, kqv, ksv, vqv, vsv,
-                                                block_table, start)
-                return out, kqv, ksv, vqv, vsv
-        else:
-            def step(qv, kv, vv, kpv, vpv, cosv, sinv):
-                from ..ops.paged_attention import (paged_prefill_attention,
-                                                   write_chunk_kv)
-
-                qr = _apply_rope_chunk(qv, cosv, sinv, start)
-                kr = _apply_rope_chunk(kv, cosv, sinv, start)
-                kpv, vpv = write_chunk_kv(kpv, vpv, kr[0], vv[0], block_table,
-                                          start)
-                out = paged_prefill_attention(qr, kpv, vpv, block_table, start)
-                return out, kpv, vpv
+        def step(qv, kv, vv, *rest):
+            *pl, cosv, sinv = rest
+            qr, pl = _write_chunk_kv(qv, kv, vv, pl, cosv, sinv, block_table,
+                                     start)
+            return (_attend_chunk(qr, pl, block_table, start), *pl)
 
         out, *pool = apply_op(step, q, k, v, *pool, Tensor(cos), Tensor(sin),
                               op_name="paged_prefill_attention")
         out = reshape(out, [B, S, H * D])
         return self._o_lora(out, lora), tuple(pool)
+
+    def paged_decode_chunk(self, x, cos, sin, pool, attend):
+        """A tick's B decode rows AND one prompt chunk of C tokens through
+        ONE q/k/v and ONE output projection, so that the layer's weights
+        are read once for both. x: (1, B + C, hidden), the decode rows
+        first; only the attention splits, in ``attend`` — the step's one
+        :func:`_rows_and_chunk_attention`, which knows the rows' tables and
+        positions and the chunk's — and its two outputs come back joined
+        for ``o_proj``."""
+        S = x.shape[1]
+        q, k, v = self._qkv(x, 1, S)
+        out, *pool = apply_op(attend, q, k, v, Tensor(cos), Tensor(sin),
+                              *pool, op_name="paged_decode_chunk_attention")
+        out = reshape(out, [1, S, self.num_heads * self.head_dim])
+        return self.o_proj(out), tuple(pool)
 
 
 class LlamaMLP(Layer):
@@ -882,6 +944,13 @@ class LlamaDecoderLayer(Layer):
         out = h + self.mlp(self.post_attention_layernorm(h), lora=lora)
         return out, pool
 
+    def paged_decode_chunk(self, x, cos, sin, pool, attend):
+        a, pool = self.self_attn.paged_decode_chunk(
+            self.input_layernorm(x), cos, sin, pool, attend)
+        h = x + a
+        out = h + self.mlp(self.post_attention_layernorm(h))
+        return out, pool
+
 
 class LlamaModel(Layer):
     def __init__(self, cfg: LlamaConfig):
@@ -1016,6 +1085,31 @@ class LlamaModel(Layer):
         if last_idx is not None:
             h = Tensor(jax.lax.dynamic_slice_in_dim(h.value, last_idx, 1, 1))
         return h, new
+
+    def paged_decode_chunk_step(self, input_ids, pools, block_tables, pos,
+                                block_table, start, last_idx):
+        """:meth:`paged_decode_step` and :meth:`paged_prefill_chunk` as ONE
+        step, so that a tick which carries a prompt chunk reads every weight
+        once and not twice: input_ids Tensor (1, B + C) — the B decode
+        rows' tokens, then the chunk's C; embedding, norms, projections and
+        MLP run on the joined rows, the attention of each layer splits
+        (:func:`_rows_and_chunk_attention`). Returns (normed hidden
+        (1, B + 1, hidden): the decode rows, then the chunk's token at
+        ``last_idx`` — what the head is wanted for; new pools). Without
+        ``lora``: a server with adapters keeps the two separate steps."""
+        B = block_tables.shape[0]
+        attend = _rows_and_chunk_attention(block_tables, pos, block_table,
+                                           start)
+        x = self.embed_tokens(input_ids)
+        new = []
+        for layer, pool in zip(self.layers, pools):
+            x, pool = layer.paged_decode_chunk(x, self._cos, self._sin, pool,
+                                               attend)
+            new.append(pool)
+        x = apply_op(lambda v: jnp.concatenate(
+            [v[:, :B], jax.lax.dynamic_slice_in_dim(v, B + last_idx, 1, 1)],
+            axis=1), x, op_name="decode_rows_and_last")
+        return self.norm(x), new
 
     def _should_recompute(self):
         from ..framework.core import is_grad_enabled

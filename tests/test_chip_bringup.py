@@ -319,7 +319,7 @@ def test_compile_check_exempts_no_case():
     code = (
         "import json, compile_check as cc\n"
         "def boom(): raise RuntimeError('refused')\n"
-        "names = [n for n, _ in cc.kernel_cases() + cc.program_cases(None)]\n"
+        "names = [c[0] for c in cc.kernel_cases() + cc.program_cases(None)]\n"
         "failed = cc.run_cases([(n, boom) for n in names], None)\n"
         "print(json.dumps({'names': names, 'failed': failed,\n"
         "                  'exempt': hasattr(cc, 'KNOWN_' 'REFUSALS')}))\n")
@@ -336,8 +336,10 @@ def test_compile_check_exempts_no_case():
 @pytest.mark.parametrize("mode", ["auto", "pallas", "reference"])
 def test_each_serving_program_has_its_one_body(mode):
     """No kernel mode swaps a program's body: the executor jits
-    ``_decode_paged_fn`` and ``_chunk_prefill_fn`` themselves, and holds one
-    ``*_fn`` body per program and no twin."""
+    ``_decode_paged_fn`` and ONE chunk program themselves — the joint
+    ``_decode_chunk_fn`` where a chunk can ride in the decode trip's call,
+    ``_chunk_prefill_fn`` elsewhere (here: a ``tick_window`` scan) — and
+    holds one ``*_fn`` body per program and no twin."""
     import paddle_tpu as paddle
     from paddle_tpu.inference.executor import PagedExecutor
     from paddle_tpu.inference.serving import GenerationServer
@@ -349,15 +351,24 @@ def test_each_serving_program_has_its_one_body(mode):
         num_hidden_layers=1, num_attention_heads=2, num_key_value_heads=1,
         max_position_embeddings=64, dtype="float32",
         use_flash_attention=False))
-    ex = GenerationServer(model, max_len=32, cache="paged", block_size=4,
-                          kernels=mode)._exec
+
+    def executor(**kw):
+        return GenerationServer(model, max_len=32, cache="paged",
+                                block_size=4, kernels=mode, **kw)._exec
+
+    ex = executor()
     assert ex.decode_paged.__wrapped__.__func__ \
         is PagedExecutor._decode_paged_fn
+    assert ex.chunk_alone_why is None and ex.chunk_prefill is None
+    assert ex.decode_chunk.__wrapped__.__func__ \
+        is PagedExecutor._decode_chunk_fn
+    ex = executor(tick_window=2)
+    assert ex.chunk_alone_why == "tick_window" and ex.decode_chunk is None
     assert ex.chunk_prefill.__wrapped__.__func__ \
         is PagedExecutor._chunk_prefill_fn
     assert sorted(n for n in vars(PagedExecutor) if n.endswith("_fn")) == [
-        "_chunk_prefill_fn", "_decode_paged_fn", "_spec_scan_fn",
-        "_spec_verify_fn"]
+        "_chunk_prefill_fn", "_decode_chunk_fn", "_decode_paged_fn",
+        "_spec_scan_fn", "_spec_verify_fn"]
 
 
 # ------------------------------------------------------------ peaks, cache

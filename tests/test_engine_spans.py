@@ -74,7 +74,13 @@ def test_every_tick_holds_its_phases_in_order_without_overlap(model):
         assert t["dur"] >= sum(k["dur"] for k in kids)
         waits = [s for s in mine if s["name"] == "first_token_wait"]
         pf = next(k for k in kids if k["name"] == "prefill")
-        assert all(_inside(w, pf) for w in waits)
+        # a chunk dispatched on its own is waited for inside ``prefill``;
+        # one that rode in the decode trip's call after the tick's harvest
+        rode = [w for w in waits if not _inside(w, pf)]
+        assert len(rode) <= pf["args"]["riding"]
+        for w in rode:
+            assert w["t0"] >= kids[-1]["t0"] + kids[-1]["dur"]
+            assert kids[-1]["name"] == "harvest"
         for a, b in zip(waits, waits[1:]):
             assert a["t0"] + a["dur"] <= b["t0"]
         # the flight record's wait_s is the tick's two waits, and its wall
@@ -84,7 +90,8 @@ def test_every_tick_holds_its_phases_in_order_without_overlap(model):
             sum(dw) + sum(w["dur"] for w in waits), abs=1e-9)
         assert rec["t_wall_s"] == t["dur"]
         assert (t["args"]["prog"], t["args"]["chunks"]) == (
-            rec["prog"], pf["args"]["chunks"])
+            rec["prog"], pf["args"]["chunks"] + pf["args"]["riding"])
+        assert ("+chunk" in rec["prog"]) == bool(pf["args"]["riding"])
     # every request's first token was waited for once, under its own rid
     waited = [s["args"]["rid"] for s in spans
               if s["name"] == "first_token_wait"]
@@ -269,7 +276,9 @@ def test_enabled_telemetry_emits_nested_pt_annotations(model, monkeypatch):
             assert stack.pop() == (name, kw)
     assert not stack
     assert parents.pop("pt.tick") == {None}
-    assert parents.pop("pt.first_token_wait") == {"pt.prefill"}
+    # (inside ``prefill`` for a chunk of its own, straight under the tick for
+    # one that rode in the decode trip's call)
+    assert parents.pop("pt.first_token_wait") == {"pt.prefill", "pt.tick"}
     assert all(p == {"pt.tick"} for p in parents.values()), parents
     seqs = [kw["tick"] for kind, name, kw in log
             if kind == "enter" and name == "pt.tick"]

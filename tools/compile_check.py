@@ -25,6 +25,7 @@ from __future__ import annotations
 import argparse
 import contextlib
 import os
+import re
 import sys
 import time
 import traceback
@@ -206,6 +207,73 @@ def kernel_cases():
     ]
 
 
+def weight_read_bytes(hlo_text):
+    """{weight's path: bytes of it that the ENTRY computation's
+    instructions read} for the parameters named as weights, from a compiled
+    program's text: an async ``slice-start`` reads its slice, every other
+    user the whole array. What says whether a program that joins two steps
+    streams a weight once or once per step."""
+    size = {"bf16": 2, "f16": 2, "f32": 4, "s8": 1, "u8": 1, "s32": 4}
+    lines = hlo_text[hlo_text.index("\nENTRY"):].split("\n")[1:]
+    out = {}
+    for line in lines:
+        m = re.match(r"\s*(%\S+) = (\w+)\[([\d,]*)\]\S* parameter\(.*"
+                     r"op_name=\"([^\"]*weight[^\"]*)\"", line)
+        if m is None:
+            continue
+        name, whole = m.group(1), size[m.group(2)] * int(np.prod(
+            [int(d) for d in m.group(3).split(",") if d]))
+        read = 0
+        for user in lines:
+            head, _, body = user.partition(" = ")
+            if head.strip() == name or not re.search(
+                    re.escape(name) + r"[,)]", body):
+                continue
+            cut = re.search(r"slice-start\(.*slice=\{([^}]*)\}", body)
+            if cut is None:
+                read += whole
+            else:
+                ext = [int(b) - int(a) for a, b in
+                       re.findall(r"\[(\d+):(\d+)\]", cut.group(1))]
+                read += size[m.group(2)] * int(np.prod(ext))
+        out[m.group(4)] = read
+    return out
+
+
+def reads_weights_like(other):
+    """A case's check: no weight is read more in this program than in the
+    case ``other`` (the decode program, which reads each once and the
+    cross-program prefetch's twice)."""
+    def check(text, texts):
+        if other not in texts:
+            return " weight_reads=unchecked"
+        mine, ref = weight_read_bytes(text), weight_read_bytes(texts[other])
+        more = {k: (v, ref.get(k)) for k, v in mine.items()
+                if v > ref.get(k, 0)}
+        if more or not mine:
+            raise RuntimeError(f"weights read more than in {other}: {more}")
+        return (f" weight_reads={sum(mine.values()) / sum(ref.values()):.2f}"
+                f"x_decode")
+    return check
+
+
+def copies_no_pool(text, texts):
+    """A case's check: the compiled program copies no KV pool whole. A pool
+    is updated in place (donated, aliased to its output); where a write sits
+    between two readers the compiler keeps the old pool for the first and
+    copies all of it — 134 MB a pool a call at the Mistral cells' size,
+    which the joint decode + chunk step did in its first layer until both
+    writes were put before both attention calls (PR 32)."""
+    entry = text[text.index("\nENTRY"):]
+    pools = set(re.findall(
+        r"= (\w+\[[\d,]+\])\S* parameter\(\d+\).*op_name=\"flat_pools", entry))
+    n = sum(len(re.findall(r"= " + re.escape(p) + r"\S* copy\(", entry))
+            for p in pools)
+    if n or not pools:
+        raise RuntimeError(f"{n} whole-pool copies (pools: {sorted(pools)})")
+    return " pool_copies=0"
+
+
 # ------------------------------------------------------------ program cases
 # Whole programs, through the same entry points chip_smoke.py drives, at
 # Llama-3-8B WIDTHS but two layers deep (layers unroll: depth only multiplies
@@ -239,7 +307,7 @@ def _train_program(devs, mesh_shape, axes, cfg, B, S, **engine_kw):
 
 
 def _serve_programs(devs, cfg, tp, model_cls=None, **served):
-    """(decode, prefill, reference-forward) of a paged server."""
+    """(decode, chunk, reference-forward) of a paged server."""
     import paddle_tpu as paddle
     from paddle_tpu.framework.core import Tensor
     from paddle_tpu.inference import GenerationServer
@@ -288,13 +356,22 @@ def _serve_programs(devs, cfg, tp, model_cls=None, **served):
                     rep((M,), i32), rep((), i32), rep((), i32), None, (),
                     slot_pools, rep((3,), i32))
 
+    # the chunk program the server dispatches: where a chunk can ride in
+    # the decode trip's call (executor.py, ``chunk_alone_why``) the joint
+    # program over B + C rows, else the chunk program
+    if srv._decode_chunk is not None:
+        chunk_prog = (srv._decode_chunk, decode_args[:10] + (
+            rep((1, B), i32), rep((1, srv.prefill_chunk), i32),
+            rep((M,), i32), rep((), i32), rep((), i32), True))
+    else:
+        chunk_prog = (srv._chunk_prefill, prefill_args)
+
     def fwd(p, ids):
         with (mesh_context(mesh) if mesh is not None
               else contextlib.nullcontext()):
             return functional_call(model, p, Tensor(ids)).value
 
-    return [(srv._decode_paged, decode_args),
-            (srv._chunk_prefill, prefill_args),
+    return [(srv._decode_paged, decode_args), chunk_prog,
             (jax.jit(fwd), (params, rep((1, srv.max_len), i32)))]
 
 
@@ -314,10 +391,25 @@ def program_cases(devs):
     def serve(tp, i):
         def build():
             if tp not in built:
+                # one chip: the Mistral cells' served shape (64 slots of
+                # 4096, chunks of 128), so that the temps are the cells'
+                shape = {} if tp > 1 else dict(
+                    max_batch=64, max_len=4096, num_blocks=4097)
                 built[tp] = _serve_programs(
-                    devs, wide(max_position_embeddings=512), tp)
+                    devs, wide(max_position_embeddings=shape.get(
+                        "max_len", 512)), tp, **shape)
             return built[tp][i]
         return build
+
+    def mistral_cell():
+        """The joint decode + chunk program as the Mistral cells run it:
+        16 layers at the published widths, 64 slots of 4096, the 4 GiB
+        pool (what the 2-layer case cannot show: PR 32's whole-pool copies
+        appeared with 16 layers only)."""
+        return _serve_programs(
+            devs, llama3_8b_config(num_hidden_layers=16, vocab_size=32768,
+                                   max_position_embeddings=4096), 1,
+            max_batch=64, max_len=4096, num_blocks=4096)[1]
 
     def phi(i):
         """Phi-4-mini-flash-reasoning whole, as its benchmark cell serves
@@ -343,19 +435,23 @@ def program_cases(devs):
             wide(max_position_embeddings=2048), 4, 2048, fsdp=True,
             batch_spec=P(("data", "sharding")))),
         ("serve.1chip_decode", serve(1, 0)),
-        ("serve.1chip_prefill_chunk", serve(1, 1)),
+        ("serve.1chip_decode_chunk", serve(1, 1),
+         reads_weights_like("serve.1chip_decode")),
         ("serve.1chip_reference_forward", serve(1, 2)),
         ("serve.tp4_decode", serve(4, 0)),
-        ("serve.tp4_prefill_chunk", serve(4, 1)),
+        ("serve.tp4_decode_chunk", serve(4, 1),
+         reads_weights_like("serve.tp4_decode")),
         ("serve.tp4_reference_forward", serve(4, 2)),
+        ("serve.mistral_l16_decode_chunk", mistral_cell,
+         copies_no_pool),
         ("serve.phi4flash_decode_B128", phi(0)),
         ("serve.phi4flash_prefill_chunk", phi(1)),
     ]
 
 
 def run_cases(cases, sharding, only=()):
-    failed = []
-    for name, build in cases:
+    failed, texts = [], {}
+    for name, build, *check in cases:
         if only and not any(name.startswith(o) for o in only):
             continue
         t0 = time.time()
@@ -363,10 +459,13 @@ def run_cases(cases, sharding, only=()):
             fn, specs = build()
             if sharding is None:       # a program case: args are abstract
                 compiled = fn.lower(*specs).compile()
-                n = compiled.as_text().count("tpu_custom_call")
+                text = texts[name] = compiled.as_text()
+                n = text.count("tpu_custom_call")
                 mem = compiled.memory_analysis()
                 extra = (f" args={mem.argument_size_in_bytes / 1e9:.2f}GB "
                          f"temps={mem.temp_size_in_bytes / 1e9:.2f}GB")
+                for c in check:        # what else the compiled text must show
+                    extra += c(text, texts)
             else:
                 _, n = compile_on(fn, specs, sharding)
                 extra = ""

@@ -37,12 +37,20 @@ chip). ``--ragged-depths 0,8,32`` repeats each row at explicit blocks per
 group (``PagedAttentionGeometry.kv_block_depth``; 0 = derived), which is
 how the group width in ``ops/paged_attention_pallas.py`` was chosen.
 
+``--ops rows`` adds the WEIGHT-STREAMING rows: what the Mistral cells'
+widest matmul, bf16 ``[M, 4096] x [4096, 14336]``, and the whole SwiGLU MLP
+around it cost at ``--rows-m`` rows (64 = the decode tick, 128 = a prompt
+chunk, 192 = both in one program), over 8 layers of distinct weights in
+ONE program: us a layer and the share of the time the weight read alone
+takes at the chip's bandwidth peak.
+
 Usage:
     python tools/kernel_bench.py [--json] [--iters 10]
         [--shapes 2,4,8;4,8,16] [--window 4] [--heads 8] [--kv-heads 2]
         [--head-dim 128]
-        [--ops decode,verify,prefill,ragged] [--quant fp,int8] [--tp N]
+        [--ops decode,verify,prefill,ragged,rows] [--quant fp,int8] [--tp N]
     python tools/kernel_bench.py --ops ragged [--ragged-depths 0,8,32]
+    python tools/kernel_bench.py --ops rows [--rows-m 64,128,192]
 
 One JSON line per (op, quant, B, M, bs) combo under --json (bench.py
 style); a human table otherwise.
@@ -107,7 +115,7 @@ def main():
     ap.add_argument("--kv-heads", type=int, default=2)
     ap.add_argument("--head-dim", type=int, default=128)
     ap.add_argument("--ops", default="decode,verify,prefill",
-                    help="comma list of decode,verify,prefill,ragged")
+                    help="comma list of decode,verify,prefill,ragged,rows")
     ap.add_argument("--quant", default="fp,int8")
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--ragged-rows", type=int, default=64)
@@ -118,6 +126,8 @@ def main():
     ap.add_argument("--ragged-tokens", type=int, default=44000)
     ap.add_argument("--ragged-depths", default="0",
                     help="comma list of blocks per group (0 = derived)")
+    ap.add_argument("--rows-m", default="64,128,192",
+                    help="comma list: rows of the weight-streaming matmul")
     ap.add_argument("--tp", type=int, default=1,
                     help="also run every combo sharded over an N-way "
                          "'tp' mesh (shard_map, serving shard layout) "
@@ -370,6 +380,13 @@ def main():
             ops.set_kernel_mode(mode)
         return out_rows
 
+    def chip_peaks():
+        """The benchmark's published peaks of this device, None off a chip
+        it lists."""
+        with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                               "peaks.json")) as f:
+            return json.load(f).get(jax.devices()[0].device_kind)
+
     def ragged_rows():
         """The decode kernel and the chunk at the serving cells' shapes:
         us a call and the share of the roofline (module docstring)."""
@@ -381,9 +398,7 @@ def main():
 
         H, KV, D, bs, N, C, calls = 32, 8, 128, 16, 4096, 128, 16
         B, M = args.ragged_rows, args.ragged_table
-        peaks = json.load(open(os.path.join(
-            os.path.dirname(__file__), "..", "benchmarks", "peaks.json")))
-        peak = peaks.get(jax.devices()[0].device_kind)
+        peak = chip_peaks()
         rng = np.random.RandomState(args.seed)
         pool = [jnp.asarray(rng.randn(N, bs, KV, D).astype(np.float32),
                             jnp.bfloat16) for _ in range(2)]
@@ -465,11 +480,64 @@ def main():
                     "bf16_flops"))
         return out
 
+    def streaming_rows():
+        """The 4096 x 14336 matmul and the SwiGLU MLP at M rows (module
+        docstring): the weights are read once whatever M is, so the time
+        says what the extra rows cost."""
+        hid, ffn, layers = 4096, 14336, 8
+        peak = chip_peaks()
+        rng = np.random.RandomState(args.seed)
+
+        def weights(shape):
+            return [jnp.asarray(rng.randn(*shape).astype(np.float32) * 0.02,
+                                jnp.bfloat16) for _ in range(layers)]
+
+        wg, wu, wd = (weights((hid, ffn)), weights((hid, ffn)),
+                      weights((ffn, hid)))
+
+        def up(x, *ws):
+            for w in ws:        # each layer's input hangs on the one before
+                # (a sum over every column: a slice would let the compiler
+                # read only the columns kept)
+                x = x + (jnp.sum(jnp.matmul(x, w), axis=1, keepdims=True)
+                         * 0).astype(x.dtype)
+            return x
+
+        def mlp(x, *ws):
+            for g, u, d in zip(ws[:layers], ws[layers:2 * layers],
+                               ws[2 * layers:]):
+                x = x + jnp.matmul(jax.nn.silu(jnp.matmul(x, g))
+                                   * jnp.matmul(x, u), d)
+            return x
+
+        out = []
+        for M in (int(m) for m in args.rows_m.split(",")):
+            x = jnp.asarray(rng.randn(M, hid).astype(np.float32),
+                            jnp.bfloat16)
+            for label, fn, ws, n_w in (("matmul", up, wu, 1),
+                                       ("mlp", mlp, wg + wu + wd, 3)):
+                secs, _ = timed(fn, (x, *ws))
+                read_s = n_w * hid * ffn * 2 / peak["hbm_bytes_per_s"] \
+                    if peak and on_tpu else None
+                out.append({
+                    "metric": "weight_stream_us", "op": label, "M": M,
+                    "backend": backend,
+                    "us_per_layer": round(secs / layers * 1e6, 1),
+                    "weight_read_us": (round(read_s * 1e6, 1)
+                                       if read_s else None),
+                    "read_share_pct": (round(100 * read_s * layers / secs, 2)
+                                       if read_s else None),
+                    "parity": True})
+        return out
+
     rows = []
     op_list = args.ops.split(",")
     if "ragged" in op_list:
         op_list.remove("ragged")
         rows += ragged_rows()
+    if "rows" in op_list:
+        op_list.remove("rows")
+        rows += streaming_rows()
     for B, M, bs in parse_shapes(args.shapes):
         for quant in args.quant.split(","):
             rng = np.random.RandomState(args.seed)
@@ -599,6 +667,11 @@ def main():
                       f"{r['us_per_call']:>9} us/call  roofline "
                       f"{r['roofline_pct']} %  max|diff| "
                       f"{r['max_abs_diff']:.2e}")
+                continue
+            if r["metric"] == "weight_stream_us":
+                print(f"{r['op']:8} M={r['M']:<4} {r['us_per_layer']:>8} "
+                      f"us/layer  weight read alone {r['weight_read_us']} "
+                      f"us = {r['read_share_pct']} %")
                 continue
             if r["metric"] == "geometry_sweep":
                 print(f"{r['op']:8} sweep  winner="
