@@ -1,6 +1,6 @@
 """Per-layer cache spec — what each layer of a served model keeps per request.
 
-A served model declares, layer by layer, which of five kinds of state it
+A served model declares, layer by layer, which of six kinds of state it
 holds (``model.cache_spec()``); the allocator's block size, the executor's
 pools, admission, preemption and the snapshot payloads are all derived from
 that one declaration instead of from "every layer owns one K and one V pool":
@@ -13,6 +13,15 @@ that one declaration instead of from "every layer owns one K and one V pool":
   (``layout="head"``: for a number of kv heads that is no sublane multiple,
   which a token-major block would pad in HBM); the model that declares the
   layout is the one that reads and writes the pool.
+- ``latent(width)``: ONE row of ``width`` values a position — the
+  compressed key/value of latent attention (MLA: the normed latent and the
+  one rotated rope key all heads share), after the norm and after the
+  rotation — in blocks of the SAME shared pool, handed out by the same
+  allocator under the same block ids: one pool tensor a layer, ``(block_size,
+  row_width)`` a block, where ``row_width`` is ``width`` rounded up to whole
+  128-lane tiles (what the device's tiled layout holds either way; the pad
+  lanes stay zero and :meth:`CacheSpec.block_bytes` counts them). Admission,
+  preemption, swap and prefix sharing treat its blocks as a ``full`` layer's.
 - ``window(n, kv_heads, head_dim)``: K/V of the last ``n`` positions only, in
   a RING of ``ceil(n / block_size) + 1`` blocks that the slot owns for as
   long as it is occupied (position ``p`` lives in ring block
@@ -36,10 +45,11 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
-__all__ = ["LayerCache", "CacheSpec", "CacheSpecError", "full", "window",
-           "shared", "state", "none", "dense_decoder_spec"]
+__all__ = ["LayerCache", "CacheSpec", "CacheSpecError", "full", "latent",
+           "window", "shared", "state", "none", "dense_decoder_spec"]
 
-KINDS = ("full", "window", "shared", "state", "none")
+KINDS = ("full", "latent", "window", "shared", "state", "none")
+LANES = 128
 
 
 class CacheSpecError(ValueError):
@@ -57,8 +67,17 @@ class LayerCache:
     shapes: Tuple[Tuple[str, Tuple[int, ...], str], ...] = ()   # state
     layout: str = "token"                 # full / window: "token" | "head"
 
-    def block_shape(self, block_size: int) -> Tuple[int, int, int]:
-        """One block of this layer's K (or V) pool."""
+    @property
+    def row_width(self) -> int:
+        """latent: the pool row as the device holds it — the width in whole
+        lane tiles."""
+        return -(-self.head_dim // LANES) * LANES
+
+    def block_shape(self, block_size: int) -> Tuple[int, ...]:
+        """One block of this layer's K (or V) pool; of a latent layer's one
+        pool."""
+        if self.kind == "latent":
+            return block_size, self.row_width
         if self.layout == "head":
             return self.kv_heads, block_size, self.head_dim
         return block_size, self.kv_heads, self.head_dim
@@ -66,6 +85,12 @@ class LayerCache:
 
 def full(kv_heads: int, head_dim: int, layout: str = "token") -> LayerCache:
     return LayerCache("full", int(kv_heads), int(head_dim), layout=layout)
+
+
+def latent(width: int) -> LayerCache:
+    if width < 1:
+        raise ValueError(f"latent width must be >= 1, got {width}")
+    return LayerCache("latent", kv_heads=1, head_dim=int(width))
 
 
 def window(n: int, kv_heads: int, head_dim: int,
@@ -107,6 +132,10 @@ class CacheSpec:
                 raise ValueError(f"layer {i} shares layer {l.source}, which "
                                  f"must be an earlier 'full' layer")
         self.full_layers = self.of_kind("full")
+        self.latent_layers = self.of_kind("latent")
+        # the layers that own blocks of the shared pool, in layer order
+        self.pool_layers = [i for i, l in enumerate(self.layers)
+                            if l.kind in ("full", "latent")]
         self.slot_layers = [i for i, l in enumerate(self.layers)
                             if l.kind in ("window", "state")]
 
@@ -126,13 +155,22 @@ class CacheSpec:
         for l in self.layers:
             if l.kind in ("full", "window"):
                 return l.kv_heads, l.head_dim
+            if l.kind == "latent":
+                return 1, l.row_width
         return 0, 0
 
     # ------------------------------------------------------------------ bytes
+    def latent_block_bytes(self, block_size: int) -> int:
+        """The ``latent`` layers' part of :meth:`block_bytes`: one row of
+        ``row_width`` values a token a layer, as the device holds it."""
+        return sum(block_size * self.layers[i].row_width
+                   * self.dtype.itemsize for i in self.latent_layers)
+
     def block_bytes(self, block_size: int, kv_quant: str = "none") -> int:
         """Bytes of ONE block of the shared pool over all ``full`` layers
-        (K + V; int8 codes carry one f32 scale per block and kv head)."""
-        n = 0
+        (K + V; int8 codes carry one f32 scale per block and kv head) and
+        all ``latent`` layers (one row a token)."""
+        n = self.latent_block_bytes(block_size)
         for i in self.full_layers:
             l = self.layers[i]
             if kv_quant == "int8":

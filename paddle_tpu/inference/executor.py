@@ -39,6 +39,23 @@ from ..jit import functional_call
 __all__ = ["PagedExecutor"]
 
 
+def _has_expert_capacity(model) -> bool:
+    """True where some expert layer of ``model`` fills buckets of a fixed
+    capacity: what a row is dropped from then depends on the rows it is
+    joined with."""
+    return any(getattr(l, "capacity_factor", None) is not None
+               for l in model.sublayers())
+
+
+def _step_stats(model) -> tuple:
+    """The arrays a model's step left for its caller to return beside the
+    step's outputs (``take_step_stats``: per-layer expert loads), as a
+    tuple of zero or one arrays — zero for a model that leaves none."""
+    take = getattr(model.model, "take_step_stats", None)
+    stats = take() if take is not None else None
+    return () if stats is None else (stats,)
+
+
 class PagedExecutor:
     """Owns the paged device state + compiled programs for one engine.
 
@@ -59,11 +76,16 @@ class PagedExecutor:
         cdtype = convert_dtype(cfg.dtype)
         bs = engine.block_size
         kv_quant = engine.kv_quant
-        # the shared block pool: one entry per ``full`` layer of the spec
+        # the shared block pool: K and V per ``full`` layer of the spec,
+        # ONE tensor per ``latent`` layer, in layer order
         self.pools: List[Any] = []
-        for i in spec.full_layers:
+        for i in spec.pool_layers:
             block = spec.layers[i].block_shape(bs)
             kv = spec.layers[i].kv_heads
+            if spec.layers[i].kind == "latent":
+                self.pools.append(jnp.zeros((int(num_blocks),) + block,
+                                            cdtype))
+                continue
             for _kv in range(2):
                 if kv_quant == "int8":
                     # K codes, K scales, V codes, V scales — the scale
@@ -76,9 +98,15 @@ class PagedExecutor:
                 else:
                     self.pools.append(jnp.zeros(
                         (int(num_blocks),) + block, cdtype))
-        # tensors per layer entry in the flat pool list: fp (K, V) = 2;
-        # int8 (Kq, Kscale, Vq, Vscale) = 4
+        # tensors per ``full`` layer entry in the flat pool list: fp (K, V)
+        # = 2; int8 (Kq, Kscale, Vq, Vscale) = 4; a ``latent`` layer has 1
         self.pool_stride = 4 if kv_quant == "int8" else 2
+        self._pool_index: Dict[int, range] = {}
+        at = 0
+        for i in spec.pool_layers:
+            n = 1 if spec.layers[i].kind == "latent" else self.pool_stride
+            self._pool_index[i] = range(at, at + n)
+            at += n
         # what a slot owns for as long as it is occupied: the window
         # layers' rings (slot b = blocks 1 + b*R .. (b+1)*R; block 0 is
         # scratch) and the state layers' slot-indexed arrays
@@ -177,8 +205,9 @@ class PagedExecutor:
         sequence-dim constraint), a speculative server and a
         ``tick_window`` scan have no one-tick plain trip to ride in,
         adapter rows would gather C more copies of the chunk's adapter,
-        routed experts see another capacity when rows are joined, and a
-        model class may not offer the joint step."""
+        routed experts that fill fixed-capacity buckets see another capacity
+        when rows are joined (a dropless expert layer has none, and rides),
+        and a model class may not offer the joint step."""
         engine = self.engine
         if self.spec.has_slot_state:
             return "slot_state"
@@ -190,7 +219,7 @@ class PagedExecutor:
             return "tick_window"
         if engine._lora is not None:
             return "lora"
-        if getattr(engine.cfg, "moe_num_experts", 0) > 0:
+        if _has_expert_capacity(engine.model):
             return "moe"
         if not hasattr(engine.model.model, "paged_decode_chunk_step"):
             return "model"
@@ -237,13 +266,13 @@ class PagedExecutor:
         layer's entry of the flat block-pool list — fp → (K, V); int8 →
         (Kq, Kscale, Vq, Vscale); the model's paged methods branch on the
         tuple arity, so the same compiled-fn bodies serve both pool
-        formats — a ``window`` layer's ring pools or a ``state`` layer's
+        formats — a ``latent`` layer's one pool, a ``window`` layer's ring
+        pools or a ``state`` layer's
         slot arrays out of ``slot_p``, and ``()`` for a layer that owns
         nothing."""
-        st = self.pool_stride
         views = [()] * len(self.spec.layers)
-        for n, i in enumerate(self.spec.full_layers):
-            views[i] = tuple(Tensor(flat_p[st * n + j]) for j in range(st))
+        for i, idx in self._pool_index.items():
+            views[i] = tuple(Tensor(flat_p[j]) for j in idx)
         for i, idx in self._slot_index.items():
             views[i] = tuple(Tensor(slot_p[j]) for j in idx)
         return views
@@ -251,7 +280,7 @@ class PagedExecutor:
     def _flat_pools(self, new):
         """The model's new views, split back into (block pools, slot
         pools) in the order :meth:`_pool_views` read them."""
-        flat = [t.value for i in self.spec.full_layers for t in new[i]]
+        flat = [t.value for i in self.spec.pool_layers for t in new[i]]
         return flat, [t.value for i in self._slot_index for t in new[i]]
 
     # ----------------------------------------------------------- slot state
@@ -351,7 +380,10 @@ class PagedExecutor:
         which rows may touch them. ``active``: int32 (B,), 0 = idle, 1 =
         decodes from ``tokens[b]``, 2 = decodes from ``prev[-1, b]``, where
         ``prev`` (k, B) is the token stack of the trip before, still
-        unread (:meth:`_feed`)."""
+        unread (:meth:`_feed`). Returns the (k, B) token stack, the block
+        pools, the slot pools — and, for a model that leaves step stats
+        (:func:`_step_stats`), those of every tick, stacked, as a fourth
+        output, which the engine reads where it reads the stack."""
         engine = self.engine
         model = engine.model
         lora = self._gather_lora(lora_flat, aidx)
@@ -366,9 +398,9 @@ class PagedExecutor:
                 h, new = model.model.paged_decode_step(Tensor(toks[:, None]),
                                                        pools, tables, p,
                                                        lora=lora, **slot_kw)
-                return engine._head(h), new
+                return engine._head(h), new, _step_stats(model)
 
-            logits, new = functional_call(model, params, call_fn=call)
+            logits, new, stats = functional_call(model, params, call_fn=call)
             flat = self._flat_pools(new)
             lg = logits.value[:, 0].astype(jnp.float32)   # (B, V)
             if greedy:
@@ -378,16 +410,16 @@ class PagedExecutor:
 
                 nxt = sample_token_rows(lg, jax.random.fold_in(key, k),
                                         temps, topks, topps)
-            return (nxt, flat, p + active), nxt
+            return (nxt, flat, p + active), (nxt, stats)
 
         n = engine.tick_window if ticks is None else ticks
         carry = (tokens, (list(flat_pools), list(slot_pools)), pos)
         if n == 1:
-            (_, (flat, slot), _), stack = one_tick(carry, 0)
-            return stack[None], flat, slot
-        (_, (flat, slot), _), stack = jax.lax.scan(one_tick, carry,
-                                                   jnp.arange(n))
-        return stack, flat, slot
+            (_, (flat, slot), _), (stack, stats) = one_tick(carry, 0)
+            return (stack[None], flat, slot, *stats)
+        (_, (flat, slot), _), (stack, stats) = jax.lax.scan(
+            one_tick, carry, jnp.arange(n))
+        return (stack, flat, slot, *(st[:, 0] for st in stats))
 
     def _chunk_prefill_fn(self, params, chunk, flat_pools, table, start,
                           last_idx, aidx=None, lora_flat=(), slot_pools=(),
@@ -435,11 +467,11 @@ class PagedExecutor:
                                                      lora=lora,
                                                      last_idx=last_idx,
                                                      **slot_kw)
-            return engine._head(h), new
+            return engine._head(h), new, _step_stats(model)
 
-        logits, new = functional_call(model, params, call_fn=call)
+        logits, new, stats = functional_call(model, params, call_fn=call)
         return (logits.value[:, 0].astype(jnp.float32),
-                *self._flat_pools(new))
+                *self._flat_pools(new), *stats)
 
     def _decode_chunk_fn(self, params, tokens, flat_pools, tables, pos,
                          temps, topks, topps, active, key, prev, chunk,
@@ -455,7 +487,8 @@ class PagedExecutor:
         row masked (``active`` 0, zeroed ``tables``), as idle rows always
         run — so a server compiles two programs whatever its traffic.
         Returns the trip's (1, B) token stack, the chunk's float32 logits
-        row (1, V), the pools."""
+        row (1, V), the pools (and the model's step stats, if it leaves
+        any, as in :meth:`_decode_paged_fn`)."""
         engine = self.engine
         model = engine.model
         tokens, _ = self._feed(tokens, prev, active)
@@ -465,9 +498,9 @@ class PagedExecutor:
         def call():
             h, new = model.model.paged_decode_chunk_step(
                 Tensor(ids), pools, tables, pos, table, start, last_idx)
-            return engine._head(h), new
+            return engine._head(h), new, _step_stats(model)
 
-        logits, new = functional_call(model, params, call_fn=call)
+        logits, new, stats = functional_call(model, params, call_fn=call)
         lg = logits.value[0].astype(jnp.float32)          # (B + 1, V)
         if greedy:
             nxt = jnp.argmax(lg[:-1], axis=-1).astype(jnp.int32)
@@ -476,7 +509,7 @@ class PagedExecutor:
 
             nxt = sample_token_rows(lg[:-1], jax.random.fold_in(key, 0),
                                     temps, topks, topps)
-        return nxt[None], lg[-1:], self._flat_pools(new)[0]
+        return (nxt[None], lg[-1:], self._flat_pools(new)[0], *stats)
 
     def _spec_verify_fn(self, params, tokens, proposals, flat_pools, tables,
                         pos, temps, topks, topps, kcaps, key, qprobs,

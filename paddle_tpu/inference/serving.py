@@ -88,6 +88,8 @@ class _Trip:
     #                             runs out inside it: not in the next trip
     k: int                      # ticks in it = tokens a row has in flight
     tick: int                   # flight seq of the tick that dispatched it
+    stats: tuple = ()           # step stats of this call and of the chunk
+    #                             calls before it, on the device, unread
 
 
 @dataclass
@@ -314,10 +316,16 @@ class GenerationServer:
 
         self.cache_spec = (model.cache_spec() if hasattr(model, "cache_spec")
                            else dense_decoder_spec(cfg))
-        if self.cache_spec.has_slot_state:
-            # per-slot window rings and recurrent state: features that
-            # would need a state rollback, a quantized ring or a sharded
-            # state are refused here, by name, not found out mid-request
+        # what a spec cannot serve is refused here, by name, not found out
+        # mid-request: per-slot window rings and recurrent state would need
+        # a state rollback, a quantized ring or a sharded state; for one
+        # latent row a position no int8 code pool, no adapter or verify
+        # path and no head axis to shard exist
+        cannot = ("keeps per-slot state (window rings, recurrent state)"
+                  if self.cache_spec.has_slot_state else
+                  "keeps a 'latent' cache (one compressed row a position)"
+                  if self.cache_spec.latent_layers else None)
+        if cannot is not None:
             for what, on in (("cache='dense'", cache != "paged"),
                              ("spec= (speculative decoding)",
                               spec is not None),
@@ -326,8 +334,7 @@ class GenerationServer:
                              ("mesh=", mesh not in (None, 1))):
                 if on:
                     raise CacheSpecError(
-                        f"{type(model).__name__} keeps per-slot state "
-                        f"(window rings, recurrent state): {what} is not "
+                        f"{type(model).__name__} {cannot}: {what} is not "
                         f"supported for it")
         if kv_quant not in ("none", "int8"):
             raise ValueError(
@@ -586,7 +593,8 @@ class GenerationServer:
             "serving_prefill_chunks_alone",
             "prefill chunks that had a program call to themselves (reason "
             "label: no_decoding_row, second_chunk, slot_state, cp, spec, "
-            "tick_window, lora, moe, model)")
+            "tick_window, lora, moe = an expert layer with capacity "
+            "buckets, model)")
         # the same, by cache kind, for a spec that has such layers (per
         # ONE layer of the kind: a reader multiplies by the layer count)
         self._c_dec_ctx_win = reg.counter(
@@ -617,6 +625,23 @@ class GenerationServer:
             "decode row-ticks computed for a request after its eos (the "
             "rest of its window, and the trip dispatched before the host "
             "had read it)")
+        # an expert layer that holds a share of the routed experts
+        # (incubate/.../moe/held_experts.py) returns its per-layer loads
+        # with each program call's outputs; folded where the trip is read
+        self._c_moe_pairs = reg.counter(
+            "serving_moe_pairs",
+            "(token, expert) pairs routed by real rows (held label: 1 = "
+            "the expert lives here and the pair was computed, 0 = it lives "
+            "on another chip and its part is left out)")
+        self._c_moe_active = reg.counter(
+            "serving_moe_experts_active",
+            "held experts that got at least one row, summed over layers "
+            "and program calls")
+        self._c_moe_load_max = reg.counter(
+            "serving_moe_load_max",
+            "rows on the busiest held expert of a layer, summed over "
+            "layers and program calls")
+        self._stats_unread: List[Any] = []
         # decode trips dispatched and not read yet, oldest first: one
         # between steps, two for the moment between a dispatch and the
         # retiring of the trip before
@@ -1542,9 +1567,10 @@ class GenerationServer:
 
     def _chunk_operands(self, ck: _Chunk):
         """(chunk, table, start, last_idx) as the chunk programs take them;
-        ``last_idx`` is the last real prompt token on the final chunk and
-        ignored on earlier ones."""
-        last_idx = (ck.n - 1 - ck.start) if ck.end == ck.n else 0
+        ``last_idx`` is the chunk's last real token (the rows after it are
+        padding): on the final chunk the prompt's last token, whose logits
+        are wanted; the logits of an earlier chunk are ignored."""
+        last_idx = ck.end - 1 - ck.start
         return (jnp.asarray(ck.ids), jnp.asarray(self._bt[ck.slot]),
                 jnp.int32(ck.start), jnp.int32(last_idx))
 
@@ -1563,7 +1589,7 @@ class GenerationServer:
         if self._decode_chunk is None:
             aidx = (jnp.asarray(self.aidx[slot:slot + 1])
                     if self._lora is not None else None)
-            lg, self._pools, self._slot_pools = self._chunk_prefill(
+            lg, self._pools, self._slot_pools, *stats = self._chunk_prefill(
                 self.params, chunk, self._pools, table, start, last_idx,
                 aidx, self._lora_flat(), self._slot_pools,
                 jnp.asarray(np.array([slot, ck.end - ck.start,
@@ -1578,11 +1604,13 @@ class GenerationServer:
             bt, posv, active = self._masked_rows
             temps, topks, topps, _, _ = self._samp_arrays()
             # (the stack of an all-masked trip is nobody's tokens)
-            _, lg, self._pools = self._decode_chunk(
+            _, lg, self._pools, *stats = self._decode_chunk(
                 self.params, jnp.asarray(self.tokens), self._pools, bt, posv,
                 temps, topks, topps, active, self._base_key,
                 self._exec.prev_stack(None, 1), chunk, table, start,
                 last_idx, self._all_greedy(range(self.max_batch)))
+        # (read with the next decode trip's tokens, not now)
+        self._stats_unread += stats
         self._c_pf_alone.inc(reason=why)
         self._chunk_dispatched(ck, _t0, _w0)
         self._chunk_ended(ck, lg)
@@ -2043,16 +2071,17 @@ class GenerationServer:
             posv = self.pos * active_mask
             temps, topks, topps, _, aidx = self._samp_arrays()
             if ck is None:
-                stack, self._pools, self._slot_pools = self._decode_paged(
-                    self.params, jnp.asarray(self.tokens), self._pools,
-                    jnp.asarray(bt), jnp.asarray(posv), temps, topks, topps,
-                    jnp.asarray(feed), key, aidx, self._lora_flat(),
-                    self._all_greedy(active), ticks, self._slot_pools,
-                    self._exec.prev_stack(prev, k))
+                stack, self._pools, self._slot_pools, *stats = \
+                    self._decode_paged(
+                        self.params, jnp.asarray(self.tokens), self._pools,
+                        jnp.asarray(bt), jnp.asarray(posv), temps, topks,
+                        topps, jnp.asarray(feed), key, aidx,
+                        self._lora_flat(), self._all_greedy(active), ticks,
+                        self._slot_pools, self._exec.prev_stack(prev, k))
             else:
                 _t0 = tel.clock() if tel.enabled else 0.0
                 _w0 = self._wall()
-                stack, lg, self._pools = self._decode_chunk(
+                stack, lg, self._pools, *stats = self._decode_chunk(
                     self.params, jnp.asarray(self.tokens), self._pools,
                     jnp.asarray(bt), jnp.asarray(posv), temps, topks, topps,
                     jnp.asarray(feed), key, self._exec.prev_stack(prev, k),
@@ -2068,8 +2097,12 @@ class GenerationServer:
                 if k >= min(req.max_new_tokens - len(req.generated) - flying,
                             self.max_len - 1 - int(self.pos[s])):
                     ends.append(s)
+            # the step stats of this call, and of the chunk calls made
+            # since the trip before, come back with this trip's tokens
             self._trips.append(_Trip(stack, active, active_mask,
-                                     frozenset(ends), k, tick))
+                                     frozenset(ends), k, tick,
+                                     tuple(self._stats_unread) + tuple(stats)))
+            self._stats_unread = []
             self._trip_no += 1
             self.pos = self.pos + active_mask * k
         self._retire_pending(keep=1)
@@ -2110,6 +2143,7 @@ class GenerationServer:
             with tel.phase("decode_wait", self._tick_seq, rows=rows,
                            trip=trip.tick):
                 nxt_host = np.asarray(trip.stack)
+            self._fold_step_stats(trip.stats)
             del self._trips[0]
             if reason is None:
                 self._c_overlapped.inc()
@@ -2117,6 +2151,26 @@ class GenerationServer:
                 self._c_early.inc(reason=reason)
             self._harvest_phase(rows, trip.tick, self._harvest_window,
                                 nxt_host, trip.rows, trip.mask)
+        if keep == 0 and self._stats_unread:
+            # chunk calls that no decode trip followed (a server that only
+            # prefills; the last chunks before a read of the counters)
+            self._fold_step_stats(self._stats_unread)
+            self._stats_unread = []
+
+    def _fold_step_stats(self, stats) -> None:
+        """Add the per-layer expert loads that program calls returned beside
+        their outputs (``(ticks, layers, held + 1)`` int32 a call: the rows
+        each held expert got, and in the last column the pairs routed to
+        experts that live elsewhere) to the ``serving_moe_*`` counters. The
+        arrays are outputs of calls whose tokens the host has just read, or
+        of calls before those: reading them waits for nothing."""
+        for a in stats:
+            a = np.asarray(a)           # graftlint: noqa[host-sync]
+            held = a[..., :-1]
+            self._c_moe_pairs.inc(int(held.sum()), held="1")
+            self._c_moe_pairs.inc(int(a[..., -1].sum()), held="0")
+            self._c_moe_active.inc(int((held > 0).sum()))
+            self._c_moe_load_max.inc(int(held.max(axis=-1).sum()))
 
     # ----------------------------------------------------------- speculative
     def _spec_tick(self, active) -> None:
@@ -2506,22 +2560,28 @@ class GenerationServer:
 
     def cache_bytes(self) -> Dict[str, int]:
         """Bytes in use and allotted, by cache kind of the spec: ``full``
-        in blocks of the shared pool, ``window`` and ``state`` per occupied
-        slot (``serving_cache_bytes{kind=}`` in the registry)."""
+        and ``latent`` in blocks of the shared pool (a latent row as the
+        device holds it, pad lanes included), ``window`` and ``state`` per
+        occupied slot (``serving_cache_bytes{kind=}`` in the registry)."""
         occupied = sum(sl is not None for sl in self._slots)
         per_slot = self.cache_spec.slot_bytes(self.block_size)
-        out = {"cache_bytes_full": (self.alloc.blocks_in_use
-                                    * self.alloc.bytes_per_block),
-               "cache_bytes_full_allotted": ((self.alloc.num_blocks - 1)
-                                             * self.alloc.bytes_per_block),
+        lat = self.cache_spec.latent_block_bytes(self.block_size)
+        full = self.alloc.bytes_per_block - lat
+        usable = self.alloc.num_blocks - 1
+        out = {"cache_bytes_full": self.alloc.blocks_in_use * full,
+               "cache_bytes_full_allotted": usable * full,
                "state_slots": occupied if per_slot["state"] else 0}
+        if lat:
+            out["cache_bytes_latent"] = self.alloc.blocks_in_use * lat
+            out["cache_bytes_latent_allotted"] = usable * lat
         g = self._tel.registry.gauge("serving_cache_bytes")
         for kind in ("window", "state"):
             out[f"cache_bytes_{kind}"] = occupied * per_slot[kind]
             out[f"cache_bytes_{kind}_allotted"] = (self.max_batch
                                                    * per_slot[kind])
-        for kind in ("full", "window", "state"):
-            g.set(float(out[f"cache_bytes_{kind}"]), kind=kind)
+        for kind in ("full", "latent", "window", "state"):
+            if f"cache_bytes_{kind}" in out:
+                g.set(float(out[f"cache_bytes_{kind}"]), kind=kind)
         self._tel.registry.gauge("serving_state_slots").set(
             float(out["state_slots"]))
         return out

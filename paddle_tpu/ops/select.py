@@ -257,6 +257,54 @@ def select_paged_attention(q_shape, pool_shape, *, head_major=False,
     return PALLAS
 
 
+def select_latent_attention(q_shape, pool_shape, v_width: int, *,
+                            platform=None, is_partitioned=None) -> str:
+    """Attention over a paged latent pool (ops/latent_attention.py), decode
+    rows and prompt chunks alike. q (B, W, H, row_width); pool (N, bs,
+    row_width). Pallas when kernels are on and the trace is not partitioned;
+    Mosaic needs the row and its value part in whole lane tiles (the cache
+    spec pads the row; a value width that is no multiple of 128 goes to the
+    jnp composition) and whole sublane tiles of tokens in a block."""
+    backend = _kernel_backend(platform, is_partitioned)
+    if backend is None:
+        return XLA
+    if backend == INTERPRET:
+        return backend
+    _, bs, d = pool_shape
+    if d % 128 or v_width % 128 or bs % 8:
+        return XLA
+    return PALLAS
+
+
+def select_grouped_matmul(x_shape, w_shape, *, platform=None,
+                          is_partitioned=None) -> str:
+    """Grouped matrix product over the held experts that got rows
+    (ops/grouped_matmul.py): x (M, K) sorted by group, w (G, K, N).
+    ``"xla"`` is ``jax.lax.ragged_dot``, which XLA:TPU compiles to a grouped
+    kernel of its own (no dense product over all groups: seen in the
+    compiled text from the sandbox, PR 33); ``"pallas"`` is the megablox
+    kernel, which needs M in whole 128-row tiles and lane-aligned K and N.
+    Chosen by measurement on the v5e at the serving cell's shapes
+    (tools/kernel_bench.py --ops experts; PERF.md, PR 33): see
+    :data:`GROUPED_MATMUL_ON_TPU`."""
+    backend = _kernel_backend(platform, is_partitioned)
+    if backend != PALLAS or GROUPED_MATMUL_ON_TPU == XLA:
+        return XLA
+    m, k = x_shape
+    n = w_shape[2]
+    if m % 128 or k % 128 or n % 128:
+        return XLA
+    return PALLAS
+
+
+# the measured winner of the two grouped products on the v5e: the held
+# experts' part of a layer at 128 / 384 rows (512 / 1536 pairs, 32 experts of
+# 4096 x 2048) takes 2.17 / 2.56 ms with megablox — 86 / 77 % of the time the
+# read of the active experts' matrices alone takes — and 5.11 / 5.27 ms with
+# ragged_dot (my chip run, PR 33, tools/expert_bench.py)
+GROUPED_MATMUL_ON_TPU = PALLAS
+
+
 def select_selective_scan(h_shape, tokens: int = 1, *, platform=None,
                           is_partitioned=None) -> str:
     """Selective-scan state update (ops/selective_scan.py): the one-step

@@ -133,6 +133,26 @@ def _ssm_case(rows, tokens, d=5120, S=16):
              ((tokens, S), f), ((d,), f), ((S, d), f)])
 
 
+def _latent_case(B, W, N=8501, bs=64, M=196, H=32, D=384, Dv=256):
+    """Attention over a paged latent pool at the Mistral-Small-4 cell's
+    shapes: 128 slots, 12,288-token tables (+ a chunk of slack) in blocks of
+    64, rows of 256 + 64 values in three lane tiles, 32 heads."""
+    from paddle_tpu.ops import latent_attention_pallas as lk
+
+    return (lambda q, pool, t, p: lk.latent_attention(
+                q, pool, t, p, v_width=Dv, scale=0.1949, qscale=(0.1, 8192)),
+            [((B, W, H, D), _BF16), ((N, bs, D), _BF16), ((B, M), jnp.int32),
+             ((B,), jnp.int32)])
+
+
+def _gmm_case(rows, impl, E=32, K=4096, N=2048):
+    """The grouped product over the held experts, both implementations."""
+    from paddle_tpu.ops.grouped_matmul import grouped_matmul
+
+    return (lambda x, w, g: grouped_matmul(x, w, g, impl=impl),
+            [((rows, K), _BF16), ((E, K, N), _BF16), ((E,), jnp.int32)])
+
+
 _CELL = dict(N=4096, bs=16, M=256)
 # Phi-4-mini-flash's cell: 128 slots, 8192-token tables, 10 packed kv heads
 # (no sublane multiple: head-major blocks)
@@ -198,6 +218,14 @@ def kernel_cases():
         ("paged.phi_pair_last_token_B1",
          lambda: _paged_case(1, 1, False, **_PHI)),
         ("paged.phi_window_decode_B128_ring33", lambda: _window_case(128)),
+        ("latent.decode_B128_M196", lambda: _latent_case(128, 1)),
+        ("latent.chunk_C256_M196", lambda: _latent_case(1, 256)),
+        ("latent.decode_B128_bs16_M784",
+         lambda: _latent_case(128, 1, N=40001, bs=16, M=784)),
+        ("experts.ragged_dot_512_pairs", lambda: _gmm_case(512, "xla")),
+        ("experts.ragged_dot_1536_pairs", lambda: _gmm_case(1536, "xla")),
+        ("experts.megablox_512_pairs", lambda: _gmm_case(512, "pallas")),
+        ("experts.megablox_1536_pairs", lambda: _gmm_case(1536, "pallas")),
         ("ssm.step_B128_5120x16", lambda: _ssm_case(128, 1)),
         ("ssm.chunk_scan_C128_5120x16", lambda: _ssm_case(1, 128)),
         ("lora.decode_4096x4096", lambda: _lora_case(8, 1, HID8, HID8)),
@@ -272,6 +300,24 @@ def copies_no_pool(text, texts):
     if n or not pools:
         raise RuntimeError(f"{n} whole-pool copies (pools: {sorted(pools)})")
     return " pool_copies=0"
+
+
+def copies_no_stack(*shapes):
+    """A case's check: nothing in the compiled program produces a second
+    array of an expert stack's shape (``shapes``: HLO shape texts) — no
+    copy, transpose, convert or fusion of a whole [held, in, out] stack: the
+    grouped products read the weights where they lie."""
+    def check(text, texts):
+        hits = []
+        for shape in shapes:
+            hits += re.findall(
+                r"= " + re.escape(shape) + r"\S* (?!parameter|get-tuple-"
+                r"element|bitcast)[\w\-]+\(", text)
+        if hits:
+            raise RuntimeError(f"{len(hits)} whole expert stacks made anew: "
+                               f"{sorted(set(hits))[:4]}")
+        return " stack_copies=0"
+    return check
 
 
 # ------------------------------------------------------------ program cases
@@ -427,7 +473,33 @@ def program_cases(devs):
             return built["phi"][i]
         return build
 
+    def mistral4(i):
+        """Mistral-Small-4's share as its benchmark cell serves it
+        (benchmarks/configs/mistral-small-4-119b-l6-ep4.json): 6 layers at
+        the published widths, experts 0-31 of 128, a quarter of the
+        vocabulary, 128 slots of 12,288, blocks of 64, chunks of 256, the
+        640k-token latent pool. ~16 GB of host RAM."""
+        def build():
+            if "mistral4" not in built:
+                from paddle_tpu.models.mistral4 import (Mistral4Config,
+                                                        Mistral4ForCausalLM)
+
+                built["mistral4"] = _serve_programs(
+                    devs, Mistral4Config(
+                        num_hidden_layers=6, vocab_size=32768,
+                        experts_held=(0, 32), max_position_embeddings=12288),
+                    1, model_cls=Mistral4ForCausalLM, max_batch=128,
+                    max_len=12288, block_size=64, prefill_chunk=256,
+                    num_blocks=8501)
+            return built["mistral4"][i]
+        return build
+
+    stacks = copies_no_stack("bf16[32,4096,2048]", "bf16[32,2048,4096]")
     return [
+        ("serve.mistral4_l6_decode_B128", mistral4(0), copies_no_pool,
+         stacks),
+        ("serve.mistral4_l6_decode_chunk", mistral4(1), copies_no_pool,
+         stacks, reads_weights_like("serve.mistral4_l6_decode_B128")),
         ("train.1chip_509m_B4_S2048", lambda: _train_program(
             devs, (1,), ("data",), proxy, 4, 2048)),
         ("train.2x2_fsdp_8b_width_L2", lambda: _train_program(

@@ -1,0 +1,205 @@
+"""Microbenchmark of the two ops of the latent-attention + held-experts
+serving cell, at that cell's shapes (benchmarks/configs/
+mistral-small-4-119b-l6-ep4.json), on the chip:
+
+    chiprun -- python3 tools/expert_bench.py [--ops experts,latent]
+        [--rows 128,384] [--layers 6] [--iters 20]
+
+``experts``: the held experts' part of a layer — sort the (token, expert)
+pairs, three grouped products over the 32 held experts' ``[32, 4096, 2048]``
+stacks, weight and un-sort — for ``--rows`` rows routed top-4 over 128
+experts by a seeded router (about a quarter of the pairs are held), with the
+grouped product as ``jax.lax.ragged_dot`` (``xla``) and as the megablox
+kernel (``pallas``): ``--layers`` layers of DISTINCT weights in one program
+(one dispatch, and no layer finds its weights in a cache), microseconds a
+layer beside the time the read of the active experts' matrices alone takes at
+the bandwidth peak. This is the reading ``ops/select.py``'s
+``GROUPED_MATMUL_ON_TPU`` was set from (PERF.md, PR 33).
+
+``latent``: decode attention over the paged latent pool, 128 rows at
+lognormal contexts (median 3,072, as the cell's window sees them), 6
+dependent calls in one program; microseconds a call and the share of the
+memory roofline (640 bytes a position, as ``benchmarks/roofline/
+latent_attention.py`` counts them), at each ``--group-tokens`` and
+``--block-sizes`` (the pool's block: one DMA descriptor a block).
+
+One JSON line per row on standard output.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+
+H, F, E, HELD, K = 4096, 2048, 128, 32, 4
+
+
+def _timed(fn, args, iters):
+    import jax
+
+    out = fn(*args)
+    jax.block_until_ready(out)
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / iters, out
+
+
+def experts(rows_list, layers, iters, peaks):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.incubate.distributed.models.moe.held_experts import (
+        held_expert_sum, route)
+    from paddle_tpu.ops import select
+
+    key = jax.random.key(0)
+    bf = jnp.bfloat16
+    make = jax.jit(lambda k, shape: (jax.random.normal(k, shape, jnp.float32)
+                                     * 0.02).astype(bf), static_argnums=1)
+    ws = [tuple(make(jax.random.fold_in(key, 10 * i + j), s)
+                for j, s in enumerate(((HELD, H, F), (HELD, H, F),
+                                       (HELD, F, H))))
+          for i in range(layers)]
+    router = make(jax.random.fold_in(key, 999), (H, E))
+    bias = jnp.zeros((E,), bf)
+    for rows in rows_list:
+        x = make(jax.random.fold_in(key, rows), (rows, H)) * 50
+        outs = {}
+        for impl in ("xla", "pallas"):
+            select.GROUPED_MATMUL_ON_TPU = impl
+
+            def prog(x, ws, router):
+                y, counts = x, []
+                for wg, wu, wd in ws:
+                    idx, w = route(y, router, bias, K, True, 1.0)
+                    part, c = held_expert_sum(y, idx, w, wg, wu, wd, 0)
+                    # the next layer's input depends on this one's output
+                    # (normed, as a layer's input is)
+                    y = x + part
+                    y = (y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True)
+                                           )).astype(bf)
+                    counts.append(c)
+                return y, jnp.stack(counts)
+
+            s, (y, counts) = _timed(jax.jit(prog), (x, ws, router), iters)
+            counts = np.asarray(counts)
+            active = int((counts[:, :HELD] > 0).sum())
+            held = int(counts[:, :HELD].sum())
+            read_us = active * 3 * H * F * 2 / peaks["hbm_bytes_per_s"] \
+                / layers * 1e6
+            outs[impl] = np.asarray(y, np.float32)
+            # the first layer alone against a dense product, expert by expert
+            idx, w = route(x, router, bias, K, True, 1.0)
+            part, _ = jax.jit(held_expert_sum, static_argnums=6)(
+                x, idx, w, *ws[0], 0)
+            want = jnp.zeros((rows, H), jnp.float32)
+            for e in range(HELD):
+                col = jnp.sum(jnp.where(idx == e, w, 0.0), axis=-1)
+                h1 = (jax.nn.silu(x @ ws[0][0][e]) * (x @ ws[0][1][e]))
+                want = want + col[:, None] * (h1 @ ws[0][2][e]).astype(
+                    jnp.float32)
+            part, want = np.asarray(part), np.asarray(want)
+            print(json.dumps({
+                "op": "experts_check", "impl": impl, "rows": rows,
+                "finite": bool(np.isfinite(part).all()),
+                "max_abs_diff_vs_dense": float(np.nanmax(np.abs(part - want))),
+                "max_abs": float(np.abs(want).max())}), flush=True)
+            print(json.dumps({
+                "op": "experts", "impl": impl, "rows": rows, "pairs": rows * K,
+                "pairs_held_per_layer": held / layers,
+                "experts_active_per_layer": active / layers,
+                "load_max_per_layer": float(counts[:, :HELD].max(1).mean()),
+                "us_per_layer": round(s / layers * 1e6, 1),
+                "weight_read_us": round(read_us, 1),
+                "read_share_pct": round(100 * read_us / (s / layers * 1e6),
+                                        1)}), flush=True)
+        print(json.dumps({"op": "experts", "rows": rows, "max_abs_diff":
+                          float(np.abs(outs["xla"] - outs["pallas"]).max()),
+                          "max_abs": float(np.abs(outs["xla"]).max())}),
+              flush=True)
+
+
+def latent(groups, iters, peaks, bs=16, B=128, tokens=640016, heads=32,
+           D=384, Dv=256, calls=6):
+    M, N = (12288 + 256) // bs, tokens // bs
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops import latent_attention_pallas as lk
+
+    rng = np.random.default_rng(0)
+    ctx = np.clip(rng.lognormal(np.log(3072), 0.6, B), 300, 12000).astype(
+        np.int32)
+    tables = np.zeros((B, M), np.int32)
+    nxt = 1
+    for b in range(B):
+        n = -(-int(ctx[b] + 1) // bs)
+        tables[b, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    assert nxt <= N, nxt
+    key = jax.random.key(1)
+    pool = jax.jit(lambda k: jax.random.normal(k, (N, bs, D), jnp.bfloat16))(
+        key)
+    q = jax.random.normal(jax.random.fold_in(key, 1), (B, 1, heads, D),
+                          jnp.bfloat16) * 0.3
+    tables, pos = jnp.asarray(tables), jnp.asarray(ctx)
+    nbytes = float(ctx.sum() + B) * 640
+    for g in groups:
+        lk._GROUP_TOKENS = g
+
+        def prog(q, pool, tables, pos):
+            out = q
+            for _ in range(calls):
+                o = lk.latent_attention(out, pool, tables, pos, v_width=Dv,
+                                        scale=0.195, qscale=(0.1, 8192))
+                out = jnp.pad(o, ((0, 0),) * 3 + ((0, D - Dv),))
+            return out
+
+        s, _ = _timed(jax.jit(prog), (q, pool, tables, pos), iters)
+        us = s / calls * 1e6
+        print(json.dumps({
+            "op": "latent_decode", "B": B, "block_size": bs,
+            "group_tokens": g,
+            "positions": int(ctx.sum() + B), "us_per_call": round(us, 1),
+            "roofline_pct": round(
+                100 * nbytes / peaks["hbm_bytes_per_s"] / (us * 1e-6), 1)}),
+            flush=True)
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--ops", default="experts,latent")
+    ap.add_argument("--rows", default="128,384")
+    ap.add_argument("--layers", type=int, default=6)
+    ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--group-tokens", default="1024,2048")
+    ap.add_argument("--block-sizes", default="16,64")
+    args = ap.parse_args()
+    import jax
+
+    kind = jax.devices()[0].device_kind
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "peaks.json")) as f:
+        table = json.load(f)
+    if kind not in table:
+        raise SystemExit(f"no peaks for device_kind {kind!r}: this tool "
+                         f"measures on the chip")
+    print(json.dumps({"device_kind": kind}), flush=True)
+    ops = args.ops.split(",")
+    if "experts" in ops:
+        experts([int(r) for r in args.rows.split(",")], args.layers,
+                args.iters, table[kind])
+    if "latent" in ops:
+        for bs in (int(b) for b in args.block_sizes.split(",")):
+            latent([int(g) for g in args.group_tokens.split(",")],
+                   args.iters, table[kind], bs=bs)
+
+
+if __name__ == "__main__":
+    main()
