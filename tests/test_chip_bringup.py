@@ -303,7 +303,7 @@ def test_selected_kernels_compile_for_the_v5e_target():
     # stay with the full `tools/compile_check.py` run
     out = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "compile_check.py"),
-         "--only", "flash.gqa,flash.head_dim,norm,paged,ssm,lora,w8"],
+         "--only", "flash.gqa,flash.head_dim,norm,paged,ssm,lora,w8,latent"],
         env=_clean_env(), capture_output=True, text=True, timeout=600)
     tail = out.stdout[-3000:] + out.stderr[-2000:]
     assert out.returncode == 0, tail

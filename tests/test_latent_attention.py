@@ -57,12 +57,70 @@ def test_kernel_matches_the_composition(interpret, name, B, W, H, pos):
         "latent_attention": {"pallas-interpret": 1}}
 
 
+@pytest.fixture
+def groups_of_32(monkeypatch):
+    """Groups of 4 blocks of 8 tokens, so that tables of 20 blocks hold up
+    to 5 groups a row and the copy stream crosses rows mid-slot-parity."""
+    monkeypatch.setattr(lk, "_GROUP_TOKENS", 32)
+
+
+# every boundary of the one copy stream over a call's (row, tile, group)s
+_STREAM_CASES = [
+    ("one-block-beside-M-blocks", 4, 1, 4, [3, 159, 0, 150]),
+    ("M-blocks-beside-one-block", 4, 1, 4, [159, 2, 158, 7]),
+    ("rows-end-on-a-group-boundary", 4, 1, 4, [31, 63, 32, 127]),
+    ("every-row-at-pos-0", 3, 1, 4, [0, 0, 0]),
+    ("one-row-of-five-groups", 1, 1, 4, [159]),
+    ("one-row-at-pos-0", 1, 1, 4, [0]),
+    ("odd-then-even-group-counts", 5, 1, 4, [40, 100, 70, 20, 130]),
+    ("long-first-and-last-row", 3, 1, 4, [140, 5, 155]),
+    ("chunk-tiles-straddle-a-group", 1, 128, 4, [24]),
+    ("chunk-tiles-end-on-a-group", 1, 128, 4, [32]),
+    ("chunks-of-two-rows", 2, 128, 4, [8, 30]),
+]
+
+
+@pytest.mark.parametrize("name,B,W,H,pos", _STREAM_CASES)
+def test_stream_crosses_rows_tiles_and_groups(interpret, groups_of_32, name,
+                                              B, W, H, pos):
+    q, pool, tables, posv = _case(B, W, H, 24, 16, 8, 20, pos, seed=3)
+    want = la._reference(q, pool, tables, posv, 16, 0.31, (0.1, 16))
+    got = lk.latent_attention(q, pool, tables, posv, v_width=16, scale=0.31,
+                              qscale=(0.1, 16))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+
+
 def test_blocks_past_the_frontier_are_never_read(interpret):
     q, pool, tables, posv = _case(2, 1, 4, 24, 16, 8, 6, [9, 3])
     pool = pool.at[0].set(jnp.nan)                 # the scratch block
     tables = tables.at[:, 2:].set(0)               # past both frontiers
     got = lk.latent_attention(q, pool, tables, posv, v_width=16, scale=0.3)
     assert np.isfinite(np.asarray(got)).all()
+
+
+@pytest.mark.parametrize("name,B,W,H,pos", _STREAM_CASES)
+def test_the_row_ahead_reads_its_own_live_blocks_only(
+        interpret, groups_of_32, name, B, W, H, pos):
+    """A program fetches the NEXT program's first group: every block no live
+    table entry names is poison, every dead entry names poison, and the
+    output is what the clean pool gives, bit for bit."""
+    q, pool, tables, posv = _case(B, W, H, 24, 16, 8, 20, pos, seed=4)
+    kw = dict(v_width=16, scale=0.31, qscale=(0.1, 16))
+    clean = lk.latent_attention(q, pool, tables, posv, **kw)
+    tables, live = np.array(tables), np.zeros(pool.shape[0], bool)
+    nlive = [(pos[b] + W - 1) // 8 + 1 for b in range(B)]
+    for b in range(B):
+        live[tables[b, :nlive[b]]] = True
+    dead = np.flatnonzero(~live)                   # the scratch block too
+    for b in range(B):
+        tables[b, nlive[b]:] = dead[(7 * b + np.arange(20 - nlive[b]))
+                                    % len(dead)]
+        assert not live[tables[b, nlive[b]:]].any()
+    pool = jnp.where(jnp.asarray(live)[:, None, None], pool, jnp.nan)
+    got = lk.latent_attention(q, pool, jnp.asarray(tables), posv, **kw)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
 
 
 def test_writes_land_where_the_tables_say():

@@ -418,6 +418,63 @@ def _scan_inputs(lead, seed, d=5120, S=16):
     return x, dt, A, Bm, Cm, f(d)
 
 
+def _latent_cell_setup(B, W, seed):
+    """The Mistral-Small-4 cell's shapes (benchmarks/configs): 128 slots,
+    12,288-token tables in blocks of 64, rows of 256 + 64 values in three
+    lane tiles, 32 heads. Ragged decode rows at the cell's contexts
+    (1.6k-3.2k) beside the edges of the copy stream: position 0, either side
+    of a block and of a 1,024-token group, one block beside the longest row,
+    the first and the last row of the call. Every block no live entry names,
+    and every dead table entry, is NaN."""
+    from paddle_tpu.ops import latent_attention as la
+
+    rng = np.random.RandomState(seed)
+    bs, M, H, D, Dv = 64, 196, 32, 384, 256
+    if B == 1:
+        pos = np.array([2048 + 40], np.int32)       # a chunk mid-block
+    else:
+        edges = [0, 63, 64, 1023, 1024, 1025, 2047, 2048, 12287, 5]
+        pos = np.concatenate([
+            edges[:5], rng.randint(1600, 3200, B - len(edges)),
+            edges[5:]]).astype(np.int32)
+    nlive = (pos + W - 1) // bs + 1
+    N = int(nlive.sum()) + 2
+    dead = N - 1
+    tables = np.full((B, M), dead, np.int32)
+    free, took_ = rng.permutation(np.arange(1, dead)), 0
+    for b in range(B):
+        tables[b, :nlive[b]] = free[took_:took_ + nlive[b]]
+        took_ += nlive[b]
+    pool = rng.randn(N, bs, D).astype("float32")
+    pool[..., 320:] = 0.0
+    pool[[0, dead]] = 0.0
+    q = rng.randn(B, W, H, D).astype("float32") * 0.3
+    q[..., 320:] = 0.0
+    q, pool = jnp.asarray(q, jnp.bfloat16), jnp.asarray(pool, jnp.bfloat16)
+    tables, pos = jnp.asarray(tables), jnp.asarray(pos)
+    want = jax.jit(lambda q_, p_: la._reference(
+        q_.astype(jnp.float32), p_.astype(jnp.float32), tables, pos, Dv,
+        0.1949, (0.1, 8192)))(q, pool)
+    return q, pool.at[0].set(jnp.nan).at[dead].set(jnp.nan), tables, pos, want
+
+
+@pytest.mark.parametrize("B,W", [(128, 1), (1, 256)],
+                         ids=["decode_B128_ragged", "chunk_C256"])
+def test_latent_attention_at_the_serving_cells_shapes(B, W):
+    """One copy stream over all the rows of a call (PR 34): the compiled
+    kernel against the jnp composition in float32."""
+    from paddle_tpu.ops import latent_attention as la
+
+    q, pool, tables, pos, want = _latent_cell_setup(B, W, 11 + W)
+    got = jax.jit(lambda *a: la.latent_attention(
+        *a, v_width=256, scale=0.1949, qscale=(0.1, 8192)))(
+            q, pool, tables, pos)
+    took("latent_attention")
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all()          # no dead block was ever read
+    np.testing.assert_allclose(got, np.asarray(want), rtol=3e-2, atol=3e-2)
+
+
 def test_ssm_step_kernel_at_the_published_widths():
     """One token for 128 slots, d_inner 5120 x d_state 16, float32 state;
     a masked row (dt 0) keeps its state bit for bit."""

@@ -3,7 +3,8 @@ serving cell, at that cell's shapes (benchmarks/configs/
 mistral-small-4-119b-l6-ep4.json), on the chip:
 
     chiprun -- python3 tools/expert_bench.py [--ops experts,latent]
-        [--rows 128,384] [--layers 6] [--iters 20]
+        [--rows 128,384] [--layers 6] [--iters 20] [--contexts 1600,2600]
+        [--variants as_is,copies_only,compute_only]
 
 ``experts``: the held experts' part of a layer — sort the (token, expert)
 pairs, three grouped products over the 32 held experts' ``[32, 4096, 2048]``
@@ -17,11 +18,15 @@ the bandwidth peak. This is the reading ``ops/select.py``'s
 ``GROUPED_MATMUL_ON_TPU`` was set from (PERF.md, PR 33).
 
 ``latent``: decode attention over the paged latent pool, 128 rows at
-lognormal contexts (median 3,072, as the cell's window sees them), 6
-dependent calls in one program; microseconds a call and the share of the
-memory roofline (640 bytes a position, as ``benchmarks/roofline/
-latent_attention.py`` counts them), at each ``--group-tokens`` and
-``--block-sizes`` (the pool's block: one DMA descriptor a block).
+lognormal contexts (sigma 0.6, scaled to each mean of ``--contexts``; the
+cell's window sees 1.6k -> 3.2k, mean 2.6k), 6 dependent calls in one program;
+microseconds a call and a row and the share of the memory roofline (640 bytes
+a position, as ``benchmarks/roofline/latent_attention.py`` counts them), at
+each ``--group-tokens`` and ``--block-sizes`` (the pool's block: one DMA
+descriptor a block), in each of ``--variants``: the kernel ``as_is``, its
+``copies_only`` (no matmul, no softmax) and its ``compute_only`` (no copy
+started or waited for) — made HERE, by swapping the kernel module's two
+helpers while it is traced; nothing serves them (PERF.md, PR 34).
 
 One JSON line per row on standard output.
 """
@@ -32,6 +37,7 @@ import json
 import os
 import sys
 import time
+from unittest import mock
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
@@ -125,51 +131,71 @@ def experts(rows_list, layers, iters, peaks):
               flush=True)
 
 
-def latent(groups, iters, peaks, bs=16, B=128, tokens=640016, heads=32,
-           D=384, Dv=256, calls=6):
+# the three readings of the latent kernel: as it is; its copies alone (the
+# group update patched out: no matmul, no softmax); its arithmetic alone (no
+# copy is started or waited for: the tile is whatever the slot holds)
+LATENT_VARIANTS = {
+    "as_is": {},
+    "copies_only": {"_attend_group": lambda *a, **k: None},
+    "compute_only": {"_dma": lambda *a, **k: None},
+}
+
+
+def latent(groups, contexts, variants, iters, peaks, bs=16, B=128,
+           tokens=640016, heads=32, D=384, Dv=256, calls=6):
     M, N = (12288 + 256) // bs, tokens // bs
     import jax
     import jax.numpy as jnp
     import numpy as np
     from paddle_tpu.ops import latent_attention_pallas as lk
 
-    rng = np.random.default_rng(0)
-    ctx = np.clip(rng.lognormal(np.log(3072), 0.6, B), 300, 12000).astype(
-        np.int32)
-    tables = np.zeros((B, M), np.int32)
-    nxt = 1
-    for b in range(B):
-        n = -(-int(ctx[b] + 1) // bs)
-        tables[b, :n] = np.arange(nxt, nxt + n)
-        nxt += n
-    assert nxt <= N, nxt
+    # one lognormal sample of B contexts (sigma 0.6, as the cell's rows are
+    # spread), scaled to each asked mean; unscaled its mean is ~3.7k
+    base = np.clip(np.random.default_rng(0).lognormal(np.log(3072), 0.6, B),
+                   300, 12000)
     key = jax.random.key(1)
     pool = jax.jit(lambda k: jax.random.normal(k, (N, bs, D), jnp.bfloat16))(
         key)
     q = jax.random.normal(jax.random.fold_in(key, 1), (B, 1, heads, D),
                           jnp.bfloat16) * 0.3
-    tables, pos = jnp.asarray(tables), jnp.asarray(ctx)
-    nbytes = float(ctx.sum() + B) * 640
-    for g in groups:
-        lk._GROUP_TOKENS = g
+    for mean in contexts:
+        ctx = np.clip(base * (mean / base.mean()), 64, 12000).astype(np.int32)
+        tables = np.zeros((B, M), np.int32)
+        nxt = 1
+        for b in range(B):
+            n = -(-int(ctx[b] + 1) // bs)
+            tables[b, :n] = np.arange(nxt, nxt + n)
+            nxt += n
+        assert nxt <= N, nxt
+        tbl, pos = jnp.asarray(tables), jnp.asarray(ctx)
+        nbytes = float(ctx.sum() + B) * 640
+        for g in groups:
+            for variant in variants:
+                def prog(q, pool, tables, pos):
+                    out = q
+                    for _ in range(calls):
+                        o = lk.latent_attention(
+                            out, pool, tables, pos, v_width=Dv, scale=0.195,
+                            qscale=(0.1, 8192))
+                        out = jnp.pad(o, ((0, 0),) * 3 + ((0, D - Dv),))
+                    return out
 
-        def prog(q, pool, tables, pos):
-            out = q
-            for _ in range(calls):
-                o = lk.latent_attention(out, pool, tables, pos, v_width=Dv,
-                                        scale=0.195, qscale=(0.1, 8192))
-                out = jnp.pad(o, ((0, 0),) * 3 + ((0, D - Dv),))
-            return out
-
-        s, _ = _timed(jax.jit(prog), (q, pool, tables, pos), iters)
-        us = s / calls * 1e6
-        print(json.dumps({
-            "op": "latent_decode", "B": B, "block_size": bs,
-            "group_tokens": g,
-            "positions": int(ctx.sum() + B), "us_per_call": round(us, 1),
-            "roofline_pct": round(
-                100 * nbytes / peaks["hbm_bytes_per_s"] / (us * 1e-6), 1)}),
-            flush=True)
+                # the kernel's own jit would hand back another variant's trace
+                jax.clear_caches()
+                with mock.patch.multiple(lk, _GROUP_TOKENS=g,
+                                         **LATENT_VARIANTS[variant]):
+                    s, _ = _timed(jax.jit(prog), (q, pool, tbl, pos), iters)
+                us = s / calls * 1e6
+                print(json.dumps({
+                    "op": "latent_decode", "variant": variant, "B": B,
+                    "block_size": bs, "group_tokens": g,
+                    "context_mean": round(float(ctx.mean()), 1),
+                    "positions": int(ctx.sum() + B),
+                    "us_per_call": round(us, 1),
+                    "us_per_row": round(us / B, 3),
+                    "roofline_pct": round(
+                        100 * nbytes / peaks["hbm_bytes_per_s"] / (us * 1e-6),
+                        1)}), flush=True)
 
 
 def main():
@@ -180,6 +206,10 @@ def main():
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--group-tokens", default="1024,2048")
     ap.add_argument("--block-sizes", default="16,64")
+    ap.add_argument("--contexts", default="3700",
+                    help="mean live context of the 128 decode rows")
+    ap.add_argument("--variants", default="as_is",
+                    help="of " + ",".join(LATENT_VARIANTS))
     args = ap.parse_args()
     import jax
 
@@ -198,7 +228,8 @@ def main():
     if "latent" in ops:
         for bs in (int(b) for b in args.block_sizes.split(",")):
             latent([int(g) for g in args.group_tokens.split(",")],
-                   args.iters, table[kind], bs=bs)
+                   [int(c) for c in args.contexts.split(",")],
+                   args.variants.split(","), args.iters, table[kind], bs=bs)
 
 
 if __name__ == "__main__":
