@@ -12,9 +12,15 @@ that stands in for one.
 Beside :class:`~.moe_layer.MoELayer` and unlike it: **no capacity and no
 dropped token** (``capacity_factor`` is None, which is also what the serving
 executor asks before it joins rows of two requests in one call), the expert
-width is its own number, the experts are SwiGLU, and routing is DeepSeek-V3's
-— sigmoid scores in float32, the ``top_k`` of score + a per-expert selection
-bias, weights = the chosen scores over their sum, times a scaling factor.
+width is its own number (and the shared experts', a Layer it is handed, their
+own), the experts are SwiGLU, and **the routing rule is something the layer
+is given**: a function of the router's float32 logits and ``top_k`` (and of
+the layer's per-expert selection bias, where the rule has one) that returns
+the chosen experts and their weights. Two rules are here:
+:func:`deepseek_v3_rule` — sigmoid scores, the ``top_k`` of score + bias,
+weights = the chosen scores over their sum, times a scaling factor — and
+:func:`softmax_of_chosen` — the ``top_k`` largest logits, weights = softmax
+over those logits only.
 
 The product: the (token, expert) pairs are sorted by expert with the pairs
 of absent experts (and of rows that are padding) last; the held experts'
@@ -28,7 +34,7 @@ elsewhere) for the caller's counters.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -55,16 +61,29 @@ def _normal(std):
     return init
 
 
-def route(h, router_w, router_b, top_k: int, norm_topk: bool, scale: float):
-    """h (T, d) -> (chosen expert ids (T, k) int32, their weights (T, k)
-    float32) over the router's whole width; scores in float32."""
-    g = jnp.matmul(h, router_w, preferred_element_type=jnp.float32)
-    s = jax.nn.sigmoid(g.astype(jnp.float32))
-    _, idx = jax.lax.top_k(s + router_b.astype(jnp.float32), top_k)
-    w = jnp.take_along_axis(s, idx, axis=-1)
-    if norm_topk:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
-    return idx.astype(jnp.int32), w * scale
+def deepseek_v3_rule(norm_topk: bool = True, scale: float = 1.0):
+    """DeepSeek-V3's rule (arXiv:2412.19437): scores = sigmoid(logits); the
+    ``top_k`` of score + the selection bias are chosen; weights = the chosen
+    scores (over their sum, with ``norm_topk``) times ``scale``."""
+    def rule(logits, top_k: int, bias):
+        s = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        if norm_topk:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        return idx.astype(jnp.int32), w * scale
+
+    return rule
+
+
+def softmax_of_chosen(logits, top_k: int, bias=None):
+    """The ``top_k`` largest logits are chosen; weights = softmax over those
+    ``top_k`` logits only (the granitemoe family's rule). No selection
+    bias."""
+    if bias is not None:
+        raise ValueError("softmax_of_chosen takes no selection bias")
+    top, idx = jax.lax.top_k(logits, top_k)
+    return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
 
 
 def held_expert_sum(h, idx, w, w_gate, w_up, w_down, lo: int, valid=None):
@@ -100,17 +119,23 @@ class HeldExpertsLayer(Layer):
     half-open range whose weights live here (default: all of them);
     ``d_hidden`` one expert's width; ``shared`` the shared experts as one
     Layer from a (T, d) Tensor to a (T, d) Tensor (a SwiGLU of width
-    ``n_shared_experts * d_hidden``), or None; ``dtype`` the type the expert
-    stacks are drawn in (None: the layer's default, float32)."""
+    their own width), or None; ``rule`` the routing rule, ``(float32 logits
+    (T, n_routed_experts), top_k, selection bias) -> (chosen ids (T, k)
+    int32, weights (T, k) float32)`` (default: :func:`deepseek_v3_rule`);
+    ``selection_bias`` whether the layer holds a per-expert bias for the
+    rule (``router_bias``; a rule without one is handed None); ``dtype`` the
+    type the expert stacks are drawn in (None: the layer's default,
+    float32)."""
 
     # dropless: there is no capacity (the serving executor keys on this)
     capacity_factor = None
 
     def __init__(self, d_model: int, d_hidden: int, n_routed_experts: int,
                  top_k: int, experts_held: Optional[Tuple[int, int]] = None,
-                 shared: Optional[Layer] = None, norm_topk_prob: bool = True,
-                 routed_scaling_factor: float = 1.0, init_std: float = 0.02,
-                 dtype=None):
+                 shared: Optional[Layer] = None,
+                 rule: Optional[Callable] = None,
+                 selection_bias: Optional[bool] = None,
+                 init_std: float = 0.02, dtype=None):
         super().__init__()
         lo, hi = experts_held or (0, n_routed_experts)
         if not 0 <= lo < hi <= n_routed_experts:
@@ -120,14 +145,16 @@ class HeldExpertsLayer(Layer):
             raise ValueError(f"top_k {top_k} > {n_routed_experts} experts")
         self.n_routed_experts, self.top_k = int(n_routed_experts), int(top_k)
         self.experts_held = (int(lo), int(hi))
-        self.norm_topk_prob = bool(norm_topk_prob)
-        self.routed_scaling_factor = float(routed_scaling_factor)
+        if selection_bias is None:
+            selection_bias = rule is None
+        self.rule = deepseek_v3_rule() if rule is None else rule
         init = _normal(init_std)
         E = hi - lo
         self.router = Linear(d_model, n_routed_experts, bias_attr=False,
                              weight_attr=init)
-        self.router_bias = self.create_parameter(
+        self.router_bias = (self.create_parameter(
             [n_routed_experts], default_initializer=init)
+            if selection_bias else None)
         self.experts_gate = self.create_parameter(
             [E, d_model, d_hidden], dtype=dtype, default_initializer=init)
         self.experts_up = self.create_parameter(
@@ -139,9 +166,10 @@ class HeldExpertsLayer(Layer):
     def routed(self, h, valid=None):
         """The routed part alone for raw h (T, d): ((T, d) float32,
         counts)."""
-        idx, w = route(h, self.router.weight.value, self.router_bias.value,
-                       self.top_k, self.norm_topk_prob,
-                       self.routed_scaling_factor)
+        logits = jnp.matmul(h, self.router.weight.value,
+                            preferred_element_type=jnp.float32)
+        bias = None if self.router_bias is None else self.router_bias.value
+        idx, w = self.rule(logits, self.top_k, bias)
         return held_expert_sum(h, idx, w, self.experts_gate.value,
                                self.experts_up.value, self.experts_down.value,
                                self.experts_held[0], valid)

@@ -11,5 +11,8 @@ from .phi4flash import (Phi4FlashConfig, Phi4FlashForCausalLM,
                         phi4flash_tiny_config)
 from .mistral4 import (Mistral4Config, Mistral4ForCausalLM,
                        mistral4_tiny_config)
+from .granitemoehybrid import (GraniteMoeHybridConfig,
+                               GraniteMoeHybridForCausalLM,
+                               granitemoehybrid_tiny_config)
 
 __all__ = [n for n in dir() if not n.startswith("_")]

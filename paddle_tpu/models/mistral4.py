@@ -54,8 +54,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..framework.core import Tensor
-from ..incubate.distributed.models.moe.held_experts import (HeldExpertsLayer,
-                                                            _normal)
+from ..incubate.distributed.models.moe.held_experts import (
+    HeldExpertsLayer, _normal, deepseek_v3_rule)
 from ..nn.layer.common import Embedding, Linear
 from ..nn.layer.container import LayerList
 from ..nn.layer_base import Layer
@@ -344,9 +344,10 @@ class Mistral4DecoderLayer(Layer):
         self.mlp = HeldExpertsLayer(
             cfg.hidden_size, cfg.moe_intermediate_size, cfg.n_routed_experts,
             cfg.num_experts_per_tok, experts_held=cfg.experts_held,
-            shared=shared, norm_topk_prob=cfg.norm_topk_prob,
-            routed_scaling_factor=cfg.routed_scaling_factor,
-            init_std=cfg.initializer_range, dtype=cfg.dtype)
+            shared=shared, rule=deepseek_v3_rule(
+                cfg.norm_topk_prob, cfg.routed_scaling_factor),
+            selection_bias=True, init_std=cfg.initializer_range,
+            dtype=cfg.dtype)
 
     def _experts(self, x, valid=None):
         y, counts = self.mlp(self.post_attention_layernorm(Tensor(x)).value,

@@ -29,7 +29,10 @@ def grouped_matmul(x, w, group_sizes, out_dtype=jnp.float32, impl=None):
                                   preferred_element_type=out_dtype)
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
+    tm = _TILING[0]
     tk, tn = (min(t, d) for t, d in zip(_TILING[1:], w.shape[1:]))
+    m = x.shape[0]
+    if m % tm:      # whole row tiles: the rows added belong to no group
+        x = jnp.pad(x, ((0, -m % tm), (0, 0)))
     return gmm(x, w, group_sizes.astype(jnp.int32),
-               preferred_element_type=out_dtype,
-               tiling=(_TILING[0], tk, tn))
+               preferred_element_type=out_dtype, tiling=(tm, tk, tn))[:m]

@@ -283,7 +283,9 @@ def select_grouped_matmul(x_shape, w_shape, *, platform=None,
     ``"xla"`` is ``jax.lax.ragged_dot``, which XLA:TPU compiles to a grouped
     kernel of its own (no dense product over all groups: seen in the
     compiled text from the sandbox, PR 33); ``"pallas"`` is the megablox
-    kernel, which needs M in whole 128-row tiles and lane-aligned K and N.
+    kernel, which needs lane-aligned K and N and M in whole 128-row tiles
+    (``grouped_matmul`` pads the rows up to one: rows after the last group
+    cost nothing); fewer than one tile of rows stay with ``"xla"``.
     Chosen by measurement on the v5e at the serving cell's shapes
     (tools/kernel_bench.py --ops experts; PERF.md, PR 33): see
     :data:`GROUPED_MATMUL_ON_TPU`."""
@@ -292,7 +294,7 @@ def select_grouped_matmul(x_shape, w_shape, *, platform=None,
         return XLA
     m, k = x_shape
     n = w_shape[2]
-    if m % 128 or k % 128 or n % 128:
+    if m < 128 or k % 128 or n % 128:
         return XLA
     return PALLAS
 
@@ -325,6 +327,31 @@ def select_selective_scan(h_shape, tokens: int = 1, *, platform=None,
     if 2 * 2 * tokens * s * 128 * 4 > _VMEM_BLOCK_BUDGET:
         return XLA
     return PALLAS
+
+
+def select_ssm2_step(h_shape, *, platform=None, is_partitioned=None) -> str:
+    """Mamba-2's one-step state update over all slots (ops/ssm2.py). h
+    (rows, heads, head_dim, d_state) float32: always the jnp composition,
+    BY MEASUREMENT. XLA fuses the update and the read-out into one in-place
+    pass over the state: 96 slots of 128 x 64 x 128 (2 x 403 MB a layer) take
+    1,249.6 us a layer, 78.7 % of the bandwidth peak. A Pallas kernel written
+    beside it (a row's 32 heads a program, the state aliased in place, a
+    head's decay and ``dt * x`` handed in as columns and broadcast over the
+    lanes, the read-out a lane reduction) took 1,587.5 us (61.9 %): its
+    column broadcasts and lane reductions cost the vector unit ~120 cycles a
+    head where the copies need 75. It was deleted (my chip run, PR 35,
+    tools/expert_bench.py --ops ssm2; PERF.md section 6)."""
+    return XLA
+
+
+def select_ssd_chunk(h_shape, tokens: int, *, platform=None,
+                     is_partitioned=None) -> str:
+    """Mamba-2's chunk scan of one slot in the chunked (dual) form
+    (ops/ssm2.py): always the jnp composition. Its work IS four matrix
+    products (2.2 GFLOP a layer at 256 tokens of 128 heads x 64 x 128), which
+    XLA puts on the matrix unit; beside the chunk's 52 GFLOP of projections
+    a hand-written kernel has nothing to win (PERF.md section 6, PR 35)."""
+    return XLA
 
 
 def lora_block_out(seq: int, in_dim: int, out_dim: int, rank: int,

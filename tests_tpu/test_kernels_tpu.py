@@ -511,6 +511,85 @@ def test_ssm_chunk_scan_kernel_at_the_published_widths():
                                rtol=1e-5, atol=1e-5)
 
 
+def _ssm2_inputs(lead, seed, H=128, P=64, N=128):
+    rng = np.random.RandomState(seed)
+    f = lambda *s: jnp.asarray(rng.randn(*s).astype("float32"))
+    dt = jnp.asarray(rng.uniform(1e-3, 0.1, (lead, H)).astype("float32"))
+    A = -jnp.asarray(rng.uniform(1.0, 16.0, (H,)).astype("float32"))
+    return f(lead, H, P), dt, A, f(lead, N), f(lead, N), f(H)
+
+
+def test_ssm2_step_at_the_serving_cells_shapes():
+    """One token for 96 slots of 128 heads x 64 x 128 float32 (4 MB a slot a
+    layer: Granite 4.0-H), the served op — XLA's one fusion over the state
+    — against the recurrence in numpy float64 on a few rows; a masked row
+    (dt 0) keeps its state bit for bit."""
+    from paddle_tpu.ops import ssm2
+
+    x, dt, A, Bm, Cm, D = _ssm2_inputs(96, 11)
+    h = jax.jit(lambda k: jax.random.normal(k, (96, 128, 64, 128),
+                                            jnp.float32))(jax.random.key(12))
+    dt = dt.at[5].set(0.0)
+    got_y, got_h = jax.jit(ssm2.ssm2_step)(x, dt, A, Bm, Cm, D, h + 0.0)
+    took("ssm2_step", "xla")
+    f = lambda a: np.asarray(a, np.float64)
+    for b in (0, 5, 95):
+        want_h = (np.exp(f(dt[b]) * f(A))[:, None, None] * f(h[b])
+                  + (f(dt[b])[:, None] * f(x[b]))[:, :, None]
+                  * f(Bm[b])[None, None, :])
+        want_y = (want_h * f(Cm[b])).sum(-1) + f(D)[:, None] * f(x[b])
+        np.testing.assert_allclose(np.asarray(got_h[b]), want_h, rtol=1e-5,
+                                   atol=1e-5)
+        np.testing.assert_allclose(np.asarray(got_y[b]), want_y, rtol=1e-4,
+                                   atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(got_h[5]), np.asarray(h[5]))
+
+
+def test_ssd_chunk_at_the_serving_cells_shapes():
+    """A 256-token chunk of one slot from a NON-zero state, the last 56
+    tokens padding, in the chunked form against the sequential recurrence:
+    the state agrees as float32 does (the products run at ``highest``)."""
+    from paddle_tpu.ops import ssm2
+
+    x, dt, A, Bm, Cm, D = _ssm2_inputs(256, 13)
+    h0 = jnp.asarray(np.random.RandomState(14).randn(128, 64, 128)
+                     .astype("float32"))
+    dt = dt.at[200:].set(0.0)
+    want_y, want_h = jax.jit(ssm2.ssd_chunk_ref)(x, dt, A, Bm, Cm, D, h0)
+    got_y, got_h = jax.jit(ssm2.ssd_chunk)(x, dt, A, Bm, Cm, D, h0)
+    took("ssd_chunk", "xla")
+    np.testing.assert_allclose(np.asarray(got_y[:200]),
+                               np.asarray(want_y[:200]), rtol=1e-3, atol=1e-3)
+    np.testing.assert_allclose(np.asarray(got_h), np.asarray(want_h),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("rows", [960, 2560], ids=["tick", "chunk"])
+@pytest.mark.parametrize("k,n", [(4096, 768), (768, 4096)],
+                         ids=["gate_up", "down"])
+def test_grouped_products_at_the_768_wide_experts(rows, k, n):
+    """36 held experts of 4096 x 768, half of the pairs held (a tick's 96 x
+    10 and a chunk's 256 x 10 pairs; 960 rows fill no whole row tile):
+    megablox, as ``select_grouped_matmul`` chooses, against ragged_dot."""
+    from paddle_tpu.ops import select
+    from paddle_tpu.ops.grouped_matmul import grouped_matmul
+
+    rng = np.random.RandomState(15)
+    x = jnp.asarray(rng.randn(rows, k).astype("float32")).astype(jnp.bfloat16)
+    w = jax.jit(lambda key: (jax.random.normal(key, (36, k, n), jnp.float32)
+                             * 0.02).astype(jnp.bfloat16))(jax.random.key(16))
+    sizes = rng.multinomial(rows // 2, [1 / 36] * 36).astype("int32")
+    sizes[7] = 0                                   # an expert with no row
+    held = int(sizes.sum())
+    g = jnp.asarray(sizes)
+    got = jax.jit(lambda x, w, g: grouped_matmul(x, w, g))(x, w, g)
+    took("expert_gmm", select.GROUPED_MATMUL_ON_TPU)
+    want = jax.lax.ragged_dot(x, w, g, preferred_element_type=jnp.float32)
+    assert got.shape == (rows, n)
+    np.testing.assert_allclose(np.asarray(got[:held]),
+                               np.asarray(want[:held]), rtol=2e-2, atol=2e-2)
+
+
 @pytest.mark.parametrize("B,S,IN,OUT", [(8, 1, 4096, 4096),
                                         (8, 1, 4096, 14336),
                                         (1, 128, 4096, 4096)],

@@ -133,6 +133,22 @@ def _ssm_case(rows, tokens, d=5120, S=16):
              ((tokens, S), f), ((d,), f), ((S, d), f)])
 
 
+def _ssm2_case(rows, tokens, H=128, P=64, N=128):
+    """Mamba-2's state update at the Granite 4.0-H cell's shapes: the
+    one-step update over 96 slots and the chunk scan of one slot in the
+    chunked form (jnp compositions both: no Mosaic call)."""
+    from paddle_tpu.ops import ssm2
+
+    f = jnp.float32
+    if tokens == 1:
+        return (ssm2.ssm2_step,
+                [((rows, H, P), f), ((rows, H), f), ((H,), f), ((rows, N), f),
+                 ((rows, N), f), ((H,), f), ((rows, H, P, N), f)])
+    return (ssm2.ssd_chunk,
+            [((tokens, H, P), f), ((tokens, H), f), ((H,), f),
+             ((tokens, N), f), ((tokens, N), f), ((H,), f), ((H, P, N), f)])
+
+
 def _latent_case(B, W, N=8501, bs=64, M=196, H=32, D=384, Dv=256):
     """Attention over a paged latent pool at the Mistral-Small-4 cell's
     shapes: 128 slots, 12,288-token tables (+ a chunk of slack) in blocks of
@@ -226,6 +242,16 @@ def kernel_cases():
         ("experts.ragged_dot_1536_pairs", lambda: _gmm_case(1536, "xla")),
         ("experts.megablox_512_pairs", lambda: _gmm_case(512, "pallas")),
         ("experts.megablox_1536_pairs", lambda: _gmm_case(1536, "pallas")),
+        # the Granite 4.0-H cell: 36 held experts of 4096 x 768; a tick's
+        # 96 x 10 pairs (no whole row tile: padded up) and a chunk's 2,560
+        ("experts.megablox_960_pairs_w768",
+         lambda: _gmm_case(960, "pallas", E=36, N=768)),
+        ("experts.megablox_2560_pairs_w768_down",
+         lambda: _gmm_case(2560, "pallas", E=36, K=768, N=4096)),
+        ("experts.ragged_dot_960_pairs_w768",
+         lambda: _gmm_case(960, "xla", E=36, N=768)),
+        ("ssm2.step_B96_128x64x128", lambda: _ssm2_case(96, 1)),
+        ("ssm2.chunk_C256_128x64x128", lambda: _ssm2_case(1, 256)),
         ("ssm.step_B128_5120x16", lambda: _ssm_case(128, 1)),
         ("ssm.chunk_scan_C128_5120x16", lambda: _ssm_case(1, 128)),
         ("lora.decode_4096x4096", lambda: _lora_case(8, 1, HID8, HID8)),
@@ -285,6 +311,21 @@ def reads_weights_like(other):
     return check
 
 
+def _whole_copies(text, operand, dtype, what):
+    """Fail if the compiled program's entry copies, whole, an array it was
+    handed under the operand name ``operand`` (HLO dtype pattern ``dtype``)."""
+    entry = text[text.index("\nENTRY"):]
+    arrays = set(re.findall(
+        r"= (" + dtype + r"\[[\d,]+\])\S* parameter\(\d+\).*op_name=\""
+        + operand, entry))
+    n = sum(len(re.findall(r"= " + re.escape(a) + r"\S* copy\(", entry))
+            for a in arrays)
+    if n or not arrays:
+        raise RuntimeError(f"{n} whole-{what} copies ({what}s: "
+                           f"{sorted(arrays)})")
+    return f" {what}_copies=0"
+
+
 def copies_no_pool(text, texts):
     """A case's check: the compiled program copies no KV pool whole. A pool
     is updated in place (donated, aliased to its output); where a write sits
@@ -292,27 +333,28 @@ def copies_no_pool(text, texts):
     copies all of it — 134 MB a pool a call at the Mistral cells' size,
     which the joint decode + chunk step did in its first layer until both
     writes were put before both attention calls (PR 32)."""
-    entry = text[text.index("\nENTRY"):]
-    pools = set(re.findall(
-        r"= (\w+\[[\d,]+\])\S* parameter\(\d+\).*op_name=\"flat_pools", entry))
-    n = sum(len(re.findall(r"= " + re.escape(p) + r"\S* copy\(", entry))
-            for p in pools)
-    if n or not pools:
-        raise RuntimeError(f"{n} whole-pool copies (pools: {sorted(pools)})")
-    return " pool_copies=0"
+    return _whole_copies(text, "flat_pools", r"\w+", "pool")
+
+
+def copies_no_state(text, texts):
+    """A case's check: the compiled program copies no float32 slot-state
+    array whole (a Mamba-2 layer's state is 403 MB over 96 slots: it is
+    donated and updated in place, a row of it or all rows)."""
+    return _whole_copies(text, "slot_pools", "f32", "state")
 
 
 def copies_no_stack(*shapes):
     """A case's check: nothing in the compiled program produces a second
     array of an expert stack's shape (``shapes``: HLO shape texts) — no
     copy, transpose, convert or fusion of a whole [held, in, out] stack: the
-    grouped products read the weights where they lie."""
+    grouped products read the weights where they lie. (A fusion that only
+    bitcasts its operand makes no array.)"""
     def check(text, texts):
         hits = []
         for shape in shapes:
             hits += re.findall(
                 r"= " + re.escape(shape) + r"\S* (?!parameter|get-tuple-"
-                r"element|bitcast)[\w\-]+\(", text)
+                r"element|bitcast)[\w\-]+\((?!.*calls=%bitcast_fusion)", text)
         if hits:
             raise RuntimeError(f"{len(hits)} whole expert stacks made anew: "
                                f"{sorted(set(hits))[:4]}")
@@ -494,8 +536,38 @@ def program_cases(devs):
             return built["mistral4"][i]
         return build
 
+    def granite4h(i):
+        """Granite 4.0-H Small's share as its benchmark cell serves it
+        (benchmarks/configs/granite-4.0-h-small-l10-ep2.json): 10 layers at
+        the published widths (nine Mamba-2, attention at 5), experts 0-35 of
+        72, half of the vocabulary, 96 slots of 3,072 with 38 MB of state
+        each, blocks of 16, chunks of 256. ~16 GB of host RAM."""
+        def build():
+            if "granite4h" not in built:
+                from paddle_tpu.models.granitemoehybrid import (
+                    GraniteMoeHybridConfig, GraniteMoeHybridForCausalLM)
+
+                built["granite4h"] = _serve_programs(
+                    devs, GraniteMoeHybridConfig(
+                        num_hidden_layers=10, vocab_size=50176,
+                        experts_held=(0, 36), max_position_embeddings=3072),
+                    1, model_cls=GraniteMoeHybridForCausalLM, max_batch=96,
+                    max_len=3072, block_size=16, prefill_chunk=256,
+                    num_blocks=7693)
+            return built["granite4h"][i]
+        return build
+
     stacks = copies_no_stack("bf16[32,4096,2048]", "bf16[32,2048,4096]")
+    stacks768 = copies_no_stack("bf16[36,4096,768]", "bf16[36,768,4096]")
+    # (the decode program makes no second tied embedding either; the chunk
+    # program's one-row head is a fused multiply and reduce over the table,
+    # which reads as one inside its fusion: its temp bytes say it is none)
+    embedding = copies_no_stack("bf16[50176,4096]", "bf16[4096,50176]")
     return [
+        ("serve.granite4h_l10_decode_B96", granite4h(0), copies_no_pool,
+         copies_no_state, stacks768, embedding),
+        ("serve.granite4h_l10_prefill_chunk", granite4h(1), copies_no_pool,
+         copies_no_state, stacks768),
         ("serve.mistral4_l6_decode_B128", mistral4(0), copies_no_pool,
          stacks),
         ("serve.mistral4_l6_decode_chunk", mistral4(1), copies_no_pool,

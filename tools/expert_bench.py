@@ -1,9 +1,10 @@
-"""Microbenchmark of the two ops of the latent-attention + held-experts
-serving cell, at that cell's shapes (benchmarks/configs/
-mistral-small-4-119b-l6-ep4.json), on the chip:
+"""Microbenchmark of the ops of the held-experts serving cells, at those
+cells' shapes (benchmarks/configs/mistral-small-4-119b-l6-ep4.json,
+granite-4.0-h-small-l10-ep2.json), on the chip:
 
-    chiprun -- python3 tools/expert_bench.py [--ops experts,latent]
-        [--rows 128,384] [--layers 6] [--iters 20] [--contexts 1600,2600]
+    chiprun -- python3 tools/expert_bench.py [--ops experts,latent,ssm2]
+        [--shape mistral4|granite4h] [--rows 128,384] [--layers 6]
+        [--iters 20] [--contexts 1600,2600]
         [--variants as_is,copies_only,compute_only]
 
 ``experts``: the held experts' part of a layer — sort the (token, expert)
@@ -15,7 +16,19 @@ kernel (``pallas``): ``--layers`` layers of DISTINCT weights in one program
 (one dispatch, and no layer finds its weights in a cache), microseconds a
 layer beside the time the read of the active experts' matrices alone takes at
 the bandwidth peak. This is the reading ``ops/select.py``'s
-``GROUPED_MATMUL_ON_TPU`` was set from (PERF.md, PR 33).
+``GROUPED_MATMUL_ON_TPU`` was set from (PERF.md, PR 33). ``--shape
+granite4h``: 36 held of 72 experts of ``[4096, 768]``, top 10 by the softmax
+rule (half of the pairs are held; ``--rows 96,256`` are the cell's tick and
+chunk: ~480 and ~1,280 held pairs; PERF.md, PR 35).
+
+``ssm2``: Mamba-2's one-step state update over 96 slots of 128 x 64 x 128
+float32 (``ops/ssm2.py``): ``--layers`` layers of DISTINCT states in one
+program, each layer's input made from the one before, the states donated;
+microseconds a layer and the share of the memory roofline (the state read
+and written once) — the reading a hand-written kernel has to beat (PR 35's
+read 61.9 % against the composition's 78.7 %, and was deleted). Then the
+chunk scan of one slot (256 tokens, chunked form) from a state to a state,
+microseconds a layer beside its 2.2 GFLOP at the bf16 peak (PERF.md, PR 35).
 
 ``latent``: decode attention over the paged latent pool, 128 rows at
 lognormal contexts (sigma 0.6, scaled to each mean of ``--contexts``; the
@@ -41,7 +54,9 @@ from unittest import mock
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-H, F, E, HELD, K = 4096, 2048, 128, 32, 4
+# hidden, expert width, router width, experts held, experts a token, rule
+SHAPES = {"mistral4": (4096, 2048, 128, 32, 4, "deepseek_v3"),
+          "granite4h": (4096, 768, 72, 36, 10, "softmax_of_chosen")}
 
 
 def _timed(fn, args, iters):
@@ -56,13 +71,21 @@ def _timed(fn, args, iters):
     return (time.perf_counter() - t0) / iters, out
 
 
-def experts(rows_list, layers, iters, peaks):
+def experts(rows_list, layers, iters, peaks, shape="mistral4"):
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from paddle_tpu.incubate.distributed.models.moe.held_experts import (
-        held_expert_sum, route)
+    from paddle_tpu.incubate.distributed.models.moe import held_experts as he
     from paddle_tpu.ops import select
+
+    held_expert_sum = he.held_expert_sum
+    H, F, E, HELD, K, rule = SHAPES[shape]
+
+    def route(x, router, bias):
+        logits = jnp.matmul(x, router, preferred_element_type=jnp.float32)
+        if rule == "deepseek_v3":
+            return he.deepseek_v3_rule()(logits, K, bias)
+        return he.softmax_of_chosen(logits, K)
 
     key = jax.random.key(0)
     bf = jnp.bfloat16
@@ -83,7 +106,7 @@ def experts(rows_list, layers, iters, peaks):
             def prog(x, ws, router):
                 y, counts = x, []
                 for wg, wu, wd in ws:
-                    idx, w = route(y, router, bias, K, True, 1.0)
+                    idx, w = route(y, router, bias)
                     part, c = held_expert_sum(y, idx, w, wg, wu, wd, 0)
                     # the next layer's input depends on this one's output
                     # (normed, as a layer's input is)
@@ -101,7 +124,7 @@ def experts(rows_list, layers, iters, peaks):
                 / layers * 1e6
             outs[impl] = np.asarray(y, np.float32)
             # the first layer alone against a dense product, expert by expert
-            idx, w = route(x, router, bias, K, True, 1.0)
+            idx, w = route(x, router, bias)
             part, _ = jax.jit(held_expert_sum, static_argnums=6)(
                 x, idx, w, *ws[0], 0)
             want = jnp.zeros((rows, H), jnp.float32)
@@ -112,12 +135,14 @@ def experts(rows_list, layers, iters, peaks):
                     jnp.float32)
             part, want = np.asarray(part), np.asarray(want)
             print(json.dumps({
-                "op": "experts_check", "impl": impl, "rows": rows,
+                "op": "experts_check", "shape": shape, "impl": impl,
+                "rows": rows,
                 "finite": bool(np.isfinite(part).all()),
                 "max_abs_diff_vs_dense": float(np.nanmax(np.abs(part - want))),
                 "max_abs": float(np.abs(want).max())}), flush=True)
             print(json.dumps({
-                "op": "experts", "impl": impl, "rows": rows, "pairs": rows * K,
+                "op": "experts", "shape": shape, "impl": impl, "rows": rows,
+                "pairs": rows * K,
                 "pairs_held_per_layer": held / layers,
                 "experts_active_per_layer": active / layers,
                 "load_max_per_layer": float(counts[:, :HELD].max(1).mean()),
@@ -129,6 +154,92 @@ def experts(rows_list, layers, iters, peaks):
                           float(np.abs(outs["xla"] - outs["pallas"]).max()),
                           "max_abs": float(np.abs(outs["xla"]).max())}),
               flush=True)
+
+
+def ssm2(rows, layers, iters, peaks, heads=128, P=64, N=128, tokens=256):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops import select
+    from paddle_tpu.ops import ssm2 as op
+
+    f = jnp.float32
+    key = jax.random.key(2)
+
+    def draw(i, shape, scale=1.0):
+        return jax.jit(lambda k: jax.random.normal(k, shape, f) * scale)(
+            jax.random.fold_in(key, i))
+
+    A = -jnp.exp(draw(0, (heads,), 0.5))
+    D = jnp.ones((heads,), f)
+    x = draw(1, (rows, heads, P))
+    dt = jnp.abs(draw(2, (rows, heads), 0.05)) + 1e-3
+    Bm, Cm = draw(3, (rows, N)), draw(4, (rows, N))
+    states = [draw(10 + i, (rows, heads, P, N)) for i in range(layers)]
+
+    def prog(x, states):
+        new = []
+        for h in states:
+            y, h = op.ssm2_step(x, dt, A, Bm, Cm, D, h)
+            # the next layer's input depends on this one's output
+            x = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + 1e-5)
+            new.append(h)
+        return x, new
+
+    fn = jax.jit(prog, donate_argnums=(1,))
+    x1, states = fn(x, states)
+    jax.block_until_ready(x1)
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        x1, states = fn(x, states)
+    jax.block_until_ready(x1)
+    us = (time.perf_counter() - t0) / iters / layers * 1e6
+    nbytes = 2.0 * rows * heads * P * N * 4
+    print(json.dumps({
+        "op": "ssm2_step", "rows": rows,
+        "selected": select.selected().get("ssm2_step"),
+        "state_mb_per_row": heads * P * N * 4 / 1e6,
+        "us_per_layer": round(us, 1),
+        "roofline_pct": round(
+            100 * nbytes / peaks["hbm_bytes_per_s"] / (us * 1e-6), 1)}),
+        flush=True)
+    del states
+
+    xs = draw(5, (tokens, heads, P))
+    dts = jnp.abs(draw(6, (tokens, heads), 0.05)) + 1e-3
+    Bs, Cs = draw(7, (tokens, N)), draw(8, (tokens, N))
+    h0 = [draw(30 + i, (heads, P, N)) for i in range(layers)]
+
+    def chunks(scan):
+        def prog(x, h0):
+            new = []
+            for h in h0:
+                y, h = scan(x, dts, A, Bs, Cs, D, h)
+                x = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True)
+                                      + 1e-5)
+                new.append(h)
+            return x, new
+        return jax.jit(prog)
+
+    got = {}
+    for name, scan in (("chunked", op.ssd_chunk),
+                       ("sequential", op.ssd_chunk_ref)):
+        s, (y, hs) = _timed(chunks(scan), (xs, h0), max(iters // 4, 2))
+        got[name] = (np.asarray(y), np.asarray(hs[0]))
+        flops = 2.0 * heads * P * (tokens * tokens + 2 * tokens * N) \
+            + 2.0 * tokens * tokens * N
+        print(json.dumps({
+            "op": "ssd_chunk", "form": name, "tokens": tokens,
+            "us_per_layer": round(s / layers * 1e6, 1),
+            "gflop_per_layer": round(flops / 1e9, 2),
+            "bf16_peak_pct": round(100 * flops / peaks["bf16_flops"]
+                                   / (s / layers), 1)}), flush=True)
+    print(json.dumps({"op": "ssd_chunk", "max_abs_diff_y": float(np.abs(
+        got["chunked"][0] - got["sequential"][0]).max()),
+        "max_abs_diff_state": float(np.abs(
+            got["chunked"][1] - got["sequential"][1]).max()),
+        "max_abs_state": float(np.abs(got["sequential"][1]).max())}),
+        flush=True)
 
 
 # the three readings of the latent kernel: as it is; its copies alone (the
@@ -201,6 +312,9 @@ def latent(groups, contexts, variants, iters, peaks, bs=16, B=128,
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--ops", default="experts,latent")
+    ap.add_argument("--shape", default="mistral4", choices=sorted(SHAPES))
+    ap.add_argument("--slots", type=int, default=96,
+                    help="rows of the ssm2 one-step update")
     ap.add_argument("--rows", default="128,384")
     ap.add_argument("--layers", type=int, default=6)
     ap.add_argument("--iters", type=int, default=20)
@@ -224,7 +338,9 @@ def main():
     ops = args.ops.split(",")
     if "experts" in ops:
         experts([int(r) for r in args.rows.split(",")], args.layers,
-                args.iters, table[kind])
+                args.iters, table[kind], args.shape)
+    if "ssm2" in ops:
+        ssm2(args.slots, args.layers, args.iters, table[kind])
     if "latent" in ops:
         for bs in (int(b) for b in args.block_sizes.split(",")):
             latent([int(g) for g in args.group_tokens.split(",")],
