@@ -179,7 +179,7 @@ class PagedExecutor:
         self.chunk_prefill = self.decode_chunk = None
         if self.chunk_alone_why is None:
             self.decode_chunk = self._jit(self._decode_chunk_fn,
-                                          donate_argnums=(2,),
+                                          donate_argnums=(2, 16),
                                           static_argnums=(15,))
         else:
             self.chunk_prefill = self._jit(self._chunk_prefill_fn,
@@ -200,16 +200,18 @@ class PagedExecutor:
         """None where a prompt chunk can ride in the decode trip's program
         call, else the reason it cannot — what the engine counts its chunks
         under (``serving_prefill_chunks_alone{reason}``). Decided once,
-        from what the server was built with: per-slot state and a ``cp``
-        mesh shape the chunk program itself (slot operands, the
-        sequence-dim constraint), a speculative server and a
+        from what the server was built with: per-slot state splits the
+        joint step where the model's class says how (a method: it is asked
+        for, not the class's name), a ``cp`` mesh shapes the chunk program
+        itself (the sequence-dim constraint), a speculative server and a
         ``tick_window`` scan have no one-tick plain trip to ride in,
         adapter rows would gather C more copies of the chunk's adapter,
         routed experts that fill fixed-capacity buckets see another capacity
         when rows are joined (a dropless expert layer has none, and rides),
         and a model class may not offer the joint step."""
         engine = self.engine
-        if self.spec.has_slot_state:
+        joint = hasattr(engine.model.model, "paged_decode_chunk_step")
+        if self.spec.has_slot_state and not joint:
             return "slot_state"
         if self.cp > 1:
             return "cp"
@@ -221,9 +223,7 @@ class PagedExecutor:
             return "lora"
         if _has_expert_capacity(engine.model):
             return "moe"
-        if not hasattr(engine.model.model, "paged_decode_chunk_step"):
-            return "model"
-        return None
+        return None if joint else "model"
 
     def _jit(self, body, **jit_kw):
         """jit one program body. Under a tp/cp mesh the body traces inside
@@ -475,29 +475,36 @@ class PagedExecutor:
 
     def _decode_chunk_fn(self, params, tokens, flat_pools, tables, pos,
                          temps, topks, topps, active, key, prev, chunk,
-                         table, start, last_idx, greedy=False):
+                         table, start, last_idx, greedy=False, slot_pools=(),
+                         slot=None):
         """A one-tick decode trip AND one prompt chunk in one program, so
         that the tick reads every weight once (the chunk alone would
         stream them all a second time): the operands of
         :meth:`_decode_paged_fn` (``tokens`` … ``prev``; ``greedy`` STATIC)
         and of :meth:`_chunk_prefill_fn` (``chunk`` (1, C), the slot's
-        ``table``, ``start``, ``last_idx``), the model's joint step over
-        B + C rows, the head on B + 1. It is THE chunk program of a server
-        that has it — a chunk that meets no decoding row runs it with every
-        row masked (``active`` 0, zeroed ``tables``), as idle rows always
-        run — so a server compiles two programs whatever its traffic.
+        ``table``, ``start``, ``last_idx``; ``slot_pools``, DONATED, and
+        ``slot`` where the spec has slot state — for every other spec both
+        are empty and the program is what it is without them), the model's
+        joint step over B + C rows, the head on B + 1. It is THE chunk
+        program of a server that has it — a chunk that meets no decoding row
+        runs it with every row masked (``active`` 0, zeroed ``tables``), as
+        idle rows always run — so a server compiles two programs whatever
+        its traffic.
         Returns the trip's (1, B) token stack, the chunk's float32 logits
-        row (1, V), the pools (and the model's step stats, if it leaves
-        any, as in :meth:`_decode_paged_fn`)."""
+        row (1, V), the block pools, the slot pools (and the model's step
+        stats, if it leaves any, as in :meth:`_decode_paged_fn`)."""
         engine = self.engine
         model = engine.model
-        tokens, _ = self._feed(tokens, prev, active)
+        tokens, active = self._feed(tokens, prev, active)
         ids = jnp.concatenate([tokens[None, :], chunk], axis=1)
-        pools = self._pool_views(flat_pools)
+        pools = self._pool_views(flat_pools, slot_pools)
+        slot_kw = ({"active": active, "slot": slot}
+                   if self.spec.has_slot_state else {})
 
         def call():
             h, new = model.model.paged_decode_chunk_step(
-                Tensor(ids), pools, tables, pos, table, start, last_idx)
+                Tensor(ids), pools, tables, pos, table, start, last_idx,
+                **slot_kw)
             return engine._head(h), new, _step_stats(model)
 
         logits, new, stats = functional_call(model, params, call_fn=call)
@@ -509,7 +516,7 @@ class PagedExecutor:
 
             nxt = sample_token_rows(lg[:-1], jax.random.fold_in(key, 0),
                                     temps, topks, topps)
-        return (nxt[None], lg[-1:], self._flat_pools(new)[0], *stats)
+        return (nxt[None], lg[-1:], *self._flat_pools(new), *stats)
 
     def _spec_verify_fn(self, params, tokens, proposals, flat_pools, tables,
                         pos, temps, topks, topps, kcaps, key, qprobs,

@@ -1574,6 +1574,15 @@ class GenerationServer:
         return (jnp.asarray(ck.ids), jnp.asarray(self._bt[ck.slot]),
                 jnp.int32(ck.start), jnp.int32(last_idx))
 
+    def _slot_operand(self, ck: _Chunk):
+        """``slot`` as the chunk programs take it: int32 (slot index, real
+        tokens in the chunk, 1 on the request's last chunk); None for a
+        spec without slot state, whose programs take none."""
+        if not self.cache_spec.has_slot_state:
+            return None
+        return jnp.asarray(np.array([ck.slot, ck.end - ck.start,
+                                     ck.end == ck.n], np.int32))
+
     def _chunk_alone(self, ck: _Chunk, why: str) -> None:
         """Dispatch one prompt chunk in a program call of its own, counted
         under ``why``, and on a final chunk read the first token. A server
@@ -1592,8 +1601,7 @@ class GenerationServer:
             lg, self._pools, self._slot_pools, *stats = self._chunk_prefill(
                 self.params, chunk, self._pools, table, start, last_idx,
                 aidx, self._lora_flat(), self._slot_pools,
-                jnp.asarray(np.array([slot, ck.end - ck.start,
-                                      ck.end == ck.n], np.int32)))
+                self._slot_operand(ck))
         else:
             if self._masked_rows is None:
                 B = self.max_batch
@@ -1604,11 +1612,12 @@ class GenerationServer:
             bt, posv, active = self._masked_rows
             temps, topks, topps, _, _ = self._samp_arrays()
             # (the stack of an all-masked trip is nobody's tokens)
-            _, lg, self._pools, *stats = self._decode_chunk(
+            _, lg, self._pools, self._slot_pools, *stats = self._decode_chunk(
                 self.params, jnp.asarray(self.tokens), self._pools, bt, posv,
                 temps, topks, topps, active, self._base_key,
                 self._exec.prev_stack(None, 1), chunk, table, start,
-                last_idx, self._all_greedy(range(self.max_batch)))
+                last_idx, self._all_greedy(range(self.max_batch)),
+                self._slot_pools, self._slot_operand(ck))
         # (read with the next decode trip's tokens, not now)
         self._stats_unread += stats
         self._c_pf_alone.inc(reason=why)
@@ -2081,11 +2090,14 @@ class GenerationServer:
             else:
                 _t0 = tel.clock() if tel.enabled else 0.0
                 _w0 = self._wall()
-                stack, lg, self._pools, *stats = self._decode_chunk(
-                    self.params, jnp.asarray(self.tokens), self._pools,
-                    jnp.asarray(bt), jnp.asarray(posv), temps, topks, topps,
-                    jnp.asarray(feed), key, self._exec.prev_stack(prev, k),
-                    *self._chunk_operands(ck), self._all_greedy(active))
+                stack, lg, self._pools, self._slot_pools, *stats = \
+                    self._decode_chunk(
+                        self.params, jnp.asarray(self.tokens), self._pools,
+                        jnp.asarray(bt), jnp.asarray(posv), temps, topks,
+                        topps, jnp.asarray(feed), key,
+                        self._exec.prev_stack(prev, k),
+                        *self._chunk_operands(ck), self._all_greedy(active),
+                        self._slot_pools, self._slot_operand(ck))
                 self._c_pf_fused.inc()
                 self._chunk_dispatched(ck, _t0, _w0)
             # rows that this trip takes to the end of their budget: the

@@ -36,8 +36,10 @@ code in Hugging Face ``transformers``) and Mamba-2 (arXiv:2405.21060).
   commutes with the product; ``logits_scaling`` 16 is exact in any float
   type), so the engine's tied head needs to know nothing of it.
 
-Serving: ``paged_decode_step`` and ``paged_prefill_chunk`` as
-``GenerationServer(cache="paged")`` calls them, built from
+Serving: ``paged_decode_step``, ``paged_prefill_chunk`` and — both as one
+step, so that a tick which carries a prompt chunk reads the weights once —
+``paged_decode_chunk_step`` as ``GenerationServer(cache="paged")`` calls
+them, built from
 :meth:`GraniteMoeHybridForCausalLM.cache_spec`: a Mamba-2 layer owns slot
 state (``ssm``: heads x head_dim x d_state float32 — 4 MB a slot a layer at
 the published widths — and ``conv``: the last ``d_conv - 1`` inputs of the
@@ -259,43 +261,101 @@ class _Mamba2(Layer):
         y, _ = ssd_chunk_ref(x, dt, self._A(), Bm, Cm, self._D(), h0)
         return self._finish(y, z)
 
-    def decode(self, u, view, step):
-        """One token a slot: u (B, H); view = (ssm (B, heads, head_dim,
-        d_state) f32, conv tail (B, d_conv - 1, conv_dim)). A row that is
-        idle or prefilling keeps both: dt 0, tail kept."""
+    def _rows(self, xbc, dt, view, act):
+        """The decode rows' convolution step and state update from their
+        projections xbc (B, conv_dim), dt (B, heads): view = (ssm (B, heads,
+        head_dim, d_state) f32, conv tail (B, d_conv - 1, conv_dim)). A row
+        that ``act`` (B,) masks keeps both: dt 0, tail kept. Returns (y (B,
+        heads, head_dim) f32, new view)."""
         from ..ops import selective_scan as ss
         from ..ops.ssm2 import ssm2_step
 
         h, tail = view
-        act = step.active
-        z, xbc, dt = self._split(u)
         xc, new_tail = ss.causal_conv_step(tail, xbc, *self._conv())
         x, dt, Bm, Cm = self._operands(jax.nn.silu(xc), dt, act)
         y, h = ssm2_step(x, dt, self._A(), Bm, Cm, self._D(), h)
-        new_tail = jnp.where(act[:, None, None], new_tail, tail)
-        return self._finish(y, z), (h, new_tail)
+        return y, (h, jnp.where(act[:, None, None], new_tail, tail))
 
-    def chunk(self, u, view, step):
-        """One prompt chunk of the request in ``step.slot``: u (C, H), the
-        first ``step.n_valid`` rows real; from the slot's state (zero on the
-        request's first chunk) to its state after the last real token."""
+    def _chunk_start(self, view, step):
+        """What the chunk of ``step`` starts from: its slot's (state, conv
+        tail) as ``view`` holds them, zeros on the request's first chunk."""
+        h, tail = view
+        fresh = step.start == 0
+        return (jnp.where(fresh, 0.0, h[step.slot]),
+                jnp.where(fresh, 0, tail[step.slot]))
+
+    def _chunk_rows(self, xbc, dt, view, step, start_from):
+        """One prompt chunk of the request in ``step.slot`` from its
+        projections xbc (C, conv_dim), dt (C, heads), the first
+        ``step.n_valid`` rows real: from ``start_from``
+        (:meth:`_chunk_start`) to the slot's state after the last real
+        token, written into ``view``. Returns (y (C, heads, head_dim) f32,
+        new view)."""
         from ..ops import selective_scan as ss
         from ..ops.ssm2 import ssd_chunk
 
-        h, tail = view
-        slot, n_valid = step.slot, step.n_valid
-        fresh = step.start == 0
-        tail0 = jnp.where(fresh, 0, tail[slot])
-        h0 = jnp.where(fresh, 0.0, h[slot])
-        z, xbc, dt = self._split(u)
+        h0, tail0 = start_from
+        n_valid = step.n_valid
         xc, new_tail = ss.causal_conv_chunk(tail0, xbc, *self._conv(),
                                             n_valid)
         x, dt, Bm, Cm = self._operands(jax.nn.silu(xc), dt,
-                                       jnp.arange(u.shape[0]) < n_valid)
+                                       jnp.arange(xbc.shape[0]) < n_valid)
         y, hT = ssd_chunk(x, dt, self._A(), Bm, Cm, self._D(), h0,
                           self.cfg.mamba_chunk_size)
-        return self._finish(y, z), (h.at[slot].set(hT),
-                                    tail.at[slot].set(new_tail))
+
+        # (a slice written at the slot, not ``.at[slot].set``: the scatter
+        # reads the old row back for its bounds check, in the layout of the
+        # scan's product, and behind the joint step's conditional the
+        # compiler then lays the WHOLE state out anew for that one row —
+        # 403 MB copied a layer at the published widths)
+        def put(a, row):
+            return jax.lax.dynamic_update_slice_in_dim(a, row[None],
+                                                       step.slot, 0)
+
+        h, tail = view
+        return y, (put(h, hT), put(tail, new_tail))
+
+    def decode(self, u, view, step):
+        """One token a slot: u (B, H), rows masked by ``step.active``."""
+        z, xbc, dt = self._split(u)
+        y, view = self._rows(xbc, dt, view, step.active)
+        return self._finish(y, z), view
+
+    def chunk(self, u, view, step):
+        """One prompt chunk: u (C, H)."""
+        z, xbc, dt = self._split(u)
+        y, view = self._chunk_rows(xbc, dt, view, step,
+                                   self._chunk_start(view, step))
+        return self._finish(y, z), view
+
+    def joint(self, u, view, step):
+        """Both of the above over u (B + C, H), the decode rows first: the
+        two projections — all but the whole of the mixer's weights — ONCE
+        over the joined rows; the state splits. A call whose decode rows are
+        all masked (a tick's second chunk, a chunk that met no decoding row)
+        skips their update, which would read and write every slot's state
+        to change nothing: ``step.decodes`` is the program's own operand.
+        The chunk's start is read BEFORE the rows' update — its slot is no
+        decoding row, so the update leaves that row as it is — from the
+        array as the program was handed it: read behind the conditional, the
+        one row in the layout the scan wants has the compiler lay the whole
+        state out anew (tools/compile_check.py holds both)."""
+        B = step.active.shape[0]
+        z, xbc, dt = self._split(u)
+        cfg = self.cfg
+
+        def rows(view):
+            return self._rows(xbc[:B], dt[:B], view, step.active)
+
+        def no_rows(view):
+            return jnp.zeros((B, cfg.mamba_n_heads, cfg.mamba_d_head),
+                             jnp.float32), view
+
+        start_from = self._chunk_start(view, step)
+        yd, view = jax.lax.cond(step.decodes, rows, no_rows, view)
+        yc, view = self._chunk_rows(xbc[B:], dt[B:], view, step, start_from)
+        y = jnp.concatenate([yd.reshape(B, -1), yc.reshape(-1, cfg.d_inner)])
+        return self._finish(y, z), view
 
 
 class _Attention(Layer):
@@ -357,14 +417,40 @@ class _Attention(Layer):
 
         q, k, v = self._qkv(u, kernel_scale=True)
         kp, vp = pa.write_chunk_kv(*view, k, v, step.table, step.start)
-        # the chunk's queries in blocks of _ATTN_ROWS: the attention kernel
-        # holds a call's query rows (x 4 heads a KV head) whole in VMEM, and
-        # a chunk of 256 does not fit (refused by the compiler, PR 35)
-        o = jnp.concatenate([
+        return self._out(self._attend_chunk(q, kp, vp, step)), (kp, vp)
+
+    @staticmethod
+    def _attend_chunk(q, kp, vp, step):
+        """The chunk's queries q (C, heads, d) over its slot's blocks, in
+        blocks of _ATTN_ROWS: the attention kernel holds a call's query rows
+        (x 4 heads a KV head) whole in VMEM, and a chunk of 256 does not fit
+        (refused by the compiler, PR 35)."""
+        from ..ops import paged_attention as pa
+
+        return jnp.concatenate([
             pa.paged_prefill_attention(q[None, s:s + _ATTN_ROWS], kp, vp,
                                        step.table, step.start + s)[0]
             for s in range(0, q.shape[0], _ATTN_ROWS)])
-        return self._out(o), (kp, vp)
+
+    def joint(self, u, view, step):
+        """Both of the above over u (B + C, H), the decode rows first: the
+        four projections once over the joined rows; BOTH writes, then both
+        attentions over the pool as it then stands (no row reads what
+        another row of the call writes; with a write between the two reads
+        the compiler keeps the old pool for the first and copies it whole:
+        PERF.md, PR 32)."""
+        from ..ops import paged_attention as pa
+
+        B = step.active.shape[0]
+        q, k, v = self._qkv(u, kernel_scale=True)
+        kp, vp = pa.write_decode_kv(*view, k[:B], v[:B], step.tables,
+                                    step.pos)
+        kp, vp = pa.write_chunk_kv(kp, vp, k[B:], v[B:], step.table,
+                                   step.start)
+        rows = pa.paged_decode_attention(q[:B, None], kp, vp, step.tables,
+                                         step.pos)[:, 0]
+        return self._out(jnp.concatenate(
+            [rows, self._attend_chunk(q[B:], kp, vp, step)])), (kp, vp)
 
 
 class GraniteMoeHybridLayer(Layer):
@@ -402,8 +488,8 @@ class GraniteMoeHybridLayer(Layer):
 
     def serve(self, mode, x, view, step, valid):
         """The rows x (T, H) of one served step in ``mode`` ("decode" |
-        "chunk"). Returns (x, the layer's new view, the expert layer's row
-        counts)."""
+        "chunk" | "joint": the decode rows, then a chunk). Returns (x, the
+        layer's new view, the expert layer's row counts)."""
         u = self.input_layernorm(Tensor(x)).value
         a, view = getattr(self.mixer, mode)(u, view, step)
         x, counts = self._experts(self._mix(x, a), valid)
@@ -504,6 +590,29 @@ class GraniteMoeHybridModel(Layer):
         x, new = self._serve("chunk", self._embed(input_ids.value[0]), views,
                              step, jnp.arange(C) < slot[1])
         h = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, 0)
+        return self._final(h[None]), new
+
+    def paged_decode_chunk_step(self, input_ids, views, block_tables, pos,
+                                block_table, start, last_idx, active=None,
+                                slot=None):
+        """Both of the above as ONE step over B + C rows (input_ids (1, B +
+        C), the decode rows first), so that a tick which carries a prompt
+        chunk reads every weight — the held experts' 0.68 GB a layer, the
+        mixers' projections — once. Only what differs by kind of row splits:
+        the convolution, the state's update and scan, the attention. Returns
+        (final-normed hidden over ``logits_scaling`` (1, B + 1, H): the
+        decode rows, then the chunk's token at ``last_idx``; new views)."""
+        B = block_tables.shape[0]
+        C = input_ids.shape[1] - B
+        act = jnp.ones((B,), bool) if active is None else active > 0
+        step = types.SimpleNamespace(
+            tables=block_tables, pos=pos, active=act, decodes=jnp.any(act),
+            table=block_table, start=start, slot=slot[0], n_valid=slot[1])
+        valid = jnp.concatenate([act, jnp.arange(C) < slot[1]])
+        x, new = self._serve("joint", self._embed(input_ids.value[0]), views,
+                             step, valid)
+        h = jnp.concatenate(
+            [x[:B], jax.lax.dynamic_slice_in_dim(x, B + last_idx, 1, 0)])
         return self._final(h[None]), new
 
 

@@ -36,6 +36,8 @@ from paddle_tpu.ops import select
 
 from benchmarks.drivers import serve_ssm_moe as drv
 from benchmarks.reference import ssm_moe_lm as ref
+from tests.test_fused_tick import _drive, _partitioned as _chunks
+from tests.test_fused_tick import _server as _fused_tick_server
 
 
 @pytest.fixture(autouse=True)
@@ -387,11 +389,307 @@ def test_snapshot_and_restore_carry_the_state(built):
     assert fresh.run()[rid] == want
 
 
+# ---------------------------- (c') a chunk rides in the decode trip's call
+def _two_programs(model, **kw):
+    """The same server made to run every chunk through ``_chunk_prefill_fn``
+    (the path of a slot-state class WITHOUT the joint step)."""
+    return _fused_tick_server(model, two_programs=True, **{
+        "max_batch": 3, "max_len": 192, "block_size": BS,
+        "prefill_chunk": CHUNK, **kw})
+
+
+def _joint_operands(srv, seq, slot, rows, chunk_no):
+    """A state worth comparing, and the operands of one step over it: the
+    slots in ``rows`` hold a request of 20 tokens each and are about to
+    decode its next token, ``slot`` has had ``chunk_no`` chunks of ``seq``
+    and is about to take the next. Returns (flat pools, slot pools, the
+    decode operands (tokens, tables, pos), the chunk operands (chunk,
+    table, start, last_idx, slot triple))."""
+    ex, params = srv._exec, srv.params
+    flat, slot_p = list(ex.pools), list(ex.slot_pools)
+    W, B = srv._table_width, srv.max_batch
+    tables = np.zeros((B, W), np.int32)
+
+    def prefill(s, toks, upto):
+        nonlocal flat, slot_p
+        need = -(-len(toks) // BS)
+        tables[s, :need] = [srv.alloc.alloc() for _ in range(need)]
+        for start in range(0, upto, CHUNK):
+            end = min(start + CHUNK, upto)
+            chunk = np.zeros((1, CHUNK), np.int32)
+            chunk[0, :end - start] = toks[start:end]
+            _, flat, slot_p, _ = ex._chunk_prefill_fn(
+                params, jnp.asarray(chunk), flat, jnp.asarray(tables[s]),
+                jnp.int32(start), jnp.int32(end - start - 1), None, (),
+                slot_p, jnp.asarray([s, end - start, end == len(toks)],
+                                    jnp.int32))
+
+    tokens, pos = np.zeros((B,), np.int32), np.zeros((B,), np.int32)
+    for s in rows:
+        mine = _tokens(21, 40 + s)
+        prefill(s, mine, 20)
+        tokens[s], pos[s] = mine[20], 20
+    prefill(slot, seq, chunk_no * CHUNK)
+    start = chunk_no * CHUNK
+    end = min(start + CHUNK, len(seq))
+    chunk = np.zeros((1, CHUNK), np.int32)
+    chunk[0, :end - start] = seq[start:end]
+    return flat, slot_p, (tokens, tables, pos), (
+        jnp.asarray(chunk), jnp.asarray(tables[slot]), jnp.int32(start),
+        jnp.int32(end - start - 1),
+        jnp.asarray([slot, end - start, end == len(seq)], jnp.int32))
+
+
+@pytest.mark.parametrize("rows,chunk_no", [
+    ((0, 2), 1), ((0, 2), 0), ((2,), 2), ((), 1)],
+    ids=["second_chunk_of_a_prompt", "first_chunk_starts_from_zero",
+         "final_partial_chunk", "every_decode_row_masked"])
+def test_the_joint_step_is_the_two_steps_one_after_the_other(built, rows,
+                                                            chunk_no):
+    """``paged_decode_chunk_step`` over B + C rows against
+    ``paged_decode_step`` and then ``paged_prefill_chunk`` on the same
+    views: the decode rows' hidden states, the chunk's row at ``last_idx``,
+    the K/V pool, every slot's state and conv tail. The rows of one product
+    are independent, so what differs is summation order at most; a slot
+    that neither decodes nor prefills keeps its state bit for bit."""
+    model, _ = built
+    srv = _server(model)
+    ex, params = srv._exec, srv.params
+    seq = _tokens(41, 30)                       # 16 + 16 + 9
+    flat, slot_p, (tokens, tables, pos), ck = _joint_operands(
+        srv, seq, 1, rows, chunk_no)
+    chunk, table, start, last_idx, triple = ck
+    B = srv.max_batch
+    active = np.zeros((B,), np.int32)
+    active[list(rows)] = 1
+    bt = np.where(active[:, None] > 0, tables, 0)
+    masked = jnp.asarray(active), jnp.asarray(bt), jnp.asarray(pos * active)
+
+    def two_steps():
+        act, btv, posv = masked
+        h_rows, new = model.model.paged_decode_step(
+            Tensor(jnp.asarray(tokens)[:, None]),
+            ex._pool_views(flat, slot_p), btv, posv, active=act)
+        h_last, new = model.model.paged_prefill_chunk(
+            Tensor(chunk), new, table, start, last_idx=last_idx, slot=triple)
+        model.model.take_step_stats()
+        return jnp.concatenate([h_rows.value[:, 0], h_last.value[0]]), new
+
+    def joint():
+        act, btv, posv = masked
+        ids = jnp.concatenate([jnp.asarray(tokens)[None], chunk], axis=1)
+        h, new = model.model.paged_decode_chunk_step(
+            Tensor(ids), ex._pool_views(flat, slot_p), btv, posv, table,
+            start, last_idx, active=act, slot=triple)
+        model.model.take_step_stats()
+        return h.value[0], new
+
+    want_h, want = functional_call(model, params, call_fn=two_steps)
+    got_h, got = functional_call(model, params, call_fn=joint)
+    keep = list(rows) + [B]
+    np.testing.assert_allclose(np.asarray(got_h)[keep],
+                               np.asarray(want_h)[keep], rtol=2e-5,
+                               atol=1e-6)
+    (wf, ws), (gf, gs) = ex._flat_pools(want), ex._flat_pools(got)
+    for a, b in zip(gf, wf):
+        # (block 0 is the masked rows' scratch: nobody reads it)
+        np.testing.assert_allclose(np.asarray(a)[1:], np.asarray(b)[1:],
+                                   rtol=2e-5, atol=1e-6)
+    before = [np.asarray(a) for a in slot_p]
+    for a, b, was in zip(gs, ws, before):
+        a, b = np.asarray(a), np.asarray(b)
+        scale = max(float(np.abs(b).max()), 1e-6)
+        assert np.abs(a - b).max() <= 2e-5 * scale
+        for s in range(B):
+            if s != 1 and s not in rows:
+                np.testing.assert_array_equal(a[s], was[s])
+    # the chunk moved its slot's state, a decoding row its own
+    assert any(np.abs(np.asarray(a)[1] - was[1]).max() > 0
+               for a, was in zip(gs, before))
+
+
+MEETS = [(0, _tokens(9, 31), 30), (2, _tokens(40, 32), 20),
+         (3, _tokens(21, 33), 24), (9, _tokens(70, 34), 12)]
+TOGETHER = [(0, _tokens(12, 35), 40), (3, _tokens(37, 36), 10),
+            (3, _tokens(50, 37), 10)]
+LONE = [(0, _tokens(37, 38), 6)]
+
+
+@pytest.mark.parametrize("case", ["a_chunk_meets_decoding_rows",
+                                  "two_prefilling_slots_in_one_tick",
+                                  "a_lone_prompt"])
+def test_chunks_that_ride_serve_the_references_tokens(built, case):
+    """Token for token what the two-program path serves, and the
+    reference's; how each chunk went is counted under ``fused`` /
+    ``second_chunk`` / ``no_decoding_row``, never ``slot_state``."""
+    model, weights = built
+    arrivals = {"a_chunk_meets_decoding_rows": MEETS,
+                "two_prefilling_slots_in_one_tick": TOGETHER,
+                "a_lone_prompt": LONE}[case]
+    ref_srv = _two_programs(model, max_batch=4)
+    assert ref_srv._decode_chunk is None
+    want = _drive(ref_srv, arrivals)
+    srv = _server(model, max_batch=4)
+    got = _drive(srv, arrivals,
+                 check=lambda s, _: s.assert_conserved())
+    assert got == want
+    for i, (_, prompt, new) in enumerate(arrivals):
+        assert _gaps(weights, prompt, got[i][len(prompt):]).max() < TOL
+    total, fused, alone = _chunks(srv)
+    assert _chunks(ref_srv) == (total, 0, {"two_programs": total})
+    assert set(alone) <= {"no_decoding_row", "second_chunk"}
+    if case == "a_lone_prompt":
+        assert (total, fused, alone) == (3, 0, {"no_decoding_row": 3})
+    elif case == "two_prefilling_slots_in_one_tick":
+        assert alone["second_chunk"] >= 3 and fused >= 3
+    else:
+        assert fused >= total // 2 and alone["no_decoding_row"] >= 1
+
+
+def test_a_masked_call_leaves_every_slots_state_bit_for_bit(built):
+    """A tick's second chunk runs the joint program with every decode row
+    masked: the rows' update is skipped (``lax.cond`` on the program's own
+    ``active``), so the slots that decode keep state AND conv tail as they
+    were, whatever tokens the masked rows carry."""
+    model, _ = built
+    srv = _server(model)
+    ex = srv._exec
+    seq = _tokens(41, 30)
+    flat, slot_p, (tokens, tables, pos), ck = _joint_operands(
+        srv, seq, 1, (0, 2), 1)
+    before = [np.asarray(a) for a in slot_p]
+    B = srv.max_batch
+    zeros = jnp.zeros((B,), jnp.int32)
+    out = ex._decode_chunk_fn(
+        srv.params, jnp.asarray(tokens), flat,
+        jnp.zeros((B, srv._table_width), jnp.int32), zeros,
+        jnp.zeros((B,), jnp.float32), zeros, jnp.zeros((B,), jnp.float32),
+        zeros, srv._base_key, ex.prev_stack(None, 1), *ck[:4], True,
+        slot_p, ck[4])
+    _, lg, _, new_slot, _ = out
+    assert np.isfinite(np.asarray(lg)).all()
+    for a, was in zip(new_slot, before):
+        a = np.asarray(a)
+        for s in (0, 2):
+            np.testing.assert_array_equal(a[s], was[s])
+        assert np.abs(a[1] - was[1]).max() > 0
+
+
+LONG = [(0, _tokens(21, 41), 40), (0, _tokens(9, 43), 40),
+        (4, _tokens(60, 42), 30)]
+
+
+@pytest.mark.parametrize("victim", ["a_decoding_row", "the_prefilling_slot"])
+def test_preempt_with_a_joint_trip_pending_carries_the_state(built, victim):
+    model, weights = built
+    want = _drive(_two_programs(model), LONG)
+    hit = []
+
+    def preempt(srv, step):
+        if step == 6:
+            assert srv._trips and _chunks(srv)[1] >= 2 and srv._prefilling[2]
+            assert srv._slots[2].pf_next == 48          # 3 of 4 chunks ran
+            assert srv._preempt_slot(0 if victim == "a_decoding_row" else 2)
+            assert srv._trips == []
+            hit.append(step)
+        srv.assert_conserved()
+
+    srv = _server(model, telemetry=True)
+    got = _drive(srv, LONG, check=preempt)
+    assert got == want and hit == [6]
+    assert _count(srv, "serving_decode_trips_retired_early",
+                  reason="preempt") == 1
+    if victim == "a_decoding_row":
+        assert _count(srv, "serving_state_saves") == 1
+    for i, (_, prompt, new) in enumerate(LONG):
+        assert _gaps(weights, prompt, got[i][len(prompt):]).max() < TOL
+    _chunks(srv)
+
+
+def test_save_slot_with_a_joint_trip_pending_is_the_reference_state(built):
+    """``save_slot`` retires the pending joint trip first: a decoding
+    slot's state is the reference's over exactly the tokens the engine then
+    holds, and the prefilling slot's over the chunks that ran."""
+    model, weights = built
+    srv = _server(model, telemetry=True)
+    for step in range(7):
+        for at, prompt, new in LONG:
+            if at == step:
+                srv.submit(prompt, max_new_tokens=new)
+        srv.step()
+    assert srv._trips and srv._prefilling[2] and _chunks(srv)[1] >= 2
+    saved = {s: srv._exec.save_slot(s) for s in (0, 2)}
+    assert srv._trips == []
+    consumed = {}
+    req = srv._slots[0]
+    consumed[0] = (list(req.prompt) + list(req.generated))[:int(srv.pos[0])]
+    consumed[2] = list(srv._slots[2].prompt)[:srv._slots[2].pf_next]
+    assert len(consumed[2]) == 48
+    for s, toks in consumed.items():
+        want = ref.states_at(weights, TINY, toks)
+        for j, i in enumerate(MAMBA):
+            ssm = saved[s][2 * j]
+            assert np.abs(ssm - want[i]).max() < 1e-4 * np.abs(want[i]).max()
+
+
+def test_snapshot_and_restore_with_a_joint_trip_pending(built):
+    model, _ = built
+    want = _drive(_two_programs(model), LONG)
+    srv = _server(model, telemetry=True)
+    rids = {}
+    for step in range(7):
+        for i, (at, prompt, new) in enumerate(LONG):
+            if at == step:
+                rids[i] = srv.submit(prompt, max_new_tokens=new)
+        srv.step()
+    assert srv._trips and srv._prefilling[2] and _chunks(srv)[1] >= 2
+    snap = srv.snapshot()
+    assert srv._trips == []
+    fresh = _server(model)
+    fresh.restore(snap)
+    out = fresh.run()
+    assert [out[rids[i]] for i in range(3)] == [want[i] for i in range(3)]
+    out = srv.run()                    # the captured server goes on
+    assert [out[rids[i]] for i in range(3)] == [want[i] for i in range(3)]
+    srv.assert_conserved()
+
+
+def test_the_benchmarks_warm_up_compiles_all_a_window_uses(built):
+    """The Granite case of ``tests/test_fused_tick.py``'s warm-up test:
+    ``benchmarks/drivers/serve_paged.py::measure`` warms with two prompts of
+    ``prefill_chunk + 9`` tokens, 4 new tokens each, drained. After it a
+    chunk that meets decoding rows, two chunks in a tick, a decode-only tick
+    and a masked chunk compile nothing: the server has its two programs."""
+    from paddle_tpu.analysis.recompile_guard import compile_count
+
+    model, _ = built
+    srv = _server(model, max_batch=4)
+    assert srv._chunk_prefill is None
+    rng = np.random.default_rng(0)
+    for _ in range(2):
+        srv.submit(rng.integers(1, TINY["vocab_size"],
+                                size=srv.prefill_chunk + 9).tolist(),
+                   max_new_tokens=4, temperature=0.0)
+    srv.run()
+    warm = _chunks(srv)
+    assert warm[0] == 4
+    before = compile_count()
+    got = _drive(srv, MEETS + TOGETHER)
+    assert compile_count() == before
+    total, fused, alone = _chunks(srv)
+    assert fused - warm[1] >= 8 and alone["second_chunk"] >= 3
+    assert len(got) == len(MEETS) + len(TOGETHER)
+
+
 # ------------------------------------------------------- (d) what is refused
 def test_prefix_sharing_is_off_and_unsupported_features_are_named(built):
     model, _ = built
     srv = _server(model)
-    assert srv._exec.chunk_alone_why == "slot_state"
+    # slot state does not keep a chunk out of the decode trip's call: the
+    # class has the joint step, so the server has the two programs of a
+    # dense one
+    assert srv.cache_spec.has_slot_state
+    assert srv._exec.chunk_alone_why is None and srv._chunk_prefill is None
     shared = _tokens(48, 20)
     for tail in (1, 2):
         srv.submit(shared + [tail], max_new_tokens=4)
@@ -481,9 +779,9 @@ def test_the_published_cut_has_the_bytes_the_issue_reckons():
 
 # --------------------------------------------------------- (f) the counters
 def test_the_counters_step_for_step(built):
-    """One request alone: every chunk runs alone under ``slot_state``; the
-    decode rows and contexts, the expert pairs (3 a real row a layer, held or
-    absent) and the state's gauges are what the closed forms say; the expert
+    """One request alone: every chunk meets no decoding row and none runs
+    under ``slot_state``; the decode rows and contexts, the expert pairs (3
+    a real row a layer, held or absent) and the state's gauges are what the closed forms say; the expert
     counts add no program call and no phase to a tick."""
     model, _ = built
     srv = _server(model, telemetry=True)
@@ -497,7 +795,9 @@ def test_the_counters_step_for_step(built):
     n0 = compile_count()
     assert _count(srv, "serving_prefill_chunks") == 3
     assert _count(srv, "serving_prefill_chunks_alone",
-                  reason="slot_state") == 3
+                  reason="no_decoding_row") == 3
+    assert _count(srv, "serving_prefill_chunks_alone",
+                  reason="slot_state") == 0
     assert _count(srv, "serving_prefill_tokens") == n
     assert _count(srv, "serving_decode_rows") == ticks
     assert _count(srv, "serving_decode_ctx") == sum(range(n + 1, n + new))

@@ -118,7 +118,7 @@ def _teacher_forced_logits(model, seq, n_prefill, joint, slot=1):
         ops = (jnp.asarray(chunk), jnp.asarray(table), jnp.int32(start),
                jnp.int32(end - start - 1))
         if joint:
-            _, lg, flat, stats = ex._decode_chunk_fn(
+            _, lg, flat, _, stats = ex._decode_chunk_fn(
                 params, jnp.zeros((B,), jnp.int32), flat,
                 jnp.zeros((B, srv._table_width), jnp.int32),
                 jnp.zeros((B,), jnp.int32), *zeros,
