@@ -304,6 +304,10 @@ class PagedExecutor:
                 else:
                     p = p[slot]
                 out.append(np.asarray(p))   # graftlint: noqa[host-sync]
+        if out:
+            # every program call threads the slot pools through: a read of
+            # them waited for the newest call
+            self.engine._calls_drained("save_slot")
         return out
 
     def restore_slot(self, slot: int, arrays) -> None:

@@ -90,6 +90,7 @@ class _Trip:
     tick: int                   # flight seq of the tick that dispatched it
     stats: tuple = ()           # step stats of this call and of the chunk
     #                             calls before it, on the device, unread
+    call: int = 0               # its program call's number (_call)
 
 
 @dataclass
@@ -103,6 +104,7 @@ class _Chunk:
     start: int                  # first position, block-aligned
     end: int                    # one past its last real token
     n: int                      # length of the sequence being prefilled
+    call: int = 0               # number of the program call that carried it
 
 
 class GenerationServer:
@@ -641,6 +643,29 @@ class GenerationServer:
             "serving_moe_load_max",
             "rows on the busiest held expert of a layer, summed over "
             "layers and program calls")
+        # what the engine knows of the device's queue (docs/serving.md,
+        # "The pending trip"): every stretch from a blocking read that
+        # left no program call in flight to where the next dispatch begins
+        self._c_starved = reg.counter(
+            "serving_device_starved_seconds",
+            "seconds the device's queue was KNOWN to be empty while the "
+            "server had work (a lower bound on idle; after label: the "
+            "read that emptied it — first_token_wait, decode_wait, a "
+            "between-steps reason — or arrival); telemetry on only")
+        self._c_no_work = reg.counter(
+            "serving_device_no_work_seconds",
+            "seconds the device's queue was known to be empty and the "
+            "server had no request; telemetry on only")
+        # program calls dispatched / known to have finished: plain ints,
+        # kept with telemetry off too. The device runs one stream in
+        # order, so a blocking read of call n's outputs proves every call
+        # up to n finished
+        self._calls_out = 0
+        self._calls_seen = 0
+        # False where some device program runs outside this count (a
+        # drafter with a program of its own): no span, no reading
+        self._queue_tracked = True
+        self._q_open: Any = None        # the open device-queue span
         self._stats_unread: List[Any] = []
         # decode trips dispatched and not read yet, oldest first: one
         # between steps, two for the moment between a dispatch and the
@@ -809,6 +834,13 @@ class GenerationServer:
                     self._spec_scan = self._exec.spec_scan
                 else:
                     self._spec_verify = self._exec.spec_verify
+                    # a host-side drafter runs a program of its own that
+                    # this engine neither dispatches nor reads
+                    self._queue_tracked = False
+        if self._tel.enabled:
+            # nothing dispatched yet: the queue is known empty from here on
+            self._queue_open(self._tel.clock(), "start")
+            self._queue_settle("no_work")
 
     # ------------------------------------------------------------ compiled fns
     @property
@@ -1047,7 +1079,89 @@ class GenerationServer:
             tr.set_meta(rid, tenant=tenant, priority=priority,
                         prompt_len=len(prompt), adapter=adapter or "")
             tr.begin(rid, "queued", priority=priority, tenant=tenant)
+            self._work_arrived()
         return rid
+
+    # ----------------------------------------------------------- device queue
+    def _call(self, prog: str, fn, *args):
+        """Dispatch one program call, ``fn(*args)``, and count it: its
+        number is ``_calls_out`` once this returns, which the blocking read
+        of its outputs hands to :meth:`_call_read`. An open device-queue
+        span ends where the call BEGINS — somewhere inside it the device
+        starts to run, and a lower bound on idle claims none of it."""
+        if self._q_open is not None:
+            self._queue_close(prog=prog)
+        out = fn(*args)
+        self._calls_out += 1
+        return out
+
+    def _call_read(self, call: int, ph, after: Optional[str] = None) -> None:
+        """A blocking host read of program call ``call``'s outputs has
+        returned (``ph``: the wait phase around it): that call and every
+        call dispatched before it have finished — the device runs one
+        stream in order. Where none is left in flight the device's queue is
+        KNOWN to be empty from the read's end until the next dispatch, and
+        with telemetry on a span on the device-queue row opens (named by
+        :meth:`_queue_settle`, ended by :meth:`_call`). A lower bound: a
+        queue that ran dry before the host looked is not seen until it
+        looks."""
+        if call > self._calls_seen:
+            self._calls_seen = call
+        if self._tel.enabled and self._calls_seen == self._calls_out:
+            self._queue_open(ph.t1, after or ph.name)
+
+    def _calls_drained(self, after: str) -> None:
+        """A blocking read of state that EVERY dispatched call threads
+        through (the pools) has returned: all of them have finished."""
+        self._calls_seen = self._calls_out
+        if self._tel.enabled:
+            self._queue_open(self._tel.clock(), after)
+            self._queue_settle()
+
+    def _queue_open(self, t0: float, after: str) -> None:
+        """Open the device-queue span at ``t0`` (telemetry on), unless one
+        is open or this server keeps no count."""
+        if self._q_open is None and self._queue_tracked:
+            self._q_open = self._tel.queue_span(t0, after, self._tick_seq)
+
+    def _queue_settle(self, name: Optional[str] = None) -> None:
+        """Name the open device-queue span, once what the read decided has
+        been folded: ``starved`` where the server has work (an occupied
+        slot, a queued request), ``no_work`` where it has none."""
+        q = self._q_open
+        if q is not None and q.name is None:
+            q.settle(name or (
+                "starved" if len(self._sched) > 0
+                or any(sl is not None for sl in self._slots) else "no_work"))
+
+    def _queue_close(self, **args) -> float:
+        """End the open device-queue span now and book its seconds;
+        returns the reading of the clock it ended at."""
+        q, self._q_open = self._q_open, None
+        t1 = q.close(**args)
+        if q.name == "starved":
+            self._c_starved.inc(t1 - q.t0, after=q.args["after"])
+        else:
+            self._c_no_work.inc(t1 - q.t0)
+        return t1
+
+    def _work_arrived(self) -> None:
+        """A request entered a server that had none (telemetry on): what
+        follows an open ``no_work`` span is the host's time to dispatch it,
+        ``starved`` after ``arrival``."""
+        q = self._q_open
+        if q is not None and q.name == "no_work":
+            self._queue_open(self._queue_close(), "arrival")
+            self._queue_settle("starved")
+
+    def device_calls_in_flight(self) -> Optional[int]:
+        """Program calls dispatched and not yet KNOWN to have finished (an
+        upper bound on what the device still has to run; 0 once ``run()``
+        has drained); None for a server that cannot keep the count (a
+        host-side drafter's program runs outside it)."""
+        if not self._queue_tracked:
+            return None
+        return self._calls_out - self._calls_seen
 
     def _bucket_for(self, n: int) -> int:
         for b in self.buckets:
@@ -1056,25 +1170,32 @@ class GenerationServer:
         raise ValueError(f"prompt length {n} exceeds largest bucket "
                          f"{self.buckets[-1]}")
 
-    def _first_token(self, req: _Request, lg) -> int:
-        """Sample the first generated token from prefill logits (1, V) —
-        same ``next_token`` as model.generate, so temperature/top_k/top_p
-        semantics match; one host sync per assignment. Greedy requests
-        skip the eager sampling-op chain (fold_in + filtering, ~1ms of
-        dispatch per admission) for a host argmax — same token."""
+    def _first_token(self, req: _Request, lg, call: int) -> int:
+        """Sample the first generated token from prefill logits (1, V), an
+        output of program call ``call`` — same ``next_token`` as
+        model.generate, so temperature/top_k/top_p semantics match; one
+        host sync per assignment. Greedy requests skip the eager
+        sampling-op chain (fold_in + filtering, ~1ms of dispatch per
+        admission) for a host argmax — same token."""
         tel, tick = self._tel, self._tick_seq
         if req.temperature == 0.0:
-            with tel.phase("first_token_wait", tick, rid=req.rid):
+            with tel.phase("first_token_wait", tick, rid=req.rid) as ph:
                 # (the whole (1, V) array: ``lg[0]`` would launch an eager
                 # slice between the chunk and the decode program)
                 row = np.asarray(lg)[0]
-            return int(np.argmax(row))
-        from ..models.generation import next_token
+            first = int(np.argmax(row))
+        else:
+            from ..models.generation import next_token
 
-        key = jax.random.fold_in(self._base_key, (req.rid << 20) | 1)
-        nxt, _ = next_token(lg, key, req.temperature, req.top_k, req.top_p)
-        with tel.phase("first_token_wait", tick, rid=req.rid):
-            return int(nxt[0])
+            key = jax.random.fold_in(self._base_key, (req.rid << 20) | 1)
+            nxt, _ = next_token(lg, key, req.temperature, req.top_k,
+                                req.top_p)
+            with tel.phase("first_token_wait", tick, rid=req.rid) as ph:
+                first = int(nxt[0])
+        self._call_read(call, ph)
+        # (the request that just got its token is work)
+        self._queue_settle("starved")
+        return first
 
     def _activate_slot(self, slot: int, req: _Request, first: int) -> None:
         """Move a freshly-prefilled request into the decode phase."""
@@ -1129,9 +1250,11 @@ class GenerationServer:
         # Rows beyond the true prompt length hold right-pad garbage, but
         # decode writes sequentially from pos=n, overwriting each such row
         # BEFORE the attention mask (arange <= pos) can reach it.
-        lg, self._caches = self._prefill(bucket)(
+        lg, self._caches = self._call(
+            "prefill_dense", self._prefill(bucket),
             self.params, jnp.asarray(prompt), n, self._caches, slot)
-        self._activate_slot(slot, req, self._first_token(req, lg))
+        self._activate_slot(slot, req,
+                            self._first_token(req, lg, self._calls_out))
         self._slots[slot] = req
 
     def _fill_free_slots(self) -> int:
@@ -1331,7 +1454,9 @@ class GenerationServer:
         state it would have seen. Returns False (entry untouched) if
         device headroom vanished."""
         req = ent.req
-        res = self._offload.swap_in(ent.swap, self._pools)
+        # (the upload's writes into the pools are device work too)
+        res = self._call("swap_in", self._offload.swap_in, ent.swap,
+                         self._pools)
         if res is None:
             return False
         if res == "corrupt":
@@ -1363,7 +1488,8 @@ class GenerationServer:
             # the window rings and recurrent state the request left with
             tel = self._tel
             _t0 = tel.clock() if tel.enabled else 0.0
-            self._exec.restore_slot(slot, handle.extra)
+            self._call("restore_slot", self._exec.restore_slot, slot,
+                       handle.extra)
             if tel.enabled:
                 tel.tracer.complete(req.rid, "state_restore", _t0,
                                     tel.clock(), slot=slot)
@@ -1598,7 +1724,8 @@ class GenerationServer:
         if self._decode_chunk is None:
             aidx = (jnp.asarray(self.aidx[slot:slot + 1])
                     if self._lora is not None else None)
-            lg, self._pools, self._slot_pools, *stats = self._chunk_prefill(
+            lg, self._pools, self._slot_pools, *stats = self._call(
+                "chunk_prefill", self._chunk_prefill,
                 self.params, chunk, self._pools, table, start, last_idx,
                 aidx, self._lora_flat(), self._slot_pools,
                 self._slot_operand(ck))
@@ -1612,12 +1739,14 @@ class GenerationServer:
             bt, posv, active = self._masked_rows
             temps, topks, topps, _, _ = self._samp_arrays()
             # (the stack of an all-masked trip is nobody's tokens)
-            _, lg, self._pools, self._slot_pools, *stats = self._decode_chunk(
+            _, lg, self._pools, self._slot_pools, *stats = self._call(
+                "decode_chunk_masked", self._decode_chunk,
                 self.params, jnp.asarray(self.tokens), self._pools, bt, posv,
                 temps, topks, topps, active, self._base_key,
                 self._exec.prev_stack(None, 1), chunk, table, start,
                 last_idx, self._all_greedy(range(self.max_batch)),
                 self._slot_pools, self._slot_operand(ck))
+        ck.call = self._calls_out
         # (read with the next decode trip's tokens, not now)
         self._stats_unread += stats
         self._c_pf_alone.inc(reason=why)
@@ -1664,7 +1793,8 @@ class GenerationServer:
         if req.replay is not None:
             self._activate_replayed(slot, req)
         else:
-            self._activate_slot(slot, req, self._first_token(req, lg))
+            self._activate_slot(slot, req,
+                                self._first_token(req, lg, ck.call))
         self._prefilling[slot] = None
         if self.role == "prefill" and self._slots[slot] is req:
             # prefill-class replica: the request now holds exactly
@@ -2081,7 +2211,8 @@ class GenerationServer:
             temps, topks, topps, _, aidx = self._samp_arrays()
             if ck is None:
                 stack, self._pools, self._slot_pools, *stats = \
-                    self._decode_paged(
+                    self._call(
+                        "decode_paged", self._decode_paged,
                         self.params, jnp.asarray(self.tokens), self._pools,
                         jnp.asarray(bt), jnp.asarray(posv), temps, topks,
                         topps, jnp.asarray(feed), key, aidx,
@@ -2091,13 +2222,15 @@ class GenerationServer:
                 _t0 = tel.clock() if tel.enabled else 0.0
                 _w0 = self._wall()
                 stack, lg, self._pools, self._slot_pools, *stats = \
-                    self._decode_chunk(
+                    self._call(
+                        "decode_chunk", self._decode_chunk,
                         self.params, jnp.asarray(self.tokens), self._pools,
                         jnp.asarray(bt), jnp.asarray(posv), temps, topks,
                         topps, jnp.asarray(feed), key,
                         self._exec.prev_stack(prev, k),
                         *self._chunk_operands(ck), self._all_greedy(active),
                         self._slot_pools, self._slot_operand(ck))
+                ck.call = self._calls_out
                 self._c_pf_fused.inc()
                 self._chunk_dispatched(ck, _t0, _w0)
             # rows that this trip takes to the end of their budget: the
@@ -2113,7 +2246,8 @@ class GenerationServer:
             # since the trip before, come back with this trip's tokens
             self._trips.append(_Trip(stack, active, active_mask,
                                      frozenset(ends), k, tick,
-                                     tuple(self._stats_unread) + tuple(stats)))
+                                     tuple(self._stats_unread) + tuple(stats),
+                                     self._calls_out))
             self._stats_unread = []
             self._trip_no += 1
             self.pos = self.pos + active_mask * k
@@ -2153,8 +2287,11 @@ class GenerationServer:
             rows = len(trip.rows)
             # the trip's one host sync, apart from the fold that follows it
             with tel.phase("decode_wait", self._tick_seq, rows=rows,
-                           trip=trip.tick):
+                           trip=trip.tick) as ph:
                 nxt_host = np.asarray(trip.stack)
+            # (a read between steps names its reason, not the phase)
+            self._call_read(trip.call, ph,
+                            reason if self._tick_seq < 0 else None)
             self._fold_step_stats(trip.stats)
             del self._trips[0]
             if reason is None:
@@ -2163,11 +2300,14 @@ class GenerationServer:
                 self._c_early.inc(reason=reason)
             self._harvest_phase(rows, trip.tick, self._harvest_window,
                                 nxt_host, trip.rows, trip.mask)
+            self._queue_settle()
         if keep == 0 and self._stats_unread:
             # chunk calls that no decode trip followed (a server that only
-            # prefills; the last chunks before a read of the counters)
+            # prefills; the last chunks before a read of the counters):
+            # the newest of them is the newest call of all
             self._fold_step_stats(self._stats_unread)
             self._stats_unread = []
+            self._calls_drained(reason or "stats")
 
     def _fold_step_stats(self, stats) -> None:
         """Add the per-layer expert loads that program calls returned beside
@@ -2238,7 +2378,8 @@ class GenerationServer:
                     req = self._slots[s]
                     toks = req.prompt + req.generated
                     ctx[s, :len(toks)] = toks
-                outs, accs, self._pools = self._spec_scan(
+                outs, accs, self._pools = self._call(
+                    "spec_scan", self._spec_scan,
                     self.params, jnp.asarray(ctx), self._pools,
                     jnp.asarray(bt), jnp.asarray(posv), temps, topks, topps,
                     kcaps, jnp.asarray(active_mask), key, aidx,
@@ -2251,19 +2392,23 @@ class GenerationServer:
                 proposals, qprobs = self.drafter.propose(
                     contexts, k, temps=self.temps,
                     key=jax.random.fold_in(key, 1))
-                outs, accs, self._pools = self._spec_verify(
+                outs, accs, self._pools = self._call(
+                    "spec_verify", self._spec_verify,
                     self.params, jnp.asarray(self.tokens),
                     jnp.asarray(proposals), self._pools, jnp.asarray(bt),
                     jnp.asarray(posv), temps, topks, topps,
                     kcaps, jax.random.fold_in(key, 2),
                     None if qprobs is None else jnp.asarray(qprobs),
                     aidx, self._lora_flat(), self._all_greedy(active))
-        with tel.phase("decode_wait", tick, rows=rows, trip=tick):
+        call = self._calls_out
+        with tel.phase("decode_wait", tick, rows=rows, trip=tick) as ph:
             outs, accs = np.asarray(outs), np.asarray(accs)
             if not self._spec_fused:
                 outs, accs = outs[None], accs[None]   # one window a trip
+        self._call_read(call, ph)
         self._harvest_phase(rows, tick, self._harvest_spec, outs, accs,
                             active)
+        self._queue_settle()
         # (a drafter reads the tokens on the host: never left pending)
         self._c_early.inc(reason="spec")
         if tel.enabled:
@@ -3012,6 +3157,7 @@ class GenerationServer:
                         prompt_len=len(req.prompt),
                         adapter=req.adapter or "")
             tr.begin(req.rid, "queued", restored=True)
+            self._work_arrived()
 
     def admit_migrated(self, d: Dict[str, Any], *,
                        source_config: Optional[Dict[str, Any]] = None
@@ -3400,16 +3546,21 @@ class GenerationServer:
             # only occupied slots advance — idle slots must not drift
             # their write position (their garbage scatters would
             # eventually go OOB)
-            stack, self._caches = self._decode(
+            stack, self._caches = self._call(
+                "decode_dense", self._decode,
                 self.params, jnp.asarray(self.tokens), self._caches,
                 jnp.asarray(self.pos), jnp.asarray(self.temps),
                 jnp.asarray(self.topks), jnp.asarray(self.topps),
                 jnp.asarray(active_mask), key)
-        with tel.phase("decode_wait", tick, rows=len(active), trip=tick):
+            call = self._calls_out
+        with tel.phase("decode_wait", tick, rows=len(active),
+                       trip=tick) as ph:
             nxt_host = np.asarray(stack)
+        self._call_read(call, ph)
         self.pos = self.pos + active_mask * nxt_host.shape[0]
         self._harvest_phase(len(active), tick, self._harvest_window,
                             nxt_host, active, active_mask)
+        self._queue_settle()
         return sum(sl is not None for sl in self._slots) + len(self._sched)
 
     def run(self) -> Dict[int, List[int]]:

@@ -8,9 +8,10 @@ primitives — the same promotion ``faults.py`` got when training gained
 fault injection. Serving code keeps importing from here; everything is
 re-exported unchanged.
 """
-from ..telemetry import (DEFAULT_BUCKETS, ENGINE_RID,  # noqa: F401
-                         NULL_FLIGHT, NULL_PHASE, NULL_TRACER, TRAIN_RID,
-                         Counter, FlightRecorder,
+from ..telemetry import (DEFAULT_BUCKETS, DEVICE_QUEUE_RID,  # noqa: F401
+                         DEVICE_QUEUE_SPANS, ENGINE_RID, NULL_FLIGHT,
+                         NULL_PHASE, NULL_TRACER, TRAIN_RID, Counter,
+                         FlightRecorder,
                          Gauge, GoodputLedger, Histogram, MetricsRegistry,
                          ServingTelemetry, SpanTracer, TrainTelemetry,
                          train_watchdog, watchdog)

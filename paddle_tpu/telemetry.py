@@ -25,7 +25,11 @@ Serving tier (facade: :class:`ServingTelemetry`, held by
   reserved :data:`ENGINE_RID` row with the phases of every engine tick
   (:meth:`ServingTelemetry.phase`; each phase is also a ``pt.<name>``
   ``jax.profiler.TraceAnnotation``, which puts it on the clock of the
-  device trace). Completed spans are also forwarded to the host
+  device trace), and the reserved :data:`DEVICE_QUEUE_RID` row with a
+  ``starved`` / ``no_work`` span for every stretch over which the engine
+  KNOWS the device had nothing to run (:meth:`ServingTelemetry.queue_span`;
+  ``pt.starved`` / ``pt.no_work`` on the device trace's clock). Completed
+  spans are also forwarded to the host
   profiler's event recorder whenever a ``paddle_tpu.profiler.Profiler``
   is recording, so serving timelines land in the SAME ``export()`` trace
   as the op-level ``RecordEvent`` spans.
@@ -89,8 +93,9 @@ from . import profiler as _profiler
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
            "SpanTracer", "FlightRecorder", "ServingTelemetry", "watchdog",
-           "DEFAULT_BUCKETS", "TRAIN_RID", "ENGINE_RID", "GoodputLedger",
-           "TrainTelemetry", "train_watchdog"]
+           "DEFAULT_BUCKETS", "TRAIN_RID", "ENGINE_RID", "DEVICE_QUEUE_RID",
+           "DEVICE_QUEUE_SPANS", "GoodputLedger", "TrainTelemetry",
+           "train_watchdog"]
 
 # generic latency-ish bucket ladder (seconds); histograms can override
 DEFAULT_BUCKETS: Tuple[float, ...] = (
@@ -105,7 +110,20 @@ TRAIN_RID = -1
 # reserved rid for the serving engine itself: the phases of every tick
 # (ServingTelemetry.phase), one row below the train loop's
 ENGINE_RID = -2
-_ROW_NAMES = {TRAIN_RID: "train loop", ENGINE_RID: "engine"}
+# reserved rid for what the engine knows of the device's queue: one span for
+# every stretch from a blocking read that left NO program call in flight to
+# where the next call's dispatch begins (ServingTelemetry.queue_span). A row
+# of its own: these spans cross phase and tick boundaries, and the engine
+# row's readers find children by containment.
+DEVICE_QUEUE_RID = -3
+# the names a span of that row can have — ``starved``: the server had work
+# (an occupied slot or a queued request) and the host had not dispatched it;
+# ``no_work``: it had none. A LOWER bound on the device's idle time: a queue
+# that runs dry before the host looks is not seen until the host looks. The
+# benchmark's readers are held to this tuple by a tier-1 test.
+DEVICE_QUEUE_SPANS = ("starved", "no_work")
+_ROW_NAMES = {TRAIN_RID: "train loop", ENGINE_RID: "engine",
+              DEVICE_QUEUE_RID: "device queue"}
 # engine-row phases in which the host does nothing but wait for the device
 _WAIT_PHASES = frozenset(("decode_wait", "first_token_wait"))
 
@@ -887,7 +905,7 @@ class _Phase:
     too, so any ``jax.profiler`` trace of the process carries the phase
     on its host plane, on the clock of its device planes."""
 
-    __slots__ = ("tel", "name", "args", "t0", "dur", "_ann")
+    __slots__ = ("tel", "name", "args", "t0", "t1", "dur", "_ann")
 
     def __init__(self, tel: "ServingTelemetry", name: str, tick: int,
                  args: Dict[str, Any]):
@@ -911,13 +929,54 @@ class _Phase:
 
     def __exit__(self, *exc):
         tel = self.tel
-        t1 = tel.clock()
+        t1 = self.t1 = tel.clock()
         self._ann.__exit__(*exc)
         self.dur = t1 - self.t0
         if self.name in _WAIT_PHASES:
             tel.wait_s += self.dur
         tel.tracer.complete(ENGINE_RID, self.name, self.t0, t1, **self.args)
         return False
+
+
+class _QueueSpan:
+    """One open span on the :data:`DEVICE_QUEUE_RID` row, opened by
+    :meth:`ServingTelemetry.queue_span` at the end of the blocking read
+    that left no program call in flight. It gets its name
+    (:data:`DEVICE_QUEUE_SPANS`) once the engine knows whether it has work
+    left (:meth:`settle`: after the harvest that follows the read), and
+    from then on a ``pt.<name>`` ``jax.profiler.TraceAnnotation`` is open
+    too — begun there and ended in :meth:`close`, across whatever phases and
+    ticks lie between, which the profiler takes: it records a whole event
+    when the annotation ends."""
+
+    __slots__ = ("tel", "name", "t0", "args", "_ann")
+
+    def __init__(self, tel: "ServingTelemetry", t0: float,
+                 args: Dict[str, Any]):
+        self.tel = tel
+        self.name: Optional[str] = None
+        self.t0 = t0
+        self.args = args
+        self._ann = None
+
+    def settle(self, name: str) -> None:
+        if self.name is None:
+            self.name = name
+            self._ann = jax.profiler.TraceAnnotation("pt." + name,
+                                                     **self.args)
+            self._ann.__enter__()
+
+    def close(self, **args) -> float:
+        """End the span now (the next program call is about to be
+        dispatched, or what the server knows has changed); returns the
+        reading of the clock it ended at."""
+        # (a dispatch before the engine settled it: it had work)
+        self.settle("starved")
+        t1 = self.tel.clock()
+        self._ann.__exit__(None, None, None)
+        self.tel.tracer.complete(DEVICE_QUEUE_RID, self.name, self.t0, t1,
+                                 **self.args, **args)
+        return t1
 
 
 NULL_TRACER = _NullTracer()
@@ -969,6 +1028,17 @@ class ServingTelemetry:
         if not self.enabled:
             return NULL_PHASE
         return _Phase(self, name, tick, args)
+
+    def queue_span(self, t0: float, after: str, tick: int) -> _QueueSpan:
+        """Open a span on the :data:`DEVICE_QUEUE_RID` row at ``t0``, a
+        reading of this facade's clock: the end of the blocking read
+        ``after`` (``first_token_wait``, ``decode_wait``, or the reason of
+        a read between steps) that left the device's queue KNOWN to be
+        empty. The caller keeps it, names it (``settle``) and ends it
+        (``close``) where the next program call's dispatch begins. Only
+        for an enabled facade: a disabled one reads no clock to have a
+        ``t0`` from."""
+        return _QueueSpan(self, t0, {"after": after, "tick": tick})
 
     def watchdog(self, **kw) -> List[Dict[str, Any]]:
         kw.setdefault("warm_progs", self.flight.warm_progs)

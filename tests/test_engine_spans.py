@@ -11,7 +11,8 @@ import paddle_tpu as paddle
 from paddle_tpu.inference.scheduler import Scheduler
 from paddle_tpu.inference.serving import GenerationServer
 from paddle_tpu.inference.transport import CountingClock
-from paddle_tpu.telemetry import ENGINE_RID, NULL_PHASE, ServingTelemetry
+from paddle_tpu.telemetry import (DEVICE_QUEUE_SPANS, ENGINE_RID, NULL_PHASE,
+                                  ServingTelemetry)
 
 # children of a tick, in the order a tick runs them; first_token_wait is the
 # one grandchild (inside ``prefill``)
@@ -261,7 +262,12 @@ def test_enabled_telemetry_emits_nested_pt_annotations(model, monkeypatch):
     srv = _server(model, telemetry=True)
     _submit(srv, lens=(21, 9))
     srv.run()
-    log = [e for e in _Recorder.log if e[1].startswith("pt.")]
+    # (the device-queue row's two cross phases and ticks by design and are
+    # held by tests/test_device_queue.py)
+    queue = {"pt." + n for n in DEVICE_QUEUE_SPANS}
+    assert {e[1] for e in _Recorder.log} >= queue
+    log = [e for e in _Recorder.log if e[1].startswith("pt.")
+           and e[1] not in queue]
     assert {e[1] for e in log} == {"pt." + p for p in PHASES} | {
         "pt.tick", "pt.first_token_wait"}
     stack, parents = [], {}

@@ -33,6 +33,7 @@ from paddle_tpu.inference import GenerationServer
 from paddle_tpu.inference.cache_spec import CacheSpecError
 from paddle_tpu.jit import functional_call
 from paddle_tpu.ops import select
+from paddle_tpu.telemetry import ENGINE_RID
 
 from benchmarks.drivers import serve_ssm_moe as drv
 from benchmarks.reference import ssm_moe_lm as ref
@@ -808,7 +809,8 @@ def test_the_counters_step_for_step(built):
     assert 0 < held < rows * 3 * 4
     assert 0 < _count(srv, "serving_moe_experts_active") <= (3 + ticks) * 16
     assert _count(srv, "serving_moe_load_max") >= held / 4
-    seen = {s["name"] for s in srv.telemetry.tracer.spans() if s["rid"] < 0}
+    # (the engine row: the device-queue row beside it is not a tick's phase)
+    seen = {s["name"] for s in srv.telemetry.tracer.spans(ENGINE_RID)}
     assert seen <= {"tick", "admit", "prefill", "first_token_wait",
                     "decode_dispatch", "decode_wait", "harvest"}
     # a second request compiles nothing: the server has its two programs

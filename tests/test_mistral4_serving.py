@@ -32,6 +32,7 @@ from paddle_tpu.inference import GenerationServer
 from paddle_tpu.inference.cache_spec import CacheSpecError
 from paddle_tpu.inference.executor import PagedExecutor
 from paddle_tpu.ops import select
+from paddle_tpu.telemetry import ENGINE_RID
 
 from benchmarks.drivers import serve_latent_moe as drv
 from benchmarks.reference import latent_moe_lm as ref
@@ -518,7 +519,8 @@ def test_the_expert_counts_add_no_program_call_and_no_phase_to_a_tick(built):
     srv.submit(_tokens(18, 14), max_new_tokens=8)
     srv.run()
     assert compile_count() == n0
-    spans = [s for s in srv.telemetry.tracer.spans() if s["rid"] < 0]
+    # (the engine row: the device-queue row beside it is not a tick's phase)
+    spans = srv.telemetry.tracer.spans(ENGINE_RID)
     by_tick = {}
     for s in spans:
         by_tick.setdefault(s.get("args", {}).get("tick"), []).append(

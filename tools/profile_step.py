@@ -18,6 +18,19 @@ between two programs nearly always BEGINS under ``pt.decode_wait`` — the
 device finishes before the host learns of it — so the phase open at its
 beginning is counted, not charged.)
 
+The engine also says, on its own, when it KNOWS the device's queue to be
+empty: ``pt.starved`` / ``pt.no_work`` run from the end of a blocking read
+that left no program call in flight to where the next dispatch begins
+(they cross phases and ticks, and are kept out of the charging above). Two
+lines lay them over the device's real gaps: **soundness**, the share of their
+time during which no op ran on the device (a lower bound on idle must never
+claim busy time; what is missing is the next program's head where the
+trace's two clocks disagree), and
+**coverage**, the share of the device's idle time that lies inside them (what
+is missing began before the host looked). Where most spans hold the start of
+a program that the host had not begun to dispatch, a third line says by how
+much the trace's device plane runs early, and both shares with it moved.
+
 Reads the ``.xplane.pb`` with ``jax.profiler.ProfileData`` and nothing else.
 """
 from __future__ import annotations
@@ -31,6 +44,8 @@ import sys
 from typing import Dict, List, Tuple
 
 OUTSIDE = "outside pt.tick"
+# the device-queue row's annotations (paddle_tpu.telemetry.DEVICE_QUEUE_SPANS)
+QUEUE = ("pt.starved", "pt.no_work")
 _HLO = re.compile(r"^%?([\w\-]+?)(?:\.\d+)? = \(?(\w+\[[\d,]*\])?.*?\s([\w\-]+)\(")
 
 Event = Tuple[str, float, float]          # name, start s, end s
@@ -115,6 +130,98 @@ def charge(bounds, labels, start: float, length: float) -> Dict[str, float]:
     return out
 
 
+def overlap(a: List[Tuple[float, float]], b: List[Tuple[float, float]]
+            ) -> float:
+    """Seconds that lie both in ``a`` and in ``b`` (each a sorted list of
+    disjoint intervals)."""
+    total, i, j = 0.0, 0, 0
+    while i < len(a) and j < len(b):
+        total += max(0.0, min(a[i][1], b[j][1]) - max(a[i][0], b[j][0]))
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
+def plane_skew(spans: List[Event], mods: List[Event]) -> List[float]:
+    """Per span, the seconds by which a program that began INSIDE it and
+    still ran at its end began before that end. The span ends before the
+    host begins to dispatch anything, so no program can have begun there:
+    each is a lower bound on how far the trace's device plane runs early
+    against its host plane."""
+    out = []
+    for _, s, e in spans:
+        began = [m[1] for m in mods if s < m[1] < e < m[2]]
+        if began:
+            out.append(e - min(began))
+    return out
+
+
+def queue_lines(host: List[Event], runs: List[Tuple[float, float]],
+                gaps: List[Tuple[float, float]],
+                mods: List[Event] = ()) -> List[str]:
+    """Soundness and coverage of the engine's own ``pt.starved`` /
+    ``pt.no_work`` against the device's gaps, between its first and its
+    last op; and, where the trace's two planes disagree, by how much."""
+    first, last = runs[0][0], runs[-1][1]
+    idle = [(t, t + length) for t, length in gaps]
+    out = []
+    known_all: List[Event] = []
+    for name in QUEUE:
+        evs = [(n, max(s, first), min(e, last)) for n, s, e in host
+               if n == name and e > first and s < last]
+        known_all += evs
+        known = union(evs)
+        sec = sum(e - s for s, e in known)
+        out.append(f"  {name}: {len(evs)} spans, {sec:.4f} s, of which "
+                   f"{overlap(known, idle):.4f} s with no op on the device")
+    known = union(known_all)
+    sec, idle_s = sum(e - s for s, e in known), sum(e - s for s, e in idle)
+    inside = overlap(known, idle)
+    if not known_all:
+        return ["  no pt.starved / pt.no_work annotation: a program "
+                "without the device-queue row, or telemetry off"]
+    # where the busy part of a span lies: before its first idle moment (the
+    # device still ran when the read returned), after its last (the next
+    # program began before the span ended), or between
+    head = tail = 0.0
+    for s, e in known:
+        mine = [(max(a, s), min(b, e)) for a, b in idle if b > s and a < e]
+        if mine:
+            head += mine[0][0] - s
+            tail += e - mine[-1][1]
+    return out + [
+        f"  soundness {100 * inside / sec:.1f} % of the {sec:.4f} s the "
+        f"engine called the queue empty, no op ran (ops ran for "
+        f"{head:.4f} s at the spans' beginnings, {tail:.4f} s at their "
+        f"ends, {max(sec - inside - head - tail, 0.0):.4f} s between)",
+        f"  coverage {100 * inside / idle_s:.1f} % of the device's "
+        f"{idle_s:.4f} s of idle time lie inside them" if idle_s > 0
+        else "  coverage: the device had no idle time"
+    ] + skew_lines(known_all, mods, idle, sec, idle_s)
+
+
+def skew_lines(spans: List[Event], mods: List[Event],
+               idle: List[Tuple[float, float]], sec: float,
+               idle_s: float) -> List[str]:
+    early = sorted(plane_skew(spans, mods))
+    if 2 * len(early) <= len(spans) or idle_s <= 0:
+        return []
+    shift = early[0]                  # the least: what every span agrees on
+    moved = [(a + shift, b + shift) for a, b in idle]
+    inside = overlap(union(spans), moved)
+    return [
+        f"  clock check: in {len(early)} of {len(spans)} spans the next "
+        f"program begins on the device plane {early[0] * 1e3:.3f}-"
+        f"{early[-1] * 1e3:.3f} ms BEFORE the span ends, which is before "
+        f"the host began to dispatch it: this trace's device plane runs at "
+        f"least {shift * 1e3:.3f} ms early against its host plane; with "
+        f"the device plane moved by that, soundness "
+        f"{100 * inside / sec:.1f} %, coverage "
+        f"{100 * inside / idle_s:.1f} %"]
+
+
 def program_at(mods: List[Event], starts: List[float], t: float):
     """(which run, name) of the program running at ``t``."""
     i = bisect.bisect_right(starts, t + 1e-9) - 1
@@ -151,7 +258,9 @@ def report(planes, top: int, host_prefix: str) -> List[str]:
 
     host = [ev for p, lines in planes.items() if p.startswith("/host:")
             for evs in lines.values() for ev in evs]
-    anns = [ev for ev in host if ev[0].startswith("pt.")]
+    # (the queue row's annotations cross phases: not part of the nesting)
+    anns = [ev for ev in host if ev[0].startswith("pt.")
+            and ev[0] not in QUEUE]
     bounds, labels = phase_timeline(anns)
     mods = sorted(planes[dev].get("XLA Modules", []), key=lambda m: m[1])
     starts = [m[1] for m in mods]
@@ -188,6 +297,9 @@ def report(planes, top: int, host_prefix: str) -> List[str]:
         out.append(f"  {t:8.4f}  {around}: " + ", ".join(
             f"{ph} {sec:.4f}" for ph, sec in parts))
 
+    out += ["", "the engine's own device-queue spans (a LOWER bound on "
+            "idle: the queue known empty, read to next dispatch):"]
+    out += queue_lines(host, runs, gaps, mods)
     out += ["", "host annotations (count, seconds):"]
     seen: Dict[str, List[float]] = {}
     for name, s, e in host:
