@@ -14,5 +14,6 @@ from .mistral4 import (Mistral4Config, Mistral4ForCausalLM,
 from .granitemoehybrid import (GraniteMoeHybridConfig,
                                GraniteMoeHybridForCausalLM,
                                granitemoehybrid_tiny_config)
+from .afmoe import AfmoeConfig, AfmoeForCausalLM, afmoe_tiny_config
 
 __all__ = [n for n in dir() if not n.startswith("_")]

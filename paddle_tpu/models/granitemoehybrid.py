@@ -422,15 +422,11 @@ class _Attention(Layer):
     @staticmethod
     def _attend_chunk(q, kp, vp, step):
         """The chunk's queries q (C, heads, d) over its slot's blocks, in
-        blocks of _ATTN_ROWS: the attention kernel holds a call's query rows
-        (x 4 heads a KV head) whole in VMEM, and a chunk of 256 does not fit
-        (refused by the compiler, PR 35)."""
+        calls of _ATTN_ROWS rows."""
         from ..ops import paged_attention as pa
 
-        return jnp.concatenate([
-            pa.paged_prefill_attention(q[None, s:s + _ATTN_ROWS], kp, vp,
-                                       step.table, step.start + s)[0]
-            for s in range(0, q.shape[0], _ATTN_ROWS)])
+        return pa.paged_prefill_attention_in_rows(q, kp, vp, step.table,
+                                                  step.start, _ATTN_ROWS)
 
     def joint(self, u, view, step):
         """Both of the above over u (B + C, H), the decode rows first: the

@@ -63,11 +63,10 @@ from ..framework.core import Tensor
 from ..nn.layer_base import Layer
 from ..nn.layer.common import Embedding, Linear
 from ..nn.layer.container import LayerList
+from ..ops.paged_attention import masked_attention
 
 __all__ = ["Phi4FlashConfig", "Phi4FlashForCausalLM", "phi4flash_tiny_config",
            "layer_kinds"]
-
-NEG_INF = -1e30
 
 
 @dataclasses.dataclass
@@ -169,19 +168,6 @@ def _diff_combine(out, lam, lam0, subln_w, eps):
     return o.reshape(B, W, (H // 2) * D).astype(out.dtype)
 
 
-def _masked_attention(q, k, v, mask):
-    """q (B, C, H, D); k, v (B, T, KV, D); mask (B, C, T) -> (B, C, H, D);
-    f32 scores and softmax, 1/sqrt(D)."""
-    B, C, H, D = q.shape
-    KV = k.shape[2]
-    qg = q.reshape(B, C, KV, H // KV, D)
-    s = jnp.einsum("bcgrd,btgd->bgrct", qg, k).astype(jnp.float32) \
-        / math.sqrt(D)
-    s = jnp.where(mask[:, None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, -1).astype(q.dtype)
-    return jnp.einsum("bgrct,btgd->bcgrd", p, v).reshape(B, C, H, D)
-
-
 def _val(p):
     return p.value
 
@@ -249,8 +235,8 @@ class _DiffAttention(Layer):
         mask = j <= i
         if self.kind == "window":
             mask = mask & (j > i - self.cfg.sliding_window)
-        o = _masked_attention(self._q(u), kv[0], kv[1],
-                              jnp.broadcast_to(mask, (u.shape[0], T, T)))
+        o = masked_attention(self._q(u), kv[0], kv[1],
+                             jnp.broadcast_to(mask, (u.shape[0], T, T)))
         return self._out(o), kv
 
     # -- decode: one token for every slot
@@ -292,32 +278,11 @@ class _DiffAttention(Layer):
             o = pa.paged_prefill_attention(q, kp, vp, ctx["table"], start,
                                            head_major=True)
             return self._out(o), (kp, vp)
-        # window: the ring holds the window and one block, less than the
-        # window plus a chunk, so the chunk attends the ring's past and its
-        # own K/V side by side and only then overwrites the ring
-        from ..ops.select import XLA, record
-
-        record("window_chunk_attention", XLA)
-        W, bs = self.cfg.sliding_window, kp.shape[2]
-        C = u.shape[1]
-        ring = ctx["ring_row"]                             # (R,) block ids
-        R = ring.shape[0]
-        nb = -(-W // bs)
-        jj = start // bs - nb + jnp.arange(nb)
-        ids = ring[jj % R]
-        past_pos = (jj[:, None] * bs + jnp.arange(bs)[None]).reshape(-1)
-        keys = jnp.concatenate(
-            [pa.gather_block_kv(kp, ids, head_major=True)[0], k[0]])
-        vals = jnp.concatenate(
-            [pa.gather_block_kv(vp, ids, head_major=True)[0], v[0]])
-        qpos = start + jnp.arange(C)
-        kpos = jnp.concatenate([past_pos, qpos])
-        mask = ((kpos[None] >= 0) & (kpos[None] <= qpos[:, None])
-                & (kpos[None] > qpos[:, None] - W))
-        o = _masked_attention(q, keys[None], vals[None], mask[None])
-        valid = jnp.arange(C) < ctx["n_valid"]
-        bid = jnp.where(valid, ring[(qpos // bs) % R], 0)
-        kp, vp = pa._set_tokens(kp, vp, k[0], v[0], bid, qpos % bs, True)
+        # window: attend the ring's past and the chunk side by side, then
+        # overwrite the ring (ops/paged_attention.py)
+        o, kp, vp = pa.window_chunk_attention(
+            q, k[0], v[0], kp, vp, ctx["ring_row"], start, ctx["n_valid"],
+            self.cfg.sliding_window, head_major=True)
         return self._out(o), (kp, vp)
 
 
@@ -550,11 +515,13 @@ class Phi4FlashModel(Layer):
         """Block ids of each row's window ring: slot ``b`` owns blocks
         ``1 + b*R .. (b+1)*R`` of every window layer's pool (block 0 is
         scratch)."""
+        from ..ops.paged_attention import slot_ring_tables
+
         for k, v in zip(self.kinds, views):
             if k == "window":
                 # pool = scratch block + max_batch rings (executor.py)
                 R = -(-self.cfg.sliding_window // v[0].shape[2]) + 1
-                return 1 + rows[:, None] * R + jnp.arange(R)[None, :]
+                return slot_ring_tables(rows, R)
         return None
 
     def paged_decode_step(self, token, views, block_tables, pos, lora=None,

@@ -230,6 +230,19 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, pos,
                                   head_major)
 
 
+def paged_prefill_attention_in_rows(q, k_pool, v_pool, block_table, start,
+                                    rows, head_major=False):
+    """:func:`paged_prefill_attention` of a chunk q (C, H, D) in calls of
+    ``rows`` query rows: the kernel holds a call's query rows (times the
+    query heads a KV head) whole in VMEM, and a chunk of 256 rows at 32
+    query heads of 128 does not fit (the compiler refuses it).
+    Returns (C, H, D)."""
+    return jnp.concatenate([
+        paged_prefill_attention(q[None, s:s + rows], k_pool, v_pool,
+                                block_table, start + s, head_major)[0]
+        for s in range(0, q.shape[0], rows)])
+
+
 def paged_prefill_attention(q, k_pool, v_pool, block_table, start,
                             head_major=False):
     """Chunked-prefill attention: one chunk of queries against ALL paged
@@ -318,6 +331,61 @@ def paged_window_attention(q, k_pool, v_pool, ring_tables, pos, window,
     scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
     p = jax.nn.softmax(scores, -1).astype(q.dtype)
     return jnp.einsum("bgrt,btgd->bgrd", p, cv).reshape(B, 1, H, D)
+
+
+def slot_ring_tables(rows, ring_blocks):
+    """Block ids of each row's window ring: slot ``b`` owns blocks ``1 +
+    b*R .. (b+1)*R`` of every window layer's ring pool (block 0 is scratch;
+    inference/executor.py lays the pools out so). rows (B,) -> (B, R)."""
+    return 1 + rows[:, None] * ring_blocks + jnp.arange(ring_blocks)[None, :]
+
+
+def masked_attention(q, k, v, mask):
+    """q (B, C, H, D); k, v (B, T, KV, D); mask (B, C, T) -> (B, C, H, D);
+    float32 scores and softmax, ``1/sqrt(D)``: attention under any mask, for
+    the callers whose keys are not a causal run of positions."""
+    B, C, H, D = q.shape
+    KV = k.shape[2]
+    qg = q.reshape(B, C, KV, H // KV, D)
+    s = jnp.einsum("bcgrd,btgd->bgrct", qg, k).astype(jnp.float32) \
+        / math.sqrt(D)
+    s = jnp.where(mask[:, None, None], s, NEG_INF)
+    p = jax.nn.softmax(s, -1).astype(q.dtype)
+    return jnp.einsum("bgrct,btgd->bcgrd", p, v).reshape(B, C, H, D)
+
+
+def window_chunk_attention(q, k, v, k_pool, v_pool, ring, start, n_valid,
+                           window, head_major=False):
+    """One prompt chunk over a window layer's ring, then its write: q (1, C,
+    H, D) at positions ``start + arange(C)`` (``start`` block-aligned), k, v
+    (C, KV, D) the chunk's own, ``ring`` (R,) the slot's ring block ids, the
+    first ``n_valid`` rows real. The ring holds the window and one block,
+    less than the window plus a chunk, so the chunk attends the ring's past
+    (the ``ceil(window / bs)`` blocks before its first) and its own K/V
+    side by side, and only then overwrites the ring. Returns (out (1, C, H,
+    D), k_pool, v_pool)."""
+    from .select import XLA, record
+
+    record("window_chunk_attention", XLA)
+    bs = k_pool.shape[2 if head_major else 1]
+    C = q.shape[1]
+    R = ring.shape[0]
+    nb = -(-window // bs)
+    jj = start // bs - nb + jnp.arange(nb)
+    ids = ring[jj % R]
+    past_pos = (jj[:, None] * bs + jnp.arange(bs)[None]).reshape(-1)
+    keys = jnp.concatenate([gather_block_kv(k_pool, ids, head_major)[0], k])
+    vals = jnp.concatenate([gather_block_kv(v_pool, ids, head_major)[0], v])
+    qpos = start + jnp.arange(C)
+    kpos = jnp.concatenate([past_pos, qpos])
+    mask = ((kpos[None] >= 0) & (kpos[None] <= qpos[:, None])
+            & (kpos[None] > qpos[:, None] - window))
+    o = masked_attention(q, keys[None], vals[None], mask[None])
+    valid = jnp.arange(C) < n_valid
+    bid = jnp.where(valid, ring[(qpos // bs) % R], 0)
+    k_pool, v_pool = _set_tokens(k_pool, v_pool, k, v, bid, qpos % bs,
+                                 head_major)
+    return o, k_pool, v_pool
 
 
 _QEPS = 1e-8   # scale floor: an all-zero block quantizes to scale ~0 with

@@ -590,6 +590,87 @@ def test_grouped_products_at_the_768_wide_experts(rows, k, n):
                                np.asarray(want[:held]), rtol=2e-2, atol=2e-2)
 
 
+@pytest.mark.parametrize("rows", [1024, 2048], ids=["tick", "chunk"])
+@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)],
+                         ids=["gate_up", "down"])
+def test_grouped_products_at_trinity_minis_experts(rows, k, n):
+    """64 held experts of 2048 x 1024, half of the pairs held (a tick's 128
+    x 8 pairs, a chunk's 256 x 8): what ``select_grouped_matmul`` chooses,
+    against ragged_dot."""
+    from paddle_tpu.ops import select
+    from paddle_tpu.ops.grouped_matmul import grouped_matmul
+
+    rng = np.random.RandomState(17)
+    x = jnp.asarray(rng.randn(rows, k).astype("float32")).astype(jnp.bfloat16)
+    w = jax.jit(lambda key: (jax.random.normal(key, (64, k, n), jnp.float32)
+                             * 0.02).astype(jnp.bfloat16))(jax.random.key(18))
+    sizes = rng.multinomial(rows // 2, [1 / 64] * 64).astype("int32")
+    sizes[3] = 0                                   # an expert with no row
+    held = int(sizes.sum())
+    g = jnp.asarray(sizes)
+    got = jax.jit(lambda x, w, g: grouped_matmul(x, w, g))(x, w, g)
+    took("expert_gmm", select.GROUPED_MATMUL_ON_TPU)
+    want = jax.lax.ragged_dot(x, w, g, preferred_element_type=jnp.float32)
+    np.testing.assert_allclose(np.asarray(got[:held]),
+                               np.asarray(want[:held]), rtol=2e-2, atol=2e-2)
+
+
+def _trinity_case(B, M, window, seed, N=None):
+    """Head-major pools of 4 KV heads of 128, queries of 32 heads (group
+    8), per-row tables of M blocks of 16 (a ring of 129 for the window
+    walk, an 18,432-token table for the full one) and positions before, at
+    and far past the window."""
+    rng = np.random.RandomState(seed)
+    N = N or 1 + B * M
+    q = jnp.asarray(rng.randn(B, 1, 32, 128).astype("float32"), jnp.bfloat16)
+    pools = [jax.jit(lambda key: jax.random.normal(
+        key, (N, 4, 16, 128), jnp.float32).astype(jnp.bfloat16))(
+            jax.random.key(seed + i)) for i in range(2)]
+    if window:
+        tables = 1 + np.arange(B)[:, None] * M + np.arange(M)[None, :]
+        pos = np.array([0, 17, window - 1, window, 3 * window + 5,
+                        9 * M * 16 + 3, *rng.randint(window, 12000, B - 6)])
+    else:
+        tables = rng.randint(1, N, (B, M))
+        pos = np.array([0, 15, 16, 2047, 2048, M * 16 - 1,
+                        *rng.randint(1000, M * 16, B - 6)])
+    return (q, *pools, jnp.asarray(tables, jnp.int32),
+            jnp.asarray(pos, jnp.int32))
+
+
+@pytest.mark.parametrize("kind", ["window", "full"])
+def test_trinity_minis_attention_walks_at_the_cells_shapes(kind):
+    """128 decode rows, group 8, head-major: the window walk over rings of
+    129 blocks (window 2,048; rings that wrapped many times) and the full
+    walk over 18,432-token tables, each against the jnp composition the
+    select rule would otherwise run."""
+    from paddle_tpu.ops import paged_attention as pa
+    from paddle_tpu.ops import select
+
+    if kind == "window":
+        args = _trinity_case(128, 129, 2048, 41)
+
+        def op(*a):
+            return pa.paged_window_attention(*a, 2048, head_major=True)
+    else:
+        args = _trinity_case(128, 1152, 0, 42, N=20001)
+
+        def op(*a):
+            return pa.paged_decode_attention(*a, head_major=True)
+    got = jax.jit(op)(*args)
+    took("paged_window_attention" if kind == "window" else "paged_attention")
+    prev = select.kernel_mode()
+    select.set_kernel_mode("reference")
+    try:
+        want = jax.jit(op)(*args)
+    finally:
+        select.set_kernel_mode(prev)
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(want, np.float32), rtol=3e-2,
+                               atol=3e-2)
+
+
 @pytest.mark.parametrize("B,S,IN,OUT", [(8, 1, 4096, 4096),
                                         (8, 1, 4096, 14336),
                                         (1, 128, 4096, 4096)],

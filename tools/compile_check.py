@@ -110,7 +110,8 @@ def _paged_case(B, W, quantized, N=512, bs=16, M=32, H=H8, KV=KV8, D=D8,
 
 def _window_case(B, ring=33, window=512, bs=16, KV=10, H=40, D=128):
     """Decode over a per-slot ring of head-major blocks (the differential
-    pair packed 128 wide: Phi-4-mini-flash's window layers)."""
+    pair packed 128 wide: Phi-4-mini-flash's window layers; 4 KV heads of
+    128 at group 8 and a window of 2,048: Trinity-Mini's)."""
     from paddle_tpu.ops import paged_attention_pallas as pk
 
     pool = ((1 + B * ring, KV, bs, D), _BF16)
@@ -173,6 +174,7 @@ _CELL = dict(N=4096, bs=16, M=256)
 # Phi-4-mini-flash's cell: 128 slots, 8192-token tables, 10 packed kv heads
 # (no sublane multiple: head-major blocks)
 _PHI = dict(N=32769, bs=16, M=520, H=40, KV=10, D=128, head_major=True)
+_TRINITY = dict(N=62501, bs=16, M=1152, H=32, KV=4, D=128, head_major=True)
 _CELL_INT8 = dict(N=4096, bs=32, M=128)     # same 4096-token tables
 
 
@@ -234,6 +236,15 @@ def kernel_cases():
         ("paged.phi_pair_last_token_B1",
          lambda: _paged_case(1, 1, False, **_PHI)),
         ("paged.phi_window_decode_B128_ring33", lambda: _window_case(128)),
+        # the Trinity-Mini cell: head-major blocks of 4 KV heads of 128 at
+        # group 8 — rings of 129 blocks over a window of 2,048, the full
+        # layers' tables of 18,432 tokens, a chunk's 128 query rows a call
+        ("paged.trinity_window_decode_B128_ring129", lambda: _window_case(
+            128, ring=129, window=2048, KV=4, H=32)),
+        ("paged.trinity_full_decode_B128_M1152", lambda: _paged_case(
+            128, 1, False, **_TRINITY)),
+        ("paged.trinity_full_prefill_C128_M1152", lambda: _paged_case(
+            1, 128, False, **_TRINITY)),
         ("latent.decode_B128_M196", lambda: _latent_case(128, 1)),
         ("latent.chunk_C256_M196", lambda: _latent_case(1, 256)),
         ("latent.decode_B128_bs16_M784",
@@ -250,6 +261,14 @@ def kernel_cases():
          lambda: _gmm_case(2560, "pallas", E=36, K=768, N=4096)),
         ("experts.ragged_dot_960_pairs_w768",
          lambda: _gmm_case(960, "xla", E=36, N=768)),
+        # the Trinity-Mini cell: 64 held experts of 2048 x 1024; a tick's
+        # 128 x 8 pairs (about half of them held) and a chunk's 2,048
+        ("experts.megablox_512_pairs_e64_w1024",
+         lambda: _gmm_case(512, "pallas", E=64, K=2048, N=1024)),
+        ("experts.megablox_2048_pairs_e64_w1024_down",
+         lambda: _gmm_case(2048, "pallas", E=64, K=1024, N=2048)),
+        ("experts.ragged_dot_512_pairs_e64_w1024",
+         lambda: _gmm_case(512, "xla", E=64, K=2048, N=1024)),
         ("ssm2.step_B96_128x64x128", lambda: _ssm2_case(96, 1)),
         ("ssm2.chunk_C256_128x64x128", lambda: _ssm2_case(1, 256)),
         ("ssm.step_B128_5120x16", lambda: _ssm_case(128, 1)),
@@ -343,6 +362,14 @@ def copies_no_state(text, texts, temps):
     array whole (a Mamba-2 layer's state is 403 MB over 96 slots: it is
     donated and updated in place, a row of it or all rows)."""
     return _whole_copies(text, "slot_pools", "f32", "state")
+
+
+def copies_no_ring(text, texts, temps):
+    """A case's check: the compiled program copies no window layer's ring
+    pool whole (bfloat16 slot pools: 541 MB a pool over 128 slots of
+    Trinity-Mini's rings; donated and written a token or a chunk at a
+    time)."""
+    return _whole_copies(text, "slot_pools", "bf16", "ring")
 
 
 def makes_no_second(shape, temps_gb):
@@ -564,6 +591,29 @@ def program_cases(devs):
             return built["mistral4"][i]
         return build
 
+    def trinity(i):
+        """Trinity-Mini's share as its benchmark cell serves it
+        (benchmarks/configs/trinity-mini-l8-ep2.json) but one period deep —
+        window layers 0-2 (0-1 dense), a full layer at 3 with experts 2-3 —
+        at the published widths, experts 0-63 of 128, half of the
+        vocabulary, 128 slots of 18,432 with 3 x 4.2 MB of rings each,
+        blocks of 16, chunks of 256, a quarter of the cell's pool. ~10 GB
+        of host RAM."""
+        def build():
+            if "trinity" not in built:
+                from paddle_tpu.models.afmoe import (AfmoeConfig,
+                                                     AfmoeForCausalLM)
+
+                built["trinity"] = _serve_programs(
+                    devs, AfmoeConfig(
+                        num_hidden_layers=4, vocab_size=100096,
+                        experts_held=(0, 64), max_position_embeddings=18432),
+                    1, model_cls=AfmoeForCausalLM, max_batch=128,
+                    max_len=18432, block_size=16, prefill_chunk=256,
+                    num_blocks=16385)
+            return built["trinity"][i]
+        return build
+
     def granite4h(i):
         """Granite 4.0-H Small's share as its benchmark cell serves it
         (benchmarks/configs/granite-4.0-h-small-l10-ep2.json): 10 layers at
@@ -591,7 +641,15 @@ def program_cases(devs):
     # program's one-row head is a fused multiply and reduce over the table,
     # which reads as one inside its fusion: its temp bytes say it is none)
     embedding = copies_no_stack("bf16[50176,4096]", "bf16[4096,50176]")
+    stacks1024 = copies_no_stack("bf16[64,2048,1024]", "bf16[64,1024,2048]")
     return [
+        # the joint program: a window layer's chunk reads its ring's past
+        # before any write, then both writes, then both attentions
+        ("serve.trinity_l4_decode_B128", trinity(0), copies_no_pool,
+         copies_no_ring, stacks1024),
+        ("serve.trinity_l4_decode_chunk", trinity(1), copies_no_pool,
+         copies_no_ring, stacks1024,
+         reads_weights_like("serve.trinity_l4_decode_B128")),
         ("serve.granite4h_l10_decode_B96", granite4h(0), copies_no_pool,
          copies_no_state, stacks768, embedding),
         # the joint decode + chunk program, which is every chunk's (PR 36):
