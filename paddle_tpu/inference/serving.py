@@ -632,6 +632,11 @@ class GenerationServer:
             "serving_decode_ctx_shared",
             "positions a decode row-tick attended in the full layer whose "
             "pool other layers share")
+        self._c_ring_groups = reg.counter(
+            "serving_ring_groups",
+            "groups of a window layer's ring walk in decode row-ticks, by "
+            "how the kernel copies them (copy label: group = one copy for "
+            "K and one for V, block = one a block); telemetry on only")
         self._c_state_saves = reg.counter(
             "serving_state_saves",
             "slot states copied to the host (reason label: preempt, "
@@ -706,6 +711,7 @@ class GenerationServer:
         wins = [l.window for l in self.cache_spec.layers
                 if l.kind == "window"]
         self._ctx_window = min(wins) if wins else 0
+        self._ring_plan = None          # set with the block size (paged)
         self._ctx_shared = bool(self.cache_spec.of_kind("shared"))
         # flight-record seq of the tick in progress (0 with telemetry off,
         # -1 between ticks with it on): what every engine-row phase names
@@ -734,6 +740,8 @@ class GenerationServer:
             if bs < 1:
                 raise ValueError(f"block_size must be >= 1, got {block_size}")
             self.block_size = bs
+            if wins:
+                self._ring_plan = self._ring_walk_plan(bs)
             chunk = int(prefill_chunk)
             if chunk < 1:
                 raise ValueError(
@@ -3574,6 +3582,7 @@ class GenerationServer:
         ctx = k * int(pos_after[active].sum()) \
             - len(active) * (k * (k - 1) // 2)
         W, ctx_win, discarded = self._ctx_window, 0, 0
+        walks = [] if self._ring_plan and self._tel.enabled else None
         for s in active:
             req = self._slots[s]
             done = False
@@ -3617,6 +3626,8 @@ class GenerationServer:
                 p0 = int(pos_after[s]) - k
                 ctx_win += sum(min(p0 + t, W)
                                for t in range(1, max(take, 0) + 1))
+                if walks is not None:
+                    walks.extend(range(p0, p0 + max(take, 0)))
             if done:
                 cut = k - max(take, 0)
                 if cut:
@@ -3634,8 +3645,44 @@ class GenerationServer:
             self._c_discarded.inc(discarded)
         if W:
             self._c_dec_ctx_win.inc(ctx_win)
+        if walks:
+            self._count_ring_groups(walks)
         if self._ctx_shared:
             self._c_dec_ctx_shared.inc(ctx)
+
+    def _ring_walk_plan(self, bs):
+        """(window, ring, G, one_copy) of the ring walk of ONE window layer
+        of the spec (the narrowest window), as the attention kernel plans
+        it from the shapes (ops/paged_attention_pallas.py): a decode row's
+        query rows per kv head are the model's query heads per kv head of
+        the pool."""
+        from ..ops import paged_attention_pallas as pk
+
+        spec = self.cache_spec
+        i = min(spec.of_kind("window"), key=lambda j: spec.layers[j].window)
+        l, isz = spec.layers[i], spec.dtype.itemsize
+        ring = spec.ring_blocks(i, bs)
+        rep = max(1, self.cfg.num_attention_heads // l.kv_heads)
+        G, _ = pk.group_plan(rep, l.kv_heads, bs, l.head_dim, ring, isz)
+        return (l.window, ring, G, pk.whole_group_copies(
+            l.kv_heads, bs, l.head_dim, isz, ring, l.layout == "head"))
+
+    def _count_ring_groups(self, walks) -> None:
+        """``serving_ring_groups`` for the ring walks of decode row-ticks at
+        positions ``walks``: the groups that fit one copy
+        (``paged_attention_pallas.group_fits``; a slot's ring is
+        consecutive pool blocks by construction) where the kernel takes
+        whole-group copies at all, and the rest."""
+        from ..ops.paged_attention_pallas import ring_walk_groups
+
+        window, ring, G, one_copy = self._ring_plan
+        groups, fits = ring_walk_groups(walks, window, ring,
+                                        self.block_size, G)
+        group = fits if one_copy else 0
+        if group:
+            self._c_ring_groups.inc(group, copy="group")
+        if groups - group:
+            self._c_ring_groups.inc(groups - group, copy="block")
 
     def step(self) -> int:
         """One server step: admit queued requests, advance one prefill

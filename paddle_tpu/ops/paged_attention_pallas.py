@@ -15,6 +15,9 @@ consumed by ONE online-softmax update. The dense ``gather_block_kv`` copy
 of the context never materializes in HBM, table entries past a row's
 causal frontier (scratch block 0, stale ids) are never read, and there is
 still one decode program and one chunk program whatever the lengths.
+A ring walk over small head-major blocks (:func:`whole_group_copies`)
+lands a whole group of consecutive pool blocks as ONE copy for K and one
+for V instead of ``G`` of each.
 
 :func:`group_plan` decides ``G`` and the body at trace time from the
 shapes. When all of a program's query rows fit one MXU pass (decode,
@@ -121,9 +124,57 @@ def group_plan(rows, KV, bs, D, M, kv_itemsize, depth=0):
     return G, all_heads
 
 
+# Bytes of one block up to which a ring walk lands a whole group as ONE copy
+# for K and one for V. Measured on the v5e (tools/kernel_bench.py --ops
+# ragged, 128 rows at contexts of 4-12 k): Trinity-Mini's 16 KB
+# blocks read 38.6 % of the memory roofline block by block and 52.0 % with
+# whole-group copies; Phi-4-mini-flash's 40 KB blocks 55.4 % and 54.1 %.
+# Copies of 16 KB are bound by their descriptors, copies of 40 KB are not.
+_GROUP_COPY_MAX_BLOCK_BYTES = 24 * 1024
+
+
+def whole_group_copies(KV, bs, D, itemsize, ring, head_major):
+    """Whether a walk may land a group of G consecutive pool blocks as ONE
+    copy for K and one for V — decided at trace time from the shapes: a
+    ring walk (a slot's ring is consecutive pool blocks by construction,
+    ``paged_attention.slot_ring_tables``) over head-major blocks (the slot
+    ``(G, KV, bs, D)`` is the pool's own layout of G blocks) small enough
+    that a copy costs about its descriptor. Everywhere else the kernel is
+    the per-block walk alone."""
+    return (ring > 0 and head_major
+            and KV * bs * D * itemsize <= _GROUP_COPY_MAX_BLOCK_BYTES)
+
+
+def group_fits(e0, live, G, ring):
+    """A group of a ring walk that may land as one copy: whole (``live ==
+    G`` blocks before the frontier) and not crossing the ring's end (its
+    first logical block ``e0`` sits at entry ``e0 % ring``). The kernel
+    also checks that the G entries are consecutive pool blocks. Works on
+    scalars in the kernel and on numpy arrays on the host alike."""
+    return (live == G) & (e0 % ring + G <= ring)
+
+
+def ring_walk_groups(pos, window, ring, bs, G):
+    """Over the decode rows at positions ``pos`` (numpy), the groups of
+    their ring walks and how many of those fit one copy
+    (:func:`group_fits`): the walk of a row at ``p`` covers blocks
+    ``max(p - window + 1, 0) // bs`` through ``p // bs``."""
+    import numpy as np
+
+    pos = np.asarray(pos, np.int64).reshape(-1, 1)
+    if not pos.size:
+        return 0, 0
+    blk0 = np.maximum(pos - (window - 1), 0) // bs
+    n = pos // bs + 1 - blk0                      # blocks of each walk
+    i = np.arange(-(-int(n.max()) // G))[None, :]
+    live = np.clip(n - i * G, 0, G)
+    fits = group_fits(blk0 + i * G, live, G, ring)
+    return int((-(-n // G)).sum()), int(fits.sum())
+
+
 def _attn_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest, bs, rep, KV,
                  M, G, R, quantized, early, all_heads, tiled, window=0,
-                 ring=0, head_major=False):
+                 ring=0, head_major=False, one_copy=False):
     if quantized:
         ks_ref, vs_ref = rest[:2]
         rest = rest[2:]
@@ -158,8 +209,37 @@ def _attn_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest, bs, rep, KV,
         A block past the frontier is not fetched; its V slab is zeroed so
         a zero probability never meets stale non-finite bits. (Loops, not
         an unrolled ``pl.when`` a block: the trace stays the size of one
-        block whatever G is.)"""
+        block whatever G is.) Under ``one_copy`` a group that fits
+        (:func:`group_fits`) and whose G entries are consecutive pool
+        blocks lands as ONE copy for K and one for V instead; the start
+        and the wait read the same table and take the same path."""
         live = jnp.clip(nblk - blk0 - i * G, 0, G)
+        loop = live
+        if one_copy:
+            e0 = blk0 + i * G
+            r0 = e0 % ring
+            first = tbl_ref[b, r0]
+            fits = group_fits(e0, live, G, ring)
+
+            def next_to(j, ok):
+                # every entry, not the two ends: a masked row's table is
+                # all scratch, and any table given must stay exact
+                return jnp.logical_and(ok, tbl_ref[b, r0 + j] == first + j)
+
+            run = jax.lax.fori_loop(1, jnp.where(fits, G, 1), next_to, fits)
+
+            @pl.when(run)
+            def _group():
+                for s, (hbm, buf) in enumerate(((k_hbm, kbuf),
+                                                (v_hbm, vbuf))):
+                    cp = pltpu.make_async_copy(hbm.at[pl.ds(first, G)],
+                                               buf.at[slot], sem.at[s, slot])
+                    if start:
+                        cp.start()
+                    else:
+                        cp.wait()
+
+            loop = jnp.where(run, 0, live)
 
         def one(j, carry):
             e = blk0 + i * G + j
@@ -173,7 +253,7 @@ def _attn_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest, bs, rep, KV,
                     cp.wait()
             return carry
 
-        jax.lax.fori_loop(0, live, one, 0)
+        jax.lax.fori_loop(0, loop, one, 0)
         if start:
             def zero(j, carry):
                 dst = block_of(vbuf, slot, j)
@@ -389,7 +469,10 @@ def _paged_attention_call(q, k_pool, v_pool, tables, pos, k_scales=None,
                           quantized=quantized, early=early,
                           all_heads=all_heads, tiled=NQ > 1,
                           window=int(window), ring=int(ring),
-                          head_major=bool(head_major)),
+                          head_major=bool(head_major),
+                          one_copy=whole_group_copies(
+                              KV, bs, D, k_pool.dtype.itemsize, int(ring),
+                              bool(head_major))),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, Wr, D), q.dtype),
         compiler_params=pltpu.CompilerParams(

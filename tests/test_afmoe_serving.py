@@ -530,3 +530,31 @@ def test_the_attention_counter_step_for_step(built, n, new):
     held = _count(srv, "serving_moe_pairs", held="1")
     absent = _count(srv, "serving_moe_pairs", held="0")
     assert held + absent == rows * 4 * 6 and 0 < held < rows * 4 * 6
+
+
+@pytest.mark.parametrize("n,new", [(37, 30), (5, 40), (60, 9)])
+def test_the_ring_group_counter_step_for_step(built, n, new):
+    """``serving_ring_groups`` counts the groups of one window layer's ring
+    walk in each decode row-tick by the copy the kernel makes: at the
+    preset's 1 KB head-major blocks (a ring of 4 blocks of 8, walked 4 a
+    group) a whole group that starts at the ring's first entry is one copy,
+    every other group block by block; nothing is counted with telemetry
+    off."""
+    from tests.test_selective_scan import _ring_walk_groups_by_hand
+
+    model, _ = built
+    srv = _server(model, telemetry=True)
+    srv.submit(_tokens(n, 22), max_new_tokens=new)
+    srv.run()
+    srv.request_metrics()
+    # the decode rows' kernel positions: the token written, n .. n+new-2
+    groups, fits = _ring_walk_groups_by_hand(
+        range(n, n + new - 1), TINY["sliding_window"], 4, BS, 4)
+    assert 0 < fits < groups
+    assert _count(srv, "serving_ring_groups", copy="group") == fits
+    assert _count(srv, "serving_ring_groups", copy="block") == groups - fits
+    quiet = _server(model)
+    quiet.submit(_tokens(n, 22), max_new_tokens=new)
+    quiet.run()
+    assert quiet.telemetry.registry.counter(
+        "serving_ring_groups").total() == 0

@@ -129,6 +129,129 @@ def test_window_walk_matches_the_masked_ring(window, bs, ring, head_major,
                                atol=2e-5)
 
 
+# rows of a window of 64 over rings of 9 blocks of 8, walked 4 blocks a
+# group: (position, table) — "slot" is the slot's own consecutive ring
+_RING_ROWS = {
+    "below-the-window": (37, "slot"),       # one whole group, one partial
+    "whole-groups": (207, "slot"),          # two whole groups, no wrap
+    "crosses-the-ring-end": (276, "slot"),  # wraps, then whole, then partial
+    "masked": (100, "zeros"),               # a masked row: all scratch
+    "permuted": (207, "reversed"),          # not slot-affine: block by block
+}
+
+
+@pytest.mark.parametrize("row", list(_RING_ROWS))
+def test_ring_walk_one_copy_is_the_per_block_walk(row, monkeypatch):
+    """The window walk over 16 KB head-major blocks (4 KV heads of 128,
+    float32) takes whole-group copies: against the jnp mask over the ring,
+    and BITWISE against the same walk made block by block, for a row whose
+    walk crosses the ring's end, a partial last group, a row before the
+    window, a masked row and a ring table that is not the slot's run."""
+    from paddle_tpu.autotune.kernel_geometry import PagedAttentionGeometry
+
+    rng = np.random.default_rng(7)
+    B, KV, rep, D, bs, window, ring = 3, 4, 2, 128, 8, 64, 9
+    assert pk.whole_group_copies(KV, bs, D, 4, ring, True)
+    geom = PagedAttentionGeometry(kv_block_depth=4)
+    p, kind = _RING_ROWS[row]
+    # the row under test is slot 1, between two rows at other positions
+    pos = jnp.asarray([276, p, 37], jnp.int32)
+    tables = np.asarray(1 + np.arange(B)[:, None] * ring
+                        + np.arange(ring)[None, :], np.int32)
+    if kind == "zeros":
+        tables[1] = 0
+    elif kind == "reversed":
+        tables[1] = tables[1][::-1]
+    tables = jnp.asarray(tables)
+    shape = (1 + B * ring, KV, bs, D)
+    k_pool = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    v_pool = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((B, 1, KV * rep, D)), jnp.float32)
+    monkeypatch.setenv("PT_FLASH_INTERPRET", "1")
+
+    def walk():
+        return np.asarray(pk.paged_attention(
+            q, k_pool, v_pool, tables, pos, geometry=geom, window=window,
+            ring=ring, head_major=True))
+
+    got = walk()
+    monkeypatch.setattr(pk, "_GROUP_COPY_MAX_BLOCK_BYTES", 0)
+    assert not pk.whole_group_copies(KV, bs, D, 4, ring, True)
+    np.testing.assert_array_equal(got, walk())
+    monkeypatch.delenv("PT_FLASH_INTERPRET")
+    want = pa.paged_window_attention(q, k_pool, v_pool, tables, pos, window,
+                                     head_major=True)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+def _ring_walk_groups_by_hand(pos, window, ring, bs, G):
+    """The groups of each walk, listed: a group fits one copy when it holds
+    G blocks whose ring entries run up without passing the ring's end."""
+    groups = fits = 0
+    for p in pos:
+        blocks = list(range(max(p - window + 1, 0) // bs, p // bs + 1))
+        for i in range(0, len(blocks), G):
+            entries = [j % ring for j in blocks[i:i + G]]
+            groups += 1
+            fits += len(entries) == G and entries == list(
+                range(entries[0], entries[0] + G))
+    return groups, fits
+
+
+@pytest.mark.parametrize("window,bs,ring,G", [(2048, 16, 129, 16),
+                                              (512, 16, 33, 16),
+                                              (64, 8, 9, 4), (24, 8, 4, 4)])
+def test_ring_walk_groups_are_the_walks_listed(window, bs, ring, G):
+    pos = list(range(0, 12 * window, 7))
+    assert pk.ring_walk_groups(np.asarray(pos), window, ring, bs, G) == \
+        _ring_walk_groups_by_hand(pos, window, ring, bs, G)
+    assert pk.ring_walk_groups(np.zeros((0,), np.int64), window, ring, bs,
+                               G) == (0, 0)
+
+
+# (KV, bs, D, itemsize, ring, head_major) of each paged attention call of
+# the benchmark's cells (benchmarks/configs): bf16 pools, 16-token blocks
+# (a chunk of a full layer is the same call as its walk, with more rows)
+_CELL_WALKS = {
+    "trinity-window": ((4, 16, 128, 2, 129, True), True),
+    "trinity-full-walk-and-chunk": ((4, 16, 128, 2, 0, True), False),
+    "phi-window": ((10, 16, 128, 2, 33, True), False),
+    "phi-full-cross-and-chunk": ((10, 16, 128, 2, 0, True), False),
+    "mistral7b-walk-and-chunk": ((8, 16, 128, 2, 0, False), False),
+    "granite-attention": ((8, 16, 128, 2, 0, False), False),
+}
+
+
+@pytest.mark.parametrize("call", list(_CELL_WALKS))
+def test_only_trinitys_window_walk_takes_whole_group_copies(call):
+    """The rule is a property of the shapes: at the cells' shapes only the
+    ring walk of 16 KB blocks (Trinity-Mini's window layers) takes it;
+    Phi's 40 KB ring blocks and every walk without a ring keep the
+    per-block walk (Mistral-Small-4 runs no paged kernel)."""
+    shapes, want = _CELL_WALKS[call]
+    assert pk.whole_group_copies(*shapes) is want
+
+
+@pytest.mark.parametrize("cell,window,ring,lo,hi", [
+    ("trinity", 2048, 129, 0.75, 0.85), ("phi", 512, 33, 0.0, 0.0)])
+def test_the_group_share_of_ring_walks_at_the_cells_shapes(cell, window,
+                                                           ring, lo, hi):
+    """``serving_ring_groups``' share of whole-group copies at the cells'
+    contexts (4-12 k tokens): ~0.8 in Trinity-Mini's window layers (8-9
+    groups a walk, one crossing the ring's end in ~15 walks of 16, a
+    partial last one in about every other), 0 in Phi's, where the rule
+    says no."""
+    KV = {"trinity": 4, "phi": 10}[cell]
+    G, _ = pk.group_plan(32 // 4 if cell == "trinity" else 4, KV, 16, 128,
+                         ring, 2)
+    assert G == 16
+    pos = np.random.default_rng(0).integers(4000, 12000, size=20000)
+    groups, fits = pk.ring_walk_groups(pos, window, ring, 16, G)
+    one_copy = pk.whole_group_copies(KV, 16, 128, 2, ring, True)
+    share = (fits if one_copy else 0) / groups
+    assert lo <= share <= hi
+
+
 @pytest.mark.parametrize("window,ring", [(24, 0), (0, 4)])
 def test_window_and_ring_come_together(window, ring, monkeypatch):
     """The kernel walks a window through a ring table and nothing else: one

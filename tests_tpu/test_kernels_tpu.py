@@ -639,12 +639,16 @@ def _trinity_case(B, M, window, seed, N=None):
 
 
 @pytest.mark.parametrize("kind", ["window", "full"])
-def test_trinity_minis_attention_walks_at_the_cells_shapes(kind):
+def test_trinity_minis_attention_walks_at_the_cells_shapes(kind,
+                                                           monkeypatch):
     """128 decode rows, group 8, head-major: the window walk over rings of
     129 blocks (window 2,048; rings that wrapped many times) and the full
     walk over 18,432-token tables, each against the jnp composition the
-    select rule would otherwise run."""
+    select rule would otherwise run. The window walk takes whole-group
+    copies of its 16 KB blocks, and reads bitwise what it reads block by
+    block."""
     from paddle_tpu.ops import paged_attention as pa
+    from paddle_tpu.ops import paged_attention_pallas as pk
     from paddle_tpu.ops import select
 
     if kind == "window":
@@ -669,6 +673,12 @@ def test_trinity_minis_attention_walks_at_the_cells_shapes(kind):
     assert np.isfinite(got).all()
     np.testing.assert_allclose(got, np.asarray(want, np.float32), rtol=3e-2,
                                atol=3e-2)
+    assert pk.whole_group_copies(4, 16, 128, 2, 129 if kind == "window"
+                                 else 0, True) is (kind == "window")
+    if kind == "window":
+        monkeypatch.setattr(pk, "_GROUP_COPY_MAX_BLOCK_BYTES", 0)
+        by_block = np.asarray(jax.jit(lambda *a: op(*a))(*args), np.float32)
+        np.testing.assert_array_equal(got, by_block)
 
 
 @pytest.mark.parametrize("B,S,IN,OUT", [(8, 1, 4096, 4096),

@@ -36,6 +36,13 @@ peaks from ``benchmarks/peaks.json`` by ``device_kind`` (no share off the
 chip). ``--ragged-depths 0,8,32`` repeats each row at explicit blocks per
 group (``PagedAttentionGeometry.kv_block_depth``; 0 = derived), which is
 how the group width in ``ops/paged_attention_pallas.py`` was chosen.
+It also adds the WINDOW walk over per-slot rings of head-major blocks at
+the cells' shapes — Trinity-Mini's 4 KV heads (16 KB blocks, rings of 129
+over a window of 2,048) and Phi-4-mini-flash's 10 packed pairs (40 KB,
+rings of 33 over 512), ``--ring-rows`` rows (128) at contexts of 4-12 k —
+each copied as the rule says, block by block and one copy a group: how the
+block size up to which a ring walk takes whole-group copies
+(``paged_attention_pallas._GROUP_COPY_MAX_BLOCK_BYTES``) was set.
 
 ``--ops rows`` adds the WEIGHT-STREAMING rows: what the Mistral cells'
 widest matmul, bf16 ``[M, 4096] x [4096, 14336]``, and the whole SwiGLU MLP
@@ -58,6 +65,7 @@ style); a human table otherwise.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import math
 import os
@@ -124,6 +132,8 @@ def main():
                          "rest idle: zero table, position 0)")
     ap.add_argument("--ragged-table", type=int, default=256)
     ap.add_argument("--ragged-tokens", type=int, default=44000)
+    ap.add_argument("--ring-rows", type=int, default=128,
+                    help="decode rows of the ring-walk rows (--ops ragged)")
     ap.add_argument("--ragged-depths", default="0",
                     help="comma list of blocks per group (0 = derived)")
     ap.add_argument("--rows-m", default="64,128,192",
@@ -480,6 +490,80 @@ def main():
                     "bf16_flops"))
         return out
 
+    def ring_rows():
+        """The window walk over per-slot rings of head-major blocks at the
+        cells' shapes (module docstring), its groups copied as the rule
+        says, block by block, and as one copy a group: us a call and the
+        share of the memory roofline, bytes = K and V of every position
+        attended, q read and o written."""
+        from paddle_tpu.ops import paged_attention_pallas as pk
+
+        B, bs, D, calls = args.ring_rows, 16, 128, 16
+        peak = chip_peaks()
+        rng = np.random.RandomState(args.seed)
+        pos = jnp.asarray(rng.randint(4000, 12000, B).astype(np.int32))
+        out = []
+        for shape, KV, H, window in (("trinity", 4, 32, 2048),
+                                     ("phi", 10, 40, 512)):
+            ring = -(-window // bs) + 1
+            N = 1 + B * ring
+            key = jax.random.PRNGKey(args.seed)
+            k, v = (jax.random.normal(kk, (N, KV, bs, D), jnp.bfloat16)
+                    for kk in jax.random.split(key))
+            q = jax.random.normal(key, (B, 1, H, D), jnp.bfloat16)
+            t = jnp.asarray(1 + np.arange(B)[:, None] * ring
+                            + np.arange(ring)[None, :], jnp.int32)
+
+            def run(q, t, p, k, v):
+                def layer(c, _):
+                    o = pk.paged_attention(c, k, v, t, p, window=window,
+                                           ring=ring, head_major=True)
+                    return c + (o * 0).astype(c.dtype), o
+                return jax.lax.scan(layer, q, None, length=calls)[1][-1]
+
+            mode = ops.kernel_mode()
+            try:
+                ops.set_kernel_mode("reference")
+                ref = jax.jit(functools.partial(
+                    pa.paged_window_attention, window=window,
+                    head_major=True))(q, k, v, t, pos)
+            finally:
+                ops.set_kernel_mode(mode)
+            work = (2 * KV * D * 2 * int(jnp.minimum(pos + 1, window).sum())
+                    + 2 * B * H * D * 2)
+            G, _ = pk.group_plan(H // KV, KV, bs, D, ring, 2)
+            groups, fits = pk.ring_walk_groups(np.asarray(pos), window, ring,
+                                               bs, G)
+            rule = pk.whole_group_copies(KV, bs, D, 2, ring, True)
+            limit = pk._GROUP_COPY_MAX_BLOCK_BYTES
+            for copies, bound in (("rule", limit), ("block", 0),
+                                  ("group", 1 << 30)):
+                pk._GROUP_COPY_MAX_BLOCK_BYTES = bound
+                try:
+                    ops.set_kernel_mode("pallas")
+                    secs, got = timed(run, (q, t, pos, k, v))
+                finally:
+                    ops.set_kernel_mode(mode)
+                    pk._GROUP_COPY_MAX_BLOCK_BYTES = limit
+                diff = float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                             - ref.astype(jnp.float32))))
+                one = rule if copies == "rule" else copies == "group"
+                out.append({
+                    "metric": "ring_walk_kernel_us", "op": shape,
+                    "copies": copies, "one_copy": one, "B": B,
+                    "KV": KV, "bs": bs, "ring": ring, "window": window,
+                    "block_kb": KV * bs * D * 2 / 1024,
+                    "blocks_per_group": G,
+                    "group_share": round(fits / groups, 4) if one else 0.0,
+                    "backend": backend,
+                    "us_per_call": round(secs / calls * 1e6, 1),
+                    "roofline_pct": (
+                        round(100 * work / peak["hbm_bytes_per_s"] * calls
+                              / secs, 2) if peak and on_tpu else None),
+                    "max_abs_diff": diff, "parity": diff < 2e-2,
+                })
+        return out
+
     def streaming_rows():
         """The 4096 x 14336 matmul and the SwiGLU MLP at M rows (module
         docstring): the weights are read once whatever M is, so the time
@@ -535,6 +619,7 @@ def main():
     if "ragged" in op_list:
         op_list.remove("ragged")
         rows += ragged_rows()
+        rows += ring_rows()
     if "rows" in op_list:
         op_list.remove("rows")
         rows += streaming_rows()
@@ -666,6 +751,14 @@ def main():
                       f"G={r['blocks_per_group']:<3} {r['body']:9} "
                       f"{r['us_per_call']:>9} us/call  roofline "
                       f"{r['roofline_pct']} %  max|diff| "
+                      f"{r['max_abs_diff']:.2e}")
+                continue
+            if r["metric"] == "ring_walk_kernel_us":
+                print(f"ring {r['op']:8} {r['block_kb']:>4} KB blocks "
+                      f"copies={r['copies']:5} one_copy={r['one_copy']!s:5} "
+                      f"G={r['blocks_per_group']:<3} group share "
+                      f"{r['group_share']:<6} {r['us_per_call']:>9} us/call  "
+                      f"roofline {r['roofline_pct']} %  max|diff| "
                       f"{r['max_abs_diff']:.2e}")
                 continue
             if r["metric"] == "weight_stream_us":
